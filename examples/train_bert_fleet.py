@@ -28,7 +28,8 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument('--steps', type=int, default=10)
     args = ap.parse_args()
-    on_tpu = jax.default_backend() != 'cpu'
+    from paddle_tpu.core.places import on_tpu as _on_tpu
+    on_tpu = _on_tpu()
 
     fleet.init(mesh_shape={'dp': len(jax.devices())})
     cfg = BertConfig.base() if on_tpu else BertConfig.tiny()

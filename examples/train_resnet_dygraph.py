@@ -27,7 +27,8 @@ def main():
     ap.add_argument('--steps', type=int, default=20)
     ap.add_argument('--batch', type=int, default=None)
     args = ap.parse_args()
-    on_tpu = jax.default_backend() != 'cpu'
+    from paddle_tpu.core.places import on_tpu as _on_tpu
+    on_tpu = _on_tpu()
     batch = args.batch or (128 if on_tpu else 4)
     img = 224 if on_tpu else 32
     fmt = 'NHWC' if on_tpu else 'NCHW'

@@ -56,7 +56,8 @@ def main():
 
     from paddle_tpu.fleet_runtime import bootstrap
     bootstrap()                       # no-op single-host; fleet env wires up
-    on_tpu = jax.default_backend() != 'cpu'
+    from paddle_tpu.core.places import on_tpu as _on_tpu
+    on_tpu = _on_tpu()
     hosts = jax.process_count()
     global_batch = args.batch or (256 if on_tpu else 16)
     img = 64 if on_tpu else 16

@@ -1,45 +1,14 @@
-"""jax API compatibility shims.
+"""The one import point for jax's explicit-SPMD surface.
 
-The framework tracks jax's public API, which moves: ``jax.shard_map``
-graduated from ``jax.experimental.shard_map.shard_map``, and ``lax.pcast``
-(the varying-manual-axes cast that the graduated shard_map's vma typing
-requires) does not exist before the graduation. Every internal caller goes
-through this module so the version probe happens exactly once, at import
-time, instead of at every trace.
+Every internal caller takes ``shard_map`` and ``pcast`` (the
+varying-manual-axes cast that shard_map's vma typing requires at every
+branch-merge point) from here, so the next time jax moves either name the
+change lands in one file.
 """
 from __future__ import annotations
 
 import jax
 from jax import lax as _lax
 
-_HAS_NATIVE_SHARD_MAP = hasattr(jax, 'shard_map')
-
-if _HAS_NATIVE_SHARD_MAP:
-    _shard_map = jax.shard_map
-else:                                   # pre-graduation jax (<= 0.4.x)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def shard_map(f, mesh=None, in_specs=None, out_specs=None, **kwargs):
-    """``jax.shard_map`` with a fallback to the experimental module.
-
-    Signature intersection of both generations: (f, mesh, in_specs,
-    out_specs). On the experimental fallback, replication checking is
-    disabled — callers are written against the graduated API's vma typing
-    (explicit ``pcast`` at every branch-merge point), which the old
-    rep-checker does not understand.
-    """
-    if not _HAS_NATIVE_SHARD_MAP:
-        kwargs.setdefault('check_rep', False)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
-
-
-if hasattr(_lax, 'pcast'):
-    pcast = _lax.pcast
-else:
-    def pcast(x, axis_name, to=None):
-        """No-op stand-in: pre-vma jax has no varying/replicated type split,
-        so the cast that keeps cond branches type-consistent under the
-        graduated shard_map is vacuously satisfied."""
-        return x
+shard_map = jax.shard_map
+pcast = _lax.pcast

@@ -75,9 +75,18 @@ class CUDAPinnedPlace(Place):
         super().__init__(0)
 
 
+def on_tpu():
+    """THE predicate for "this process computes on a TPU chip": kernel
+    dispatch (ops/nn_ops.py, ops/pallas_conv.py), the partitioner's CPU jit
+    shortcut, the default place, and the examples' and bench's full-size
+    shapes all ask here, so no two call sites can disagree about what the
+    chip is called."""
+    return jax.default_backend() == 'tpu'
+
+
 def is_compiled_with_cuda():
-    """Compat: reports whether an accelerator backend is present."""
-    return jax.default_backend() != 'cpu'
+    """Compat: reports whether the accelerator backend is present."""
+    return on_tpu()
 
 
 def cuda_places(device_ids=None):

@@ -544,7 +544,13 @@ class TrainStep:
         base_reg = opt.regularization
         regs = {n: (getattr(p, 'regularizer', None) or base_reg)
                 for n, p in params.items()}
-        trainable = {n for n, p in params.items() if p.trainable}
+        # a dict, not a set: its order (the layer's construction order) is
+        # the order the update ops are traced in, and a set of strings
+        # iterates differently in every process — each cold process then
+        # lowered a different HLO and the persistent compile cache never hit
+        # for the step (seen on the chip: ResNet-50 recompiled for 50 s with
+        # its own executable already on disk)
+        trainable = {n: None for n, p in params.items() if p.trainable}
 
         amp_dtype = self._amp_dtype
 
@@ -650,6 +656,35 @@ class TrainStep:
         return ({n: p.value for n, p in self._params.items()},
                 {n: b.value for n, b in self._buffers.items()})
 
+    def _replicate_state(self):
+        """GSPMD path, before the first dispatch: commit every parameter,
+        buffer, optimizer slot and gradient-merge accumulator that still
+        sits where its initialiser left it (one device) to the batch's mesh,
+        replicated. Without this the
+        first step is compiled for single-device state and hands back state
+        replicated over the mesh, so the second step compiles the whole
+        program a second time for the changed input sharding (seen on the
+        four-chip host, chip_smoke.py train_dp4). State the caller already
+        laid out over the mesh (tensor-parallel shardings) is left alone."""
+        mesh = getattr(self._data_sharding, 'mesh', None)
+        if mesh is None:
+            return
+        from jax.sharding import NamedSharding, PartitionSpec
+        replicated = NamedSharding(mesh, PartitionSpec())
+        devices = set(mesh.devices.flat)
+
+        def place(v):
+            if set(v.devices()) == devices:
+                return v
+            return jax.device_put(v, replicated)
+
+        for holder in (*self._params.values(), *self._buffers.values()):
+            holder.value = place(holder.value)
+        self._slots = jax.tree_util.tree_map(place, self._slots)
+        if self._acc is not None:
+            self._acc = jax.tree_util.tree_map(place, self._acc)
+            self._count = place(self._count)
+
     # -- checkpoint/resume (paddle_tpu/resilience/) --------------------
     def snapshot(self):
         """Non-blocking point-in-time capture for async checkpointing:
@@ -743,7 +778,8 @@ class TrainStep:
         return loss
 
     def _call_impl(self, batch):
-        if self._jitted is None:
+        first_call = self._jitted is None
+        if first_call:
             with _obs.span('train_step/build'):
                 self._jitted = self._build()
         if self._slots is None:
@@ -755,12 +791,22 @@ class TrainStep:
                     for s, (shp, fill) in
                     self._opt._slot_init(list(p.shape), p.dtype).items()}
                 for n, p in self._params.items() if p.trainable}
+        if self._accum_steps > 1 and self._acc is None:
+            # accumulators carry the GRADIENT dtype (== param dtype; fp32
+            # masters under amp): a hardcoded fp32 accumulator would promote
+            # `acc + grad` for bf16 params and the two lax.cond branches
+            # would disagree on dtypes (ADVICE r5)
+            self._acc = {n: jnp.zeros_like(p.value)
+                         for n, p in self._params.items() if p.trainable}
+            self._count = jnp.int32(0)
         batch_vals = []
         for b in batch:
             arr = b.value if isinstance(b, Tensor) else jnp.asarray(b)
             if self._data_sharding is not None:
                 arr = jax.device_put(arr, self._data_sharding)
             batch_vals.append(arr)
+        if first_call and self._data_sharding is not None:
+            self._replicate_state()
         pvals, bvals = self.state()
         if self._window is not None:
             # K-in-flight window: block on the oldest pending loss handle
@@ -769,15 +815,6 @@ class TrainStep:
             self._window.admit(self._async_k)
         with _obs.span('train_step/execute'):
             if self._accum_steps > 1:
-                if self._acc is None:
-                    # accumulators carry the GRADIENT dtype (== param dtype;
-                    # fp32 masters under amp): a hardcoded fp32 accumulator
-                    # would promote `acc + grad` for bf16 params and the two
-                    # lax.cond branches would disagree on dtypes (ADVICE r5)
-                    self._acc = {n: jnp.zeros_like(p.value)
-                                 for n, p in self._params.items()
-                                 if p.trainable}
-                    self._count = jnp.int32(0)
                 new_p, new_b, self._slots, self._acc, self._count, loss = \
                     self._jitted(pvals, bvals, self._slots, self._acc,
                                  self._count,

@@ -11,11 +11,13 @@ tests/bench (:class:`CallableReplicaLauncher`), or a cluster scheduler
 """
 from __future__ import annotations
 
+import collections
 import json
 import logging
 import os
 import subprocess
 import sys
+import threading
 import time
 
 from ..log_helper import get_logger
@@ -72,8 +74,17 @@ class ProcessReplicaLauncher(ReplicaLauncher):
         cmd += self.extra_args
         env = dict(os.environ if self.env is None else self.env)
         proc = subprocess.Popen(cmd, stdout=subprocess.PIPE,
-                                stderr=subprocess.DEVNULL, env=env,
+                                stderr=subprocess.PIPE, env=env,
                                 text=True)
+        # keep the last lines of the child's stderr: when the launch fails
+        # (on a one-chip machine a second replica cannot get the chip) the
+        # reason is in them. The thread drains the pipe for the replica's
+        # whole life, so a chatty child never blocks on a full pipe.
+        stderr_tail = collections.deque(maxlen=40)
+        drain = threading.Thread(target=stderr_tail.extend,
+                                 args=(proc.stderr,), daemon=True,
+                                 name='paddle-tpu-replica-stderr')
+        drain.start()
         deadline = time.monotonic() + self.ready_timeout_s
         line = ''
         while time.monotonic() < deadline:
@@ -85,10 +96,13 @@ class ProcessReplicaLauncher(ReplicaLauncher):
             assert ready.get('ready') and 'port' in ready
         except (ValueError, AssertionError):
             proc.kill()
+            proc.wait(timeout=10)
+            drain.join(timeout=10)
             raise RuntimeError(
                 f'replica launch failed: no ready line within '
                 f'{self.ready_timeout_s:.0f}s (got {line!r}, '
-                f'rc={proc.poll()})')
+                f'rc={proc.poll()}); child stderr tail:\n'
+                + ''.join(stderr_tail)[-2000:])
         url = f"http://127.0.0.1:{ready['port']}"
         self._procs[url] = proc
         _logger.info('launched replica %s (pid %d)', url, proc.pid)

@@ -6,14 +6,18 @@
 - pack_padded / unpack_padded / bucket_by_length: LoD↔padded conversions
   (src/lod_pack.cc)
 
-The shared library builds on first import (`make` in this directory); if no
-toolchain is available every entry point falls back to a pure-Python
-implementation with identical semantics, so the framework never hard-fails.
-`is_native()` reports which path is active.
+The shared library is built from what git tracks: the first load of every
+process runs `make` in this directory, which rebuilds when a source is newer
+than the binary and is a no-op otherwise, so a stale binary from older
+sources is never loaded. Without a toolchain every entry point takes a
+pure-Python implementation with identical semantics (one logged warning
+says so), so the framework never hard-fails. `is_native()` reports which
+path is active.
 """
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
@@ -31,16 +35,18 @@ def _load():
     if _tried:
         return _lib
     _tried = True
-    if not os.path.exists(_LIB_PATH):
-        try:
-            subprocess.run(['make', '-C', _DIR, '-s'], check=True,
-                           capture_output=True, timeout=120)
-        except Exception:
-            return None
     try:
-        lib = ctypes.CDLL(_LIB_PATH)
-    except OSError:
+        subprocess.run(['make', '-C', _DIR, '-s'], check=True,
+                       capture_output=True, text=True, timeout=300)
+    except (OSError, subprocess.SubprocessError) as e:
+        # no make/compiler, or the build failed: an existing binary cannot
+        # be shown to match the sources, so it is not loaded either
+        logging.getLogger(__name__).warning(
+            'paddle_tpu.native: `make -C %s` failed (%s: %s%s); using the '
+            'pure-Python implementations', _DIR, type(e).__name__, e,
+            ('\n' + e.stderr[-1000:]) if getattr(e, 'stderr', None) else '')
         return None
+    lib = ctypes.CDLL(_LIB_PATH)
     lib.ptpu_pipeline_create.restype = ctypes.c_void_p
     lib.ptpu_pipeline_create.argtypes = [
         ctypes.c_int64, ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,

@@ -18,6 +18,7 @@ from jax import lax
 
 from .registry import register_op
 from ..core.dtypes import to_jax_dtype
+from ..core.places import on_tpu
 
 
 def _pair(v, n=2):
@@ -626,95 +627,85 @@ def norm(x, *, axis=-1, epsilon=1e-10):
 
 
 # ---------------------------------------------------------------------------
-# Fused / paged attention and their pallas-unavailable fallback accounting.
+# Fused / paged attention: explicit kernel dispatch.
 #
-# Both attention ops prefer a pallas TPU kernel and fall back to an XLA
-# formulation elsewhere (or on kernel shape rejection). The fallback is
-# counted, not shouted: ONE process-wide warning through log_helper (the op
-# bodies run at trace time under the eager kernel cache / jit, so a warning
-# per call would really be a warning per compiled shape — still log spam in
-# a server that compiles a prefill ladder), and a counter of fallback traces
-# exposed via pallas_fallback_stats() plus an at-export `attention_pallas_
-# fallbacks` gauge in the telemetry registry.
+# Each attention op has a stock pallas TPU kernel and an XLA formulation.
+# Which one runs is a predicate on backend, rank, dtype and the kernel's own
+# shape rules, written beside the call. Where the predicate holds the kernel
+# runs and a compiler refusal is an error; where it does not, the XLA
+# formulation runs because the code says so. Nothing is caught: the op
+# bodies run at trace time inside the eager kernel-cache jit, so a Mosaic
+# refusal would surface at compile anyway, outside any handler here.
+#
+# The rules below were established on a TPU v5e with jax 0.9.0 (PERF.md
+# "Bring-up"): the flash kernel lowers at head_dim 16/64/128 whenever both
+# sequence extents are multiples of its 128-row blocks; the paged kernel
+# lowers at head_dim 128 and is refused by the pallas TPU lowering at
+# head_dim 64 (its softmax-state outputs are blocked at head_dim lanes).
 # ---------------------------------------------------------------------------
 
-_PALLAS_FALLBACKS = {'warned': False, 'count': 0, 'last': ''}
+# jax.experimental.pallas.ops.tpu.flash_attention BlockSizes.get_default:
+# every forward and backward block is 128 rows, and _verify_block raises
+# for a sequence that is shorter than its block or not a multiple of it
+_FLASH_BLOCK = 128
+# the pallas TPU lowering wants a block's last dimension to be a multiple of
+# the 128-lane tile (or the whole array dimension)
+_TPU_LANES = 128
 
 
-def _pallas_fallback(kernel_name, exc, shape):
-    _PALLAS_FALLBACKS['count'] += 1
-    _PALLAS_FALLBACKS['last'] = (
-        f'{kernel_name} q{tuple(shape)} {type(exc).__name__}: '
-        f'{str(exc)[:200]}')
-    if not _PALLAS_FALLBACKS['warned']:
-        _PALLAS_FALLBACKS['warned'] = True
-        import logging
-        from ..log_helper import get_logger
-        get_logger(__name__, logging.WARNING).warning(
-            "%s: pallas kernel unavailable for q%s (%s: %s); falling back "
-            "to the XLA formulation. Warning once per process; further "
-            "fallbacks are counted (ops.nn_ops.pallas_fallback_stats / the "
-            "attention_pallas_fallbacks gauge).",
-            kernel_name, tuple(shape), type(exc).__name__, str(exc)[:200])
+def flash_kernel_applies(q, k):
+    """True when `fused_attention` / `paged_prefill_attention` run the
+    pallas flash kernel for (B, H, S, D) ``q``/``k`` (arrays or
+    ShapeDtypeStructs): a TPU backend, rank 4, f32 or bf16, and both
+    sequence extents whole multiples of the kernel's 128-row blocks."""
+    return (on_tpu() and len(q.shape) == 4 and len(k.shape) == 4
+            and q.dtype in (jnp.float32, jnp.bfloat16)
+            and q.shape[2] % _FLASH_BLOCK == 0
+            and k.shape[2] % _FLASH_BLOCK == 0)
 
 
-def pallas_fallback_stats():
-    """{'count': fallback traces (≈ one per compiled shape), 'warned': bool,
-    'last': last fallback reason} for fused_attention + paged_attention."""
-    return dict(_PALLAS_FALLBACKS)
+def _pages_per_compute_block(requested, block_tables):
+    return max(min(int(requested), block_tables.shape[1]), 1)
 
 
-def reset_pallas_fallback_stats():
-    _PALLAS_FALLBACKS.update(warned=False, count=0, last='')
-
-
-def _collect_pallas_fallback_gauge():
-    from .. import observability as _obs
-    g = _obs.registry.gauge(
-        'attention_pallas_fallbacks',
-        'attention ops (fused_attention / paged_attention) that fell back '
-        'from the pallas TPU kernel to the XLA formulation, counted per '
-        'compiled shape')
-    g.set(float(_PALLAS_FALLBACKS['count']))
-
-
-def _register_fallback_collector():
-    try:
-        from .. import observability as _obs
-        _obs.registry.register_collector(_collect_pallas_fallback_gauge)
-    except Exception:   # circular-import-safe: the gauge is best-effort
-        pass
-
-
-_register_fallback_collector()
+def paged_kernel_applies(q, k_pages, block_tables, pages_per_compute_block):
+    """True when `paged_attention` runs the pallas paged-attention kernel:
+    a TPU backend, single-query (S, H, D) ``q``, an f32 pool (bf16/int8
+    pools need the dequant-after-gather of the XLA formulation; the
+    multi-query (S, H, K, D) verify read has no stock kernel), head_dim a
+    multiple of the 128-lane tile, and the kernel's own
+    ``pages_per_sequence % pages_per_compute_block == 0`` rule."""
+    return (on_tpu() and len(q.shape) == 3
+            and k_pages.dtype == jnp.float32
+            and q.shape[2] % _TPU_LANES == 0
+            and q.shape[1] % k_pages.shape[0] == 0
+            and block_tables.shape[1]
+            % _pages_per_compute_block(pages_per_compute_block,
+                                       block_tables) == 0)
 
 
 @register_op('fused_attention')
 def fused_attention(q, k, v, bias=None, *, sm_scale=1.0, causal=False):
-    """Fused multi-head attention, (B, H, S, D) layout. On TPU this lowers
-    to the pallas flash-attention kernel
-    (jax.experimental.pallas.ops.tpu.flash_attention — online softmax, no
-    S×S materialization, custom vjp); elsewhere (and for shapes the kernel
-    rejects) it falls back to the XLA softmax(QKᵀ)V form that the compiler
-    fuses. Measured on v5e (PERF.md §3): XLA wins on raw step time up to
+    """Fused multi-head attention, (B, H, S, D) layout. Where
+    :func:`flash_kernel_applies` holds this lowers to the pallas
+    flash-attention kernel (jax.experimental.pallas.ops.tpu.flash_attention
+    — online softmax, no S×S materialization, custom vjp); everywhere else
+    it is the XLA softmax(QKᵀ)V form that the compiler fuses. Measured on
+    v5e (PERF.md §3): XLA wins on raw step time up to
     S=2048 (56-73 TF/s vs 13-26), so this op is NOT the default attention
     path — its value is the O(S) memory footprint for long-context configs
     where the S×S score tensor won't fit."""
-    import jax as _jax
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
-    if _jax.default_backend() == 'tpu':
-        try:
-            from jax.experimental.pallas.ops.tpu.flash_attention import (
-                flash_attention)
-            # the kernel computes (QKᵀ + ab)·sm_scale; our contract is
-            # QKᵀ·sm_scale + bias, so pre-divide the bias
-            ab = None if bias is None else jnp.broadcast_to(
-                jnp.asarray(bias) / float(sm_scale),
-                q.shape[:3] + (k.shape[2],))
-            return flash_attention(q, k, v, ab=ab, causal=causal,
-                                   sm_scale=float(sm_scale))
-        except Exception as e:   # kernel shape rejection → XLA fallback
-            _pallas_fallback('fused_attention', e, q.shape)
+    if flash_kernel_applies(q, k):
+        from jax.experimental.pallas.ops.tpu.flash_attention import (
+            flash_attention)
+        # the kernel computes (QKᵀ + ab)·sm_scale; our contract is
+        # QKᵀ·sm_scale + bias, so pre-divide the bias
+        ab = None if bias is None else jnp.broadcast_to(
+            jnp.asarray(bias) / float(sm_scale),
+            q.shape[:3] + (k.shape[2],))
+        return flash_attention(q, k, v, ab=ab, causal=causal,
+                               sm_scale=float(sm_scale))
     scores = jnp.einsum('bhqd,bhkd->bhqk', q, k) * sm_scale
     if bias is not None:
         scores = scores + jnp.asarray(bias)
@@ -755,42 +746,32 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
       attends ``context_lens + j`` keys (a causal staircase over the K
       fed positions — row j sees the prior context plus fed tokens 0..j).
 
-    On TPU this dispatches the pallas paged-attention kernel
-    (jax.experimental.pallas.ops.tpu.paged_attention — ragged block walk,
-    no dense gather); elsewhere (and on kernel rejection, counted via
-    pallas_fallback_stats) the XLA fallback gathers the slot's blocks into
-    a dense (S, H, T, D) view and runs the batched-matmul → mask →
-    softmax → matmul sequence the unfused MultiHeadAttention path uses.
+    Where :func:`paged_kernel_applies` holds this dispatches the pallas
+    paged-attention kernel (jax.experimental.pallas.ops.tpu.paged_attention
+    — ragged block walk, no dense gather); everywhere else the XLA
+    formulation gathers the slot's blocks into a dense (S, H, T, D) view
+    and runs the batched-matmul → mask → softmax → matmul sequence the
+    unfused MultiHeadAttention path uses.
     Masked key positions get *exactly-zero* probability mass (the mask
     value underflows exp), and `jnp.matmul` rows are extent-independent on
     XLA CPU (measured; einsum dot_general is NOT), so a decode step is
     bitwise-identical to the matching row of a whole-sequence forward at
     the same padded key extent, and stale values in reused blocks can
     never bleed (0.0 × finite == 0.0)."""
-    import jax as _jax
     q = jnp.asarray(q)
     k_pages = jnp.asarray(k_pages)
     v_pages = jnp.asarray(v_pages)
     block_tables = jnp.asarray(block_tables, jnp.int32)
     context_lens = jnp.asarray(context_lens, jnp.int32)
-    if (_jax.default_backend() == 'tpu' and q.ndim == 3
-            and k_pages.dtype == jnp.float32):
-        # the stock pallas kernel is single-query over f32 pools; the
-        # multi-query (S,H,K,D) verify read AND the quantized pools
-        # (bf16/int8 payload needs the dequant-after-gather below) use the
-        # XLA formulation on every backend until a ragged quantized kernel
-        # lands (Ragged Paged Attention is the blueprint) — deliberate
-        # dispatch, not counted as a pallas fallback
-        try:
-            from jax.experimental.pallas.ops.tpu.paged_attention import (
-                paged_attention as _tpu_paged_attention)
-            ppcb = min(int(pages_per_compute_block), block_tables.shape[1])
-            return _tpu_paged_attention(
-                q * jnp.asarray(sm_scale, q.dtype), k_pages, v_pages,
-                context_lens, block_tables,
-                pages_per_compute_block=max(ppcb, 1))
-        except Exception as e:   # kernel shape rejection → XLA fallback
-            _pallas_fallback('paged_attention', e, q.shape)
+    if paged_kernel_applies(q, k_pages, block_tables,
+                            pages_per_compute_block):
+        from jax.experimental.pallas.ops.tpu.paged_attention import (
+            paged_attention as _tpu_paged_attention)
+        return _tpu_paged_attention(
+            q * jnp.asarray(sm_scale, q.dtype), k_pages, v_pages,
+            context_lens, block_tables,
+            pages_per_compute_block=_pages_per_compute_block(
+                pages_per_compute_block, block_tables))
     if q.ndim == 4:
         # multi-query decode (speculative verify): K fed tokens per slot.
         # Same matmul → mask → softmax → matmul sequence as the
@@ -878,17 +859,13 @@ def paged_prefill_attention(q, k, v, k_pages, v_pages, block_tables,
     UN-quantized projections — bitwise-different from the decode steps that
     later read the quantized cache, breaking the prefill/decode parity the
     engine is built on)."""
-    import jax as _jax
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
-    if (_jax.default_backend() == 'tpu'
+    if (flash_kernel_applies(q, k)
             and jnp.asarray(k_pages).dtype == jnp.float32):
-        try:
-            from jax.experimental.pallas.ops.tpu.flash_attention import (
-                flash_attention)
-            return flash_attention(q, k, v, causal=True,
-                                   sm_scale=float(sm_scale))
-        except Exception as e:
-            _pallas_fallback('paged_prefill_attention', e, q.shape)
+        from jax.experimental.pallas.ops.tpu.flash_attention import (
+            flash_attention)
+        return flash_attention(q, k, v, causal=True,
+                               sm_scale=float(sm_scale))
     b, h, lq, d = q.shape
     kd = _gather_pages(jnp.asarray(k_pages),
                        jnp.asarray(block_tables, jnp.int32), b, h, d,

@@ -275,8 +275,8 @@ class Partitioner:
         through)."""
         from ..core.compile_cache import setup_persistent_cache
         setup_persistent_cache()
-        cpu = jax.devices()[0].platform == 'cpu'
-        if self._mesh is None or (cpu and self._use_cpu_jit):
+        from ..core.places import on_tpu
+        if self._mesh is None or (self._use_cpu_jit and not on_tpu()):
             return jax.jit(fn, static_argnums=static_argnums,
                            donate_argnums=donate_argnums)
         to_shard = lambda s: (jax.tree_util.tree_map(

@@ -136,7 +136,7 @@ class VocabShardedTable:
                 body = compat.shard_map(
                     lambda wl, i: sharded_lookup(wl, i, axis, vocab),
                     mesh=mesh, in_specs=(P(axis, None), P()),
-                    out_specs=P(), check_rep=False)
+                    out_specs=P(), check_vma=False)
                 return body(w, flat_ids)
             from ..core.compile_cache import setup_persistent_cache
             setup_persistent_cache()
@@ -174,7 +174,7 @@ class VocabShardedTable:
                         P())
             fn = jax.jit(compat.shard_map(
                 body, mesh=mesh, in_specs=in_specs,
-                out_specs=P(axis, None), check_rep=False))
+                out_specs=P(axis, None), check_vma=False))
             self._push_fns[key] = fn
         n_dp = _axis_size_of(self.mesh, dp_axis) if dp_axis else 1
         qc.record_sparse_collective(

@@ -418,6 +418,14 @@ class DecodeEngine:
         arrives (same contract as InferenceEngine.warmup). Returns
         {phase: seconds}. Uses temporary blocks; the pool ends unchanged."""
         timings = {}
+        # a sampled request draws each token with a device op
+        # (sampling.TokenSampler): one throwaway draw compiles it here, not
+        # under the first sampled request
+        from .sampling import SamplingParams, TokenSampler
+        t0 = time.perf_counter()
+        TokenSampler(SamplingParams(temperature=1.0), 'warmup').sample(
+            np.zeros(2, np.float32), 0)
+        timings['sampler'] = time.perf_counter() - t0
         for bucket in self.prompt_buckets:
             # reserve spec_k headroom so the warmup spec_step below can
             # write its window without outgrowing the throwaway table

@@ -323,22 +323,31 @@ class KVCachePool:
 
     def write_prefill(self, layer, table, k, v):
         """Write the prompt's K/V rows. ``k``/``v``: (H, L, D) — the bucket-
-        padded projections; rows are written for ``ceil(context/bs)`` whole
-        blocks (tail rows inside the last block are masked garbage until
-        decode overwrites them)."""
+        padded projections; rows land in the table's first
+        ``ceil(context/bs)`` blocks (tail rows inside the last block are
+        masked garbage until decode overwrites them).
+
+        Every shape here is a function of the BUCKET length L alone: the
+        scatter always moves ``ceil(L/bs)`` blocks, and the ones past the
+        prompt's own blocks go to the scratch block. Were the count taken
+        from the prompt length, each new ``ceil(context/bs)`` would compile
+        its own slice, reshape and scatter on the first request that has it
+        — after warm-up, where chip_smoke.py counts none."""
         import jax.numpy as jnp
         h, L, d = k.shape
         pages = self.ensure_layer(layer, h, d)
+        nb = -(-L // self.block_size)
         nb_w = min(-(-table.context_len // self.block_size),
-                   len(table.blocks))
-        target = nb_w * self.block_size
+                   len(table.blocks), nb)
+        target = nb * self.block_size
         if L < target:
             pad = ((0, 0), (0, target - L), (0, 0))
             k = jnp.pad(k, pad)
             v = jnp.pad(v, pad)
-        ids = np.asarray(table.blocks[:nb_w], np.int32)
-        kb = k[:, :target].reshape(h, nb_w, self.block_size, d)
-        vb = v[:, :target].reshape(h, nb_w, self.block_size, d)
+        ids = np.asarray(table.blocks[:nb_w]
+                         + [SCRATCH_BLOCK] * (nb - nb_w), np.int32)
+        kb = k.reshape(h, nb, self.block_size, d)
+        vb = v.reshape(h, nb, self.block_size, d)
         kb, ks = self._encode_rows(kb)
         vb, vs = self._encode_rows(vb)
         pages[0] = _scatter_blocks(pages[0], ids, kb)

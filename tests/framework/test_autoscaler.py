@@ -271,3 +271,17 @@ def test_autoscaler_min_replicas_floor_spawns():
     finally:
         scaler.close()
         router.close()
+
+
+def test_process_launcher_failure_carries_child_stderr():
+    """A replica process that dies before its ready line (on a one-chip
+    machine: the second replica cannot get the chip) must say why — the
+    launch error carries the tail of the child's stderr."""
+    from paddle_tpu.elastic.launcher import ProcessReplicaLauncher
+    launcher = ProcessReplicaLauncher(extra_args=['--no-such-flag'],
+                                      ready_timeout_s=60)
+    with pytest.raises(RuntimeError) as ei:
+        launcher.launch()
+    msg = str(ei.value)
+    assert 'replica launch failed' in msg
+    assert 'child stderr tail' in msg and '--no-such-flag' in msg, msg

@@ -1,0 +1,102 @@
+"""chip_smoke.py (repo root) is the on-chip proof; here its contract is held
+on the CPU: the script itself refuses to run without a TPU and says what it
+found, and its phase functions — the same code the chip runs at full width —
+go green at tiny sizes.
+
+Three cases are child processes (the script off the chip, the script alone
+in a directory, and the train phase, whose ResNet-50 trace and compile is
+the slow one). A module fixture starts them before the in-process phases
+run, so they overlap; the tests that read them come last."""
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), '..', '..'))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+_TRAIN_CHILD = (
+    "import json, chip_smoke\n"
+    "out = chip_smoke.train_phase(chip_smoke.CompileCounter(), batch=4,\n"
+    "                             image=32, steps=4)\n"
+    "print('RESULT ' + json.dumps(out))\n")
+
+
+def _start(argv, cwd, env):
+    return subprocess.Popen([sys.executable] + argv, cwd=cwd, env=env,
+                            stdout=subprocess.PIPE, stderr=subprocess.PIPE,
+                            text=True)
+
+
+@pytest.fixture(scope='module', autouse=True)
+def children(tmp_path_factory):
+    env = dict(os.environ, JAX_PLATFORMS='cpu')
+    bare = tmp_path_factory.mktemp('bare')
+    with open(os.path.join(REPO, 'chip_smoke.py')) as f:
+        (bare / 'chip_smoke.py').write_text(f.read())
+    bare_env = dict(env)
+    bare_env.pop('PYTHONPATH', None)
+    procs = {'train': _start(['-c', _TRAIN_CHILD], REPO, env),
+             'off_chip': _start(['chip_smoke.py'], REPO, env),
+             'bare': _start(['chip_smoke.py'], str(bare), bare_env)}
+    yield procs
+    for p in procs.values():
+        if p.poll() is None:
+            p.kill()
+            p.wait(timeout=30)
+
+
+@pytest.fixture(scope='module')
+def counter():
+    return chip_smoke.CompileCounter()
+
+
+def test_serve_phase_tiny(counter):
+    from paddle_tpu.models.causal_lm import CausalLMConfig
+    # logit_tol: f32 on the CPU, where the paged and the dense read differ
+    # by an ulp or two (ROADMAP D1); 1e-5 of the logit scale is far above it
+    out = chip_smoke.serve_phase(
+        counter, cfg=CausalLMConfig.tiny(), slots=2, block_size=4,
+        max_blocks=64, max_prompt_len=16, max_new_tokens_cap=8,
+        prompt_lens=(5, 16), new_tokens=4, logit_tol=1e-5)
+    assert set(out['prefill_paths'].values()) == {'XLA gather'}
+    assert out['decode_path'] == 'XLA gather'
+
+
+def test_static_phase_tiny(counter):
+    out = chip_smoke.static_phase(counter, batch=32, steps=12)
+    assert len(out['losses']) == 12
+
+
+def test_kernels_phase_tiny():
+    out = chip_smoke.kernels_phase(
+        fused_shape=(1, 2, 128, 16), paged_slots=2, paged_heads=2,
+        paged_head_dim=128, block_size=4, pages_per_seq=4, num_blocks=16)
+    assert out['fused_attention'] == 'XLA'
+    assert out['paged_attention_d128'] == 'XLA gather'
+
+
+def test_script_exits_nonzero_off_chip_and_names_the_platform(children):
+    out, err = children['off_chip'].communicate(timeout=300)
+    assert children['off_chip'].returncode != 0
+    assert "found 'cpu'" in err, err[-2000:]
+    assert out.strip() == '', 'no result may be printed off the chip'
+
+
+def test_script_alone_in_a_directory_fails(children):
+    """Without the program beside it the script has nothing to prove."""
+    out, err = children['bare'].communicate(timeout=300)
+    assert children['bare'].returncode != 0
+    assert 'paddle_tpu' in err and out.strip() == ''
+
+
+def test_train_phase_tiny(children):
+    out, err = children['train'].communicate(timeout=600)
+    assert children['train'].returncode == 0, err[-3000:]
+    res = json.loads(next(ln for ln in out.splitlines()
+                          if ln.startswith('RESULT ')).split(' ', 1)[1])
+    assert len(res['losses']) == 4 and res['losses'][-1] < res['losses'][0]

@@ -1,16 +1,17 @@
 """Op-level contract of ops/nn_ops.py paged_attention /
 paged_prefill_attention: bitwise parity vs whole-sequence attention at the
 same padded key extent, across ragged length mixes and block-boundary
-lengths, plus clean block reuse (no stale-cache bleed) and the
-pallas-fallback accounting."""
+lengths, plus clean block reuse (no stale-cache bleed) and the explicit
+kernel dispatch (predicates, not exception handlers, pick the path)."""
 import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
 
-from paddle_tpu.ops.nn_ops import (paged_attention, paged_prefill_attention,
-                                   pallas_fallback_stats,
-                                   reset_pallas_fallback_stats)
+from paddle_tpu.ops import nn_ops
+from paddle_tpu.ops.nn_ops import (flash_kernel_applies, fused_attention,
+                                   paged_attention, paged_kernel_applies,
+                                   paged_prefill_attention)
 
 H, D, BS, MAXBPS = 2, 16, 4, 4
 E = MAXBPS * BS          # padded context extent
@@ -129,38 +130,114 @@ def test_block_reuse_no_stale_bleed():
     assert np.array_equal(clean, stale)
 
 
-def test_fallback_stats_count_and_warn_once():
-    """The pallas-unavailable fallback warns ONCE per process through
-    log_helper and counts every fallback trace afterwards."""
-    import logging
-    from paddle_tpu.ops import nn_ops
-    reset_pallas_fallback_stats()
-    records = []
+# -- kernel dispatch -------------------------------------------------------
 
-    class Grab(logging.Handler):
-        def emit(self, record):
-            records.append(record)
+def _sds(shape, dtype=jnp.float32):
+    return jax.ShapeDtypeStruct(shape, dtype)
 
-    logger = logging.getLogger('paddle_tpu.ops.nn_ops')
-    h = Grab()
-    logger.addHandler(h)
-    try:
-        nn_ops._pallas_fallback('fused_attention', ValueError('no kernel'),
-                                (1, 2, 8, 16))
-        nn_ops._pallas_fallback('paged_attention', ValueError('no kernel'),
-                                (4, 2, 16))
-        nn_ops._pallas_fallback('fused_attention', ValueError('again'),
-                                (1, 2, 16, 16))
-    finally:
-        logger.removeHandler(h)
-    stats = pallas_fallback_stats()
-    assert stats['count'] == 3
-    assert stats['warned'] is True
-    assert 'paged_attention' not in stats['last']  # last was fused again
-    assert len(records) == 1, 'must warn exactly once per process'
-    # the at-export collector surfaces the count as a gauge
-    from paddle_tpu.observability import registry
-    d = registry.to_dict()
-    g = d.get('attention_pallas_fallbacks')
-    assert g and g['samples'][0]['value'] == 3.0
-    reset_pallas_fallback_stats()
+
+# chip_smoke's serve geometry: 8 slots, 8 heads of 64, 16-token pages,
+# 12 pages per sequence (128-token prompts + 64 new tokens)
+_Q64, _POOL64 = _sds((8, 8, 64)), _sds((8, 256, 16, 64))
+_Q128, _POOL128 = _sds((8, 4, 128)), _sds((4, 256, 16, 128))
+_TABLES = _sds((8, 12), jnp.int32)
+
+
+def test_dispatch_on_cpu_selects_xla():
+    """Off the chip every predicate is false at every shape, including the
+    ones the kernels accept on a TPU."""
+    assert not flash_kernel_applies(_sds((1, 8, 128, 64)),
+                                    _sds((1, 8, 128, 64)))
+    assert not flash_kernel_applies(_sds((8, 12, 512, 64), jnp.bfloat16),
+                                    _sds((8, 12, 512, 64), jnp.bfloat16))
+    assert not paged_kernel_applies(_Q128, _POOL128, _TABLES, 4)
+
+
+def test_dispatch_on_tpu_follows_the_kernels_own_rules(monkeypatch):
+    """With a TPU backend the predicates encode what the stock kernels and
+    Mosaic accept (established on a v5e, PERF.md "Bring-up") — a refused
+    shape is XLA by predicate."""
+    monkeypatch.setattr(nn_ops, 'on_tpu', lambda: True)
+    # flash: both sequence extents whole multiples of the 128-row blocks
+    for L, want in ((128, True), (256, True), (512, True), (64, False),
+                    (16, False), (1, False), (192, False)):
+        q = _sds((1, 8, L, 64))
+        assert flash_kernel_applies(q, q) is want, L
+    assert flash_kernel_applies(_sds((8, 12, 512, 64), jnp.bfloat16),
+                                _sds((8, 12, 512, 64), jnp.bfloat16))
+    assert not flash_kernel_applies(_sds((1, 8, 128, 64)),
+                                    _sds((1, 8, 64, 64)))     # kv extent
+    assert not flash_kernel_applies(_sds((8, 128, 64)), _sds((8, 128, 64)))
+    assert not flash_kernel_applies(_sds((1, 8, 128, 64), jnp.float16),
+                                    _sds((1, 8, 128, 64), jnp.float16))
+    # paged: single-query, f32 pool, 128-lane head_dim, ppcb | pages/seq
+    assert paged_kernel_applies(_Q128, _POOL128, _TABLES, 4)
+    assert not paged_kernel_applies(_Q64, _POOL64, _TABLES, 4)  # head_dim 64
+    assert not paged_kernel_applies(_Q128, _POOL128, _TABLES, 5)  # 12 % 5
+    assert paged_kernel_applies(_Q128, _POOL128, _sds((8, 3), jnp.int32), 4)
+    assert not paged_kernel_applies(_Q128, _sds((4, 256, 16, 128),
+                                               jnp.bfloat16), _TABLES, 4)
+    assert not paged_kernel_applies(_sds((8, 4, 4, 128)), _POOL128,
+                                    _TABLES, 4)       # multi-query verify
+
+
+def _forbid_kernels(monkeypatch):
+    """Any call into a stock pallas kernel fails the test."""
+    from jax.experimental.pallas.ops.tpu import flash_attention as fa
+    from jax.experimental.pallas.ops.tpu import paged_attention as pa
+
+    def boom(*a, **k):
+        raise AssertionError('pallas kernel called')
+    monkeypatch.setattr(fa, 'flash_attention', boom)
+    monkeypatch.setattr(pa, 'paged_attention', boom)
+
+
+def test_refused_shapes_take_xla_without_touching_the_kernel(monkeypatch):
+    """A shape the kernel's own rules refuse never reaches the kernel: with
+    a (pretend) TPU backend the three ops run the XLA formulation and
+    return what they return on the CPU — by predicate, not by exception."""
+    rng = np.random.RandomState(3)
+    k_pages, v_pages, tables, k_rows, v_rows = build_cache(rng, 16, [MAXBPS])
+    q_rows = rng.randn(H, E, D).astype('float32')
+    Lq = 8
+    args_prefill = (q_rows[None, :, :Lq], k_rows[0][None, :, :Lq],
+                    v_rows[0][None, :, :Lq], k_pages, v_pages, tables[:1])
+    q1 = q_rows[:, 4][None]
+    lens = np.asarray([5], np.int32)
+    want_prefill = np.asarray(paged_prefill_attention(
+        *args_prefill, sm_scale=float(SCALE)))
+    want_decode = np.asarray(paged_attention(
+        q1, k_pages, v_pages, tables, lens, sm_scale=float(SCALE)))
+    want_fused = np.asarray(fused_attention(
+        *args_prefill[:3], sm_scale=float(SCALE), causal=True))
+    monkeypatch.setattr(nn_ops, 'on_tpu', lambda: True)
+    _forbid_kernels(monkeypatch)
+    assert np.array_equal(want_prefill, np.asarray(paged_prefill_attention(
+        *args_prefill, sm_scale=float(SCALE))))
+    assert np.array_equal(want_decode, np.asarray(paged_attention(
+        q1, k_pages, v_pages, tables, lens, sm_scale=float(SCALE))))
+    assert np.array_equal(want_fused, np.asarray(fused_attention(
+        *args_prefill[:3], sm_scale=float(SCALE), causal=True)))
+
+
+def test_accepted_shapes_reach_the_kernel_and_refusals_propagate(
+        monkeypatch):
+    """Where the predicate holds the kernel is called, and whatever it
+    raises reaches the caller — no handler turns a refusal into the XLA
+    formulation."""
+    monkeypatch.setattr(nn_ops, 'on_tpu', lambda: True)
+    _forbid_kernels(monkeypatch)
+    q = np.zeros((1, 2, 128, 16), 'float32')
+    with pytest.raises(AssertionError, match='pallas kernel called'):
+        fused_attention(q, q, q, sm_scale=1.0, causal=True)
+    pool = np.zeros((2, 8, 4, 128), 'float32')
+    with pytest.raises(AssertionError, match='pallas kernel called'):
+        paged_prefill_attention(
+            np.zeros((1, 2, 128, 128), 'float32'),
+            np.zeros((1, 2, 128, 128), 'float32'),
+            np.zeros((1, 2, 128, 128), 'float32'), pool, pool,
+            np.zeros((1, 4), np.int32), sm_scale=1.0)
+    with pytest.raises(AssertionError, match='pallas kernel called'):
+        paged_attention(np.zeros((1, 2, 128), 'float32'), pool, pool,
+                        np.zeros((1, 4), np.int32),
+                        np.asarray([3], np.int32), sm_scale=1.0)

@@ -179,10 +179,10 @@ def count_eqns(jaxpr):
 
 
 def _sub_jaxprs(v):
-    import jax
-    if isinstance(v, jax.core.Jaxpr):
+    from jax.extend import core as jex_core
+    if isinstance(v, jex_core.Jaxpr):
         return [v]
-    if isinstance(v, jax.core.ClosedJaxpr):
+    if isinstance(v, jex_core.ClosedJaxpr):
         return [v.jaxpr]
     if isinstance(v, (list, tuple)):
         return [s for x in v for s in _sub_jaxprs(x)]
@@ -335,18 +335,19 @@ def measure_executor_compile(iters=2, smoke=True):
 
 
 def _hermetic_compile_cache():
-    """Point the persistent XLA cache at a fresh temp dir BEFORE any
-    Executor configures jax (the first configuration wins for the whole
-    process): entries a developer's ~/.cache accumulated must not serve
-    this bench's 'cold' compiles."""
+    """Place the persistent XLA cache in a fresh temp dir the way any
+    outside caller does — JAX_COMPILATION_CACHE_DIR, which jax reads when
+    it is first imported, so this must run before anything imports jax:
+    entries an earlier run left in the checkout's cache must not serve
+    this bench's compiles."""
     import tempfile
+    assert 'jax' not in sys.modules, 'set the cache dir before jax loads'
     os.environ.setdefault(
-        'PADDLE_TPU_COMPILE_CACHE_DIR',
+        'JAX_COMPILATION_CACHE_DIR',
         tempfile.mkdtemp(prefix='bench_passes_xla_cache_'))
 
 
 def measure_all(iters=3, smoke=False):
-    _hermetic_compile_cache()
     out = {}
     for name, builder in MODELS.items():
         out[name] = measure_model(name, builder, iters=iters, smoke=smoke)
@@ -362,6 +363,7 @@ def main():
     ap.add_argument('--smoke', action='store_true',
                     help='tiny shapes / CI smoke sizes')
     args = ap.parse_args()
+    _hermetic_compile_cache()
     for res in measure_all(iters=args.iters, smoke=args.smoke).values():
         print(json.dumps(res), flush=True)
 
