@@ -22,9 +22,12 @@ repo's own means:
              fleet.init(mesh_shape={'dp': 4}) + TrainStep(data_sharding=...)
 
 A phase that raises ends the run non-zero at once. The last line of stdout is
-one JSON object, {"ok": true, "device": {...}, ..., "claim": null}. The
-compile seconds and ms/step it prints are informational: no rate or
-utilisation is computed, so no peak is assumed.
+one JSON object with exactly two keys,
+{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": 1}}, the
+device as JAX reports it; the driver parses that line and refuses any other
+key. The line before it, "[summary] {...}", carries each phase's figures and
+ends with "claim": null. The compile seconds and ms/step are informational:
+no rate or utilisation is computed, so no peak is assumed.
 
 The phases are plain functions that take their sizes as arguments;
 tests/framework/test_chip_smoke.py calls the same code at tiny sizes on the
@@ -733,6 +736,14 @@ def train_dp4_phase(counter, cfg=None, seq=128, per_chip=128, steps=5,
 
 # -- main --------------------------------------------------------------------
 
+def result_line(device):
+    """The last line of stdout: exactly the keys the driver's check reads."""
+    return json.dumps({'ok': True,
+                       'device': {'platform': device['platform'],
+                                  'kind': device['kind'],
+                                  'count': device['count']}})
+
+
 def main():
     import jax
 
@@ -766,10 +777,10 @@ def main():
                  f"{total['writes']}; {total['compiles']} executables built "
                  f"or loaded, {total['compile_secs']:.1f} s in XLA")
     say('done', f'all phases green in {time.perf_counter() - t0:.0f} s')
-    print(json.dumps({'ok': True, 'device': device, 'phases': phases,
-                      'compile_cache': total,
-                      'seconds': round(time.perf_counter() - t0, 1),
-                      'claim': None}), flush=True)
+    say('summary', json.dumps({'phases': phases, 'compile_cache': total,
+                               'seconds': round(time.perf_counter() - t0, 1),
+                               'claim': None}))
+    print(result_line(device), flush=True)
 
 
 if __name__ == '__main__':

@@ -80,6 +80,15 @@ def test_kernels_phase_tiny():
     assert out['paged_attention_d128'] == 'XLA gather'
 
 
+def test_result_line_holds_exactly_the_keys_the_driver_reads():
+    res = json.loads(chip_smoke.result_line(chip_smoke.device_phase()))
+    assert set(res) == {'ok', 'device'} and res['ok'] is True
+    assert set(res['device']) == {'platform', 'kind', 'count'}
+    assert isinstance(res['device']['platform'], str)
+    assert isinstance(res['device']['kind'], str)
+    assert type(res['device']['count']) is int
+
+
 def test_script_exits_nonzero_off_chip_and_names_the_platform(children):
     out, err = children['off_chip'].communicate(timeout=300)
     assert children['off_chip'].returncode != 0
