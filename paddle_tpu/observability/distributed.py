@@ -57,8 +57,14 @@ METRICS_KV_PREFIX = 'paddle_tpu/metrics/'
 
 class SpanRecorder(object):
     """Per-process JSONL span stream (`steplog` idiom: append + flush per
-    line so a kill -9'd process loses at most the in-flight span — the
-    failover drill reads a victim's spans after SIGKILL)."""
+    ``write`` so a kill -9'd process loses only what was not yet handed to
+    it — the failover drill reads a victim's spans after SIGKILL). The
+    router writes a span as it completes. The decode scheduler hands over
+    the replica spans of one engine call in one ``write`` when the next
+    engine call has returned (or its thread goes idle), so a killed replica
+    loses at most one call's spans, the last tokens of its requests among
+    them; they are leaves, so the merged tree keeps every parent
+    (tools/trace_merge.py)."""
 
     def __init__(self, path, process):
         self._path = path
@@ -83,19 +89,19 @@ class SpanRecorder(object):
             # Clock record first: the merge tool pairs (unix_time,
             # perf_counter) per process to translate perf-based spans
             # onto one wall-clock axis.
-            self._write_locked({'clock': {
+            self._fh.write(json.dumps({'clock': {
                 'pid': os.getpid(), 'process': self._process,
                 'unix_time': time.time(),
-                'perf_counter': time.perf_counter()}})
+                'perf_counter': time.perf_counter()}}) + '\n')
+            self._fh.flush()
 
-    def _write_locked(self, record):
-        self._fh.write(json.dumps(record) + '\n')
-        self._fh.flush()
-
-    def write(self, record):
+    def write(self, *records):
+        """Append the records, one line each, under one lock and one
+        flush."""
         with self._lock:
             self._ensure_open_locked()
-            self._write_locked(record)
+            self._fh.write(''.join(json.dumps(r) + '\n' for r in records))
+            self._fh.flush()
 
     def close(self):
         with self._lock:
@@ -140,30 +146,38 @@ def record_span(ctx, name, start_perf, end_perf, **args):
     `start_perf`/`end_perf` are ``time.perf_counter()`` stamps taken by
     the caller around the work. No-op (a single None/flag check) when
     the request is untraced — the disabled path must stay free."""
-    if ctx is None or not ctx.sampled:
-        return None
+    if ctx is not None and ctx.sampled:
+        record_spans([(ctx, name, start_perf, end_perf, args)])
+
+
+def record_spans(batch):
+    """Record completed spans, ``[(the span's own sampled ctx, name,
+    start_perf, end_perf, args)]``, in one pass: one reading of the clocks
+    and one ``SpanRecorder.write`` for all of them. Each goes into the JSONL
+    stream (where ``PADDLE_TPU_TRACE_DIR`` is set) and, tagged with the
+    trace ids so a per-process trace.json can still be filtered by
+    trace_id, into the in-process chrome buffer."""
     now_perf = time.perf_counter()
     now_unix = time.time()
-    dur_s = max(0.0, end_perf - start_perf)
-    start_unix = now_unix - (now_perf - start_perf)
-    span = {'name': name, 'trace_id': ctx.trace_id,
-            'span_id': ctx.span_id, 'parent_span_id': ctx.parent_span_id,
-            'start_unix': start_unix, 'dur_s': dur_s}
-    if args:
-        span['args'] = {k: v for k, v in args.items()}
     rec = span_recorder()
-    if rec is not None:
-        span = dict(span, process=rec.process)
-        rec.write({'span': span})
-    # Mirror into the in-process chrome buffer, tagged so a per-process
-    # trace.json can still be filtered by trace_id.
-    targs = dict(args)
-    targs['trace_id'] = ctx.trace_id
-    targs['span_id'] = ctx.span_id
-    if ctx.parent_span_id:
-        targs['parent_span_id'] = ctx.parent_span_id
-    tracer.complete(name, start_perf, end_perf, **targs)
-    return span
+    records = []
+    for ctx, name, start_perf, end_perf, args in batch:
+        if rec is not None:
+            span = {'name': name, 'trace_id': ctx.trace_id,
+                    'span_id': ctx.span_id,
+                    'parent_span_id': ctx.parent_span_id,
+                    'start_unix': now_unix - (now_perf - start_perf),
+                    'dur_s': max(0.0, end_perf - start_perf)}
+            if args:
+                span['args'] = dict(args)
+            span['process'] = rec.process
+            records.append({'span': span})
+        ids = {'trace_id': ctx.trace_id, 'span_id': ctx.span_id}
+        if ctx.parent_span_id:
+            ids['parent_span_id'] = ctx.parent_span_id
+        tracer.complete(name, start_perf, end_perf, **args, **ids)
+    if records:
+        rec.write(*records)
 
 
 def record_clock_offset(process, offset_s, rtt_s=None):

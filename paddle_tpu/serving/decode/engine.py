@@ -31,6 +31,7 @@ import time
 import numpy as np
 
 from .. import metrics as _m
+from ... import observability as _obs
 from ...observability import distributed as _dobs
 from ..engine import bucket_ladder
 from ..errors import InvalidRequest
@@ -40,6 +41,56 @@ from .kv_cache import (CacheContext, KVCachePool, DEFAULT_BLOCK_SIZE,
 __all__ = ['DecodeEngine']
 
 _NULL_LOCK = contextlib.nullcontext()
+
+
+class _CallClock:
+    """perf_counter stamps at the phase boundaries of one engine call
+    (``call``: prefill | step | spec_step). Each phase runs from the stamp
+    before it to its own, so the phases tile the call: pack (host arrays
+    and the CacheContext), forward (``self.model(...)`` until it returns:
+    every per-op kernel enqueued), device_wait (``block_until_ready`` on
+    the logits: host idle, device finishing), logits_copy (device to
+    host), sample (host argmax or the request's sampler).
+
+    ``record`` is the one place the stamps are read: always one
+    observation per phase into ``decode_engine_phase_seconds``, and with
+    telemetry on the ``engine/<call>`` span and its ``engine/<call>/<phase>``
+    children from the same stamps. O(1) per call."""
+
+    __slots__ = ('call', 'start', 'last', 'ends')
+
+    def __init__(self, call):
+        self.call = call
+        self.ends = []                  # [(phase, perf_counter at its end)]
+        self.start = self.last = time.perf_counter()
+
+    def end(self, phase):
+        self.last = time.perf_counter()
+        self.ends.append((phase, self.last))
+        return self.last
+
+    def fetch(self, logits):
+        """The logits on the host, with the wait for the device and the
+        copy stamped apart (``logits.numpy()`` is both at once)."""
+        logits.value.block_until_ready()
+        self.end('device_wait')
+        host = np.asarray(logits.value)
+        self.end('logits_copy')
+        _m.decode_logits_bytes_copied.inc(host.nbytes)
+        return host
+
+    def record(self, **args):
+        hist = _m.decode_engine_phase_seconds
+        spans = _obs._ENABLED
+        name = 'engine/' + self.call
+        t = self.start
+        for phase, end in self.ends:
+            hist.labels(call=self.call, phase=phase).observe(end - t)
+            if spans:
+                _obs.tracer.complete(f'{name}/{phase}', t, end)
+            t = end
+        if spans:
+            _obs.tracer.complete(name, self.start, self.last, **args)
 
 
 class DecodeEngine:
@@ -230,28 +281,30 @@ class DecodeEngine:
         ``sampler(logits_row)`` for sampled requests. Sets
         ``table.context_len = len(prompt)``."""
         from ...dygraph.tape import Tensor, no_grad_guard
+        clock = _CallClock('prefill')
         P = len(prompt)
         bucket = next(b for b in self.prompt_buckets if P <= b)
         ids = np.zeros((1, bucket), np.int64)
         ids[0, :P] = prompt
         table.context_len = P
         ctx = CacheContext(self.pool, 'prefill', [table])
-        t0 = time.perf_counter()
+        t0 = clock.end('pack')
         with self._model_lock or _NULL_LOCK:
             with no_grad_guard():
                 logits = self.model(Tensor(ids, stop_gradient=True),
                                     cache=ctx)
-                row = np.asarray(logits.numpy())[0, P - 1]
-        dt = time.perf_counter() - t0
-        _m.decode_prefill_seconds.observe(dt)
+                clock.end('forward')
+                row = clock.fetch(logits)[0, P - 1]
+        _m.decode_prefill_seconds.observe(clock.last - t0)
+        token = int(row.argmax() if sampler is None else sampler(row))
+        clock.end('sample')
+        clock.record(prompt_len=P, bucket=bucket)
         if bucket not in self._prefill_compiled:
             self._prefill_compiled.add(bucket)
             _m.decode_prefill_compiles.inc()
         _m.decode_cache_blocks_used.set(self.pool.allocator.used)
         _m.kv_cache_bytes_in_hbm.set(self.pool.bytes_in_hbm())
-        if sampler is not None:
-            return int(sampler(row))
-        return int(row.argmax())
+        return token
 
     def decode_step(self, tokens, tables, return_rows=False):
         """One lockstep step over all S slots at fixed shape.
@@ -268,6 +321,7 @@ class DecodeEngine:
         the greedy ids are the argmax of those same rows, so requesting
         rows changes no bits."""
         from ...dygraph.tape import Tensor, no_grad_guard
+        clock = _CallClock('step')
         S = self.slots
         assert len(tokens) == S and len(tables) == S
         ids = np.zeros((S, 1), np.int64)
@@ -283,27 +337,33 @@ class DecodeEngine:
             tables[s].context_len = c + 1   # the fed token becomes cached
             ctx_lens.append(c + 1)
         ctx = CacheContext(self.pool, 'decode', tables, ctx_lens)
-        t0 = time.perf_counter()
+        t0 = clock.end('pack')
         with self._model_lock or _NULL_LOCK:
             with no_grad_guard():
                 logits = self.model(Tensor(ids, stop_gradient=True),
                                     pos_ids=Tensor(pos, stop_gradient=True),
                                     cache=ctx)
-                rows = np.asarray(logits.numpy())[:, 0]
+                clock.end('forward')
+                rows = clock.fetch(logits)[:, 0]
                 out = rows.argmax(-1)
-        dt = time.perf_counter() - t0
+        dt = clock.end('sample') - t0
+        clock.record()
         self._step_compiled = True
+        self._account_step(dt, tables)
+        if return_rows:
+            return out, rows
+        return out
+
+    def _account_step(self, dt, tables):
+        """What every decode step books, lockstep or speculative."""
         _m.decode_step_seconds.observe(dt)
         _m.decode_steps.inc()
         active = sum(t is not None for t in tables)
         _m.decode_slots_active.set(active)
-        _m.decode_slot_occupancy.observe(active / max(S, 1))
+        _m.decode_slot_occupancy.observe(active / max(self.slots, 1))
         # sliding-window views for /healthz slo + fleet snapshots
-        _dobs.series('occupancy').observe(active / max(S, 1))
+        _dobs.series('occupancy').observe(active / max(self.slots, 1))
         _dobs.series('decode_step').observe(dt)
-        if return_rows:
-            return out, rows
-        return out
 
     def spec_step(self, token_lists, tables):
         """One batched (S, k) speculative/multi-token step.
@@ -325,6 +385,7 @@ class DecodeEngine:
         asserts it across ragged accept lengths). Padded lanes (j >= f)
         are garbage on scratch reads and must be ignored."""
         from ...dygraph.tape import Tensor, no_grad_guard
+        clock = _CallClock('spec_step')
         S, K = self.slots, self.spec_k
         assert len(token_lists) == S and len(tables) == S
         ids = np.zeros((S, K), np.int64)
@@ -346,24 +407,20 @@ class DecodeEngine:
             fed_counts.append(f)
         ctx = CacheContext(self.pool, 'decode', tables, ctx_lens,
                            fed_counts=fed_counts, window=K)
-        t0 = time.perf_counter()
+        t0 = clock.end('pack')
         with self._model_lock or _NULL_LOCK:
             with no_grad_guard():
                 logits = self.model(Tensor(ids, stop_gradient=True),
                                     pos_ids=Tensor(pos, stop_gradient=True),
                                     cache=ctx)
-                rows = np.asarray(logits.numpy())
-        dt = time.perf_counter() - t0
+                clock.end('forward')
+                rows = clock.fetch(logits)
+        dt = clock.last - t0
+        clock.record()
         self._spec_compiled = True
-        _m.decode_step_seconds.observe(dt)      # it IS the decode step
+        self._account_step(dt, tables)          # it IS the decode step
         _m.decode_spec_verify_seconds.observe(dt)
-        _m.decode_steps.inc()
         _m.decode_spec_rounds.inc()
-        active = sum(t is not None for t in tables)
-        _m.decode_slots_active.set(active)
-        _m.decode_slot_occupancy.observe(active / max(S, 1))
-        _dobs.series('occupancy').observe(active / max(S, 1))
-        _dobs.series('decode_step').observe(dt)
         return rows
 
     def inject_prefill(self, table, payload):

@@ -43,6 +43,7 @@ import time
 import uuid
 
 from .. import metrics as _m
+from ... import observability as _obs
 from ...observability import distributed as _dobs
 from ..breaker import CircuitBreaker
 from ..errors import (DeadlineExceeded, EngineClosed, EngineUnhealthy,
@@ -254,6 +255,11 @@ class DecodeScheduler:
         self.default_timeout_ms = default_timeout_ms
         self._waiting = collections.deque()
         self._slots = [None] * engine.slots      # _Request | None
+        # worker-owned: spans of traced requests since the last batch
+        # (_trace_span / _record_spans), seconds inside engine calls this
+        # loop iteration (_worker_loop)
+        self._spans = []
+        self._engine_s = 0.0
         self._cv = threading.Condition()
         self._closing = False
         self._abort = False
@@ -378,18 +384,47 @@ class DecodeScheduler:
             self.engine.publish_prefix(req.prompt, req.table)
 
     def _trace_span(self, req, name, start_perf, end_perf, **args):
-        """Record one replica-side span of a traced request (no-op when the
-        request carries no sampled trace — one None check)."""
-        if req.trace is None:
+        """Note one replica-side span of a traced request (no-op when the
+        request carries no sampled trace — one None check). This runs per
+        slot per step between a decode step and the next admission, so it
+        is one append; ``_record_spans`` makes the spans of it."""
+        if req.trace is not None:
+            self._spans.append((req, name, start_perf, end_perf, args))
+
+    def _record_spans(self):
+        """Record the spans noted since the last call, in one batch: child
+        contexts, dicts, the JSONL lines and the mirror into the chrome
+        buffer cost the worker thread one pass, not one per slot. It runs
+        when an engine call has just returned and before the call's tokens
+        are emitted: the HTTP threads are then idle, so a batch that yields
+        the interpreter (an id draw, the file write) hands it to nobody.
+        Emitted tokens wake 128 of them, and whatever yields between there
+        and the next engine call waits for all of them (PERF.md, PR 24):
+        per-slot spans there are what made a traced run admit earlier than
+        an untraced one."""
+        noted, self._spans = self._spans, []
+        if not noted:
             return
-        _m.trace_spans_recorded.inc()
-        _dobs.record_span(req.trace.child(), name, start_perf, end_perf,
-                          request_id=req.stream.request_id,
-                          replica_id=self.replica_id, **args)
+        _m.trace_spans_recorded.inc(len(noted))
+        _dobs.record_spans(
+            [(req.trace.child(), name, start, end,
+              dict(args, request_id=req.stream.request_id,
+                   replica_id=self.replica_id))
+             for req, name, start, end, args in noted])
+
+    def _phase(self, phase, start, end, span=False, **args):
+        """One observation of a worker-thread phase and, where ``span`` says
+        so and telemetry is on, its ``scheduler/<phase>`` span from the same
+        stamps."""
+        _m.decode_scheduler_phase_seconds.labels(phase=phase).observe(
+            end - start)
+        if span and _obs._ENABLED:
+            _obs.tracer.complete('scheduler/' + phase, start, end, **args)
 
     def _prefill(self, req):
-        self._trace_span(req, 'replica/queue_wait', req.enqueued_perf,
-                        time.perf_counter())
+        now = time.perf_counter()
+        _m.decode_queue_wait_seconds.observe(now - req.enqueued_perf)
+        self._trace_span(req, 'replica/queue_wait', req.enqueued_perf, now)
         cached = getattr(req.table, 'cached_len', 0)
         if cached:
             # prefix-cache hit: the front of the table is already-filled
@@ -425,11 +460,15 @@ class DecodeScheduler:
             self._fail_request(req, e)
             self._record_engine_failure()
             return
-        self._trace_span(req, 'replica/prefill', t0, time.perf_counter(),
+        t1 = time.perf_counter()
+        self._engine_s += t1 - t0
+        self._trace_span(req, 'replica/prefill', t0, t1,
                          prompt_len=len(req.prompt))
+        self._record_spans()
         self.breaker.record_success()
         self._publish(req)
         self._emit_token(req, first)
+        self._phase('emit', t1, time.perf_counter(), span=True)
 
     def _drain_handoffs(self, timeout=0.0):
         """Apply finished prefill handoffs: inject the KV payload into the
@@ -438,7 +477,11 @@ class DecodeScheduler:
         dropped (their table is gone)."""
         if self.disagg is None:
             return
-        for req, payload, exc in self.disagg.drain_completed(timeout):
+        t0 = time.perf_counter()
+        completed = self.disagg.drain_completed(timeout)
+        if timeout:
+            self._phase('wait', t0, time.perf_counter())
+        for req, payload, exc in completed:
             if req not in self._slots or req.table is None:
                 continue              # failed or closed while in flight
             req.handoff_pending = False
@@ -446,19 +489,23 @@ class DecodeScheduler:
                 self._fail_request(req, exc)
                 self._record_engine_failure()
                 continue
+            t0 = time.perf_counter()
             try:
                 first = self.engine.inject_prefill(req.table, payload)
             except Exception as e:
                 self._fail_request(req, e)
                 self._record_engine_failure()
                 continue
+            t1 = time.perf_counter()
+            self._engine_s += t1 - t0
             if req.handoff_t0 is not None:
                 self._trace_span(req, 'replica/handoff_wait',
-                                 req.handoff_t0, time.perf_counter(),
+                                 req.handoff_t0, t1,
                                  prompt_len=len(req.prompt))
             self.breaker.record_success()
             self._publish(req)
             self._emit_token(req, first)
+            self._phase('emit', t1, time.perf_counter(), span=True)
 
     def _record_engine_failure(self):
         """Book one engine-failure batch with the breaker; on a trip, fail
@@ -545,8 +592,7 @@ class DecodeScheduler:
         rows = None
         need_rows = any(r.sampler is not None and not r.prefilling
                         for r in active)
-        traced = [r for r in active if r.trace is not None]
-        t0 = time.perf_counter() if traced else 0.0
+        t0 = time.perf_counter()
         try:
             if need_rows:
                 out, rows = self.engine.decode_step(tokens, tables,
@@ -558,8 +604,10 @@ class DecodeScheduler:
                 self._fail_request(req, e)
             self._record_engine_failure()
             return True
-        t1 = time.perf_counter() if traced else 0.0
+        t1 = time.perf_counter()
+        self._engine_s += t1 - t0
         self.breaker.record_success()
+        self._record_spans()
         for i, req in enumerate(self._slots):
             if req is None or req.handoff_pending:
                 continue
@@ -578,6 +626,7 @@ class DecodeScheduler:
             if req.trace is not None:
                 self._trace_span(req, 'replica/token', t0, t1,
                                  index=req.generated - 1)
+        self._phase('emit', t1, time.perf_counter(), span=True)
         return True
 
     def _spec_step(self):
@@ -628,8 +677,7 @@ class DecodeScheduler:
                             req.history, n)][:n]
                 toks = [req.next_token] + drafts
             fed[i] = toks
-        traced = [r for r in active if r.trace is not None]
-        t0 = time.perf_counter() if traced else 0.0
+        t0 = time.perf_counter()
         try:
             rows = self.engine.spec_step(fed, tables)
         except Exception as e:
@@ -637,8 +685,10 @@ class DecodeScheduler:
                 self._fail_request(req, e)
             self._record_engine_failure()
             return True
-        t1 = time.perf_counter() if traced else 0.0
+        t1 = time.perf_counter()
+        self._engine_s += t1 - t0
         self.breaker.record_success()
+        self._record_spans()
         for i, req in enumerate(self._slots):
             if req is None or req.handoff_pending:
                 continue
@@ -679,6 +729,7 @@ class DecodeScheduler:
                     _m.decode_spec_accepted_tokens.inc(emitted - 1)
                 _m.decode_spec_acceptance.set(
                     self._spec_accepted / max(self._spec_drafted, 1))
+        self._phase('emit', t1, time.perf_counter(), span=True)
         return True
 
     def _fail_all_locked(self):
@@ -699,13 +750,30 @@ class DecodeScheduler:
         _m.decode_slots_active.set(0)
 
     def _worker_loop(self):
+        try:
+            self._run_cycles()
+        finally:
+            self._record_spans()        # what the last iteration noted
+
+    def _run_cycles(self):
+        """The worker thread's life, one iteration (``cycle``) after another:
+        each is observed whole and by phase (``_phase``), so the thread's
+        self time is cycle - wait - engine; iterations that admitted or ran
+        an engine call also leave ``scheduler/cycle|admit|emit`` spans, which
+        contain the engine's own (one thread: containment is the tree).
+        ``emit`` is what follows an engine call that returned tokens, once
+        per call; with ``admit`` and the engine's phases it tiles the busy
+        part of a cycle."""
+        cycle = 0
         while True:
+            t0 = time.perf_counter()
             with self._cv:
                 if self._closing and self._abort:
                     self._fail_all_locked()
                     break
                 self._expire_waiting(time.monotonic())
                 admitted = self._admit_locked()
+            t_admit = time.perf_counter()
             for req in admitted:
                 self._prefill(req)
             # finished prefill handoffs join before the step; when ONLY
@@ -722,6 +790,7 @@ class DecodeScheduler:
             else:
                 stepped = self._step()
             if not stepped and not admitted:
+                self._record_spans()    # idle: nothing else will
                 with self._cv:
                     if self._closing:
                         if self._abort:
@@ -729,7 +798,19 @@ class DecodeScheduler:
                         if not self._waiting:
                             break
                     else:
+                        w0 = time.perf_counter()
                         self._cv.wait(timeout=0.05)
+                        self._phase('wait', w0, time.perf_counter())
+            engine_s, self._engine_s = self._engine_s, 0.0
+            worked = bool(admitted) or engine_s > 0
+            cycle += worked
+            self._phase('admit', t0, t_admit, span=worked)
+            if engine_s:
+                _m.decode_scheduler_phase_seconds.labels(
+                    phase='engine').observe(engine_s)
+            self._phase('cycle', t0, time.perf_counter(), span=worked,
+                        cycle=cycle, admitted=len(admitted),
+                        slots_active=sum(r is not None for r in self._slots))
 
     # -- lifecycle ---------------------------------------------------------
     def close(self, drain=True, timeout=None):

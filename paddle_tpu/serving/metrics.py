@@ -202,6 +202,29 @@ decode_step_seconds = _LazyMetric(
     'decode_prefill_seconds this is the prefill-vs-decode time split')
 decode_steps = _LazyMetric(
     'counter', 'decode_steps', 'lockstep decode steps executed')
+# the inside of an engine call and of a worker-thread cycle, always on and
+# O(1) per call or request, never per slot per step: ~30 clock reads and ~25
+# observations in a cycle of two prefills and a step
+decode_engine_phase_seconds = _LazyMetric(
+    'histogram', 'decode_engine_phase_seconds',
+    'wall seconds per phase of one engine call (labels call=prefill|step|'
+    'spec_step, phase=pack|forward|device_wait|logits_copy|sample); the '
+    'phases of a call tile it')
+decode_logits_bytes_copied = _LazyMetric(
+    'counter', 'decode_logits_bytes_copied',
+    'bytes of logits copied from the device to the host by engine calls')
+decode_scheduler_phase_seconds = _LazyMetric(
+    'histogram', 'decode_scheduler_phase_seconds',
+    'wall seconds of the scheduler worker thread per loop iteration (label '
+    'phase: cycle = the whole iteration; inside it admit = the locked '
+    'expire-and-admit pass, engine = its engine calls, emit = what follows '
+    'an engine call that returned tokens, once per call: the per-slot loop '
+    'after a step, the first token after a prefill; wait = blocked idle); '
+    'self time = cycle - wait - engine')
+decode_queue_wait_seconds = _LazyMetric(
+    'histogram', 'decode_queue_wait_seconds',
+    'accepted by submit -> admitted to a slot, per generation (every '
+    'request, traced or not)')
 decode_tokens_generated = _LazyMetric(
     'counter', 'decode_tokens_generated',
     'tokens emitted to generation streams (rate = tokens/s)')
