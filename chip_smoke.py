@@ -314,6 +314,9 @@ def serve_phase(counter, cfg=None, slots=8, block_size=16, max_blocks=256,
         warm_s = time.perf_counter() - t0
         warm_compiles = counter.since(c0)
         assert engine.warmed
+        # one program per prefill rung and one lockstep step
+        programs = engine.compiled_programs()
+        assert programs == len(engine.prompt_buckets) + 1, programs
         srv.start()
         try:
             rng = np.random.RandomState(seed)
@@ -360,11 +363,14 @@ def serve_phase(counter, cfg=None, slots=8, block_size=16, max_blocks=256,
                 token_lists.append(toks)
             assert token_lists[1] == token_lists[-1], \
                 'replay by request_id differs'
-            # no compile after warm-up: by the engine's own counter (eager
-            # kernel-cache misses) and by jax's (executables built or loaded)
-            assert kstats['misses'] == 0, kstats
+            # no compile after warm-up: by the engine's own count of its
+            # programs and by jax's (executables built or loaded); and every
+            # engine call was one of those programs: the eager per-op kernel
+            # cache saw no lookup at all
+            assert engine.compiled_programs() == programs
             assert after_warm['compiles'] == 0, \
                 f'compiles after warm-up: {after_warm}'
+            assert (kstats['hits'], kstats['misses']) == (0, 0), kstats
 
             # logits, not tokens (the scheduler is idle again: the engine is
             # single-threaded by design): the shortest prompt, on a low rung
@@ -409,8 +415,9 @@ def serve_phase(counter, cfg=None, slots=8, block_size=16, max_blocks=256,
                  f"POST /generate (prompts {min(prompt_lens)}-"
                  f"{max(prompt_lens)}, {new_tokens} new) + 1 replay: all "
                  f"200, token counts and ids ok, stream ok, sampled replay "
-                 f"identical; after warm-up: kernel-cache misses "
-                 f"{kstats['misses']}, XLA compiles {after_warm['compiles']}")
+                 f"identical; {programs} engine programs; after warm-up: "
+                 f"XLA compiles {after_warm['compiles']}, eager kernel-cache "
+                 f"lookups {kstats['hits'] + kstats['misses']}")
     say('serve', f"logits vs uncached forward at pad {engine.padded_context}"
                  f", as a share of max|logit| (tolerance {logit_tol:g}): "
                  + '; '.join(

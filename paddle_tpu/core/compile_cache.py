@@ -16,6 +16,12 @@ this package's location and listed in .gitignore) — fixed, because a
 directory that moves from one process to the next never hits. A directory
 that cannot be created is an error, not a silently cold cache.
 
+What the key holds is kept still the same way: MLIR locations in a program
+are cut to their innermost frame (``jax_include_full_tracebacks_in_locations``
+off; see :func:`setup_persistent_cache`), because a pallas kernel's key
+includes them and a whole traceback differs from one caller, and one
+checkout path, to the next.
+
 Environment knobs (documented in README):
 - PADDLE_TPU_COMPILE_CACHE=0          disable entirely
 - PADDLE_TPU_COMPILE_CACHE_MIN_COMPILE_SECS=<f>
@@ -124,5 +130,16 @@ def setup_persistent_cache():
         jax.config.update('jax_persistent_cache_min_compile_time_secs',
                           float(min_secs))
         jax.config.update('jax_persistent_cache_min_entry_size_bytes', -1)
+    # A key that moves never hits either. A pallas kernel's key holds its
+    # Mosaic body, MLIR locations included, and by jax's default a location
+    # is the whole Python traceback: the key then changes with the caller's
+    # stack (tape.dispatch_op calls through another line with telemetry on)
+    # and with the checkout's path. Found on the chip: the decode engine's
+    # three prefill programs that hold the flash kernel compiled again, 36 s,
+    # in every traced process beside their own cached executables. One
+    # frame per location, the innermost, keeps the key still. jax's own
+    # variable, where set, decides.
+    if 'JAX_INCLUDE_FULL_TRACEBACKS_IN_LOCATIONS' not in os.environ:
+        jax.config.update('jax_include_full_tracebacks_in_locations', False)
     _configured = cache_dir
     return cache_dir

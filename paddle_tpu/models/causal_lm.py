@@ -5,23 +5,30 @@ Built from the BERT building blocks (models/bert.py TransformerLayer /
 MultiHeadAttention) with a causal mask and a weight-tied LM head, so the
 incremental-decode cache path added to MultiHeadAttention is exercised by a
 real model rather than a bespoke one. Two execution modes share every
-parameter and (on CPU) every bit of arithmetic:
+parameter and the same arithmetic:
 
 - **whole-sequence** (``cache=None``): the full (B, L) padded sequence in
   one forward — training, and the uncached reference that
   :func:`greedy_generate` uses;
 - **incremental** (``cache=`` a serving/decode CacheContext): prefill
   writes the prompt's K/V into paged cache blocks, decode steps run at
-  fixed (S, 1) shape reading K/V through per-slot block tables.
+  fixed (S, 1) shape reading K/V through per-slot block tables. The decode
+  engine traces this mode once per shape into one jitted XLA program
+  (serving/decode/engine.py), so ``cache`` and every tensor here may be a
+  tracer.
 
-Bitwise-parity contract (the decode engine's acceptance bar): on CPU, a
-decode step's logits row is `np.array_equal` to the matching row of a
-whole-sequence forward padded to the SAME context extent (the engine's
-``padded_context``). This needs the unfused matmul attention path — XLA
-CPU keeps matmul rows bitwise stable across the sequence extent, while the
+Parity contract (the decode engine's acceptance bar, ROADMAP D1's rule):
+the engine's greedy token stream equals :func:`greedy_generate`'s at
+``pad_len == engine.padded_context``, and a decode step's logits row
+agrees with the matching row of a whole-sequence forward padded to the
+SAME context extent within a stated tolerance — one fused program and
+~300 eager kernels round differently (one ulp seen on CPU: 1.2e-7 at a
+scale of 0.47; tests/framework/test_decode_fused_programs.py). Both paths
+keep the unfused matmul attention formulation over the same padded extent
+— XLA CPU keeps matmul rows stable across the sequence extent, while the
 einsum in fused_attention's fallback does not (measured; see
 ops/nn_ops.py:paged_attention) — so ``use_fused_attention`` defaults off
-here and the config asserts it stays off when parity matters.
+here.
 """
 from __future__ import annotations
 
@@ -130,8 +137,8 @@ def greedy_generate(model, prompt_ids, max_new_tokens, eos_id=None,
     Every step re-runs the full (1, pad_len) sequence and reads the logits
     row of the last real position — O(L²) work, but a single compile for
     the whole generation (the fixed-shape discipline that also fixed
-    models/transformer.py's decode retracing). This is the bitwise
-    REFERENCE the decode engine is tested against: run it with
+    models/transformer.py's decode retracing). This is the REFERENCE the
+    decode engine is tested against: run it with
     ``pad_len == engine.padded_context`` and the streamed tokens must be
     identical (tools/bench_decode.py asserts it on every request).
 
@@ -169,7 +176,7 @@ def sampled_generate(model, prompt_ids, max_new_tokens, sampler, eos_id=None,
     is the float logits row of the last real position and ``index`` the
     0-based generated-token index. Pair it with a
     ``serving.decode.TokenSampler`` bound to the same request_id/params and
-    ``pad_len == engine.padded_context`` to get the bitwise replay
+    ``pad_len == engine.padded_context`` to get the replay
     reference for the engine's sampled path (the per-token fold_in key
     depends only on (seed, index), so cached and uncached loops draw the
     same stream).

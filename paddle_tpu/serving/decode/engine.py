@@ -1,24 +1,40 @@
 """DecodeEngine: phase-split stateful generation over a paged KV cache.
 
+Every engine call runs the model as ONE jitted XLA program (`_Program`):
+parameters, buffers and the pool's per-layer K/V arrays are its arguments
+(the pool DONATED, so the paged writes update it in place), everything about
+the request is a small traced array (token ids, positions, write
+coordinates, block tables, context lengths), and it returns the logits rows
+the host samples from plus the new pool arrays. The host's part of a call is
+packing those arrays, one dispatch, one wait, one copy of the rows, and the
+argmax or the request's sampler.
+
 The compile-count story (the whole point — models/transformer.py's original
 decode re-compiled per generated length):
 
 - **prefill** runs the prompt once at a bucket-ladder shape (reusing
   serving.engine.bucket_ladder — powers of two up to ``max_prompt_len``),
-  writing its K/V into cache blocks: ≤ ``len(prompt_buckets)`` compiles,
-  ever.
+  writing its K/V into cache blocks, and hands the host the row of the
+  prompt's last token, sliced on the device: one program per rung,
+  ``len(prompt_buckets)`` compiles, ever.
 - **decode** steps all S slots in lockstep at ONE fixed shape
   ((S, 1) tokens + (S, max_blocks_per_seq) tables + (S,) context lengths):
-  exactly one compile, regardless of how long any sequence runs.
+  exactly one program, regardless of how long any sequence runs (one more,
+  at (S, k), with speculation on).
 
-tests/framework/test_decode_engine.py asserts both bounds through the eager
-kernel-cache counters.
+The programs are kept per MODEL object and keyed by the pool's geometry, so
+engines of equal geometry over one model share executables.
+tests/framework/test_decode_fused_programs.py asserts the counts by XLA's
+own compile events, and that a warm engine call touches the eager per-op
+kernel cache not at all.
 
-Bitwise contract (CPU): each decode step's logits row equals the matching
-row of an uncached whole-sequence forward padded to ``padded_context`` —
-see ops/nn_ops.py:paged_attention and models/causal_lm.py for why the
-extent and the matmul formulation matter. ``check_parity`` in the tests and
-tools/bench_decode.py asserts it per request.
+Numerical contract with the uncached whole-sequence forward
+(models/causal_lm.py, ROADMAP D1): equal greedy token streams, and logits
+rows within a stated tolerance — a fused program and ~300 eager kernels
+round differently (one ulp seen on the CPU: 1.2e-7 at a logit scale of
+0.47). Exact equality holds between runs of the SAME program only: replay
+by request id, spill and reinject, a same-dtype handoff. See
+ops/nn_ops.py:paged_attention for why every read pads to ``padded_context``.
 
 The engine is single-threaded by design (one scheduler worker owns it);
 it holds no queueing or lifecycle logic — that is scheduler.py.
@@ -26,16 +42,23 @@ it holds no queueing or lifecycle logic — that is scheduler.py.
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
+import weakref
 
+import jax
 import numpy as np
 
 from .. import metrics as _m
 from ... import observability as _obs
 from ...observability import distributed as _dobs
+from ...core.compile_cache import setup_persistent_cache
+from ...dygraph.jit import _bind
+from ...dygraph.tape import Tensor, no_grad_guard
 from ..engine import bucket_ladder
 from ..errors import InvalidRequest
-from .kv_cache import (CacheContext, KVCachePool, DEFAULT_BLOCK_SIZE,
+from .kv_cache import (CacheContext, KVCachePool, decode_coords,
+                       prefill_coords, DEFAULT_BLOCK_SIZE,
                        DEFAULT_MAX_BLOCKS, DEFAULT_SLOTS)
 
 __all__ = ['DecodeEngine']
@@ -43,14 +66,94 @@ __all__ = ['DecodeEngine']
 _NULL_LOCK = contextlib.nullcontext()
 
 
+class _Program:
+    """``model`` as the pure function every engine call runs, jitted:
+
+        run(mode, geometry, params, buffers, layers, scales,
+            ids, pos, coords, last) -> (rows, layers, scales)
+
+    ``mode`` ('prefill' | 'decode') and the pool's ``geometry`` are static;
+    ``layers`` / ``scales`` (the pool's arrays) are donated and come back
+    written; parameters are arguments, read from the model at each call, so
+    no weight is baked into an executable and a swapped weight is served.
+    The trace binds them the way dygraph/jit.py::functionalize does, and
+    the pool it writes is a `KVCachePool.over` the traced arrays: nothing
+    traced outlives the trace. ``rows`` is what the host needs and no more:
+    prefill (1, L) -> row ``last`` (V,); decode (S, 1) -> (S, V); decode
+    (S, K) -> (S, K, V).
+
+    One per model object (:meth:`of`), shared by every engine over it: jit's
+    own cache then holds one executable per (mode, geometry, shapes)."""
+
+    _by_model = weakref.WeakKeyDictionary()
+
+    @classmethod
+    def of(cls, model):
+        prog = cls._by_model.get(model)
+        if prog is None:
+            prog = cls._by_model[model] = cls(model)
+        return prog
+
+    def __init__(self, model):
+        setup_persistent_cache()
+        self._params = dict(model.named_parameters())
+        self._buffers = dict(model.named_buffers())
+        # the jitted closure must not keep the model alive: it is the value
+        # of a dictionary that holds the model weakly
+        model_ref = weakref.ref(model)
+        params, buffers = self._params, self._buffers
+
+        def run(mode, geometry, pvals, bvals, layers, scales, ids, pos,
+                coords, last):
+            pool = KVCachePool.over(geometry, layers, scales)
+            ctx = CacheContext(pool, mode, coords)
+            if pos is not None:
+                pos = Tensor(pos, stop_gradient=True)
+            with _bind(params, pvals), _bind(buffers, bvals), \
+                    no_grad_guard():
+                logits = model_ref()(Tensor(ids, stop_gradient=True),
+                                     pos_ids=pos, cache=ctx).value
+            if mode == 'prefill':
+                rows = jax.lax.dynamic_index_in_dim(logits[0], last, 0,
+                                                    keepdims=False)
+            elif ids.shape[1] == 1:
+                rows = logits[:, 0]
+            else:
+                rows = logits
+            return (rows,) + pool.arrays()
+
+        self.jitted = jax.jit(run, static_argnums=(0, 1),
+                              donate_argnums=(4, 5))
+
+    def __call__(self, pool, mode, ids, pos, coords, last=None):
+        """Run one engine call's program over ``pool`` and return the rows
+        (a device array: the call is enqueued, not finished)."""
+        fn = functools.partial(
+            self.jitted, mode, pool.geometry,
+            {n: p.value for n, p in self._params.items()},
+            {n: b.value for n, b in self._buffers.items()})
+        if not pool.num_layers:
+            # the pool allocates here, before the first trace that takes its
+            # arrays as arguments: an abstract trace over an empty pool
+            # returns the arrays `ensure_layer` would make
+            shapes = jax.eval_shape(fn, {}, {}, ids, pos, coords, last)[1]
+            for layer, (k, _) in shapes.items():
+                pool.ensure_layer(layer, k.shape[0], k.shape[3])
+        rows, layers, scales = fn(*pool.arrays(), ids, pos, coords, last)
+        pool.adopt(layers, scales)
+        return rows
+
+
 class _CallClock:
     """perf_counter stamps at the phase boundaries of one engine call
     (``call``: prefill | step | spec_step). Each phase runs from the stamp
-    before it to its own, so the phases tile the call: pack (host arrays
-    and the CacheContext), forward (``self.model(...)`` until it returns:
-    every per-op kernel enqueued), device_wait (``block_until_ready`` on
-    the logits: host idle, device finishing), logits_copy (device to
-    host), sample (host argmax or the request's sampler).
+    before it to its own, so the phases tile the call: pack (the host
+    arrays the program reads), forward (the dispatch of the call's ONE
+    program: arguments handed over, the program enqueued; its trace and
+    compile too, the first time a shape is seen), device_wait
+    (``block_until_ready`` on the rows: host idle, the device running the
+    program — the device's time for the call), logits_copy (the rows,
+    device to host), sample (host argmax or the request's sampler).
 
     ``record`` is the one place the stamps are read: always one
     observation per phase into ``decode_engine_phase_seconds``, and with
@@ -69,12 +172,12 @@ class _CallClock:
         self.ends.append((phase, self.last))
         return self.last
 
-    def fetch(self, logits):
-        """The logits on the host, with the wait for the device and the
-        copy stamped apart (``logits.numpy()`` is both at once)."""
-        logits.value.block_until_ready()
+    def fetch(self, rows):
+        """The rows on the host, with the wait for the device and the copy
+        stamped apart (``np.asarray`` alone is both at once)."""
+        rows.block_until_ready()
         self.end('device_wait')
-        host = np.asarray(logits.value)
+        host = np.asarray(rows)
         self.end('logits_copy')
         _m.decode_logits_bytes_copied.inc(host.nbytes)
         return host
@@ -126,6 +229,7 @@ class DecodeEngine:
         # steps; a shared lock serializes the two MODEL calls (the dygraph
         # tape's no_grad flag is process-global). None = zero overhead.
         self._model_lock = model_lock
+        self._program = _Program.of(model)
         self.slots = int(slots or DEFAULT_SLOTS)
         self.max_prompt_len = int(max_prompt_len)
         self.max_new_tokens_cap = int(max_new_tokens_cap)
@@ -136,7 +240,7 @@ class DecodeEngine:
         max_total = self.max_prompt_len + self.max_new_tokens_cap
         max_bps = -(-max_total // block_size)
         # KV storage dtype: arg wins, else the strict-parsed
-        # PADDLE_TPU_KV_DTYPE knob (default f32 — the bitwise-exact path)
+        # PADDLE_TPU_KV_DTYPE knob (default f32 — the unquantized path)
         from ..tier.knobs import (ENV_KV_DTYPE, KV_DTYPE_CHOICES,
                                   parse_choice_env)
         if kv_dtype is None:
@@ -226,7 +330,7 @@ class DecodeEngine:
     def padded_context(self):
         """The key extent every attention read pads to — run the uncached
         reference (models/causal_lm.greedy_generate) at this pad_len for
-        bitwise-identical tokens."""
+        identical tokens."""
         return self.pool.padded_context
 
     def validate(self, prompt_ids, max_new_tokens):
@@ -275,26 +379,38 @@ class DecodeEngine:
         _m.decode_cache_blocks_used.set(self.pool.allocator.used)
 
     # -- phases ------------------------------------------------------------
+    def _run(self, clock, mode, ids, pos, coords, last=None):
+        """The call's one program, from dispatch to its rows on the host,
+        stamping forward, device_wait and logits_copy on ``clock``. The
+        model lock is held throughout: a first call of a shape traces the
+        model with its parameters bound to tracers, which a second engine
+        over the same model (serving/tier/disagg.py) must not see."""
+        with self._model_lock or _NULL_LOCK:
+            rows = self._program(self.pool, mode, ids, pos, coords, last)
+            clock.end('forward')
+            return clock.fetch(rows)
+
+    def compiled_programs(self):
+        """Executables held for this engine's MODEL, over every engine and
+        geometry that ran it: after warm-up, for one engine on a fresh
+        model, ``len(prompt_buckets) + 1`` (+ 1 with speculation on)."""
+        return self._program.jitted._cache_size()
+
     def prefill(self, prompt, table, sampler=None):
         """Run the bucket-padded prompt once, writing K/V into ``table``'s
         blocks, and return the FIRST generated token — greedy, or drawn by
         ``sampler(logits_row)`` for sampled requests. Sets
         ``table.context_len = len(prompt)``."""
-        from ...dygraph.tape import Tensor, no_grad_guard
         clock = _CallClock('prefill')
         P = len(prompt)
         bucket = next(b for b in self.prompt_buckets if P <= b)
         ids = np.zeros((1, bucket), np.int64)
         ids[0, :P] = prompt
         table.context_len = P
-        ctx = CacheContext(self.pool, 'prefill', [table])
+        coords = prefill_coords(self.pool, table, bucket)
         t0 = clock.end('pack')
-        with self._model_lock or _NULL_LOCK:
-            with no_grad_guard():
-                logits = self.model(Tensor(ids, stop_gradient=True),
-                                    cache=ctx)
-                clock.end('forward')
-                row = clock.fetch(logits)[0, P - 1]
+        row = self._run(clock, 'prefill', ids, None, coords,
+                        np.int32(P - 1))
         _m.decode_prefill_seconds.observe(clock.last - t0)
         token = int(row.argmax() if sampler is None else sampler(row))
         clock.end('sample')
@@ -320,7 +436,6 @@ class DecodeEngine:
         (``(ids, rows)``) so the scheduler can sample non-greedy slots —
         the greedy ids are the argmax of those same rows, so requesting
         rows changes no bits."""
-        from ...dygraph.tape import Tensor, no_grad_guard
         clock = _CallClock('step')
         S = self.slots
         assert len(tokens) == S and len(tables) == S
@@ -336,16 +451,10 @@ class DecodeEngine:
             pos[s, 0] = c
             tables[s].context_len = c + 1   # the fed token becomes cached
             ctx_lens.append(c + 1)
-        ctx = CacheContext(self.pool, 'decode', tables, ctx_lens)
+        coords = decode_coords(self.pool, tables, ctx_lens)
         t0 = clock.end('pack')
-        with self._model_lock or _NULL_LOCK:
-            with no_grad_guard():
-                logits = self.model(Tensor(ids, stop_gradient=True),
-                                    pos_ids=Tensor(pos, stop_gradient=True),
-                                    cache=ctx)
-                clock.end('forward')
-                rows = clock.fetch(logits)[:, 0]
-                out = rows.argmax(-1)
+        rows = self._run(clock, 'decode', ids, pos, coords)
+        out = rows.argmax(-1)
         dt = clock.end('sample') - t0
         clock.record()
         self._step_compiled = True
@@ -379,12 +488,12 @@ class DecodeEngine:
         are masked until rewritten, per the kv_cache scratch contract).
 
         Returns (S, k, V) logits rows: row j of a slot is the target
-        model's distribution AFTER fed tokens 0..j — bitwise-identical to
-        the (S, 1) lockstep row at the same context (the multi-query
-        `paged_attention` staircase; tests/framework/test_spec_decode.py
-        asserts it across ragged accept lengths). Padded lanes (j >= f)
+        model's distribution AFTER fed tokens 0..j — the (S, 1) lockstep
+        row at the same context, up to the rounding of another program (the
+        multi-query `paged_attention` staircase;
+        tests/framework/test_spec_decode.py asserts equal token streams
+        across ragged accept lengths). Padded lanes (j >= f)
         are garbage on scratch reads and must be ignored."""
-        from ...dygraph.tape import Tensor, no_grad_guard
         clock = _CallClock('spec_step')
         S, K = self.slots, self.spec_k
         assert len(token_lists) == S and len(tables) == S
@@ -405,16 +514,10 @@ class DecodeEngine:
             tables[s].context_len = c + f
             ctx_lens.append(c + 1)
             fed_counts.append(f)
-        ctx = CacheContext(self.pool, 'decode', tables, ctx_lens,
-                           fed_counts=fed_counts, window=K)
+        coords = decode_coords(self.pool, tables, ctx_lens,
+                               fed_counts=fed_counts, window=K)
         t0 = clock.end('pack')
-        with self._model_lock or _NULL_LOCK:
-            with no_grad_guard():
-                logits = self.model(Tensor(ids, stop_gradient=True),
-                                    pos_ids=Tensor(pos, stop_gradient=True),
-                                    cache=ctx)
-                clock.end('forward')
-                rows = clock.fetch(logits)
+        rows = self._run(clock, 'decode', ids, pos, coords)
         dt = clock.last - t0
         clock.record()
         self._spec_compiled = True

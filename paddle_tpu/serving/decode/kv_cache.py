@@ -10,9 +10,10 @@ recycles them the moment a slot finishes, and the attention ops read
 through the block table so the cache never moves.
 
 Sizing happens ONCE at engine start (`PADDLE_TPU_DECODE_{SLOTS,BLOCK_SIZE,
-MAX_BLOCKS}`); per-layer arrays allocate lazily on the first prefill (head
-count / head dim are discovered from the model's first K projection, so the
-pool needs no model config duplicated into it).
+MAX_BLOCKS}`); per-layer arrays allocate lazily, before the engine's first
+call (head count / head dim are discovered from an abstract trace of the
+model's K projections, so the pool needs no model config duplicated into
+it) or by the first whole-block injection.
 
 Block 0 is the **scratch block**: never allocated, the padding target for
 inactive decode slots and short block tables. Writes to it are harmless
@@ -20,14 +21,22 @@ inactive decode slots and short block tables. Writes to it are harmless
 the XLA fallback, so stale block contents can never bleed between requests;
 tests/ops/test_paged_attention.py proves reuse-after-free is clean).
 
-Functional updates: jax arrays are immutable, so writes go through jitted
-scatters with the pool array DONATED — XLA updates in place instead of
-copying the pool per token (the same donation lever as PR 1's executor).
+Functional updates: jax arrays are immutable, so writes are scatters over a
+DONATED pool array — XLA updates in place instead of copying the pool per
+token (the same donation lever as PR 1's executor). There are two writers.
+An engine call (engine.py) is ONE jitted program: the pool's arrays are its
+donated arguments, `write_prefill` / `write_tokens` run inside its trace on
+a pool built over the traced arrays (:meth:`KVCachePool.over`), and the
+engine adopts the arrays the program returns; every write coordinate is a
+traced array (:func:`prefill_coords`, :func:`decode_coords`), so a program
+serves every request of its shape. Between engine calls `write_whole_blocks`
+(handoff, reinjection) scatters eagerly through the small jitted kernels
+below.
 
 Quantized storage (``kv_dtype``, docs/SERVING.md "Tiered KV cache"): the
-pools hold payload at ``f32`` (exact, the default — this path is
-bitwise-unchanged), ``bf16`` (half the bytes; decode reads cast back to
-f32 — an exact roundtrip for every representable value), or ``int8``
+pools hold payload at ``f32`` (exact, the default), ``bf16`` (half the
+bytes; decode reads cast back to f32 — an exact roundtrip for every
+representable value), or ``int8``
 (quarter the bytes: one symmetric int8 row + one f32 scale per
 (head, position) row via quant_collectives.rowwise_quantize — the PR 9/15
 sparse-push codec; KV rows and embedding rows are the same shape problem).
@@ -51,9 +60,9 @@ import numpy as np
 from ..errors import InvalidRequest, OutOfBlocks
 
 __all__ = ['BlockAllocator', 'BlockTable', 'KVCachePool', 'CacheContext',
-           'DEFAULT_SLOTS', 'DEFAULT_BLOCK_SIZE', 'DEFAULT_MAX_BLOCKS',
-           'SCRATCH_BLOCK', 'KV_PAYLOAD_DTYPES', 'KV_DTYPE_CODES',
-           'kv_row_bytes']
+           'prefill_coords', 'decode_coords', 'DEFAULT_SLOTS',
+           'DEFAULT_BLOCK_SIZE', 'DEFAULT_MAX_BLOCKS', 'SCRATCH_BLOCK',
+           'KV_PAYLOAD_DTYPES', 'KV_DTYPE_CODES', 'kv_row_bytes']
 
 DEFAULT_SLOTS = int(os.environ.get('PADDLE_TPU_DECODE_SLOTS', '8'))
 DEFAULT_BLOCK_SIZE = int(os.environ.get('PADDLE_TPU_DECODE_BLOCK_SIZE', '16'))
@@ -231,12 +240,13 @@ class KVCachePool:
 
     ``max_blocks_per_seq`` fixes the batched block-table width — and with
     it ``padded_context = max_blocks_per_seq * block_size``, the key extent
-    every attention read uses. The bitwise contract with whole-sequence
-    decode holds at exactly that padded length (see ops/nn_ops.py).
+    every attention read uses. The parity contract with whole-sequence
+    decode (engine.py) holds at exactly that padded length (see
+    ops/nn_ops.py).
     """
 
     def __init__(self, block_size=None, num_blocks=None,
-                 max_blocks_per_seq=None, dtype='float32', kv_dtype=None):
+                 max_blocks_per_seq=None, kv_dtype=None):
         self.block_size = int(block_size or DEFAULT_BLOCK_SIZE)
         self.num_blocks = int(num_blocks or DEFAULT_MAX_BLOCKS)
         self.max_blocks_per_seq = int(max_blocks_per_seq or 8)
@@ -246,10 +256,7 @@ class KVCachePool:
                 f'kv_dtype={kv_dtype!r} is not supported; supported values: '
                 + ', '.join(repr(c) for c in KV_PAYLOAD_DTYPES))
         self.kv_dtype = kv_dtype
-        # 'f32' keeps honoring the legacy ``dtype`` arg so the default path
-        # allocates EXACTLY the arrays it always did (bitwise contract)
-        self.dtype = dtype if kv_dtype == 'f32' else KV_PAYLOAD_DTYPES[
-            kv_dtype]
+        self.dtype = KV_PAYLOAD_DTYPES[kv_dtype]
         self.allocator = BlockAllocator(self.num_blocks)
         self._layers = {}          # layer idx -> [k_pages, v_pages]
         self._scales = {}          # int8 only: layer -> [k_scales, v_scales]
@@ -261,6 +268,37 @@ class KVCachePool:
     @property
     def num_layers(self):
         return len(self._layers)
+
+    @property
+    def geometry(self):
+        """Everything of the pool an engine program's trace depends on, as
+        the constructor's arguments: hashable, so it can key a compiled
+        program, and engines of equal geometry share executables."""
+        return (self.block_size, self.num_blocks, self.max_blocks_per_seq,
+                self.kv_dtype)
+
+    @classmethod
+    def over(cls, geometry, layers, scales):
+        """A pool of ``geometry`` over the given per-layer arrays: what the
+        inside of an engine program writes and reads. The arrays are the
+        program's traced arguments; the program returns :meth:`arrays` and
+        the engine's own pool :meth:`adopt` s them, so no tracer ever
+        reaches a pool that outlives the trace. Its allocator is unused."""
+        pool = cls(*geometry)
+        pool.adopt(layers, scales)
+        return pool
+
+    def arrays(self):
+        """(layers, scales): ``{layer: [k, v]}`` payload arrays and, for
+        int8 pools, their row scales (``{}`` otherwise). The pytrees an
+        engine program takes donated and returns."""
+        return self._layers, self._scales
+
+    def adopt(self, layers, scales):
+        """Take the arrays an engine program returned (the donated ones it
+        was given are deleted by then). jit hands out fresh containers, so
+        they are kept as they come."""
+        self._layers, self._scales = layers, scales
 
     def new_table(self, total_tokens):
         """Allocate a table holding ``total_tokens`` (prompt + budget).
@@ -321,31 +359,29 @@ class KVCachePool:
             total += sum(int(a.nbytes) for a in arrs)
         return total
 
-    def write_prefill(self, layer, table, k, v):
+    def write_prefill(self, layer, block_ids, k, v):
         """Write the prompt's K/V rows. ``k``/``v``: (H, L, D) — the bucket-
-        padded projections; rows land in the table's first
-        ``ceil(context/bs)`` blocks (tail rows inside the last block are
-        masked garbage until decode overwrites them).
+        padded projections; ``block_ids``: (ceil(L/bs),) int32 from
+        :func:`prefill_coords`, the table's first ``ceil(context/bs)`` blocks
+        and the scratch block for the rest (tail rows inside the last block
+        are masked garbage until decode overwrites them).
 
-        Every shape here is a function of the BUCKET length L alone: the
-        scatter always moves ``ceil(L/bs)`` blocks, and the ones past the
-        prompt's own blocks go to the scratch block. Were the count taken
-        from the prompt length, each new ``ceil(context/bs)`` would compile
-        its own slice, reshape and scatter on the first request that has it
-        — after warm-up, where chip_smoke.py counts none."""
+        Every shape here is a function of the BUCKET length L alone, and the
+        prompt's length reaches the scatter only as the values of
+        ``block_ids``: one prefill program per rung serves every prompt on
+        it. Were the block count taken from the prompt length, each new
+        ``ceil(context/bs)`` would compile again on the first request that
+        has it — after warm-up, where chip_smoke.py counts none."""
         import jax.numpy as jnp
         h, L, d = k.shape
         pages = self.ensure_layer(layer, h, d)
         nb = -(-L // self.block_size)
-        nb_w = min(-(-table.context_len // self.block_size),
-                   len(table.blocks), nb)
         target = nb * self.block_size
         if L < target:
             pad = ((0, 0), (0, target - L), (0, 0))
             k = jnp.pad(k, pad)
             v = jnp.pad(v, pad)
-        ids = np.asarray(table.blocks[:nb_w]
-                         + [SCRATCH_BLOCK] * (nb - nb_w), np.int32)
+        ids = jnp.asarray(block_ids, jnp.int32)
         kb = k.reshape(h, nb, self.block_size, d)
         vb = v.reshape(h, nb, self.block_size, d)
         kb, ks = self._encode_rows(kb)
@@ -361,10 +397,11 @@ class KVCachePool:
         """One decode step's K/V: ``k``/``v`` (H, S, D) written at
         (block_ids[s], offsets[s]) per slot. Inactive slots point at the
         scratch block."""
+        import jax.numpy as jnp
         h, s, d = k.shape
         pages = self.ensure_layer(layer, h, d)
-        ids = np.asarray(block_ids, np.int32)
-        offs = np.asarray(offsets, np.int32)
+        ids = jnp.asarray(block_ids, jnp.int32)
+        offs = jnp.asarray(offsets, jnp.int32)
         k, ks = self._encode_rows(k)
         v, vs = self._encode_rows(v)
         pages[0] = _scatter_tokens(pages[0], ids, offs, k)
@@ -440,61 +477,86 @@ class KVCachePool:
         return self.allocator.used / max(self.allocator.capacity, 1)
 
 
+def prefill_coords(pool, table, bucket):
+    """What a prefill program reads of one request, as host arrays whose
+    shapes depend on the rung and the pool's geometry alone:
+    ``block_tables`` (1, max_blocks_per_seq), the table padded with scratch,
+    and ``write_ids`` (ceil(bucket/bs),), the blocks `write_prefill` scatters
+    the bucket-padded K/V into: the table's first ``ceil(context/bs)``
+    blocks, then the scratch block for the rows past the prompt."""
+    bs = pool.block_size
+    nb = -(-int(bucket) // bs)
+    nb_w = min(-(-table.context_len // bs), len(table.blocks), nb)
+    return {'block_tables': np.asarray(
+                [table.padded(pool.max_blocks_per_seq)], np.int32),
+            'write_ids': np.asarray(
+                table.blocks[:nb_w] + [SCRATCH_BLOCK] * (nb - nb_w),
+                np.int32)}
+
+
+def decode_coords(pool, tables, context_lens, fed_counts=None, window=1):
+    """What a decode program reads of the S slots, as host arrays whose
+    shapes depend on S, ``window`` and the pool's geometry alone:
+    ``block_tables`` (S, max_blocks_per_seq), ``context_lens`` (S,) and the
+    flattened, slot-major write coordinates ``write_ids`` / ``write_offs``
+    (S·window,). ``tables[s] is None`` is an inactive slot: it reads and
+    writes the scratch block.
+
+    ``window`` K > 1 is the (S, K) step (speculative verify, chunked suffix
+    fill): slot s feeds ``fed_counts[s]`` ≤ K real tokens at positions
+    context_lens[s]-1 .. context_lens[s]-1+f-1, and its K-f padded lanes
+    write to the scratch block (harmless by the masking contract above).
+    ``context_lens[s]`` stays the extent of fed ROW 0; `paged_attention`'s
+    multi-query form gives row j the causal staircase extent
+    context_lens + j."""
+    if fed_counts is None:
+        fed_counts = [1 if t is not None else 0 for t in tables]
+    ids, offs, padded = [], [], []
+    for t, c, f in zip(tables, context_lens, fed_counts):
+        if t is None:                       # inactive slot
+            ids.extend([SCRATCH_BLOCK] * window)
+            offs.extend([0] * window)
+            padded.append([SCRATCH_BLOCK] * pool.max_blocks_per_seq)
+            continue
+        base = int(c) - 1          # first token written this step
+        for j in range(window):
+            if j < int(f):
+                b, o = t.slot_for(base + j)
+            else:                  # padded lane: scratch write
+                b, o = SCRATCH_BLOCK, 0
+            ids.append(b)
+            offs.append(o)
+        padded.append(t.padded(pool.max_blocks_per_seq))
+    return {'block_tables': np.asarray(padded, np.int32),
+            'write_ids': np.asarray(ids, np.int32),
+            'write_offs': np.asarray(offs, np.int32),
+            'context_lens': np.asarray(
+                [max(int(c), 1) for c in context_lens], np.int32)}
+
+
 class CacheContext:
     """The duck-typed ``cache=`` object MultiHeadAttention calls into
     (models/bert.py). One context per model forward; each attention layer's
-    ``attend(q, k, v, sm_scale=)`` call consumes the next layer index.
+    ``attend(q, k, v, sm_scale=)`` call consumes the next layer index. It
+    lives inside an engine program's trace: ``pool`` is a
+    :meth:`KVCachePool.over` the program's arrays and ``coords`` are the
+    traced arrays of :func:`prefill_coords` / :func:`decode_coords`.
 
     mode='prefill': q/k/v are (1, H, Lb, D) for one bucket-padded prompt —
     K/V are written into the request's blocks, attention runs causal over
     the paged view (`paged_prefill_attention`).
 
-    mode='decode': q/k/v are (S, H, 1, D), one token per slot — K/V land at
-    each slot's next position, attention reads through the batched block
-    tables (`paged_attention`) at fixed shape.
-
-    mode='decode' with ``window`` K > 1 (speculative verify — the (S, K)
-    step): q/k/v are (S, H, K, D); each slot feeds ``fed_counts[s]`` ≤ K
-    real tokens at positions context_len-1 .. context_len-1+f-1 and the
-    remaining K-f padded lanes write to the scratch block (harmless by the
-    masking contract above). ``context_lens[s]`` is still the extent of
-    fed ROW 0; `paged_attention`'s multi-query form gives row j the causal
-    staircase extent context_lens + j.
+    mode='decode': q/k/v are (S, H, K, D). K = 1 is the lockstep step, one
+    token per slot — K/V land at each slot's next position, attention reads
+    through the batched block tables (`paged_attention`) at fixed shape.
+    K > 1 is the multi-token window :func:`decode_coords` describes.
     """
 
-    def __init__(self, pool, mode, tables, context_lens=None,
-                 fed_counts=None, window=1):
+    def __init__(self, pool, mode, coords):
         self.pool = pool
         self.mode = mode
-        self.tables = tables          # prefill: [BlockTable]; decode: list
-        self.context_lens = context_lens
-        self.window = int(window)
+        self.coords = coords
         self._layer = 0
-        if mode == 'decode':
-            if fed_counts is None:
-                fed_counts = [1 if t is not None else 0 for t in tables]
-            ids, offs, padded = [], [], []
-            for t, c, f in zip(tables, context_lens, fed_counts):
-                if t is None:                       # inactive slot
-                    ids.extend([SCRATCH_BLOCK] * self.window)
-                    offs.extend([0] * self.window)
-                    padded.append([SCRATCH_BLOCK]
-                                  * pool.max_blocks_per_seq)
-                    continue
-                base = int(c) - 1          # first token written this step
-                for j in range(self.window):
-                    if j < int(f):
-                        b, o = t.slot_for(base + j)
-                    else:                  # padded lane: scratch write
-                        b, o = SCRATCH_BLOCK, 0
-                    ids.append(b)
-                    offs.append(o)
-                padded.append(t.padded(pool.max_blocks_per_seq))
-            self._write_ids = np.asarray(ids, np.int32)
-            self._write_offs = np.asarray(offs, np.int32)
-            self._batched_tables = np.asarray(padded, np.int32)
-            self._ctx = np.asarray(
-                [max(int(c), 1) for c in context_lens], np.int32)
 
     def _scale_inputs(self, layer):
         """Extra dispatch inputs for int8 pools ({} otherwise — the f32/bf16
@@ -508,50 +570,34 @@ class CacheContext:
         from ...dygraph.tape import Tensor, dispatch_op
         layer = self._layer
         self._layer += 1
+        c = self.coords
         kv = k.value if isinstance(k, Tensor) else k
         vv = v.value if isinstance(v, Tensor) else v
         if self.mode == 'prefill':
-            table = self.tables[0]
             # (1, H, L, D) -> (H, L, D) rows for the block scatter
-            self.pool.write_prefill(layer, table, kv[0], vv[0])
+            self.pool.write_prefill(layer, c['write_ids'], kv[0], vv[0])
             k_pages, v_pages = self.pool.pages(layer)
-            bt = np.asarray([table.padded(self.pool.max_blocks_per_seq)],
-                            np.int32)
             inputs = {'q': q, 'k': k, 'v': v, 'k_pages': k_pages,
-                      'v_pages': v_pages, 'block_tables': bt}
+                      'v_pages': v_pages, 'block_tables': c['block_tables']}
             inputs.update(self._scale_inputs(layer))
             return dispatch_op('paged_prefill_attention', inputs,
                                {'sm_scale': float(sm_scale)})
-        if self.window > 1:
-            # multi-token decode (speculative verify): (S, H, K, D) ->
-            # (H, S·K, D) rows, slot-major, matching the flattened write
-            # coordinates built above; q stays rank-4 for the multi-query
-            # paged_attention read
-            s, h, k_w, d = kv.shape
-            self.pool.write_tokens(
-                layer, self._write_ids, self._write_offs,
-                kv.transpose(1, 0, 2, 3).reshape(h, s * k_w, d),
-                vv.transpose(1, 0, 2, 3).reshape(h, s * k_w, d))
-            k_pages, v_pages = self.pool.pages(layer)
-            inputs = {'q': q, 'k_pages': k_pages, 'v_pages': v_pages,
-                      'block_tables': self._batched_tables,
-                      'context_lens': self._ctx}
-            inputs.update(self._scale_inputs(layer))
-            return dispatch_op('paged_attention', inputs,
-                               {'sm_scale': float(sm_scale)})
-        # decode: (S, H, 1, D) -> (H, S, D) token rows
-        self.pool.write_tokens(layer, self._write_ids, self._write_offs,
-                               kv[:, :, 0].transpose(1, 0, 2),
-                               vv[:, :, 0].transpose(1, 0, 2))
+        s, h, k_w, d = kv.shape
+        # (S, H, K, D) -> (H, S·K, D) rows, slot-major, matching the
+        # flattened write coordinates
+        self.pool.write_tokens(
+            layer, c['write_ids'], c['write_offs'],
+            kv.transpose(1, 0, 2, 3).reshape(h, s * k_w, d),
+            vv.transpose(1, 0, 2, 3).reshape(h, s * k_w, d))
         k_pages, v_pages = self.pool.pages(layer)
-        q3 = dispatch_op('reshape', {'x': q},
-                         {'shape': [q.shape[0], q.shape[1], q.shape[3]]})
-        inputs = {'q': q3, 'k_pages': k_pages, 'v_pages': v_pages,
-                  'block_tables': self._batched_tables,
-                  'context_lens': self._ctx}
+        inputs = {'k_pages': k_pages, 'v_pages': v_pages,
+                  'block_tables': c['block_tables'],
+                  'context_lens': c['context_lens']}
         inputs.update(self._scale_inputs(layer))
-        out = dispatch_op('paged_attention', inputs,
-                          {'sm_scale': float(sm_scale)})
-        return dispatch_op('reshape', {'x': out},
-                           {'shape': [q.shape[0], q.shape[1], 1,
-                                      q.shape[3]]})
+        attrs = {'sm_scale': float(sm_scale)}
+        if k_w > 1:
+            # q stays rank-4 for the multi-query paged_attention read
+            return dispatch_op('paged_attention', dict(inputs, q=q), attrs)
+        q3 = dispatch_op('reshape', {'x': q}, {'shape': [s, h, d]})
+        out = dispatch_op('paged_attention', dict(inputs, q=q3), attrs)
+        return dispatch_op('reshape', {'x': out}, {'shape': [s, h, 1, d]})

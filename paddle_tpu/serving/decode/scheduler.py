@@ -430,7 +430,7 @@ class DecodeScheduler:
             # prefix-cache hit: the front of the table is already-filled
             # shared blocks; the uncached suffix rides the SAME lockstep
             # decode step as everyone else's generation (chunked prefill —
-            # bitwise-identical rows by the PR 6 parity contract), so a
+            # the same program, so the same rows as any other step), so a
             # long shared prompt costs only its suffix
             req.table.context_len = cached
             req.next_token = req.prompt[cached]
@@ -635,8 +635,10 @@ class DecodeScheduler:
         Greedy slots feed their pending token plus up to k-1 drafter
         guesses; the target model's (S, k, V) rows verify them all in ONE
         step and the longest prefix the target agrees with is emitted
-        (rows are bitwise-identical to the lockstep rows, so the emitted
-        stream equals non-speculative greedy exactly). Rejected tails roll
+        (row j is the lockstep step's row at the same context, computed by
+        the (S, k) program: equal up to its rounding, and the emitted
+        stream equals non-speculative greedy in every test, engine.py's
+        contract). Rejected tails roll
         the block table back — one integer store; the stale K/V positions
         are masked until overwritten (kv_cache scratch contract). Sampled
         slots ride the same batched step with a single fed token (their

@@ -209,7 +209,8 @@ decode_engine_phase_seconds = _LazyMetric(
     'histogram', 'decode_engine_phase_seconds',
     'wall seconds per phase of one engine call (labels call=prefill|step|'
     'spec_step, phase=pack|forward|device_wait|logits_copy|sample); the '
-    'phases of a call tile it')
+    'phases of a call tile it: forward is the dispatch of the call\'s one '
+    'XLA program, device_wait the device running it')
 decode_logits_bytes_copied = _LazyMetric(
     'counter', 'decode_logits_bytes_copied',
     'bytes of logits copied from the device to the host by engine calls')
@@ -230,7 +231,8 @@ decode_tokens_generated = _LazyMetric(
     'tokens emitted to generation streams (rate = tokens/s)')
 decode_prefill_compiles = _LazyMetric(
     'counter', 'decode_prefill_compiles',
-    'prefill bucket shapes compiled (bounded by the prompt ladder length)')
+    'prefill rungs an engine ran for the first time, each one program '
+    '(bounded by the prompt ladder length)')
 
 # speculative decoding (engine.spec_step + scheduler verify loop); accept
 # length per round is a small integer — linear buckets up to the window

@@ -20,11 +20,12 @@ uses, and what the parity tests pin); :meth:`KVPayload.to_bytes` /
 ships, so a network hop slots in behind the same
 ``submit``/``drain_completed`` contract without touching the scheduler.
 
-Bitwise parity: the prefill engine runs the SAME model weights and the same
-bucket-padded matmul formulation, so the shipped K/V bytes equal what a
-colocated prefill would have written — the decode trajectory is
-``array_equal``-identical to colocated and to the uncached whole-sequence
-reference (tests/framework/test_disagg.py).
+Parity: the prefill engine runs the SAME model weights through the same
+prefill program (engine.py keeps the programs per model, keyed by the
+pool's geometry), so the shipped K/V bytes are what a colocated prefill
+would have written, and the handoff itself moves them byte for byte — the
+decoded token stream equals colocated's and the uncached whole-sequence
+reference's (tests/framework/test_disagg.py).
 """
 from __future__ import annotations
 

@@ -13,13 +13,15 @@ valid for ANY prompt sharing that prefix — and block granularity means a hit
 plugs straight into the request's :class:`~..decode.kv_cache.BlockTable`
 with zero copying.
 
-Bitwise-parity design (the load-bearing PR 6 contract): the uncached suffix
-is NOT run through a second prefill formulation — the scheduler feeds the
+Parity design (the load-bearing PR 6 contract): the uncached suffix is NOT
+run through a second prefill formulation — the scheduler feeds the
 remaining prompt tokens through the SAME lockstep ``(S, 1)`` decode step
-used for generation (chunked prefill), whose logits rows are already proven
-``array_equal`` to the whole-sequence forward at ``padded_context``. A
-cached-hit generation therefore emits exactly the cold generation's bytes,
-and the parity suite (tests/framework/test_prefix_cache.py) asserts it.
+used for generation (chunked prefill), whose logits rows are held to the
+whole-sequence forward at ``padded_context`` (engine.py: equal token
+streams, rows within a tolerance). A cached-hit generation emits the cold
+generation's tokens, and the parity suite
+(tests/framework/test_prefix_cache.py) asserts it; the cached blocks
+themselves are byte-for-byte what the cold request wrote.
 
 Host spill tier (docs/SERVING.md "Tiered KV cache"): with
 ``PADDLE_TPU_PREFIX_CACHE_HOST_MB`` > 0, an idle block that would be

@@ -239,3 +239,35 @@ def test_train_step_hits_in_a_second_process(children):
     first, second = children['first']['train'], children['second']['train']
     assert first['misses'] > 0 and first['hits'] == 0, (first, second)
     assert second['hits'] > 0 and second['misses'] == 0, (first, second)
+
+
+def test_program_locations_do_not_move_with_the_call_stack():
+    """A pallas kernel's cache key holds its Mosaic body with every MLIR
+    location in it, so a location that is the whole Python traceback (jax's
+    default) makes the key follow the caller: found on the chip, where the
+    decode engine's prefill programs with the flash kernel missed their own
+    cached executables in every process that ran with telemetry on
+    (tape.dispatch_op calls through another line there). With the cache set
+    up a location is the innermost frame alone: the same function lowered
+    from two call sites gives the same text, debug info included."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.core.compile_cache import setup_persistent_cache
+    assert setup_persistent_cache()
+    assert not jax.config.jax_include_full_tracebacks_in_locations
+
+    def lowered():
+        # a fresh function each time: jit would hand a second lowering of
+        # the same one the first one's trace, locations and all
+        return jax.jit(lambda v: jnp.sin(v) * 2).lower(jnp.ones(3))
+
+    def one_call_site():
+        return lowered().as_text(debug_info=True)
+
+    def another_call_site():
+        text = lowered().as_text(debug_info=True)
+        return text
+
+    text = one_call_site()
+    assert 'test_compile_cache.py' in text       # locations are still there
+    assert text == another_call_site()

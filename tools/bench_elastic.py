@@ -128,10 +128,12 @@ def bench_autoscale_ramp(smoke):
             with results_lock:
                 errors.append(f'{type(e).__name__}: {e}')
 
-    # open-loop Poisson arrivals: low -> high -> zero
+    # open-loop Poisson arrivals: low -> high -> zero. The high rate has to
+    # queue requests behind one replica's two slots: an engine call of the
+    # tiny model is one ~1 ms program, a 4-token request ~5 ms
     rng = np.random.default_rng(0)
-    phases = ([(2.0, 1.5), (10.0, 3.0)] if smoke
-              else [(2.0, 3.0), (12.0, 6.0)])
+    phases = ([(2.0, 1.5), (80.0, 3.0)] if smoke
+              else [(2.0, 3.0), (100.0, 6.0)])
     arrivals, t = [], 0.0
     for rate, dur in phases:
         end = t + dur
