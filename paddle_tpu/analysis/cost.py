@@ -501,6 +501,85 @@ def _c_paged_attention(ctx):
 
 
 # ---------------------------------------------------------------------------
+# rules: the modern decoder block (ops/llm_ops.py). Bytes stay generic; the
+# FLOPs are the mathematics' (a grouped expert matmul prices the
+# assignments it is given, not experts x tokens).
+# ---------------------------------------------------------------------------
+
+@cost_rule('rms_norm')
+def _c_rms_norm(ctx):
+    return 4 * ctx.in_elems('x')
+
+
+@cost_rule('rope')
+def _c_rope(ctx):
+    # sin and cos per pair, 6 multiply-adds per pair
+    return (TRANSCENDENTAL_FLOPS + 3) * ctx.in_elems('x')
+
+
+@cost_rule('lm_head')
+def _c_lm_head(ctx):
+    return 2 * ctx.in_elems('x') * _pdim(ctx.input('w'), 1, ctx.assume_dim)
+
+
+@cost_rule('swiglu_ffn')
+def _c_swiglu_ffn(ctx):
+    f = _pdim(ctx.input('w_gate'), 1, ctx.assume_dim)
+    rows = ctx.in_elems('x') // max(1, _pdim(ctx.input('w_gate'), 0,
+                                            ctx.assume_dim))
+    return 6 * ctx.in_elems('x') * f + (TRANSCENDENTAL_FLOPS + 1) * rows * f
+
+
+@cost_rule('moe_router')
+def _c_moe_router(ctx):
+    experts = _pdim(ctx.input('w_gate'), 1, ctx.assume_dim)
+    tokens = _pdim(ctx.input('x'), 0, ctx.assume_dim)
+    return 2 * ctx.in_elems('x') * experts \
+        + TRANSCENDENTAL_FLOPS * tokens * experts
+
+
+@cost_rule('moe_experts')
+def _c_moe_experts(ctx):
+    a = ctx.assume_dim
+    gate = ctx.input('w_gate')
+    h, f = _pdim(gate, 1, a), _pdim(gate, 2, a)
+    assignments = ctx.in_elems('ids')
+    return assignments * (6 * h * f + (TRANSCENDENTAL_FLOPS + 1) * f + 2 * h)
+
+
+def _mla_dims(ctx):
+    a = ctx.assume_dim
+    q, w = ctx.input('q'), ctx.input('w_kvb')
+    return (_pdim(q, 0, a), _pdim(q, 1, a), _pdim(q, 2, a), _pdim(q, 3, a),
+            _pdim(w, 0, a), int(ctx.attr('qk_nope_dim', 0)),
+            int(ctx.attr('v_dim', 0)))
+
+
+@cost_rule('mla_prefill_attention')
+def _c_mla_prefill(ctx):
+    b, length, heads, qk, rank, nope, v = _mla_dims(ctx)
+    expand = 2 * b * length * rank * heads * (nope + v)
+    attend = b * heads * length * length * (
+        2 * qk + 2 * v + TRANSCENDENTAL_FLOPS + 2)
+    return expand + attend
+
+
+@cost_rule('mla_decode_attention')
+def _c_mla_decode(ctx):
+    # absorbed: W_UK into the query, the padded extent of latent rows read
+    # once for all heads, W_UV after the sum
+    s, k, heads, qk, rank, nope, v = _mla_dims(ctx)
+    a = ctx.assume_dim
+    pages, tables = ctx.input('pages'), ctx.input('block_tables')
+    t_pad = _pdim(tables, 1, a) * _pdim(pages, 1, a)
+    rope = qk - nope
+    queries = s * k * heads
+    return queries * (2 * nope * rank + 2 * rank * v
+                      + t_pad * (2 * (rank + rope) + 2 * rank
+                                 + TRANSCENDENTAL_FLOPS + 2))
+
+
+# ---------------------------------------------------------------------------
 # fallback coverage: every remaining op type with an INFER rule gets a
 # bytes-only cost rule so the registries stay coverage-aligned (the tier-1
 # coverage test asserts infer rules ⊆ cost rules); genuinely-unknown op

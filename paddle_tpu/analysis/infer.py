@@ -1407,6 +1407,131 @@ def _paged_prefill_attention(ctx):
 
 
 # ---------------------------------------------------------------------------
+# rules: the modern decoder block (ops/llm_ops.py)
+# ---------------------------------------------------------------------------
+
+def _dim(info, i):
+    """Dim ``i`` (negative from the end) of a VarInfo, UNKNOWN if the rank
+    is."""
+    if info is None or info.shape is None:
+        return UNKNOWN
+    return info.shape[i]
+
+
+def _mul_dims(a, b):
+    return a * b if known(a) and known(b) else UNKNOWN
+
+
+def _contracts(what, x_dim, w_dim):
+    if not dims_agree(x_dim, w_dim):
+        raise InferError(f'{what}: contraction dims differ: {x_dim} vs '
+                         f'{w_dim}')
+
+
+@infer_rule('rms_norm')
+def _rms_norm(ctx):
+    x = ctx.require('x')
+    _contracts('rms_norm x against scale', _dim(x, -1),
+               _dim(ctx.require('scale'), -1))
+    return {'Out': VarInfo(x.shape, x.dtype)}
+
+
+@infer_rule('rope')
+def _rope(ctx):
+    x = ctx.require('x')
+    if x.shape is not None and len(x.shape) not in (3, 4):
+        raise InferError(f'rope expects x of rank 3 (B, S, D) or 4 '
+                         f'(B, S, H, D), got rank {len(x.shape)}')
+    rotated = _dim(x, -1)
+    if known(rotated) and (rotated - int(ctx.attr('nope_dim', 0))) % 2:
+        raise InferError(
+            f'rope turns pairs of lanes: {rotated} lanes less nope_dim='
+            f'{ctx.attr("nope_dim", 0)} is odd', kind='bad-attr')
+    return {'Out': VarInfo(x.shape, x.dtype)}
+
+
+@infer_rule('lm_head')
+def _lm_head(ctx):
+    x, w = ctx.require('x'), ctx.require('w')
+    _contracts('lm_head', _dim(x, -1), _dim(w, 0))
+    shape = None if x.shape is None else tuple(x.shape[:-1]) + (_dim(w, 1),)
+    return {'Out': VarInfo(shape, 'float32')}
+
+
+@infer_rule('swiglu_ffn')
+def _swiglu_ffn(ctx):
+    x = ctx.require('x')
+    for slot in ('w_gate', 'w_up'):
+        _contracts(f'swiglu_ffn x against {slot}', _dim(x, -1),
+                   _dim(ctx.require(slot), 0))
+    _contracts('swiglu_ffn w_down against x', _dim(ctx.require('w_down'), 1),
+               _dim(x, -1))
+    return {'Out': VarInfo(x.shape, x.dtype)}
+
+
+@infer_rule('moe_router')
+def _moe_router(ctx):
+    x, w = ctx.require('x'), ctx.require('w_gate')
+    _contracts('moe_router', _dim(x, -1), _dim(w, 0))
+    _contracts('moe_router bias against experts', _dim(ctx.require('bias'), 0),
+               _dim(w, 1))
+    k = int(ctx.require_attr('top_k'))
+    if known(_dim(w, 1)) and not 0 < k <= _dim(w, 1):
+        raise InferError(f'moe_router top_k={k} of {_dim(w, 1)} experts',
+                         kind='bad-attr')
+    return {'Ids': VarInfo((_dim(x, 0), k), 'int32'),
+            'Weights': VarInfo((_dim(x, 0), k), 'float32')}
+
+
+@infer_rule('moe_experts')
+def _moe_experts(ctx):
+    x, gate = ctx.require('x'), ctx.require('w_gate')
+    if gate.shape is not None and len(gate.shape) != 3:
+        raise InferError(f'moe_experts expects w_gate of rank 3 (E, h, f), '
+                         f'got rank {len(gate.shape)}')
+    _contracts('moe_experts x against w_gate', _dim(x, -1), _dim(gate, 1))
+    _contracts('moe_experts w_down against x', _dim(ctx.require('w_down'), 2),
+               _dim(x, -1))
+    return {'Out': VarInfo(x.shape, x.dtype),
+            'Counts': VarInfo((_dim(gate, 0),), 'int32')}
+
+
+def _mla_out(ctx, what):
+    """(tokens..., H · v_dim) of a latent attention op, q (.., .., H, D)."""
+    q, w = ctx.require('q'), ctx.require('w_kvb')
+    if q.shape is not None and len(q.shape) != 4:
+        raise InferError(f'{what} expects q of rank 4, got rank '
+                         f'{len(q.shape)}')
+    nope, v_dim = (int(ctx.require_attr('qk_nope_dim')),
+                   int(ctx.require_attr('v_dim')))
+    heads = _dim(q, 2)
+    _contracts(f'{what} w_kvb against heads x (nope + v)', _dim(w, 1),
+               _mul_dims(heads, nope + v_dim))
+    return {'Out': VarInfo((_dim(q, 0), _dim(q, 1),
+                            _mul_dims(heads, v_dim)), q.dtype)}
+
+
+@infer_rule('mla_prefill_attention')
+def _mla_prefill_attention(ctx):
+    rank, width = _dim(ctx.require('w_kvb'), 0), _dim(ctx.require('latent'),
+                                                      -1)
+    if known(rank) and known(width) and width <= rank:
+        raise InferError(f'mla_prefill_attention: latent rows of {width} '
+                         f'hold no rotary part beside rank {rank}')
+    return _mla_out(ctx, 'mla_prefill_attention')
+
+
+@infer_rule('mla_decode_attention')
+def _mla_decode_attention(ctx):
+    pages = ctx.require('pages')
+    if pages.shape is not None and len(pages.shape) != 3:
+        raise InferError(
+            f'mla_decode_attention expects pages of rank 3 (blocks, block, '
+            f'row width), got rank {len(pages.shape)}')
+    return _mla_out(ctx, 'mla_decode_attention')
+
+
+# ---------------------------------------------------------------------------
 # rules: framework-internal ops
 # ---------------------------------------------------------------------------
 

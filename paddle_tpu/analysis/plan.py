@@ -511,35 +511,40 @@ def _model_state_bytes(model):
 
 
 def _decode_kv_geometry(model):
-    """(n_layers, n_heads, head_dim) of the model's KV cache, from the
-    causal_lm config contract (``model.cfg.{num_hidden_layers,
-    num_attention_heads, hidden_size}``). Raises a ValueError naming what
-    is missing — a budget solve over unknown geometry would silently size
-    the pool wrong."""
-    cfg = getattr(model, 'cfg', None)
-    try:
-        n_layers = int(cfg.num_hidden_layers)
-        n_heads = int(cfg.num_attention_heads)
-        head_dim = int(cfg.hidden_size) // n_heads
-    except (TypeError, AttributeError):
+    """What the model caches per token per layer, asked of the model
+    (``model.kv_cache_spec()``: ``{'kind': 'kv', 'layers', 'heads',
+    'head_dim'}`` for K and V rows of every head, ``{'kind': 'latent',
+    'layers', 'row_width'}`` for one latent row). Raises a ValueError
+    naming what is missing — a budget solve over unknown geometry would
+    silently size the pool wrong."""
+    spec = getattr(model, 'kv_cache_spec', None)
+    if spec is None:
         raise ValueError(
-            'decode-pool budget solve needs model.cfg with '
-            'num_hidden_layers / num_attention_heads / hidden_size '
-            '(the models/causal_lm.py config contract); pass an explicit '
-            'max_blocks / PADDLE_TPU_DECODE_MAX_BLOCKS for models '
-            'without it')
-    return n_layers, n_heads, head_dim
+            'decode-pool budget solve needs model.kv_cache_spec() (the '
+            'models/causal_lm.py and models/latent_moe_lm.py contract); '
+            'pass an explicit max_blocks / PADDLE_TPU_DECODE_MAX_BLOCKS '
+            'for models without it')
+    return spec()
+
+
+def decode_token_layer_bytes(model, kv_dtype='f32'):
+    """HBM bytes ONE token's cached state costs in ONE layer: K and V rows
+    of every head, or one latent row in the lanes the pool gives it, each
+    priced by kv_cache.kv_row_bytes at the storage dtype (int8 rows carry
+    their f32 scale; a latent row has no int8 form)."""
+    from ..serving.decode.kv_cache import kv_row_bytes, latent_row_lanes
+    spec = _decode_kv_geometry(model)
+    if spec['kind'] == 'latent':
+        if kv_dtype == 'int8':
+            raise ValueError('a latent KV cache has no int8 rows')
+        return kv_row_bytes(latent_row_lanes(spec['row_width']), kv_dtype)
+    return 2 * spec['heads'] * kv_row_bytes(spec['head_dim'], kv_dtype)
 
 
 def decode_pool_block_bytes(model, block_size, kv_dtype='f32'):
-    """HBM bytes ONE KV-cache block costs across every layer: K and V,
-    ``n_heads × block_size`` rows per layer, each row priced by
-    kv_cache.kv_row_bytes at the storage dtype (int8 rows carry their f32
-    scale)."""
-    from ..serving.decode.kv_cache import kv_row_bytes
-    n_layers, n_heads, head_dim = _decode_kv_geometry(model)
-    return (n_layers * 2 * n_heads * int(block_size)
-            * kv_row_bytes(head_dim, kv_dtype))
+    """HBM bytes ONE KV-cache block costs across every layer."""
+    return (_decode_kv_geometry(model)['layers'] * int(block_size)
+            * decode_token_layer_bytes(model, kv_dtype))
 
 
 def solve_decode_pool_blocks(model, hbm_mb, block_size, kv_dtype='f32',
@@ -565,8 +570,7 @@ def decode_pool_report(model, hbm_mb, block_size, kv_dtype='f32',
                        min_blocks=2):
     """The solve, itemized for tools/plan_program.py — every term of the
     closed form inspectable next to the resulting block count."""
-    n_layers, n_heads, head_dim = _decode_kv_geometry(model)
-    from ..serving.decode.kv_cache import kv_row_bytes
+    spec = _decode_kv_geometry(model)
     state = _model_state_bytes(model)
     block_bytes = decode_pool_block_bytes(model, block_size, kv_dtype)
     blocks = solve_decode_pool_blocks(model, hbm_mb, block_size, kv_dtype,
@@ -576,10 +580,9 @@ def decode_pool_report(model, hbm_mb, block_size, kv_dtype='f32',
         'kv_dtype': kv_dtype,
         'block_size': int(block_size),
         'model_state_bytes': state,
-        'kv_layers': n_layers,
-        'kv_heads': n_heads,
-        'head_dim': head_dim,
-        'row_bytes': kv_row_bytes(head_dim, kv_dtype),
+        'kv_layers': spec['layers'],
+        'kv_cache': spec,
+        'row_bytes': decode_token_layer_bytes(model, kv_dtype),
         'block_bytes': block_bytes,
         'num_blocks': int(blocks),
         'pool_bytes': int(blocks) * block_bytes,

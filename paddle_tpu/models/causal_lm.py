@@ -88,6 +88,15 @@ class TransformerLM(Layer):
     def num_cache_layers(self):
         return self.cfg.num_hidden_layers
 
+    def kv_cache_spec(self):
+        """What the decode pool holds of this model: K and V rows of every
+        head, per token per layer (serving/decode/kv_cache.py,
+        analysis/plan.py)."""
+        cfg = self.cfg
+        return {'kind': 'kv', 'layers': cfg.num_hidden_layers,
+                'heads': cfg.num_attention_heads,
+                'head_dim': cfg.hidden_size // cfg.num_attention_heads}
+
     def forward(self, input_ids, pos_ids=None, cache=None):
         """``input_ids`` (B, S) → logits (B, S, V). ``pos_ids`` defaults to
         0..S-1 per row; the decode engine passes each slot's context

@@ -56,7 +56,7 @@ from ...core.compile_cache import setup_persistent_cache
 from ...dygraph.jit import _bind
 from ...dygraph.tape import Tensor, no_grad_guard
 from ..engine import bucket_ladder
-from ..errors import InvalidRequest
+from ..errors import InvalidRequest, UnsupportedCacheFeature
 from .kv_cache import (CacheContext, KVCachePool, decode_coords,
                        prefill_coords, DEFAULT_BLOCK_SIZE,
                        DEFAULT_MAX_BLOCKS, DEFAULT_SLOTS)
@@ -70,7 +70,7 @@ class _Program:
     """``model`` as the pure function every engine call runs, jitted:
 
         run(mode, geometry, params, buffers, layers, scales,
-            ids, pos, coords, last) -> (rows, layers, scales)
+            ids, pos, coords, last) -> (rows, stats, layers, scales)
 
     ``mode`` ('prefill' | 'decode') and the pool's ``geometry`` are static;
     ``layers`` / ``scales`` (the pool's arrays) are donated and come back
@@ -80,7 +80,10 @@ class _Program:
     the pool it writes is a `KVCachePool.over` the traced arrays: nothing
     traced outlives the trace. ``rows`` is what the host needs and no more:
     prefill (1, L) -> row ``last`` (V,); decode (S, 1) -> (S, V); decode
-    (S, K) -> (S, K, V).
+    (S, K) -> (S, K, V). ``stats`` is what the forward noted on its
+    `CacheContext` for the host, stacked per name and otherwise untouched
+    (a model with routed experts: the rows each expert was given and the
+    experts of the scored rows; else empty).
 
     One per model object (:meth:`of`), shared by every engine over it: jit's
     own cache then holds one executable per (mode, geometry, shapes)."""
@@ -106,7 +109,7 @@ class _Program:
         def run(mode, geometry, pvals, bvals, layers, scales, ids, pos,
                 coords, last):
             pool = KVCachePool.over(geometry, layers, scales)
-            ctx = CacheContext(pool, mode, coords)
+            ctx = CacheContext(pool, mode, coords, last)
             if pos is not None:
                 pos = Tensor(pos, stop_gradient=True)
             with _bind(params, pvals), _bind(buffers, bvals), \
@@ -114,20 +117,28 @@ class _Program:
                 logits = model_ref()(Tensor(ids, stop_gradient=True),
                                      pos_ids=pos, cache=ctx).value
             if mode == 'prefill':
-                rows = jax.lax.dynamic_index_in_dim(logits[0], last, 0,
-                                                    keepdims=False)
+                # a model may have scored row `last` alone: (1, 1, V)
+                rows = jax.lax.dynamic_index_in_dim(
+                    logits[0], jax.numpy.minimum(last, logits.shape[1] - 1),
+                    0, keepdims=False)
             elif ids.shape[1] == 1:
                 rows = logits[:, 0]
             else:
                 rows = logits
-            return (rows,) + pool.arrays()
+            # what the forward noted for the host (`CacheContext.note`)
+            stats = {name: jax.numpy.stack(values)
+                     for name, values in ctx.stats.items()}
+            return (rows, stats) + pool.arrays()
 
         self.jitted = jax.jit(run, static_argnums=(0, 1),
                               donate_argnums=(4, 5))
 
     def __call__(self, pool, mode, ids, pos, coords, last=None):
-        """Run one engine call's program over ``pool`` and return the rows
-        (a device array: the call is enqueued, not finished)."""
+        """Run one engine call's program over ``pool`` and return its rows
+        and the forward's ``stats`` (for a model with routed experts
+        ``expert_counts`` (layers, E) and ``expert_ids`` (layers, scored
+        rows, k), else empty), all device arrays: the call is enqueued, not
+        finished."""
         fn = functools.partial(
             self.jitted, mode, pool.geometry,
             {n: p.value for n, p in self._params.items()},
@@ -136,12 +147,27 @@ class _Program:
             # the pool allocates here, before the first trace that takes its
             # arrays as arguments: an abstract trace over an empty pool
             # returns the arrays `ensure_layer` would make
-            shapes = jax.eval_shape(fn, {}, {}, ids, pos, coords, last)[1]
-            for layer, (k, _) in shapes.items():
-                pool.ensure_layer(layer, k.shape[0], k.shape[3])
-        rows, layers, scales = fn(*pool.arrays(), ids, pos, coords, last)
+            pool.allocate(*jax.eval_shape(fn, {}, {}, ids, pos, coords,
+                                          last)[2:])
+        rows, stats, layers, scales = fn(*pool.arrays(), ids, pos, coords,
+                                         last)
         pool.adopt(layers, scales)
-        return rows
+        return rows, stats
+
+
+def _first_max(rows):
+    """``rows.argmax(-1)``, NaN rows included (the first NaN's index).
+    Written as a max and a compare because on the rows ``np.asarray`` hands
+    back from the device (read-only, 16-byte aligned) numpy's float argmax
+    takes a path ten times slower: 80 ms against 7.5 for (128, 128,256)
+    float32 on the v5e's host, 3 ms on a copy that numpy allocated (PERF.md
+    section 6, PR 26)."""
+    top = rows.max(-1, keepdims=True)
+    out = (rows == top).argmax(-1)
+    nan = np.isnan(top[..., 0])
+    if nan.any():       # a NaN equals nothing, its row's maximum included
+        out[nan] = rows[nan].argmax(-1)
+    return out
 
 
 class _CallClock:
@@ -153,18 +179,24 @@ class _CallClock:
     compile too, the first time a shape is seen), device_wait
     (``block_until_ready`` on the rows: host idle, the device running the
     program — the device's time for the call), logits_copy (the rows,
-    device to host), sample (host argmax or the request's sampler).
+    device to host, and the few kB of counts the counters read), sample
+    (host argmax or the request's sampler).
 
     ``record`` is the one place the stamps are read: always one
     observation per phase into ``decode_engine_phase_seconds``, and with
     telemetry on the ``engine/<call>`` span and its ``engine/<call>/<phase>``
-    children from the same stamps. O(1) per call."""
+    children from the same stamps. The span's args carry the call's ``work``
+    (expert assignments, experts touched, context positions read: what the
+    counters were given for this call), so that a trace reader can set the
+    device time of a slice against the work of the calls in it. O(1) per
+    call."""
 
-    __slots__ = ('call', 'start', 'last', 'ends')
+    __slots__ = ('call', 'start', 'last', 'ends', 'work')
 
     def __init__(self, call):
         self.call = call
         self.ends = []                  # [(phase, perf_counter at its end)]
+        self.work = {}                  # args of the call's span: its work
         self.start = self.last = time.perf_counter()
 
     def end(self, phase):
@@ -172,15 +204,18 @@ class _CallClock:
         self.ends.append((phase, self.last))
         return self.last
 
-    def fetch(self, rows):
+    def fetch(self, rows, counts=None):
         """The rows on the host, with the wait for the device and the copy
-        stamped apart (``np.asarray`` alone is both at once)."""
+        stamped apart (``np.asarray`` alone is both at once); ``counts``,
+        a small array of the same program, is copied in the same phase."""
         rows.block_until_ready()
         self.end('device_wait')
         host = np.asarray(rows)
+        if counts is not None:
+            counts = np.asarray(counts)
         self.end('logits_copy')
         _m.decode_logits_bytes_copied.inc(host.nbytes)
-        return host
+        return host, counts
 
     def record(self, **args):
         hist = _m.decode_engine_phase_seconds
@@ -193,7 +228,8 @@ class _CallClock:
                 _obs.tracer.complete(f'{name}/{phase}', t, end)
             t = end
         if spans:
-            _obs.tracer.complete(name, self.start, self.last, **args)
+            _obs.tracer.complete(name, self.start, self.last, **self.work,
+                                 **args)
 
 
 class DecodeEngine:
@@ -230,6 +266,14 @@ class DecodeEngine:
         # tape's no_grad flag is process-global). None = zero overhead.
         self._model_lock = model_lock
         self._program = _Program.of(model)
+        # what the model caches per token per layer: [k, v] rows per head
+        # (the default), or one latent row (models/latent_moe_lm.py)
+        spec = getattr(model, 'kv_cache_spec', None)
+        self.cache_kind = spec()['kind'] if spec else 'kv'
+        # the last call's ``stats`` as its program returned them (device
+        # arrays, read by whoever asks: nothing is copied on the served
+        # path): ``expert_ids`` says which experts made the rows just read
+        self.last_stats = {}
         self.slots = int(slots or DEFAULT_SLOTS)
         self.max_prompt_len = int(max_prompt_len)
         self.max_new_tokens_cap = int(max_new_tokens_cap)
@@ -295,6 +339,13 @@ class DecodeEngine:
             self.prefix_cache = PrefixCache(self.pool)
         else:
             self.prefix_cache = prefix_cache
+        if self.cache_kind == 'latent':
+            asked = [name for name, on in (
+                ('the prefix cache (and its spill and reinject)',
+                 self.prefix_cache is not None),
+                ('kv_dtype=int8', kv_dtype == 'int8')) if on]
+            if asked:
+                raise UnsupportedCacheFeature(asked, 'latent')
 
     @staticmethod
     def _resolve_num_blocks(model, max_blocks, block_size, max_bps,
@@ -386,9 +437,30 @@ class DecodeEngine:
         model with its parameters bound to tracers, which a second engine
         over the same model (serving/tier/disagg.py) must not see."""
         with self._model_lock or _NULL_LOCK:
-            rows = self._program(self.pool, mode, ids, pos, coords, last)
+            rows, stats = self._program(self.pool, mode, ids, pos, coords,
+                                        last)
             clock.end('forward')
-            return clock.fetch(rows)
+            host, counts = clock.fetch(rows, stats.get('expert_counts'))
+        self.last_stats = stats
+        if counts is not None:
+            clock.work.update(self._account_experts(clock.call, counts))
+        return host
+
+    @staticmethod
+    def _account_experts(call, counts):
+        """One engine call's routing, from the (layers, E) rows each expert
+        was given of the call's live tokens (a rung's padding and idle
+        slots are routed and computed too, and not counted: the counters
+        hold the work the mathematics needs): assignments, experts that
+        got at least one row, and the worst layer's largest load over its
+        mean."""
+        work = {'expert_assignments': int(counts.sum()),
+                'experts_touched': int((counts > 0).sum())}
+        _m.decode_expert_assignments.inc(work['expert_assignments'])
+        _m.decode_experts_touched.inc(work['experts_touched'])
+        _m.decode_expert_load_max_over_mean.labels(call=call).observe(
+            float((counts.max(1) / np.maximum(counts.mean(1), 1e-9)).max()))
+        return work
 
     def compiled_programs(self):
         """Executables held for this engine's MODEL, over every engine and
@@ -420,6 +492,7 @@ class DecodeEngine:
             _m.decode_prefill_compiles.inc()
         _m.decode_cache_blocks_used.set(self.pool.allocator.used)
         _m.kv_cache_bytes_in_hbm.set(self.pool.bytes_in_hbm())
+        _m.kv_cache_row_bytes.set(self.pool.row_bytes())
         return token
 
     def decode_step(self, tokens, tables, return_rows=False):
@@ -454,20 +527,26 @@ class DecodeEngine:
         coords = decode_coords(self.pool, tables, ctx_lens)
         t0 = clock.end('pack')
         rows = self._run(clock, 'decode', ids, pos, coords)
-        out = rows.argmax(-1)
+        out = _first_max(rows)
         dt = clock.end('sample') - t0
-        clock.record()
+        self._account_step(clock, dt, tables)
         self._step_compiled = True
-        self._account_step(dt, tables)
         if return_rows:
             return out, rows
         return out
 
-    def _account_step(self, dt, tables):
-        """What every decode step books, lockstep or speculative."""
+    def _account_step(self, clock, dt, tables):
+        """What every decode step books, lockstep or speculative, and the
+        call's record."""
         _m.decode_step_seconds.observe(dt)
         _m.decode_steps.inc()
         active = sum(t is not None for t in tables)
+        # the live context the step attended, the fed tokens included
+        positions = self.pool.num_layers * sum(
+            t.context_len for t in tables if t is not None)
+        _m.decode_context_positions_read.inc(positions)
+        clock.work['context_positions'] = positions
+        clock.record()
         _m.decode_slots_active.set(active)
         _m.decode_slot_occupancy.observe(active / max(self.slots, 1))
         # sliding-window views for /healthz slo + fleet snapshots
@@ -519,9 +598,8 @@ class DecodeEngine:
         t0 = clock.end('pack')
         rows = self._run(clock, 'decode', ids, pos, coords)
         dt = clock.last - t0
-        clock.record()
         self._spec_compiled = True
-        self._account_step(dt, tables)          # it IS the decode step
+        self._account_step(clock, dt, tables)   # it IS the decode step
         _m.decode_spec_verify_seconds.observe(dt)
         _m.decode_spec_rounds.inc()
         return rows

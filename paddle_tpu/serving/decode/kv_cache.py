@@ -57,12 +57,13 @@ import threading
 import jax
 import numpy as np
 
-from ..errors import InvalidRequest, OutOfBlocks
+from ..errors import InvalidRequest, OutOfBlocks, UnsupportedCacheFeature
 
 __all__ = ['BlockAllocator', 'BlockTable', 'KVCachePool', 'CacheContext',
            'prefill_coords', 'decode_coords', 'DEFAULT_SLOTS',
            'DEFAULT_BLOCK_SIZE', 'DEFAULT_MAX_BLOCKS', 'SCRATCH_BLOCK',
-           'KV_PAYLOAD_DTYPES', 'KV_DTYPE_CODES', 'kv_row_bytes']
+           'KV_PAYLOAD_DTYPES', 'KV_DTYPE_CODES', 'kv_row_bytes',
+           'latent_row_lanes']
 
 DEFAULT_SLOTS = int(os.environ.get('PADDLE_TPU_DECODE_SLOTS', '8'))
 DEFAULT_BLOCK_SIZE = int(os.environ.get('PADDLE_TPU_DECODE_BLOCK_SIZE', '16'))
@@ -114,6 +115,32 @@ def _scatter_block_scales(scales, block_ids, vals):
 def _scatter_token_scales(scales, block_ids, offsets, vals):
     """scales (H, NB, BS) ← vals (H, S) at (block_ids, offsets) (S,)."""
     return scales.at[:, block_ids, offsets].set(vals)
+
+
+LANES = 128
+
+
+def latent_row_lanes(width):
+    """Lanes a latent row of ``width`` values takes in the pool: the next
+    multiple of the TPU's 128. A (blocks, block, 576) array is given the
+    compact layout with the BLOCK axis minor, and every engine program then
+    copies each layer's whole pool into the scatter's row-major layout and
+    back (found in the compiled step, PERF.md section 6, PR 26); at 640 lanes
+    row-major is the compact layout, the writes are in place, and the bytes
+    are those the tiles of a 576-lane row-major array would take anyway."""
+    return -(-int(width) // LANES) * LANES
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _scatter_latent_blocks(pages, block_ids, vals):
+    """pages (NB, BS, W) ← vals (nb, BS, W) at block_ids (nb,)."""
+    return pages.at[block_ids].set(vals)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _scatter_latent_tokens(pages, block_ids, offsets, vals):
+    """pages (NB, BS, W) ← vals (S, W) at (block_ids, offsets) (S,)."""
+    return pages.at[block_ids, offsets].set(vals)
 
 
 class BlockAllocator:
@@ -258,7 +285,10 @@ class KVCachePool:
         self.kv_dtype = kv_dtype
         self.dtype = KV_PAYLOAD_DTYPES[kv_dtype]
         self.allocator = BlockAllocator(self.num_blocks)
-        self._layers = {}          # layer idx -> [k_pages, v_pages]
+        # layer idx -> [k_pages, v_pages], each (H, NB, BS, D), or for a
+        # latent (MLA) layer -> [rows] of (NB, BS, W): one row a token,
+        # no head axis
+        self._layers = {}
         self._scales = {}          # int8 only: layer -> [k_scales, v_scales]
 
     @property
@@ -300,6 +330,28 @@ class KVCachePool:
         they are kept as they come."""
         self._layers, self._scales = layers, scales
 
+    def allocate(self, layers, scales):
+        """Zeroed arrays of the shapes and dtypes an abstract trace of the
+        model's first engine call returned for them (``{layer: [struct]}``
+        as :meth:`arrays` gives them). Under that trace `ensure_layer`
+        decided every shape from what the model wrote; this makes them
+        real, so the pool learns what the model caches with no model
+        config duplicated into it."""
+        import jax.numpy as jnp
+        for store, structs in ((self._layers, layers),
+                               (self._scales, scales)):
+            for layer, arrs in structs.items():
+                store[layer] = [jnp.zeros(a.shape, a.dtype) for a in arrs]
+
+    def row_bytes(self):
+        """Resident bytes of one token's cached state in one layer (a
+        layer's arrays over the positions they hold): the
+        kv_cache_row_bytes gauge."""
+        if not self._layers:
+            return 0
+        return self.bytes_in_hbm() // (
+            len(self._layers) * self.num_blocks * self.block_size)
+
     def new_table(self, total_tokens):
         """Allocate a table holding ``total_tokens`` (prompt + budget).
         Raises OutOfBlocks when the pool cannot cover it right now."""
@@ -316,8 +368,20 @@ class KVCachePool:
             table.blocks = []
 
     def ensure_layer(self, layer, n_heads, head_dim):
+        """The layer's arrays, made on first use: the one place that decides
+        their shapes. [k, v] of (n_heads, NB, BS, head_dim); with
+        ``n_heads`` None a latent (MLA) layer, ONE array of rows
+        (NB, BS, latent_row_lanes(head_dim)) with no head axis."""
         if layer not in self._layers:
             import jax.numpy as jnp
+            if n_heads is None:
+                if self.kv_dtype == 'int8':
+                    raise UnsupportedCacheFeature(['kv_dtype=int8'],
+                                                  'latent')
+                self._layers[layer] = [jnp.zeros(
+                    (self.num_blocks, self.block_size,
+                     latent_row_lanes(head_dim)), self.dtype)]
+                return self._layers[layer]
             shape = (n_heads, self.num_blocks, self.block_size, head_dim)
             self._layers[layer] = [jnp.zeros(shape, self.dtype),
                                    jnp.zeros(shape, self.dtype)]
@@ -410,6 +474,38 @@ class KVCachePool:
             sc = self._scales[layer]
             sc[0] = _scatter_token_scales(sc[0], ids, offs, ks)
             sc[1] = _scatter_token_scales(sc[1], ids, offs, vs)
+
+    # -- latent (MLA) layers: one array of rows, no head axis --------------
+    def _latent_rows(self, pages, rows):
+        """``rows`` (n, W) at the pool's dtype and lane width."""
+        import jax.numpy as jnp
+        lanes = pages[0].shape[-1]
+        return jnp.pad(rows.astype(self.dtype),
+                       ((0, 0), (0, lanes - rows.shape[-1])))
+
+    def write_prefill_latent(self, layer, block_ids, rows):
+        """The prompt's latent rows, ``rows`` (L, W) bucket-padded, into the
+        blocks ``block_ids`` (ceil(L/bs),) of :func:`prefill_coords`: shapes
+        depend on the rung alone, as in :meth:`write_prefill`."""
+        import jax.numpy as jnp
+        length, width = rows.shape
+        pages = self.ensure_layer(layer, None, width)
+        nb = -(-length // self.block_size)
+        target = nb * self.block_size
+        if length < target:
+            rows = jnp.pad(rows, ((0, target - length), (0, 0)))
+        pages[0] = _scatter_latent_blocks(
+            pages[0], jnp.asarray(block_ids, jnp.int32),
+            self._latent_rows(pages, rows).reshape(nb, self.block_size, -1))
+
+    def write_tokens_latent(self, layer, block_ids, offsets, rows):
+        """One decode step's latent rows, ``rows`` (S·K, W) slot-major, at
+        (block_ids[i], offsets[i])."""
+        import jax.numpy as jnp
+        pages = self.ensure_layer(layer, None, rows.shape[-1])
+        pages[0] = _scatter_latent_tokens(
+            pages[0], jnp.asarray(block_ids, jnp.int32),
+            jnp.asarray(offsets, jnp.int32), self._latent_rows(pages, rows))
 
     # -- whole-block transfer (serving/tier/disagg.py handoff) -------------
     def read_blocks(self, layer, block_ids):
@@ -552,11 +648,31 @@ class CacheContext:
     K > 1 is the multi-token window :func:`decode_coords` describes.
     """
 
-    def __init__(self, pool, mode, coords):
+    def __init__(self, pool, mode, coords, last=None):
         self.pool = pool
         self.mode = mode
         self.coords = coords
+        # prefill: index of the prompt's last row, the one the host reads
+        # (traced); the rows past it are the rung's padding
+        self.last = last
         self._layer = 0
+        self.stats = {}            # name -> [what a layer noted], `note`
+
+    def note(self, name, value):
+        """Keep ``value`` (a small traced array) under ``name`` for the
+        host: the engine's program returns every name's values stacked in
+        the order noted, beside the rows, and reads nothing of them."""
+        self.stats.setdefault(name, []).append(value)
+
+    def live_rows(self, n):
+        """(n,) bool over the call's ``n`` tokens, flattened as the model
+        feeds them: True where a row is a request's token. The others are a
+        rung's padding past the prompt, idle slots and a window's padded
+        lanes, which all write to the scratch block."""
+        import jax.numpy as jnp
+        if self.mode == 'prefill':
+            return jnp.arange(n, dtype=jnp.int32) <= self.last
+        return jnp.asarray(self.coords['write_ids']) != SCRATCH_BLOCK
 
     def _scale_inputs(self, layer):
         """Extra dispatch inputs for int8 pools ({} otherwise — the f32/bf16
@@ -565,6 +681,34 @@ class CacheContext:
         if sc is None:
             return {}
         return {'k_scales': sc[0], 'v_scales': sc[1]}
+
+    def attend_latent(self, inputs, attrs):
+        """A latent (MLA) layer's attention through the pool. ``inputs``:
+        q (B, L, H, D), the tokens' own ``latent`` rows (B, L, W) as they
+        are to be cached, and ``w_kvb``; ``attrs`` those of the two ops
+        (ops/llm_ops.py). Prefill writes the prompt's rows and attends in
+        the expanded form over the prompt itself; decode writes each slot's
+        K fed rows and reads the pool in the absorbed form."""
+        from ...dygraph.tape import dispatch_op
+        layer = self._layer
+        self._layer += 1
+        c = self.coords
+        rows = inputs['latent'].value
+        if self.mode == 'prefill':
+            self.pool.write_prefill_latent(layer, c['write_ids'], rows[0])
+            with jax.named_scope('mla/prefill_attention'):
+                return dispatch_op('mla_prefill_attention', inputs, attrs)
+        self.pool.write_tokens_latent(
+            layer, c['write_ids'], c['write_offs'],
+            rows.reshape(-1, rows.shape[-1]))
+        # the scope names the read's device ops in a profiler trace (the
+        # absorbing matmuls before and after it included: small beside it)
+        with jax.named_scope('mla/decode_read'):
+            return dispatch_op('mla_decode_attention', {
+                'q': inputs['q'], 'pages': self.pool.pages(layer)[0],
+                'block_tables': c['block_tables'],
+                'context_lens': c['context_lens'],
+                'w_kvb': inputs['w_kvb']}, attrs)
 
     def attend(self, q, k, v, sm_scale=1.0):
         from ...dygraph.tape import Tensor, dispatch_op

@@ -11,7 +11,7 @@ from __future__ import annotations
 
 __all__ = ['ServingError', 'InvalidRequest', 'Overloaded', 'DeadlineExceeded',
            'EngineClosed', 'EngineUnhealthy', 'OutOfBlocks',
-           'NoReplicaAvailable']
+           'NoReplicaAvailable', 'UnsupportedCacheFeature']
 
 
 class ServingError(RuntimeError):
@@ -92,3 +92,20 @@ class OutOfBlocks(ServingError):
             f'lower concurrency)')
         self.requested = requested
         self.available = available
+
+
+class UnsupportedCacheFeature(ServingError, ValueError):
+    """A cache feature was asked of a model whose cached state it cannot
+    hold. Raised when the engine (or the prefill role beside it) is built,
+    never under traffic: the prefix cache with its host spill and reinject,
+    the disaggregated handoff and int8 rows all move ``[k, v]`` pairs of
+    per-head rows, and a latent (MLA) layer caches ONE array of rows with
+    no head axis."""
+
+    def __init__(self, features, kind):
+        features = list(features)
+        super().__init__(
+            f'{", ".join(features)} cannot be used with a {kind} KV cache: '
+            f'they read and write [k, v] pairs of per-head rows '
+            f'(docs/SERVING.md "Latent pool")')
+        self.features = features

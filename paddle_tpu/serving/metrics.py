@@ -214,6 +214,23 @@ decode_engine_phase_seconds = _LazyMetric(
 decode_logits_bytes_copied = _LazyMetric(
     'counter', 'decode_logits_bytes_copied',
     'bytes of logits copied from the device to the host by engine calls')
+decode_expert_assignments = _LazyMetric(
+    'counter', 'decode_expert_assignments',
+    'token-to-expert assignments of the calls\' live tokens, over every '
+    'routed-expert layer (a rung\'s padding and idle slots not counted)')
+decode_experts_touched = _LazyMetric(
+    'counter', 'decode_experts_touched',
+    'experts given at least one live token, summed over layers and engine '
+    'calls: what a call needs to read of the expert weights')
+decode_expert_load_max_over_mean = _LazyMetric(
+    'histogram', 'decode_expert_load_max_over_mean',
+    'per engine call (label call), the worst layer\'s largest expert load '
+    'over its mean load', bounds=(1.0, 1.5, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0,
+                                   16.0, 32.0, 64.0, 128.0))
+decode_context_positions_read = _LazyMetric(
+    'counter', 'decode_context_positions_read',
+    'cached positions a decode step attends: the live context of every '
+    'active slot, summed over layers and steps')
 decode_scheduler_phase_seconds = _LazyMetric(
     'histogram', 'decode_scheduler_phase_seconds',
     'wall seconds of the scheduler worker thread per loop iteration (label '
@@ -328,6 +345,10 @@ kv_cache_bytes_in_hbm = _LazyMetric(
     'gauge', 'kv_cache_bytes_in_hbm',
     'resident KV pool bytes across allocated layers (payload arrays plus '
     'int8 row-scale arrays), sampled after pool writes')
+kv_cache_row_bytes = _LazyMetric(
+    'gauge', 'kv_cache_row_bytes',
+    'resident bytes of one token\'s cached state in one layer (K and V '
+    'rows of every head, or one latent row)')
 kv_cache_bytes_spilled = _LazyMetric(
     'counter', 'kv_cache_bytes_spilled',
     'serialized KV payload bytes moved from HBM to the host spill tier')

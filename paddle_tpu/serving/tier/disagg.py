@@ -37,7 +37,7 @@ import time
 import numpy as np
 
 from .. import metrics as _m
-from ..errors import ServingError
+from ..errors import ServingError, UnsupportedCacheFeature
 
 __all__ = ['KVPayload', 'PrefillReplica', 'LocalPrefillWorker']
 
@@ -132,6 +132,9 @@ class PrefillReplica:
     then free. One worker thread owns it (``LocalPrefillWorker``)."""
 
     def __init__(self, engine):
+        if engine.cache_kind != 'kv':
+            raise UnsupportedCacheFeature(['the disaggregated handoff'],
+                                          engine.cache_kind)
         self.engine = engine
 
     def prefill_to_payload(self, prompt, max_new_tokens=0):

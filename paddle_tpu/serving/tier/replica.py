@@ -60,10 +60,10 @@ def build_replica_stack(model=None, seed=DEFAULT_SEED, slots=2, block_size=4,
                         max_new_tokens_cap=16, prompt_buckets=None,
                         prefix_cache=None, disagg=None, queue_depth=64,
                         replica_id=None, model_lock=None, spec_decode=None,
-                        spec_k=None, drafter=None):
+                        spec_k=None, drafter=None, kv_dtype=None):
     """(engine, scheduler, prefill_worker|None) — the replica's serving
     stack minus the HTTP listener. ``prefix_cache``/``disagg`` default to
-    their env knobs. Used by the CLI below and, in-process, by
+    their env knobs, ``kv_dtype`` (handed to the engines) to its. Used by the CLI below and, in-process, by
     tests/framework/test_serving_tier.py and tools/bench_router.py
     (in-process multi-replica setups pass ONE shared ``model_lock`` so
     concurrent scheduler workers serialize their model calls)."""
@@ -81,7 +81,8 @@ def build_replica_stack(model=None, seed=DEFAULT_SEED, slots=2, block_size=4,
                           max_new_tokens_cap=max_new_tokens_cap,
                           prompt_buckets=prompt_buckets,
                           prefix_cache=prefix_cache, model_lock=model_lock,
-                          spec_decode=spec_decode, spec_k=spec_k)
+                          spec_decode=spec_decode, spec_k=spec_k,
+                          kv_dtype=kv_dtype)
     worker = None
     if disagg:
         from .disagg import LocalPrefillWorker, PrefillReplica
@@ -93,7 +94,7 @@ def build_replica_stack(model=None, seed=DEFAULT_SEED, slots=2, block_size=4,
             max_prompt_len=max_prompt_len,
             max_new_tokens_cap=max_new_tokens_cap,
             prompt_buckets=prompt_buckets, prefix_cache=False,
-            model_lock=model_lock)
+            model_lock=model_lock, kv_dtype=kv_dtype)
         worker = LocalPrefillWorker([PrefillReplica(prefill_engine)])
     scheduler = DecodeScheduler(engine, queue_depth=queue_depth,
                                 replica_id=replica_id, disagg=worker,

@@ -296,6 +296,33 @@ GRAD_SPECS = {
     'layer_norm': S(lambda r: [f32(r.standard_normal((3, 4))),
                                pos(r, (4,)), f32(r.standard_normal((4,)))],
                     diff=(0, 1, 2)),
+    # -- the modern decoder block (ops/llm_ops.py) --
+    'rms_norm': S(lambda r: [f32(r.standard_normal((3, 8))), pos(r, (8,))],
+                  diff=(0, 1)),
+    'rope': S(lambda r: [f32(r.standard_normal((1, 3, 2, 6))),
+                         np.array([[0, 2, 5]])],
+              attrs={'theta': 100.0, 'nope_dim': 2}),
+    'lm_head': S(lambda r: [f32(r.standard_normal((3, 4))),
+                            f32(r.standard_normal((4, 5)))], diff=(0, 1)),
+    'swiglu_ffn': S(lambda r: [f32(r.standard_normal((3, 4))),
+                               f32(r.standard_normal((4, 6)) * 0.5),
+                               f32(r.standard_normal((4, 6)) * 0.5),
+                               f32(r.standard_normal((6, 4)) * 0.5)],
+                    diff=(0, 1, 2, 3)),
+    'moe_experts': S(lambda r: [f32(r.standard_normal((5, 4))),
+                                np.array([[0, 2], [2, 1], [0, 1], [2, 0],
+                                          [2, 1]], np.int32),
+                                pos(r, (5, 2)),
+                                f32(r.standard_normal((4, 4, 6)) * 0.5),
+                                f32(r.standard_normal((4, 4, 6)) * 0.5),
+                                f32(r.standard_normal((4, 6, 4)) * 0.5)],
+                     diff=(0, 2, 3, 4, 5)),
+    'mla_prefill_attention': S(
+        lambda r: [f32(r.standard_normal((1, 5, 2, 6))),
+                   f32(r.standard_normal((1, 5, 10))),
+                   f32(r.standard_normal((8, 2 * 7)) * 0.5)],
+        diff=(0, 1, 2),
+        attrs={'qk_nope_dim': 4, 'v_dim': 3, 'sm_scale': 0.4}),
     'instance_norm': S(lambda r: [f32(r.standard_normal((2, 3, 4, 4))),
                                   pos(r, (3,)),
                                   f32(r.standard_normal((3,)))],
@@ -639,6 +666,16 @@ NONDIFF = {
         'inference-only decode-phase cache read (serving/decode/); training '
         'gradients flow through whole-sequence attention, parity tested in '
         'tests/ops/test_paged_attention.py',
+    'moe_router':
+        'integer expert ids and a top-k choice that is piecewise constant; '
+        'the weights\' gradient belongs to the training of the block '
+        '(ROADMAP R5); forward tested in tests/framework/'
+        'test_latent_moe_lm.py',
+    'mla_decode_attention':
+        'inference-only absorbed read of the paged latent cache '
+        '(serving/decode/); training gradients flow through '
+        'mla_prefill_attention, parity tested in tests/framework/'
+        'test_latent_moe_lm.py',
     'paged_prefill_attention':
         'inference-only prefill-phase cache read (serving/decode/); '
         'parity tested in tests/ops/test_paged_attention.py',

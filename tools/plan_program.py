@@ -64,9 +64,9 @@ def _format_decode_pool(doc):
     yield (f"  budget {doc['budget_mb']} MiB - model state "
            f"{doc['model_state_bytes'] / mib:.1f} MiB -> "
            f"{doc['pool_bytes'] / mib:.1f} MiB of KV pages")
-    yield (f"  block = {doc['kv_layers']} layers x 2 (K,V) x "
-           f"{doc['kv_heads']} heads x {doc['block_size']} tokens x "
-           f"{doc['row_bytes']} B/row = {doc['block_bytes']} B")
+    yield (f"  block = {doc['kv_layers']} layers x {doc['block_size']} "
+           f"tokens x {doc['row_bytes']} B a token a layer "
+           f"({doc['kv_cache']}) = {doc['block_bytes']} B")
 
 
 def main(argv=None):
