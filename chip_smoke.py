@@ -391,10 +391,20 @@ def serve_phase(counter, cfg=None, slots=8, block_size=16, max_blocks=256,
         finally:
             srv.shutdown(drain=False)
 
+        # the pool lies as the paged writes and the read take it: no
+        # compiled program copies or transposes an array of its size (the
+        # lockstep step and the ladder's top rung, as compiled for this
+        # device; serving/decode/kv_cache.py says why)
+        pool_moves = {'step': engine.pool_moves(),
+                      f'prefill_{engine.prompt_buckets[-1]}':
+                          engine.pool_moves(engine.prompt_buckets[-1])}
+        assert not any(pool_moves.values()), \
+            f'programs move the K/V pool: {pool_moves}'
+
         # which attention path each rung took: the ops' own predicates, at
         # the shapes the engine dispatched
         k_pages = engine.pool.pages(0)[0]
-        heads, _, _, d_head = k_pages.shape
+        heads, d_head = engine.pool.heads[0]
         shaped = jax.ShapeDtypeStruct
         prefill_paths = {}
         for b in engine.prompt_buckets:
@@ -417,7 +427,11 @@ def serve_phase(counter, cfg=None, slots=8, block_size=16, max_blocks=256,
                  f"200, token counts and ids ok, stream ok, sampled replay "
                  f"identical; {programs} engine programs; after warm-up: "
                  f"XLA compiles {after_warm['compiles']}, eager kernel-cache "
-                 f"lookups {kstats['hits'] + kstats['misses']}")
+                 f"lookups {kstats['hits'] + kstats['misses']}; pool "
+                 f"{'x'.join(map(str, k_pages.shape))} a layer's K or V, "
+                 f"pool-sized copies in the compiled "
+                 + ', '.join(f'{name}: {len(found)}'
+                             for name, found in pool_moves.items()))
     say('serve', f"logits vs uncached forward at pad {engine.padded_context}"
                  f", as a share of max|logit| (tolerance {logit_tol:g}): "
                  + '; '.join(
@@ -437,6 +451,7 @@ def serve_phase(counter, cfg=None, slots=8, block_size=16, max_blocks=256,
     return {'warmup_s': round(warm_s, 2), 'warmup': warm_compiles,
             'warmup_phases': {k: round(v, 2) for k, v in timings.items()},
             'logit_err': logit_err,
+            'pool_moves': {k: len(v) for k, v in pool_moves.items()},
             'prefill_paths': prefill_paths, 'decode_path': decode_path}
 
 
@@ -451,7 +466,9 @@ def kernels_phase(fused_shape=(8, 12, 512, 64), paged_slots=8, paged_heads=4,
     False): one bf16 forward+backward through dispatch_op compiles its TPU
     branch. `paged_attention` takes the XLA formulation at the served
     head_dim of 64 (paged_kernel_applies), so it is called once at the same
-    width split into 4 heads of 128, where its kernel applies. Both are
+    width split into 4 heads of 128, where its kernel applies (over the
+    pool's rows of one token, (blocks, block, heads·head_dim), which the op
+    hands the head-major kernel as a transposed view). Both are
     checked against plain jax.numpy at `tol` of the output scale (bf16
     inputs, or f32 matmuls run as one bf16 pass: a few 2^-8 roundings)."""
     import jax
@@ -500,8 +517,8 @@ def kernels_phase(fused_shape=(8, 12, 512, 64), paged_slots=8, paged_heads=4,
     Sl, Hp, Dp = paged_slots, paged_heads, paged_head_dim
     pscale = 1.0 / math.sqrt(Dp)
     qd = rng.randn(Sl, Hp, Dp).astype('float32')
-    kp = rng.randn(Hp, num_blocks, block_size, Dp).astype('float32')
-    vp = rng.randn(Hp, num_blocks, block_size, Dp).astype('float32')
+    kp = rng.randn(num_blocks, block_size, Hp * Dp).astype('float32')
+    vp = rng.randn(num_blocks, block_size, Hp * Dp).astype('float32')
     tables = rng.randint(1, num_blocks, (Sl, pages_per_seq)).astype('int32')
     lens = rng.randint(1, pages_per_seq * block_size + 1, Sl).astype('int32')
     with dygraph.guard():
@@ -511,11 +528,11 @@ def kernels_phase(fused_shape=(8, 12, 512, 64), paged_slots=8, paged_heads=4,
              'context_lens': lens}, {'sm_scale': pscale}).numpy())
     want = np.zeros_like(got)
     for s in range(Sl):             # plain numpy, f64: the block walk itself
-        ks = kp[:, tables[s]].reshape(Hp, -1, Dp)[:, :lens[s]]
-        vs = vp[:, tables[s]].reshape(Hp, -1, Dp)[:, :lens[s]]
-        sc = np.einsum('hd,htd->ht', qd[s].astype('float64'), ks) * pscale
+        ks = kp[tables[s]].reshape(-1, Hp, Dp)[:lens[s]]
+        vs = vp[tables[s]].reshape(-1, Hp, Dp)[:lens[s]]
+        sc = np.einsum('hd,thd->ht', qd[s].astype('float64'), ks) * pscale
         pr = np.exp(sc - sc.max(-1, keepdims=True))
-        want[s] = np.einsum('ht,htd->hd', pr / pr.sum(-1, keepdims=True), vs)
+        want[s] = np.einsum('ht,thd->hd', pr / pr.sum(-1, keepdims=True), vs)
     assert np.isfinite(got).all()
     perr = float(np.abs(got - want).max() / np.abs(want).max())
     assert perr <= tol, (perr, tol)

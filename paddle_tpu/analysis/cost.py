@@ -482,12 +482,15 @@ def _c_paged_attention(ctx):
     # per query row against T_pad = num_blocks_per_seq × block_size keys:
     # QK^T (2D) + softmax (~TRANS+2) + PV (2D) — the padded extent is the
     # honest decode cost; masked positions still burn the lanes
+    # pages are (num_blocks, block_size, H·D) rows of one token; H and D
+    # are q's: (S, H, D), (S, H, K, D) or prefill's (1, H, L, D)
+    q = ctx.input('q')
     kp = ctx.input('k_pages')
     bt = ctx.input('block_tables')
     a = ctx.assume_dim
-    heads = _pdim(kp, 0, a)
-    block_size = _pdim(kp, 2, a)
-    head_dim = _pdim(kp, 3, a)
+    heads = _pdim(q, 1, a)
+    block_size = _pdim(kp, 1, a)
+    head_dim = _pdim(q, -1, a)
     seqs = _pdim(bt, 0, a)
     t_pad = _pdim(bt, 1, a) * block_size
     queries = max(1, ctx.out_elems() // max(1, head_dim))

@@ -1354,11 +1354,21 @@ def _allreduce_bucket(ctx):
 # rules: paged KV-cache attention (serving/decode)
 # ---------------------------------------------------------------------------
 
+def _holds(width, values):
+    return not (known(width) and known(values)) or width >= values
+
+
 def _check_kv_scales(ctx):
-    """The optional int8-pool dequant scales: one f32 per (head, block,
-    position) row — rank 3, matching the pages' leading dims when both are
-    known. Typed here so the generic byte model prices a quantized pool as
-    1 B/elem payload + 4 B/row scales with no op-specific bytes rule."""
+    """The optional int8-pool dequant scales: one f32 per (block, position,
+    head) row — rank 3 (num_blocks, block_size, H) beside pages
+    (num_blocks, block_size, W ≥ H·D): the same blocks, q's H heads, and
+    rows wide enough for them, when known. Typed here so the generic byte
+    model prices a quantized pool as 1 B/elem payload + 4 B/row scales with
+    no op-specific bytes rule."""
+    q = ctx.input('q')
+    heads = head_dim = UNKNOWN
+    if q is not None and q.shape is not None and len(q.shape) in (3, 4):
+        heads, head_dim = q.shape[1], q.shape[-1]
     pages = ctx.input('k_pages')
     for slot in ('k_scales', 'v_scales'):
         sc = ctx.input(slot)
@@ -1371,15 +1381,22 @@ def _check_kv_scales(ctx):
         if sc.shape is not None:
             if len(sc.shape) != 3:
                 raise InferError(
-                    f'{slot} expects rank 3 (H, num_blocks, block_size), '
+                    f'{slot} expects rank 3 (num_blocks, block_size, H), '
                     f'got rank {len(sc.shape)}')
+            if not dims_agree(sc.shape[2], heads):
+                raise InferError(
+                    f'{slot} shape {tuple(sc.shape)} holds a scale for '
+                    f'{sc.shape[2]} heads, q has {heads}')
             if (pages is not None and pages.shape is not None
-                    and len(pages.shape) == 4
-                    and tuple(sc.shape) != tuple(pages.shape[:3])):
+                    and len(pages.shape) == 3
+                    and not (dims_agree(sc.shape[0], pages.shape[0])
+                             and dims_agree(sc.shape[1], pages.shape[1])
+                             and _holds(pages.shape[2],
+                                        _mul_dims(heads, head_dim)))):
                 raise InferError(
                     f'{slot} shape {tuple(sc.shape)} does not match the '
-                    f'pages\' (H, num_blocks, block_size) '
-                    f'{tuple(pages.shape[:3])}')
+                    f'pages\' (num_blocks, block_size, W >= H·D) '
+                    f'{tuple(pages.shape)}')
 
 
 @infer_rule('paged_attention')

@@ -528,17 +528,17 @@ def _decode_kv_geometry(model):
 
 
 def decode_token_layer_bytes(model, kv_dtype='f32'):
-    """HBM bytes ONE token's cached state costs in ONE layer: K and V rows
-    of every head, or one latent row in the lanes the pool gives it, each
-    priced by kv_cache.kv_row_bytes at the storage dtype (int8 rows carry
-    their f32 scale; a latent row has no int8 form)."""
-    from ..serving.decode.kv_cache import kv_row_bytes, latent_row_lanes
+    """HBM bytes ONE token's cached state costs in ONE layer: a K and a V
+    row of every head, or one latent row, each in the lanes the pool gives
+    it, priced by kv_cache.kv_row_bytes at the storage dtype (int8 rows
+    carry an f32 scale a head; a latent row has no int8 form)."""
+    from ..serving.decode.kv_cache import kv_row_bytes
     spec = _decode_kv_geometry(model)
     if spec['kind'] == 'latent':
         if kv_dtype == 'int8':
             raise ValueError('a latent KV cache has no int8 rows')
-        return kv_row_bytes(latent_row_lanes(spec['row_width']), kv_dtype)
-    return 2 * spec['heads'] * kv_row_bytes(spec['head_dim'], kv_dtype)
+        return kv_row_bytes(1, spec['row_width'], kv_dtype)
+    return 2 * kv_row_bytes(spec['heads'], spec['head_dim'], kv_dtype)
 
 
 def decode_pool_block_bytes(model, block_size, kv_dtype='f32'):

@@ -673,12 +673,14 @@ def paged_kernel_applies(q, k_pages, block_tables, pages_per_compute_block):
     a TPU backend, single-query (S, H, D) ``q``, an f32 pool (bf16/int8
     pools need the dequant-after-gather of the XLA formulation; the
     multi-query (S, H, K, D) verify read has no stock kernel), head_dim a
-    multiple of the 128-lane tile, and the kernel's own
+    multiple of the 128-lane tile, a pool row (NB, BS, Hkv·D) of whole
+    heads whose count divides q's, and the kernel's own
     ``pages_per_sequence % pages_per_compute_block == 0`` rule."""
     return (on_tpu() and len(q.shape) == 3
             and k_pages.dtype == jnp.float32
             and q.shape[2] % _TPU_LANES == 0
-            and q.shape[1] % k_pages.shape[0] == 0
+            and k_pages.shape[2] % q.shape[2] == 0
+            and q.shape[1] % (k_pages.shape[2] // q.shape[2]) == 0
             and block_tables.shape[1]
             % _pages_per_compute_block(pages_per_compute_block,
                                        block_tables) == 0)
@@ -728,12 +730,15 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     - ``q``: (S, H, D) — one query token per decode slot — or (S, H, K, D)
       for the MULTI-QUERY decode read speculative decoding verifies with
       (K fed tokens per slot in one step; see below).
-    - ``k_pages`` / ``v_pages``: (H, num_blocks, block_size, D) — the cache
-      pool. Block 0 is the scratch block (inactive slots point at it).
+    - ``k_pages`` / ``v_pages``: (num_blocks, block_size, W ≥ H·D) — the
+      cache pool, rows of one token with all its heads, zero-padded to
+      whole 128-lane tiles (serving/decode/kv_cache.py says why); H and D
+      are ``q``'s. Block 0 is the scratch block (inactive slots point at
+      it).
     - ``block_tables``: (S, max_blocks_per_seq) int32 — each slot's cache
       blocks in sequence order; tail entries beyond the context are
       arbitrary valid block ids (masked by ``context_lens``).
-    - ``k_scales`` / ``v_scales``: optional (H, num_blocks, block_size)
+    - ``k_scales`` / ``v_scales``: optional (num_blocks, block_size, H)
       f32 — per-row dequant scales for int8 pools (PADDLE_TPU_KV_DTYPE=
       int8). Dequantization happens AFTER the per-slot gather, so only the
       slots' working set is ever materialized at f32; bf16 pools pass no
@@ -748,7 +753,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 
     Where :func:`paged_kernel_applies` holds this dispatches the pallas
     paged-attention kernel (jax.experimental.pallas.ops.tpu.paged_attention
-    — ragged block walk, no dense gather); everywhere else the XLA
+    — ragged block walk, no dense gather; its pool is head-major,
+    (Hkv, num_blocks, block_size, D), so it is handed a transposed view:
+    a copy of the pool a call, ROADMAP D7); everywhere else the XLA
     formulation gathers the slot's blocks into a dense (S, H, T, D) view
     and runs the batched-matmul → mask → softmax → matmul sequence the
     unfused MultiHeadAttention path uses.
@@ -767,8 +774,13 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                             pages_per_compute_block):
         from jax.experimental.pallas.ops.tpu.paged_attention import (
             paged_attention as _tpu_paged_attention)
+        nb, bs, _ = k_pages.shape
+
+        def head_major(pages):
+            return pages.reshape(nb, bs, -1, q.shape[2]).transpose(2, 0, 1, 3)
         return _tpu_paged_attention(
-            q * jnp.asarray(sm_scale, q.dtype), k_pages, v_pages,
+            q * jnp.asarray(sm_scale, q.dtype),
+            head_major(k_pages), head_major(v_pages),
             context_lens, block_tables,
             pages_per_compute_block=_pages_per_compute_block(
                 pages_per_compute_block, block_tables))
@@ -809,24 +821,27 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
 
 
 def _gather_pages(pages, block_tables, s, h, d, scales=None):
-    """(H, NB, BS, D) cache pool + (S, nbs) tables → dense (S, H, nbs·BS, D)
-    per-slot key/value view (the XLA stand-in for the kernel's block walk).
+    """(NB, BS, W ≥ H·D) cache pool + (S, nbs) tables → dense (S, H, nbs·BS, D)
+    per-slot key/value view (the XLA stand-in for the kernel's block walk):
+    the slots' blocks are taken on axis 0, whole rows of a token as they
+    lie in the pool, and only the gathered copy is turned head-major.
 
     f32 pools pass through untouched (the bitwise-contract path). Quantized
     pools dequantize AFTER the gather — int8 payload × its per-row f32
-    ``scales`` (gathered with the identical take/reshape/transpose, shape
-    (S, H, nbs·BS)), bf16 payload a plain f32 cast — so the dense working
-    set is f32 but the resident pool never is."""
+    ``scales`` ((NB, BS, H), gathered with the identical take/reshape/
+    transpose, shape (S, H, nbs·BS)), bf16 payload a plain f32 cast — so
+    the dense working set is f32 but the resident pool never is."""
     nb = block_tables.shape[1]
-    bs = pages.shape[2]
-    g = jnp.take(pages, block_tables.reshape(-1), axis=1)
-    g = g.reshape(h, s, nb, bs, d).transpose(1, 0, 2, 3, 4)
-    g = g.reshape(s, h, nb * bs, d)
+    bs = pages.shape[1]
+    g = jnp.take(pages, block_tables.reshape(-1), axis=0)
+    if g.shape[-1] != h * d:            # a row padded to whole lane tiles
+        g = g[..., :h * d]
+    g = g.reshape(s, nb * bs, h, d).transpose(0, 2, 1, 3)
     if scales is not None:
         sc = jnp.take(jnp.asarray(scales, jnp.float32),
-                      block_tables.reshape(-1), axis=1)
-        sc = sc.reshape(h, s, nb, bs).transpose(1, 0, 2, 3)
-        return g.astype(jnp.float32) * sc.reshape(s, h, nb * bs)[..., None]
+                      block_tables.reshape(-1), axis=0)
+        sc = sc.reshape(s, nb * bs, h).transpose(0, 2, 1)
+        return g.astype(jnp.float32) * sc[..., None]
     if g.dtype != jnp.float32:
         return g.astype(jnp.float32)
     return g

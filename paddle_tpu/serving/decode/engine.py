@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import re
 import time
 import weakref
 
@@ -57,13 +58,32 @@ from ...dygraph.jit import _bind
 from ...dygraph.tape import Tensor, no_grad_guard
 from ..engine import bucket_ladder
 from ..errors import InvalidRequest, UnsupportedCacheFeature
-from .kv_cache import (CacheContext, KVCachePool, decode_coords,
+from .kv_cache import (BlockTable, CacheContext, KVCachePool, decode_coords,
                        prefill_coords, DEFAULT_BLOCK_SIZE,
                        DEFAULT_MAX_BLOCKS, DEFAULT_SLOTS)
 
 __all__ = ['DecodeEngine']
 
 _NULL_LOCK = contextlib.nullcontext()
+
+# an HLO instruction that moves data and nothing else: `%name = <result
+# shapes> copy(...)`, transpose, or an async copy's start; and the dims of
+# each array shape in the result
+_HLO_MOVE = re.compile(r'=\s*(.*?)\s(?:copy|copy-start|transpose)\(')
+_HLO_DIMS = re.compile(r'\w+\[([\d,]+)\]')
+
+
+def _moves_of_size(hlo_text, sizes):
+    """The instructions of a compiled program's text that copy or transpose
+    an array of one of the element counts ``sizes``."""
+    moves = []
+    for line in hlo_text.splitlines():
+        made = _HLO_MOVE.search(line)
+        if made and any(
+                int(np.prod([int(d) for d in dims.split(',')])) in sizes
+                for dims in _HLO_DIMS.findall(made.group(1))):
+            moves.append(line.strip())
+    return moves
 
 
 class _Program:
@@ -105,6 +125,11 @@ class _Program:
         # of a dictionary that holds the model weakly
         model_ref = weakref.ref(model)
         params, buffers = self._params, self._buffers
+        # layer -> (heads, head_dim) of a K/V row, as every trace's pool
+        # noted them (the model's own, so the same each time): what
+        # `KVCachePool.allocate` is told beside the arrays' shapes, which do
+        # not say it
+        heads = self._heads = {}
 
         def run(mode, geometry, pvals, bvals, layers, scales, ids, pos,
                 coords, last):
@@ -128,10 +153,17 @@ class _Program:
             # what the forward noted for the host (`CacheContext.note`)
             stats = {name: jax.numpy.stack(values)
                      for name, values in ctx.stats.items()}
+            heads.update(pool.heads)
             return (rows, stats) + pool.arrays()
 
         self.jitted = jax.jit(run, static_argnums=(0, 1),
                               donate_argnums=(4, 5))
+
+    def _head(self, pool, mode):
+        """The program's arguments before the pool's arrays."""
+        return (mode, pool.geometry,
+                {n: p.value for n, p in self._params.items()},
+                {n: b.value for n, b in self._buffers.items()})
 
     def __call__(self, pool, mode, ids, pos, coords, last=None):
         """Run one engine call's program over ``pool`` and return its rows
@@ -139,20 +171,30 @@ class _Program:
         ``expert_counts`` (layers, E) and ``expert_ids`` (layers, scored
         rows, k), else empty), all device arrays: the call is enqueued, not
         finished."""
-        fn = functools.partial(
-            self.jitted, mode, pool.geometry,
-            {n: p.value for n, p in self._params.items()},
-            {n: b.value for n, b in self._buffers.items()})
+        fn = functools.partial(self.jitted, *self._head(pool, mode))
         if not pool.num_layers:
             # the pool allocates here, before the first trace that takes its
             # arrays as arguments: an abstract trace over an empty pool
             # returns the arrays `ensure_layer` would make
             pool.allocate(*jax.eval_shape(fn, {}, {}, ids, pos, coords,
-                                          last)[2:])
+                                          last)[2:], self._heads)
         rows, stats, layers, scales = fn(*pool.arrays(), ids, pos, coords,
                                          last)
         pool.adopt(layers, scales)
         return rows, stats
+
+    def lower(self, pool, mode, ids, pos, coords, last=None, sharding=None):
+        """The program `__call__` would run over ``pool`` (allocated),
+        lowered and not run: nothing is donated. With ``sharding`` (of a
+        device that may be described and not attached) every argument is
+        its shape and dtype there, so `.compile()` is that device's."""
+        head = self._head(pool, mode)
+        args = head[2:] + pool.arrays() + (ids, pos, coords, last)
+        if sharding is not None:
+            args = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(
+                    np.shape(a), a.dtype, sharding=sharding), args)
+        return self.jitted.lower(*head[:2], *args)
 
 
 def _first_max(rows):
@@ -467,6 +509,38 @@ class DecodeEngine:
         geometry that ran it: after warm-up, for one engine on a fresh
         model, ``len(prompt_buckets) + 1`` (+ 1 with speculation on)."""
         return self._program.jitted._cache_size()
+
+    def lowered(self, bucket=None, sharding=None):
+        """The lockstep step's program, or with ``bucket`` that prefill
+        rung's, lowered over this engine's pool (allocated: after a first
+        call or warm-up) and not run; ``sharding`` as `_Program.lower`
+        takes it. What the programs hold is read from here: `pool_moves`,
+        chip_smoke.py, the tests."""
+        pool = self.pool
+        if bucket is None:
+            feed = np.zeros((self.slots, 1), np.int64)
+            return self._program.lower(
+                pool, 'decode', feed, feed,
+                decode_coords(pool, [None] * self.slots, [1] * self.slots),
+                sharding=sharding)
+        return self._program.lower(
+            pool, 'prefill', np.zeros((1, bucket), np.int64), None,
+            prefill_coords(pool, BlockTable([], pool.block_size), bucket),
+            np.int32(0), sharding=sharding)
+
+    def pool_moves(self, bucket=None, sharding=None):
+        """The instructions of that program, compiled for the device it
+        would run on (or ``sharding``'s), that copy or transpose an array
+        of the size of one of the pool's: a layout the compiler chose for
+        the pool against the writes' and the read's. Must be empty
+        (kv_cache.py's module docstring): the pool's arrays lie as the
+        paged scatter and the page gather take them, and the scatter is in
+        place."""
+        layers, scales = self.pool.arrays()
+        sizes = {int(a.size) for arrs in list(layers.values())
+                 + list(scales.values()) for a in arrs}
+        return _moves_of_size(
+            self.lowered(bucket, sharding).compile().as_text(), sizes)
 
     def prefill(self, prompt, table, sampler=None):
         """Run the bucket-padded prompt once, writing K/V into ``table``'s

@@ -1,6 +1,23 @@
 """Paged KV-cache pool: fixed-size blocks, free-list allocator, per-request
-block tables (docs/SERVING.md "Stateful decode"; layout per the TPU paged-
-attention kernel: (num_kv_heads, num_blocks, block_size, head_dim)).
+block tables (docs/SERVING.md "Stateful decode").
+
+Every pool array is rows of ONE token: a layer's K and V are each
+(num_blocks, block_size, row_lanes(n_heads·head_dim)), a latent (MLA) layer's
+one array (num_blocks, block_size, row_lanes(width)), int8 row scales
+(num_blocks, block_size, n_heads). A token's row of all heads is contiguous
+and a whole number of the TPU's 128-lane tiles (GPT-1: 768 = 6 × 128; any
+other width is padded up to one, :func:`row_lanes`), so row-major is the
+compact layout the compiler gives a program's arguments and results, and the
+paged scatter and the page gather work on it as it lies: no engine program
+relayouts the pool. The head-major (n_heads, num_blocks,
+block_size, head_dim) of the stock pallas paged kernel put head_dim 64 under
+the 128 lanes; the compiler then made the block axis minor and every engine
+program copied every layer's whole pool into the scatter's layout and back,
+48 copies of 201 MB a call at GPT-1 size (PERF.md section 6, PR 27).
+Head-major survives as the WIRE format alone: `read_blocks` /
+`read_block_scales` return and `write_whole_blocks` takes
+(n_heads, nb, block_size, head_dim) / (n_heads, nb, block_size) host arrays,
+transposed at the boundary over the nb blocks moved, never over the pool.
 
 Why paged: a contiguous per-request KV buffer must be sized for the WORST
 CASE length at admission, so short requests strand memory and long ones
@@ -63,7 +80,7 @@ __all__ = ['BlockAllocator', 'BlockTable', 'KVCachePool', 'CacheContext',
            'prefill_coords', 'decode_coords', 'DEFAULT_SLOTS',
            'DEFAULT_BLOCK_SIZE', 'DEFAULT_MAX_BLOCKS', 'SCRATCH_BLOCK',
            'KV_PAYLOAD_DTYPES', 'KV_DTYPE_CODES', 'kv_row_bytes',
-           'latent_row_lanes']
+           'row_lanes']
 
 DEFAULT_SLOTS = int(os.environ.get('PADDLE_TPU_DECODE_SLOTS', '8'))
 DEFAULT_BLOCK_SIZE = int(os.environ.get('PADDLE_TPU_DECODE_BLOCK_SIZE', '16'))
@@ -73,8 +90,8 @@ DEFAULT_MAX_BLOCKS = int(os.environ.get('PADDLE_TPU_DECODE_MAX_BLOCKS',
 SCRATCH_BLOCK = 0
 
 # storage payload width per element, by kv_dtype; int8 additionally carries
-# one f32 scale per (head, position) row — kv_row_bytes() is the closed
-# form the pool-sizing solve and the analysis bytes model both price
+# one f32 scale per (position, head) row — kv_row_bytes() is the closed
+# form the pool-sizing solve prices
 KV_PAYLOAD_DTYPES = {'f32': 'float32', 'bf16': 'bfloat16', 'int8': 'int8'}
 _KV_PAYLOAD_BYTES = {'f32': 4, 'bf16': 2, 'int8': 1}
 # stable small-int codes: the kv_cache_dtype gauge and the disagg KVPayload
@@ -82,63 +99,57 @@ _KV_PAYLOAD_BYTES = {'f32': 4, 'bf16': 2, 'int8': 1}
 KV_DTYPE_CODES = {'f32': 0, 'bf16': 1, 'int8': 2}
 
 
-def kv_row_bytes(head_dim, kv_dtype):
-    """Bytes of ONE cached K or V row (one head × one token position) at
-    ``kv_dtype``: payload + (int8 only) its f32 row scale."""
+def kv_row_bytes(heads, head_dim, kv_dtype):
+    """Bytes ONE token's row takes in one pool array (its K, its V, or its
+    latent row with ``heads`` 1) at ``kv_dtype``, as allocated: the payload
+    of all heads in :func:`row_lanes` lanes + (int8 only) one f32 scale a
+    head."""
     if kv_dtype not in _KV_PAYLOAD_BYTES:
         raise ValueError(
             f'kv_dtype={kv_dtype!r} is not supported; supported values: '
             + ', '.join(repr(c) for c in KV_PAYLOAD_DTYPES))
-    return (int(head_dim) * _KV_PAYLOAD_BYTES[kv_dtype]
-            + (4 if kv_dtype == 'int8' else 0))
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_blocks(pages, block_ids, vals):
-    """pages (H, NB, BS, D) ← vals (H, nb, BS, D) at block_ids (nb,)."""
-    return pages.at[:, block_ids].set(vals)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_tokens(pages, block_ids, offsets, vals):
-    """pages (H, NB, BS, D) ← vals (H, S, D) at (block_ids, offsets) (S,)."""
-    return pages.at[:, block_ids, offsets].set(vals)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_block_scales(scales, block_ids, vals):
-    """scales (H, NB, BS) ← vals (H, nb, BS) at block_ids (nb,)."""
-    return scales.at[:, block_ids].set(vals)
-
-
-@functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_token_scales(scales, block_ids, offsets, vals):
-    """scales (H, NB, BS) ← vals (H, S) at (block_ids, offsets) (S,)."""
-    return scales.at[:, block_ids, offsets].set(vals)
+    return (row_lanes(int(heads) * int(head_dim))
+            * _KV_PAYLOAD_BYTES[kv_dtype]
+            + (4 * int(heads) if kv_dtype == 'int8' else 0))
 
 
 LANES = 128
 
 
-def latent_row_lanes(width):
-    """Lanes a latent row of ``width`` values takes in the pool: the next
-    multiple of the TPU's 128. A (blocks, block, 576) array is given the
-    compact layout with the BLOCK axis minor, and every engine program then
-    copies each layer's whole pool into the scatter's row-major layout and
-    back (found in the compiled step, PERF.md section 6, PR 26); at 640 lanes
-    row-major is the compact layout, the writes are in place, and the bytes
-    are those the tiles of a 576-lane row-major array would take anyway."""
+def row_lanes(width):
+    """Lanes a token's row of ``width`` values takes in the pool: the next
+    multiple of the TPU's 128. A (blocks, block, 576) array (a latent row)
+    or (blocks, block, 320) (5 heads of 64) is given the compact layout
+    with the BLOCK axis minor, and every engine program then copies each
+    layer's whole pool into the scatter's row-major layout and back (found
+    in the compiled step, PERF.md section 6, PR 26, and for K/V rows PR 27);
+    at 640 or 384 lanes row-major is the compact layout, the writes are in
+    place, and the bytes are those the tiles of the unpadded row-major array
+    would take anyway."""
     return -(-int(width) // LANES) * LANES
 
 
+def _to_lanes(rows, lanes):
+    """``rows`` (..., W) zero-padded on the last axis to the pool's
+    ``lanes``; as they are where W fills them."""
+    import jax.numpy as jnp
+    pad = lanes - rows.shape[-1]
+    if not pad:
+        return rows
+    return jnp.pad(rows, ((0, 0),) * (rows.ndim - 1) + ((0, pad),))
+
+
+# the one pair of writes, for every pool array (K, V, latent rows, scales):
+# each is (NB, BS, W) rows of one token, W its own width
+
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_latent_blocks(pages, block_ids, vals):
+def _scatter_blocks(pages, block_ids, vals):
     """pages (NB, BS, W) ← vals (nb, BS, W) at block_ids (nb,)."""
     return pages.at[block_ids].set(vals)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
-def _scatter_latent_tokens(pages, block_ids, offsets, vals):
+def _scatter_tokens(pages, block_ids, offsets, vals):
     """pages (NB, BS, W) ← vals (S, W) at (block_ids, offsets) (S,)."""
     return pages.at[block_ids, offsets].set(vals)
 
@@ -285,11 +296,14 @@ class KVCachePool:
         self.kv_dtype = kv_dtype
         self.dtype = KV_PAYLOAD_DTYPES[kv_dtype]
         self.allocator = BlockAllocator(self.num_blocks)
-        # layer idx -> [k_pages, v_pages], each (H, NB, BS, D), or for a
-        # latent (MLA) layer -> [rows] of (NB, BS, W): one row a token,
-        # no head axis
+        # layer idx -> [k_pages, v_pages], each (NB, BS, lanes of H·D), or
+        # for a latent (MLA) layer -> [rows] of (NB, BS, lanes of W): one
+        # row a token
         self._layers = {}
         self._scales = {}          # int8 only: layer -> [k_scales, v_scales]
+        # layer idx -> (H, D), how a K/V row splits into heads: what the
+        # wire format needs and the arrays' shapes do not say
+        self.heads = {}
 
     @property
     def padded_context(self):
@@ -330,18 +344,19 @@ class KVCachePool:
         they are kept as they come."""
         self._layers, self._scales = layers, scales
 
-    def allocate(self, layers, scales):
+    def allocate(self, layers, scales, heads):
         """Zeroed arrays of the shapes and dtypes an abstract trace of the
         model's first engine call returned for them (``{layer: [struct]}``
-        as :meth:`arrays` gives them). Under that trace `ensure_layer`
-        decided every shape from what the model wrote; this makes them
-        real, so the pool learns what the model caches with no model
-        config duplicated into it."""
+        as :meth:`arrays` gives them), and the ``heads`` the traced pool
+        noted. Under that trace `ensure_layer` decided every shape from
+        what the model wrote; this makes them real, so the pool learns what
+        the model caches with no model config duplicated into it."""
         import jax.numpy as jnp
         for store, structs in ((self._layers, layers),
                                (self._scales, scales)):
             for layer, arrs in structs.items():
                 store[layer] = [jnp.zeros(a.shape, a.dtype) for a in arrs]
+        self.heads.update(heads)
 
     def row_bytes(self):
         """Resident bytes of one token's cached state in one layer (a
@@ -369,9 +384,12 @@ class KVCachePool:
 
     def ensure_layer(self, layer, n_heads, head_dim):
         """The layer's arrays, made on first use: the one place that decides
-        their shapes. [k, v] of (n_heads, NB, BS, head_dim); with
-        ``n_heads`` None a latent (MLA) layer, ONE array of rows
-        (NB, BS, latent_row_lanes(head_dim)) with no head axis."""
+        their shapes. [k, v] of (NB, BS, row_lanes(n_heads·head_dim)), a
+        token's row of all heads contiguous (the module docstring says
+        why); with ``n_heads`` None a latent (MLA) layer, ONE array of rows
+        (NB, BS, row_lanes(head_dim))."""
+        if n_heads is not None:
+            self.heads[layer] = (int(n_heads), int(head_dim))
         if layer not in self._layers:
             import jax.numpy as jnp
             if n_heads is None:
@@ -380,30 +398,32 @@ class KVCachePool:
                                                   'latent')
                 self._layers[layer] = [jnp.zeros(
                     (self.num_blocks, self.block_size,
-                     latent_row_lanes(head_dim)), self.dtype)]
+                     row_lanes(head_dim)), self.dtype)]
                 return self._layers[layer]
-            shape = (n_heads, self.num_blocks, self.block_size, head_dim)
+            rows = (self.num_blocks, self.block_size)
+            shape = rows + (row_lanes(n_heads * head_dim),)
             self._layers[layer] = [jnp.zeros(shape, self.dtype),
                                    jnp.zeros(shape, self.dtype)]
             if self.kv_dtype == 'int8':
-                # one f32 scale per (head, position) row; zero-init means
-                # unwritten rows (incl. the scratch block) dequantize to
-                # exact zeros — the masking contract at a new dtype
-                self._scales[layer] = [jnp.zeros(shape[:3], 'float32'),
-                                       jnp.zeros(shape[:3], 'float32')]
+                # one f32 scale per (position, head) row, in the payload's
+                # order; zero-init means unwritten rows (incl. the scratch
+                # block) dequantize to exact zeros — the masking contract
+                # at a new dtype
+                self._scales[layer] = [jnp.zeros(rows + (n_heads,), 'float32'),
+                                       jnp.zeros(rows + (n_heads,), 'float32')]
         return self._layers[layer]
 
     def pages(self, layer):
         return self._layers[layer]
 
     def scales(self, layer):
-        """int8 pools: [k_scales, v_scales] each (H, NB, BS) f32; ``None``
+        """int8 pools: [k_scales, v_scales] each (NB, BS, H) f32; ``None``
         for f32/bf16 pools (payload is self-describing)."""
         return self._scales.get(layer)
 
     def _encode_rows(self, vals):
-        """f32 rows (H, ..., D) → (payload at the storage dtype, row scales
-        (H, ...) f32 or ``None``). The f32 branch returns its input object
+        """f32 rows (..., H, D) → (payload at the storage dtype, row scales
+        (..., H) f32 or ``None``). The f32 branch returns its input object
         untouched — the default path must stay bitwise-identical."""
         if self.kv_dtype == 'f32':
             return vals, None
@@ -438,24 +458,16 @@ class KVCachePool:
         has it — after warm-up, where chip_smoke.py counts none."""
         import jax.numpy as jnp
         h, L, d = k.shape
-        pages = self.ensure_layer(layer, h, d)
+        self.ensure_layer(layer, h, d)
         nb = -(-L // self.block_size)
         target = nb * self.block_size
         if L < target:
             pad = ((0, 0), (0, target - L), (0, 0))
             k = jnp.pad(k, pad)
             v = jnp.pad(v, pad)
-        ids = jnp.asarray(block_ids, jnp.int32)
-        kb = k.reshape(h, nb, self.block_size, d)
-        vb = v.reshape(h, nb, self.block_size, d)
-        kb, ks = self._encode_rows(kb)
-        vb, vs = self._encode_rows(vb)
-        pages[0] = _scatter_blocks(pages[0], ids, kb)
-        pages[1] = _scatter_blocks(pages[1], ids, vb)
-        if ks is not None:
-            sc = self._scales[layer]
-            sc[0] = _scatter_block_scales(sc[0], ids, ks)
-            sc[1] = _scatter_block_scales(sc[1], ids, vs)
+        self._write(layer, _scatter_blocks,
+                    (jnp.asarray(block_ids, jnp.int32),),
+                    (nb, self.block_size), k, v)
 
     def write_tokens(self, layer, block_ids, offsets, k, v):
         """One decode step's K/V: ``k``/``v`` (H, S, D) written at
@@ -463,25 +475,28 @@ class KVCachePool:
         scratch block."""
         import jax.numpy as jnp
         h, s, d = k.shape
-        pages = self.ensure_layer(layer, h, d)
-        ids = jnp.asarray(block_ids, jnp.int32)
-        offs = jnp.asarray(offsets, jnp.int32)
-        k, ks = self._encode_rows(k)
-        v, vs = self._encode_rows(v)
-        pages[0] = _scatter_tokens(pages[0], ids, offs, k)
-        pages[1] = _scatter_tokens(pages[1], ids, offs, v)
-        if ks is not None:
-            sc = self._scales[layer]
-            sc[0] = _scatter_token_scales(sc[0], ids, offs, ks)
-            sc[1] = _scatter_token_scales(sc[1], ids, offs, vs)
+        self.ensure_layer(layer, h, d)
+        self._write(layer, _scatter_tokens,
+                    (jnp.asarray(block_ids, jnp.int32),
+                     jnp.asarray(offsets, jnp.int32)), (s,), k, v)
+
+    def _write(self, layer, scatter, at, lead, k, v):
+        """Encode ``k`` / ``v`` (H, n, D) at the storage dtype and scatter
+        them at ``at`` as rows of one token, (*lead, H·D) with ``lead`` the
+        n tokens as the scatter takes them: only the new tokens are
+        transposed, the pool is written as it lies."""
+        pages, sc = self._layers[layer], self._scales.get(layer)
+        for i, x in enumerate((k, v)):
+            rows, scales = self._encode_rows(x.transpose(1, 0, 2))
+            pages[i] = scatter(pages[i], *at, _to_lanes(
+                rows.reshape(*lead, -1), pages[i].shape[-1]))
+            if scales is not None:
+                sc[i] = scatter(sc[i], *at, scales.reshape(*lead, -1))
 
     # -- latent (MLA) layers: one array of rows, no head axis --------------
     def _latent_rows(self, pages, rows):
         """``rows`` (n, W) at the pool's dtype and lane width."""
-        import jax.numpy as jnp
-        lanes = pages[0].shape[-1]
-        return jnp.pad(rows.astype(self.dtype),
-                       ((0, 0), (0, lanes - rows.shape[-1])))
+        return _to_lanes(rows.astype(self.dtype), pages[0].shape[-1])
 
     def write_prefill_latent(self, layer, block_ids, rows):
         """The prompt's latent rows, ``rows`` (L, W) bucket-padded, into the
@@ -494,7 +509,7 @@ class KVCachePool:
         target = nb * self.block_size
         if length < target:
             rows = jnp.pad(rows, ((0, target - length), (0, 0)))
-        pages[0] = _scatter_latent_blocks(
+        pages[0] = _scatter_blocks(
             pages[0], jnp.asarray(block_ids, jnp.int32),
             self._latent_rows(pages, rows).reshape(nb, self.block_size, -1))
 
@@ -503,19 +518,34 @@ class KVCachePool:
         (block_ids[i], offsets[i])."""
         import jax.numpy as jnp
         pages = self.ensure_layer(layer, None, rows.shape[-1])
-        pages[0] = _scatter_latent_tokens(
+        pages[0] = _scatter_tokens(
             pages[0], jnp.asarray(block_ids, jnp.int32),
             jnp.asarray(offsets, jnp.int32), self._latent_rows(pages, rows))
 
     # -- whole-block transfer (serving/tier/disagg.py handoff) -------------
+    # The wire format is head-major, (H, nb, BS, D) payload and (H, nb, BS)
+    # scales, as it was when the pool lay so: payloads cross versions and
+    # the host tier keeps them. It meets the pool's rows of one token by a
+    # transposition over the nb blocks moved.
+    @staticmethod
+    def _wire(pages, block_ids, h, x):
+        """Blocks ``block_ids`` of one pool array, whose rows begin with
+        ``x`` values for each of ``h`` heads (payload: the head's D, then
+        the lanes' padding; scales: 1), as the host array (H, nb, BS, x)."""
+        got = np.asarray(pages[np.asarray(block_ids, np.int32)])
+        nb, bs, _ = got.shape
+        return np.ascontiguousarray(
+            got[..., :h * x].reshape(nb, bs, h, x).transpose(2, 0, 1, 3))
+
     def read_blocks(self, layer, block_ids):
         """Gather whole blocks as host arrays: ``(k, v)`` each
         (H, nb, block_size, D). The disaggregation payload format — a
         prefill replica reads its finished blocks out, a decode replica
         writes them into its own pool ids."""
-        ids = np.asarray(block_ids, np.int32)
         k_pages, v_pages = self._layers[layer]
-        return (np.asarray(k_pages[:, ids]), np.asarray(v_pages[:, ids]))
+        h, d = self.heads[layer]
+        return (self._wire(k_pages, block_ids, h, d),
+                self._wire(v_pages, block_ids, h, d))
 
     def read_block_scales(self, layer, block_ids):
         """int8 pools: gather the blocks' row scales as host arrays
@@ -524,9 +554,10 @@ class KVCachePool:
         scatter them back byte-exact. ``None`` for f32/bf16 pools."""
         if layer not in self._scales:
             return None
-        ids = np.asarray(block_ids, np.int32)
         ks, vs = self._scales[layer]
-        return (np.asarray(ks[:, ids]), np.asarray(vs[:, ids]))
+        h, _ = self.heads[layer]
+        return (self._wire(ks, block_ids, h, 1)[..., 0],
+                self._wire(vs, block_ids, h, 1)[..., 0])
 
     def write_whole_blocks(self, layer, block_ids, k, v,
                            k_scale=None, v_scale=None):
@@ -546,27 +577,26 @@ class KVCachePool:
                 f'handoff block_size {bs} != pool block_size '
                 f'{self.block_size}')
         pages = self.ensure_layer(layer, h, d)
+        sc = self._scales.get(layer)
         ids = np.asarray(block_ids, np.int32)
         import jax.numpy as jnp
-        k = jnp.asarray(k)
-        v = jnp.asarray(v)
-        same = (k.dtype == jnp.dtype(self.dtype)
+        same = (jnp.dtype(k.dtype) == jnp.dtype(self.dtype)
                 and (self.kv_dtype != 'int8' or k_scale is not None))
-        if same:
-            ks, vs = k_scale, v_scale
-        else:
-            if k_scale is not None:      # sender was int8: decode first
-                from ...parallel.quant_collectives import rowwise_dequantize
-                k = rowwise_dequantize(k, k_scale)
-                v = rowwise_dequantize(v, v_scale)
-            k, ks = self._encode_rows(k.astype(jnp.float32))
-            v, vs = self._encode_rows(v.astype(jnp.float32))
-        pages[0] = _scatter_blocks(pages[0], ids, k)
-        pages[1] = _scatter_blocks(pages[1], ids, v)
-        if self.kv_dtype == 'int8':
-            sc = self._scales[layer]
-            sc[0] = _scatter_block_scales(sc[0], ids, jnp.asarray(ks))
-            sc[1] = _scatter_block_scales(sc[1], ids, jnp.asarray(vs))
+        for i, (x, xs) in enumerate(((k, k_scale), (v, v_scale))):
+            # (H, nb, BS, D) -> the pool's (nb, BS, H, D), scales alike
+            x = jnp.asarray(x).transpose(1, 2, 0, 3)
+            if xs is not None:
+                xs = jnp.asarray(xs).transpose(1, 2, 0)
+            if not same:
+                if xs is not None:       # sender was int8: decode first
+                    from ...parallel.quant_collectives import (
+                        rowwise_dequantize)
+                    x = rowwise_dequantize(x, xs)
+                x, xs = self._encode_rows(x.astype(jnp.float32))
+            pages[i] = _scatter_blocks(pages[i], ids, _to_lanes(
+                x.reshape(nb, bs, h * d), pages[i].shape[-1]))
+            if sc is not None:
+                sc[i] = _scatter_blocks(sc[i], ids, xs)
 
     # -- observability -----------------------------------------------------
     def utilization(self):
@@ -718,7 +748,7 @@ class CacheContext:
         kv = k.value if isinstance(k, Tensor) else k
         vv = v.value if isinstance(v, Tensor) else v
         if self.mode == 'prefill':
-            # (1, H, L, D) -> (H, L, D) rows for the block scatter
+            # (1, H, L, D) -> (H, L, D): the prompt's projections
             self.pool.write_prefill(layer, c['write_ids'], kv[0], vv[0])
             k_pages, v_pages = self.pool.pages(layer)
             inputs = {'q': q, 'k': k, 'v': v, 'k_pages': k_pages,
@@ -727,8 +757,8 @@ class CacheContext:
             return dispatch_op('paged_prefill_attention', inputs,
                                {'sm_scale': float(sm_scale)})
         s, h, k_w, d = kv.shape
-        # (S, H, K, D) -> (H, S·K, D) rows, slot-major, matching the
-        # flattened write coordinates
+        # (S, H, K, D) -> (H, S·K, D), slot-major, matching the flattened
+        # write coordinates
         self.pool.write_tokens(
             layer, c['write_ids'], c['write_offs'],
             kv.transpose(1, 0, 2, 3).reshape(h, s * k_w, d),

@@ -1,0 +1,273 @@
+"""The K/V pool's storage layout (serving/decode/kv_cache.py): every pool
+array is rows of one token, (num_blocks, block_size, n_heads·head_dim in
+whole 128-lane tiles), so the paged writes and the page gather work on it as
+it lies; the wire format (read_blocks / write_whole_blocks) stays head-major
+and unpadded. Each case at every storage dtype."""
+import numpy as np
+import jax
+import jax.numpy as jnp
+import pytest
+
+from paddle_tpu import dygraph
+from paddle_tpu.models.causal_lm import CausalLMConfig, TransformerLM
+from paddle_tpu.ops.nn_ops import _gather_pages
+from paddle_tpu.serving.decode.engine import DecodeEngine, _moves_of_size
+from paddle_tpu.serving.decode.kv_cache import (KV_PAYLOAD_DTYPES,
+                                                KVCachePool, kv_row_bytes,
+                                                row_lanes)
+
+KV_DTYPES = ['f32', 'bf16', 'int8']
+H, D, BS, NB = 3, 8, 4, 11          # a row of 24 values: padded to 128 lanes
+
+
+def encode(rows, kv_dtype):
+    """numpy's own codec: f32 rows (..., D) → (payload, scales or None)."""
+    if kv_dtype == 'f32':
+        return rows, None
+    if kv_dtype == 'bf16':
+        return rows.astype(jnp.bfloat16), None
+    scale = np.abs(rows).max(-1) / np.float32(127.0)
+    inv = np.where(scale > 0, np.float32(1.0) / np.where(scale > 0, scale, 1),
+                   np.float32(0.0)).astype('float32')
+    q = np.clip(np.rint(rows * inv[..., None]), -127, 127).astype('int8')
+    return q, scale.astype('float32')
+
+
+class WirePool:
+    """A plain numpy model of one layer of the pool, kept in the WIRE
+    format: payload (H, NB, BS, D) at the storage dtype, scales (H, NB, BS)."""
+
+    def __init__(self, kv_dtype):
+        self.kv_dtype = kv_dtype
+        self.k = np.zeros((H, NB, BS, D), KV_PAYLOAD_DTYPES[kv_dtype])
+        self.v = np.zeros_like(self.k)
+        self.ks = np.zeros((H, NB, BS), 'float32')
+        self.vs = np.zeros_like(self.ks)
+
+    def write(self, block, offset, k_row, v_row):
+        """One token's (H, D) K and V rows."""
+        for pay, sc, row in ((self.k, self.ks, k_row), (self.v, self.vs,
+                                                        v_row)):
+            q, s = encode(row, self.kv_dtype)
+            pay[:, block, offset] = q
+            if s is not None:
+                sc[:, block, offset] = s
+
+
+def filled_pools(kv_dtype, seed=0):
+    """A pool written through write_prefill (7 tokens into blocks 5, 2 and
+    the rung's tail into scratch) and write_tokens (3 more tokens, one of
+    them a padded lane on the scratch block), beside its numpy model."""
+    rng = np.random.RandomState(seed)
+    pool = KVCachePool(block_size=BS, num_blocks=NB, max_blocks_per_seq=4,
+                       kv_dtype=kv_dtype)
+    model = WirePool(kv_dtype)
+    L = 10                       # a bucket of 10 rows: 3 blocks, 2 real
+    k = rng.randn(H, L, D).astype('float32')
+    v = rng.randn(H, L, D).astype('float32')
+    ids = [5, 2, 0]
+    pool.write_prefill(0, np.asarray(ids, np.int32), k, v)
+    for pos in range(12):        # padded to whole blocks with zeros
+        row = (k[:, pos], v[:, pos]) if pos < L else (np.zeros((H, D), 'f4'),
+                                                      np.zeros((H, D), 'f4'))
+        model.write(ids[pos // BS], pos % BS, *row)
+    tk = rng.randn(H, 3, D).astype('float32')
+    tv = rng.randn(H, 3, D).astype('float32')
+    at = [(2, 3), (9, 0), (0, 0)]
+    pool.write_tokens(0, np.asarray([b for b, _ in at], np.int32),
+                      np.asarray([o for _, o in at], np.int32), tk, tv)
+    for i, (b, o) in enumerate(at):
+        model.write(b, o, tk[:, i], tv[:, i])
+    return pool, model
+
+
+@pytest.mark.parametrize('kv_dtype', KV_DTYPES)
+def test_pool_arrays_are_rows_of_one_token(kv_dtype):
+    pool, _ = filled_pools(kv_dtype)
+    layers, scales = pool.arrays()
+    assert row_lanes(H * D) == 128 and row_lanes(768) == 768 \
+        and row_lanes(320) == 384
+    assert [a.shape for a in layers[0]] == [(NB, BS, 128)] * 2
+    assert {str(a.dtype) for a in layers[0]} == {KV_PAYLOAD_DTYPES[kv_dtype]}
+    assert pool.heads == {0: (H, D)}
+    if kv_dtype == 'int8':
+        assert [a.shape for a in scales[0]] == [(NB, BS, H)] * 2
+    else:
+        assert scales == {}
+    # the lanes past the row's values stay zero, and the sizing solve
+    # prices what is allocated
+    for a in layers[0]:
+        assert not np.asarray(a)[..., H * D:].any()
+    assert pool.bytes_in_hbm() == NB * BS * 2 * kv_row_bytes(H, D, kv_dtype)
+    assert pool.row_bytes() == 2 * kv_row_bytes(H, D, kv_dtype)
+
+
+@pytest.mark.parametrize('kv_dtype', KV_DTYPES)
+def test_writes_then_wire_reads_equal_the_numpy_model(kv_dtype):
+    pool, model = filled_pools(kv_dtype)
+    blocks = [2, 5, 9, 0, 7]          # written, scratch, never written
+    k, v = pool.read_blocks(0, blocks)
+    assert k.shape == v.shape == (H, len(blocks), BS, D)
+    assert k.dtype == v.dtype == model.k.dtype and k.flags.c_contiguous
+    assert np.array_equal(k, model.k[:, blocks])
+    assert np.array_equal(v, model.v[:, blocks])
+    sc = pool.read_block_scales(0, blocks)
+    if kv_dtype != 'int8':
+        assert sc is None
+        return
+    assert sc[0].shape == sc[1].shape == (H, len(blocks), BS)
+    assert np.array_equal(sc[0], model.ks[:, blocks])
+    assert np.array_equal(sc[1], model.vs[:, blocks])
+
+
+@pytest.mark.parametrize('kv_dtype', KV_DTYPES)
+def test_read_blocks_into_a_second_pool_is_byte_exact(kv_dtype):
+    pool, _ = filled_pools(kv_dtype, seed=1)
+    src, dst = [5, 2, 9], [1, 8, 3]
+    k, v = pool.read_blocks(0, src)
+    sc = pool.read_block_scales(0, src) or (None, None)
+    other = KVCachePool(block_size=BS, num_blocks=NB, max_blocks_per_seq=4,
+                        kv_dtype=kv_dtype)
+    other.write_whole_blocks(0, dst, k, v, k_scale=sc[0], v_scale=sc[1])
+    assert other.heads == {0: (H, D)}
+    k2, v2 = other.read_blocks(0, dst)
+    assert k2.tobytes() == k.tobytes() and v2.tobytes() == v.tobytes()
+    if kv_dtype == 'int8':
+        sc2 = other.read_block_scales(0, dst)
+        assert sc2[0].tobytes() == sc[0].tobytes()
+        assert sc2[1].tobytes() == sc[1].tobytes()
+    # and the device arrays themselves: the moved blocks are the same bytes
+    for a, b in zip(pool.arrays()[0][0], other.arrays()[0][0]):
+        assert np.array_equal(np.asarray(a)[src], np.asarray(b)[dst])
+    untouched = [i for i in range(NB) if i not in dst]
+    assert not np.asarray(other.arrays()[0][0][0])[untouched].any()
+
+
+@pytest.mark.parametrize('kv_dtype', KV_DTYPES)
+def test_gather_pages_equals_the_numpy_block_walk(kv_dtype):
+    rng = np.random.RandomState(3)
+    rows = rng.randn(NB, BS, H, D).astype('float32')
+    payload, scales = encode(rows, kv_dtype)
+    tables = np.asarray([[4, 1, 0], [7, 0, 0], [3, 10, 6]], np.int32)
+    pages = np.zeros((NB, BS, row_lanes(H * D)), payload.dtype)
+    pages[..., :H * D] = payload.reshape(NB, BS, H * D)
+    got = np.asarray(_gather_pages(jnp.asarray(pages), jnp.asarray(tables),
+                                   len(tables), H, D, scales))
+    # a row that fills its lanes (no padding) reads the same
+    assert np.array_equal(got, np.asarray(_gather_pages(
+        jnp.asarray(pages[..., :H * D]), jnp.asarray(tables), len(tables),
+        H, D, scales)))
+    assert got.dtype == np.float32 and got.shape == (3, H, 3 * BS, D)
+    for s, table in enumerate(tables):
+        t = 0
+        for block in table:             # the walk: block by block, row by row
+            for off in range(BS):
+                for h in range(H):
+                    want = payload[block, off, h].astype('float32')
+                    if scales is not None:
+                        want = want * scales[block, off, h]
+                    assert np.array_equal(got[s, h, t], want), (s, h, t)
+                t += 1
+
+
+@pytest.fixture(scope='module')
+def lm():
+    with dygraph.guard():
+        np.random.seed(0)
+        model = TransformerLM(CausalLMConfig.tiny())
+        model.eval()
+        yield model
+
+
+def _engine(lm, kv_dtype):
+    # 37 blocks: no activation of the tiny model has a pool array's size
+    eng = DecodeEngine(lm, slots=2, block_size=4, max_blocks=37,
+                       max_prompt_len=16, max_new_tokens_cap=8,
+                       prefix_cache=False, kv_dtype=kv_dtype)
+    table = eng.reserve_table(5, 4)
+    eng.prefill([3, 5, 7, 9, 11], table)         # allocates the pool
+    return eng
+
+
+@pytest.mark.parametrize('kv_dtype', KV_DTYPES)
+def test_engine_programs_alias_the_pool_and_never_move_it(lm, kv_dtype):
+    eng = _engine(lm, kv_dtype)
+    layers, scales = eng.pool.arrays()
+    arrays = [a for arrs in list(layers.values()) + list(scales.values())
+              for a in arrs]
+    assert len(arrays) == lm.num_cache_layers * (4 if kv_dtype == 'int8'
+                                                 else 2)
+    for bucket in (None, 8):
+        lowered = eng.lowered(bucket)
+        # donation held: every pool argument is aliased to a result
+        assert lowered.as_text().count('tf.aliasing_output') == len(arrays)
+        assert eng.pool_moves(bucket) == []
+
+
+def test_the_detector_sees_the_copies_the_head_major_pool_had():
+    """Lines of the programs compiled for the v5e before this layout (PERF.md
+    section 6, PR 27), and lines that are not the pool's."""
+    pool = 12 * 4104 * 16 * 64
+    text = '''
+  %copy.63 = f32[12,4104,16,64]{3,2,1,0:T(8,128)} copy(%layers_0__0_.1), sharding={replicated}
+  %copy.64 = bf16[12,4104,16,64]{3,2,0,1:T(8,128)(2,1)} copy(%fusion.26), metadata={op_name="jit(run)/jit(_scatter_blocks)/scatter"}
+  %copy-start.2 = (f32[4104,16,768]{2,1,0:T(8,128)}, f32[4104,16,768]{2,1,0:T(8,128)S(1)}, u32[]{:S(2)}) copy-start(%fusion.39)
+  %transpose.1 = f32[4104,16,12,64]{3,2,1,0} transpose(%p), dimensions={1,2,0,3}
+  %scatter.5 = f32[4104,16,768]{2,1,0:T(8,128)} scatter(%a, %b, %c), to_apply=%region
+  %copy.9 = f32[128,12,64]{2,1,0} copy(%x)
+  ROOT %fusion.3 = f32[4104,16,768]{2,1,0:T(8,128)} fusion(%p.1, %p.2), kind=kLoop
+'''
+    found = _moves_of_size(text, {pool})
+    assert [line.split(' = ')[0] for line in found] == [
+        '%copy.63', '%copy.64', '%copy-start.2', '%transpose.1']
+
+
+@pytest.fixture(scope='module')
+def v5e():
+    """One chip of a described (not attached) v5e: its compiler is
+    installed here and lays arrays out as the chip's does, which the CPU's
+    cannot show. Described inside the fixture, by the one worker that runs
+    this file."""
+    import os
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault('TPU_LOG_DIR', 'disabled')
+    try:
+        topo = topologies.get_topology_desc(platform='tpu',
+                                            topology_name='v5e:2x2')
+    except Exception as e:              # no TPU compiler on this machine
+        pytest.skip(f'no v5e:2x2 topology can be described here: {e}')
+    # an executable for a described chip is written to the persistent cache
+    # and cannot be read back without one: keep these out of it
+    jax.config.update('jax_enable_compilation_cache', False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update('jax_enable_compilation_cache', True)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize('kv_dtype', ['f32', 'bf16'])
+def test_compiled_for_the_chip_no_program_moves_the_pool(v5e, kv_dtype):
+    """At GPT-1's row (12 heads of 64: 768 = 6 × 128 lanes) the TPU's
+    compact layout of a pool argument is the row-major one the scatter and
+    the gather use: the step and a prefill rung hold no pool-sized copy,
+    where the head-major pool had 8 and 12 at this size (compiled here: no
+    chip, no time)."""
+    cfg = CausalLMConfig(vocab_size=512, hidden_size=768,
+                         num_hidden_layers=2, num_attention_heads=12,
+                         intermediate_size=1024, max_position_embeddings=64)
+    with dygraph.guard():
+        model = TransformerLM(cfg)
+        model.eval()
+        eng = DecodeEngine(model, slots=8, block_size=16, max_blocks=521,
+                           max_prompt_len=32, max_new_tokens_cap=16,
+                           prefix_cache=False, kv_dtype=kv_dtype)
+        eng.prefill([3, 5, 7], eng.reserve_table(3, 2))
+        pool = eng.pool.arrays()[0][0][0]
+        assert pool.shape == (521, 16, 768)
+        for bucket in (None, 32):
+            text = eng.lowered(bucket, v5e).compile().as_text()
+            # the pool's arguments lie row-major, and nothing moves them
+            assert f'[521,16,768]{{2,1,0' in text
+            assert _moves_of_size(text, {pool.size}) == []

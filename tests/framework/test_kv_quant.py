@@ -261,12 +261,13 @@ def test_paged_attention_cost_prices_quantized_pool():
     """The generic byte model prices an int8 pool as 1 B/elem payload plus
     4 B/row scales — the pool-bytes delta vs f32 is exactly the storage
     saving (3.56x at head_dim 32), and the scale slots must be typed f32
-    rank 3 matching the pages (InferError otherwise)."""
+    rank 3 (NB, BS, H) matching the pages' (NB, BS, H·D) blocks and
+    splitting their rows into heads (InferError otherwise)."""
     from paddle_tpu.analysis.infer import InferError
     H, NB, BS, D, S, nbs = 2, 8, 16, 32, 3, 4
     base = {'q': ((S, H, D), 'float32'),
-            'kp': ((H, NB, BS, D), 'float32'),
-            'vp': ((H, NB, BS, D), 'float32'),
+            'kp': ((NB, BS, H * D), 'float32'),
+            'vp': ((NB, BS, H * D), 'float32'),
             'bt': ((S, nbs), 'int32'), 'cl': ((S,), 'int32')}
     slots = {'q': ['q'], 'k_pages': ['kp'], 'v_pages': ['vp'],
              'block_tables': ['bt'], 'context_lens': ['cl']}
@@ -274,8 +275,9 @@ def test_paged_attention_cost_prices_quantized_pool():
     t_pad = nbs * BS
     assert c32.flops == S * H * t_pad * (4 * D + 8 + 2)
 
-    q8 = dict(base, kp=((H, NB, BS, D), 'int8'), vp=((H, NB, BS, D), 'int8'),
-              ks=((H, NB, BS), 'float32'), vs=((H, NB, BS), 'float32'))
+    q8 = dict(base, kp=((NB, BS, H * D), 'int8'),
+              vp=((NB, BS, H * D), 'int8'),
+              ks=((NB, BS, H), 'float32'), vs=((NB, BS, H), 'float32'))
     s8 = dict(slots, k_scales=['ks'], v_scales=['vs'])
     c8 = _paged_op_cost(q8, s8)
     assert c8.flops == c32.flops + 2 * S * H * t_pad * D  # dequant term
@@ -283,8 +285,9 @@ def test_paged_attention_cost_prices_quantized_pool():
     pool_i8 = 2 * H * NB * BS * (D + 4)                   # 1 B/elem + 4 B/row
     assert c32.bytes_in - c8.bytes_in == pool_f32 - pool_i8
 
-    for bad in ({'ks': ((H, NB, BS), 'int32')},           # wrong dtype
-                {'ks': ((H, NB), 'float32')},             # wrong rank
-                {'ks': ((H, NB + 1, BS), 'float32')}):    # shape mismatch
+    for bad in ({'ks': ((NB, BS, H), 'int32')},           # wrong dtype
+                {'ks': ((NB, BS), 'float32')},            # wrong rank
+                {'ks': ((NB + 1, BS, H), 'float32')},     # other blocks
+                {'ks': ((NB, BS, H + 1), 'float32')}):    # no whole heads
         with pytest.raises(InferError, match='k_scales'):
             _paged_op_cost(dict(q8, **bad), s8)

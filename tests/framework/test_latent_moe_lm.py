@@ -18,7 +18,7 @@ from paddle_tpu.dygraph.tape import Tensor, no_grad_guard
 from paddle_tpu.models.latent_moe_lm import LatentMoEConfig, LatentMoELM
 from paddle_tpu.ops import llm_ops
 from paddle_tpu.serving.decode import DecodeEngine
-from paddle_tpu.serving.decode.kv_cache import latent_row_lanes
+from paddle_tpu.serving.decode.kv_cache import row_lanes
 from paddle_tpu.serving.errors import UnsupportedCacheFeature
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), '..', '..'))
@@ -121,7 +121,7 @@ def test_prefill_then_decode_through_the_latent_pool(lm, params, kv_dtype,
     # values in the next multiple of 128 lanes
     layers, scales = engine.pool.arrays()
     assert len(layers) == lm.cfg.num_hidden_layers and not scales
-    assert lm.cfg.latent_row_width == 20 and latent_row_lanes(576) == 640
+    assert lm.cfg.latent_row_width == 20 and row_lanes(576) == 640
     for arrs in layers.values():
         assert [a.shape for a in arrs] == [(64, 4, 128)]
     assert engine.pool.row_bytes() == 128 * (4 if kv_dtype == 'f32' else 2)
@@ -593,9 +593,11 @@ def test_the_budget_solve_prices_what_the_model_caches(lm, kind):
         from paddle_tpu.serving.tier.replica import build_tiny_lm
         with guard():
             model = build_tiny_lm()
-        heads, dim = 2, 16
-        per_token = {'f32': 2 * heads * dim * 4, 'bf16': 2 * heads * dim * 2,
-                     'int8': 2 * heads * (dim + 4)}
+        # a K and a V row of 2 heads x 16 in the 128 lanes the pool gives
+        # them; int8 with an f32 scale a head
+        heads, lanes = 2, row_lanes(2 * 16)
+        per_token = {'f32': 2 * lanes * 4, 'bf16': 2 * lanes * 2,
+                     'int8': 2 * (lanes + 4 * heads)}
     layers = model.cfg.num_hidden_layers
     state = sum(p.value.nbytes for p in model.parameters())
     for dtype, row in per_token.items():
@@ -636,7 +638,7 @@ def test_the_scopes_reach_the_compiled_programs_op_names(lm):
     coords = decode_coords(engine.pool, [table, None], [6, 1])
     program = _Program.of(lm)
     pvals = {n: p.value for n, p in lm.named_parameters()}
-    lanes = latent_row_lanes(lm.cfg.latent_row_width)
+    lanes = row_lanes(lm.cfg.latent_row_width)
     layers = {i: [jnp.zeros((64, 4, lanes), jnp.float32)]
               for i in range(lm.cfg.num_hidden_layers)}
     text = program.jitted.lower(

@@ -30,10 +30,15 @@ def whole_seq_reference(q_rows, k_rows, v_rows):
     return np.asarray(jnp.matmul(jax.nn.softmax(s, -1), v4))[0]
 
 
+def rows_of(x):
+    """(H, n, D) head-major rows → the pool's (n, H·D) rows of one token."""
+    return x.transpose(1, 0, 2).reshape(x.shape[1], -1)
+
+
 def build_cache(rng, num_blocks, tables_rows):
     """Fill per-slot rows into distinct blocks; returns (pages, tables,
-    per-slot row arrays)."""
-    k_pages = np.zeros((H, num_blocks, BS, D), 'float32')
+    per-slot row arrays). Pages are (num_blocks, BS, H·D)."""
+    k_pages = np.zeros((num_blocks, BS, H * D), 'float32')
     v_pages = np.zeros_like(k_pages)
     tables, k_rows, v_rows = [], [], []
     nxt = 1
@@ -43,8 +48,8 @@ def build_cache(rng, num_blocks, tables_rows):
         table = []
         for j in range(nb):
             table.append(nxt)
-            k_pages[:, nxt] = kr[:, j * BS:(j + 1) * BS]
-            v_pages[:, nxt] = vr[:, j * BS:(j + 1) * BS]
+            k_pages[nxt] = rows_of(kr[:, j * BS:(j + 1) * BS])
+            v_pages[nxt] = rows_of(vr[:, j * BS:(j + 1) * BS])
             nxt += 1
         table += [0] * (MAXBPS - nb)
         tables.append(table)
@@ -113,15 +118,15 @@ def test_block_reuse_no_stale_bleed():
     lens = np.asarray([c], np.int32)
 
     def run(fill):
-        k_pages = np.full((H, 8, BS, D), fill, 'float32')
+        k_pages = np.full((8, BS, H * D), fill, 'float32')
         v_pages = np.full_like(k_pages, fill)
         for j in range(2):
-            k_pages[:, j + 1] = k_rows[:, j * BS:(j + 1) * BS]
-            v_pages[:, j + 1] = v_rows[:, j * BS:(j + 1) * BS]
+            k_pages[j + 1] = rows_of(k_rows[:, j * BS:(j + 1) * BS])
+            v_pages[j + 1] = rows_of(v_rows[:, j * BS:(j + 1) * BS])
         # stale garbage INSIDE the table beyond the context: positions
         # c.. of block 2 keep whatever the previous tenant wrote
-        k_pages[:, 2, c - BS:] = fill
-        v_pages[:, 2, c - BS:] = fill
+        k_pages[2, c - BS:] = fill
+        v_pages[2, c - BS:] = fill
         return np.asarray(paged_attention(q, k_pages, v_pages, table, lens,
                                           sm_scale=float(SCALE)))
 
@@ -138,8 +143,8 @@ def _sds(shape, dtype=jnp.float32):
 
 # chip_smoke's serve geometry: 8 slots, 8 heads of 64, 16-token pages,
 # 12 pages per sequence (128-token prompts + 64 new tokens)
-_Q64, _POOL64 = _sds((8, 8, 64)), _sds((8, 256, 16, 64))
-_Q128, _POOL128 = _sds((8, 4, 128)), _sds((4, 256, 16, 128))
+_Q64, _POOL64 = _sds((8, 8, 64)), _sds((256, 16, 8 * 64))
+_Q128, _POOL128 = _sds((8, 4, 128)), _sds((256, 16, 4 * 128))
 _TABLES = _sds((8, 12), jnp.int32)
 
 
@@ -175,8 +180,12 @@ def test_dispatch_on_tpu_follows_the_kernels_own_rules(monkeypatch):
     assert not paged_kernel_applies(_Q64, _POOL64, _TABLES, 4)  # head_dim 64
     assert not paged_kernel_applies(_Q128, _POOL128, _TABLES, 5)  # 12 % 5
     assert paged_kernel_applies(_Q128, _POOL128, _sds((8, 3), jnp.int32), 4)
-    assert not paged_kernel_applies(_Q128, _sds((4, 256, 16, 128),
+    assert not paged_kernel_applies(_Q128, _sds((256, 16, 4 * 128),
                                                jnp.bfloat16), _TABLES, 4)
+    # grouped queries: 4 query heads over a row of 2 KV heads, not of 3
+    assert paged_kernel_applies(_Q128, _sds((256, 16, 2 * 128)), _TABLES, 4)
+    assert not paged_kernel_applies(_Q128, _sds((256, 16, 3 * 128)),
+                                    _TABLES, 4)
     assert not paged_kernel_applies(_sds((8, 4, 4, 128)), _POOL128,
                                     _TABLES, 4)       # multi-query verify
 
@@ -230,7 +239,7 @@ def test_accepted_shapes_reach_the_kernel_and_refusals_propagate(
     q = np.zeros((1, 2, 128, 16), 'float32')
     with pytest.raises(AssertionError, match='pallas kernel called'):
         fused_attention(q, q, q, sm_scale=1.0, causal=True)
-    pool = np.zeros((2, 8, 4, 128), 'float32')
+    pool = np.zeros((8, 4, 2 * 128), 'float32')
     with pytest.raises(AssertionError, match='pallas kernel called'):
         paged_prefill_attention(
             np.zeros((1, 2, 128, 128), 'float32'),
