@@ -32,8 +32,8 @@ Layers:
   the user program on every compile-cache miss.
 
 All verification is program-BUILD-time work (it runs on compile-cache
-misses, never per step); tools/bench_verify.py prices it (<2% on the
-bench recipe, PERF.md §17). ``tools/lint_program.py`` runs the same
+misses, never per step), recorded as ``program_verify_seconds`` beside
+``executor_compile_seconds``. ``tools/lint_program.py`` runs the same
 checks from the command line over saved inference models or recipe
 builders.
 """

@@ -283,7 +283,8 @@ def stage_cut_candidates(program, fetch_names=(), feed_names=(),
     """Every cuttable forward boundary, in program order: the names of
     single-non-persistable-output activations later ops read — the same
     candidate set ``solve_stage_cuts`` optimizes over, exposed so manual
-    cuts can be enumerated against the auto-cut (tools/bench_pp.py)."""
+    cuts can be enumerated against the auto-cut
+    (tests/framework/test_pp_schedules.py)."""
     base = plan_program(program, fetch_names=fetch_names,
                         feed_names=feed_names, feed_shapes=feed_shapes,
                         assume_dim=assume_dim, checkpoints=[])

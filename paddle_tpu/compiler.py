@@ -88,7 +88,7 @@ class ExecutionStrategy:
     non-blocking :class:`~paddle_tpu.core.fetch_handle.FetchHandle` s, and
     the executor blocks on the oldest handle only when the window is full.
     `2` is classic double buffering (host feed prep + dispatch of step N+1
-    overlap device execution of step N — PERF.md §12). The
+    overlap device execution of step N). The
     `PADDLE_TPU_ASYNC` env var overrides it either way; `num_threads` /
     `num_iteration_per_drop_scope` stay accepted-for-compat no-ops (the
     step is one XLA program; scopes hold no transient kernels)."""

@@ -1,6 +1,6 @@
 """Non-blocking fetch handles + the bounded in-flight dispatch window.
 
-The async train-loop pipeline (PERF.md §12): every `Executor.run` fetch ends
+The async train-loop pipeline: every `Executor.run` fetch ends
 in `np.asarray`, a blocking device→host sync that serializes host feed prep,
 device compute, and D2H — the per-step input/host-wait loss arXiv:1909.09756
 identifies as the dominant non-compute cost at high step rates. Instead of

@@ -25,7 +25,7 @@ class Conv2D(Layer):
             else (filter_size, filter_size)
         std = math.sqrt(2.0 / (fs[0] * fs[1] * num_channels))
         # NHWC keeps HWIO weights so the conv lowers with no layout
-        # transposes (PERF.md §2: NHWC end-to-end is ~6% faster on v5e)
+        # transposes (NHWC end-to-end was ~6% faster on v5e in round 4)
         wshape = ([num_filters, num_channels // groups, fs[0], fs[1]]
                   if data_format == 'NCHW'
                   else [fs[0], fs[1], num_channels // groups, num_filters])

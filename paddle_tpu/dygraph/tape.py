@@ -8,7 +8,7 @@ walks the tape in reverse topological order. Under `jit.to_static` the tape
 records through tracers, so the whole step can still fuse into one XLA program.
 
 Hot path: repeated eager dispatches reuse jitted kernels from an LRU cache
-(see _EagerKernelCache below; PERF.md §9) instead of re-tracing per call.
+(see _EagerKernelCache below) instead of re-tracing per call.
 """
 from __future__ import annotations
 

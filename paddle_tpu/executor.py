@@ -1111,7 +1111,8 @@ class Executor:
           always returns FetchHandles and keeps up to K dispatched steps
           outstanding, blocking on the oldest handle only when the window
           is full — host feed prep and dispatch of step N+1 overlap device
-          execution of step N (PERF.md §12, tools/bench_pipeline.py).
+          execution of step N (semantics pinned by
+          tests/framework/test_async_pipeline.py).
         """
         # hang watchdog (resilience/watchdog.py, PADDLE_TPU_WATCHDOG): a
         # wedged device step breaches the 'executor_step' lease — deadline
@@ -1229,7 +1230,7 @@ class Executor:
         # (measured: the whole overlap win disappears on the CPU PJRT
         # client), and K-deep double buffering fundamentally needs the old
         # and new state live at once. The cost is the classic double-buffer
-        # transient (2× pipelined-state HBM) — PERF.md §12.
+        # transient (2× pipelined-state HBM); a CPU finding, ROADMAP S7.
         inflight_k = resolve_inflight_steps(exec_strategy)
         use_handles = bool(inflight_k) or not return_numpy
         if inflight_k:

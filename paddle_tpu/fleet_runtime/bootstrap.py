@@ -24,11 +24,10 @@ Bring-up order (each step idempotent): parse env → ``jax.distributed
 Partitioner's mesh from the now-GLOBAL device list → install the
 :class:`~paddle_tpu.fleet_runtime.coordinator.FleetSentinel`.
 
-``local_fleet(nproc)`` is the test/bench spawner: it launches ``nproc``
+``local_fleet(nproc)`` is the test spawner: it launches ``nproc``
 REAL ``jax.distributed`` CPU worker processes (one device each) with the
-full fleet env wired — generalizing what ``bench_collectives --nproc``
-hand-rolled — so multi-host behavior is exercised by actual multi-process
-rendezvous, not simulation.
+full fleet env wired, so multi-host behavior is exercised by actual
+multi-process rendezvous, not simulation.
 """
 from __future__ import annotations
 
@@ -355,9 +354,9 @@ def local_fleet(nproc, script, args=(), env=None, rank_env=None,
     ``python script args...`` with the complete fleet env wired
     (endpoints on free localhost ports, coordinator = endpoint 0,
     ``JAX_PLATFORMS=cpu``, ``XLA_FLAGS`` stripped so each process owns
-    exactly one device). This is the generalization of what
-    ``bench_collectives --nproc`` hand-rolled, shared by the fleet tests
-    and ``tools/bench_fleet.py``.
+    exactly one device). Shared by the fleet tests
+    (tests/framework/test_fleet_runtime.py, test_fleet_crash_resume.py,
+    test_elastic_resize.py).
 
     `env` merges extra vars into every rank; `rank_env` is
     ``{rank: {var: value}}`` per-rank overrides (fault injection on ONE

@@ -149,7 +149,8 @@ def greedy_generate(model, prompt_ids, max_new_tokens, eos_id=None,
     models/transformer.py's decode retracing). This is the REFERENCE the
     decode engine is tested against: run it with
     ``pad_len == engine.padded_context`` and the streamed tokens must be
-    identical (tools/bench_decode.py asserts it on every request).
+    identical (tests/framework/test_decode_engine.py asserts it on every
+    request).
 
     Returns the generated token ids (list, ≤ max_new_tokens; stops at
     ``eos_id``).

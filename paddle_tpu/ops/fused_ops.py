@@ -20,7 +20,7 @@ the same values is bit-identical, which the pass-parity suite asserts.
 
 All three bundle ops are update ops (they run after the backward marker,
 outside jax.value_and_grad), so they need no custom vjp. Tradeoff,
-measured on CPU (PERF.md §10): XLA's backend compile of the bundled
+measured on CPU (PR 3): XLA's backend compile of the bundled
 update costs ~5-10% more than N small per-param kernels — paid once EVER
 per program via the persistent compile cache (PR 1) — while the trace,
 which every cold process pays on every cache hit, shrinks ~1.4×.

@@ -693,8 +693,8 @@ def fused_attention(q, k, v, bias=None, *, sm_scale=1.0, causal=False):
     flash-attention kernel (jax.experimental.pallas.ops.tpu.flash_attention
     — online softmax, no S×S materialization, custom vjp); everywhere else
     it is the XLA softmax(QKᵀ)V form that the compiler fuses. Measured on
-    v5e (PERF.md §3): XLA wins on raw step time up to
-    S=2048 (56-73 TF/s vs 13-26), so this op is NOT the default attention
+    v5e (round 4, `git show b8d4f4f:PERF.md` §3): XLA wins on raw step time
+    up to S=2048 (56-73 TF/s vs 13-26), so this op is NOT the default attention
     path — its value is the O(S) memory footprint for long-context configs
     where the S×S score tensor won't fit."""
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)

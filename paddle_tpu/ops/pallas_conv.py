@@ -1,4 +1,4 @@
-"""TPU conv-efficiency kernels (PERF.md §1 "Where the ceiling is"):
+"""TPU conv-efficiency kernels (ROADMAP S5):
 
 1. `stem_space_to_depth` — the 7×7/s2 ResNet stem re-laid-out as a 4×4/s1
    conv on a 2×2 space-to-depth grid (input 224×224×3 → 112×115×12-ish).

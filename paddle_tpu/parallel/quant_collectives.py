@@ -197,7 +197,8 @@ def record_sparse_collective(path, num_rows, dim, comm_dtype, axis_size,
     """Count one sparse push: bytes on wire at the COO+codec size, f32
     equivalent = the dense all-reduce of the ``dense_elems``-element
     table this push replaced — their ratio is the headline sparse win
-    (tools/bench_sparse.py measures it). No-op with telemetry off."""
+    (tests/ops/test_sparse_ops.py holds its arithmetic). No-op with
+    telemetry off."""
     if not _obs._ENABLED:
         return
     comm = resolve_comm_dtype(comm_dtype)

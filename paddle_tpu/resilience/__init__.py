@@ -7,8 +7,8 @@ The design constraints (ROADMAP item 5, docs/RESILIENCE.md):
    non-blocking FetchHandles at a step boundary (donation-protected through
    the executor's inflight window, or cloned on-device for the donating
    fused TrainStep); a background writer overlaps the D2H + serialization +
-   atomic commit with subsequent compute. Stall per checkpoint < 1 step
-   (``tools/bench_resilience.py``).
+   atomic commit with subsequent compute (the stall per checkpoint is
+   ``checkpoint_stall_seconds``; not yet measured on the chip).
 2. **A committed checkpoint is never torn.** Payload and manifest are each
    written temp-in-dir + fsync + ``os.replace``; the manifest (with payload
    size + CRC32) is the commit marker and is written last. Discovery

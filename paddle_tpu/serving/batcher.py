@@ -3,7 +3,7 @@
 The throughput problem with per-request TPU dispatch is fixed cost: one
 device call costs roughly the same whether it carries 1 row or 16, so a
 server that dispatches per request wastes almost the whole machine
-(PERF.md §11 measures ~15× at bucket 16 on CPU). The batcher turns N
+(~15× at bucket 16, a CPU figure of PR 4). The batcher turns N
 concurrent small requests into one bucketed device call:
 
     submit() ─ validate ─▶ bounded queue ─▶ worker thread ─▶ engine.run_batch
@@ -82,9 +82,9 @@ class PredictionFuture:
         """``fn(future)`` runs when the outcome lands — on the completing
         (batcher worker) thread, or immediately on the caller if already
         done. Open-loop load generators use this to timestamp completions
-        without a waiter thread per in-flight request (tools/
-        bench_serving.py's Poisson section). Keep callbacks cheap: they
-        run on the serving hot path. Callback exceptions are swallowed."""
+        without a waiter thread per in-flight request. Keep callbacks
+        cheap: they run on the serving hot path. Callback exceptions are
+        swallowed."""
         with self._cb_lock:
             if not self._done.is_set():
                 self._callbacks.append(fn)

@@ -15,11 +15,10 @@ The scheduling model is S fixed decode slots stepped in lockstep:
 
 **Continuous vs drain** (``admission=``): 'continuous' admits into freed
 slots every step — the batch never drains, so slot occupancy stays near 1
-under backlog. 'drain' (the strawman tools/bench_decode.py measures
-against) only admits when ALL slots are free: short requests finish early
-and their slots idle until the longest in the wave completes. The measured
-gap on a mixed-length workload is the PR's ≥1.5× acceptance bar
-(PERF.md §13).
+under backlog. 'drain' (the strawman tests/framework/test_decode_engine.py
+counts steps against; ROADMAP D12) only admits when ALL slots are free:
+short requests finish early and their slots idle until the longest in the
+wave completes: 1.3× the lockstep steps on a heavy-tailed workload.
 
 Admission takes the request's full block reservation (prompt + token
 budget) up front, so a generation can never die of OutOfBlocks mid-flight;

@@ -64,7 +64,7 @@ def build_replica_stack(model=None, seed=DEFAULT_SEED, slots=2, block_size=4,
     """(engine, scheduler, prefill_worker|None) — the replica's serving
     stack minus the HTTP listener. ``prefix_cache``/``disagg`` default to
     their env knobs, ``kv_dtype`` (handed to the engines) to its. Used by the CLI below and, in-process, by
-    tests/framework/test_serving_tier.py and tools/bench_router.py
+    tests/framework/test_serving_tier.py and benchmark/runners/
     (in-process multi-replica setups pass ONE shared ``model_lock`` so
     concurrent scheduler workers serialize their model calls)."""
     from ..decode import DecodeEngine, DecodeScheduler

@@ -73,6 +73,48 @@ def test_repeat_dispatch_hits_cache():
     assert s['misses'] == 1 and s['hits'] == 4
 
 
+def _resnet_block():
+    from paddle_tpu.models.resnet import BottleneckBlock
+    return BottleneckBlock(64, 16, stride=1, shortcut=True), (2, 64, 8, 8)
+
+
+def _bert_layer():
+    from paddle_tpu.models.bert import BertConfig, TransformerLayer
+    cfg = BertConfig(hidden_size=64, num_attention_heads=2,
+                     intermediate_size=128, hidden_dropout_prob=0.0,
+                     attention_probs_dropout_prob=0.0)
+    return TransformerLayer(cfg), (1, 8, 64)
+
+
+@pytest.mark.parametrize('make_block', [_resnet_block, _bert_layer])
+def test_repeated_train_step_of_a_model_block_hits_cache(make_block):
+    """A whole eager training step (forward, tape backward, SGD update) of
+    a ResNet bottleneck and of a BERT transformer layer: the first step
+    fills the cache, and every dispatch of the steps after it is a hit."""
+    with dygraph.guard():
+        model, shape = make_block()
+        opt = fluid.optimizer.SGD(0.01, parameter_list=model.parameters())
+        x = dygraph.to_variable(
+            np.random.RandomState(0).randn(*shape).astype(np.float32))
+
+        def step():
+            out = model(x)
+            loss = dispatch_op('reduce_mean', {'x': out * out}, {})
+            loss.backward()
+            opt.minimize(loss)
+            opt.clear_gradients()
+            return float(loss.value)
+
+        first = step()
+        filled = kernel_cache.stats()
+        assert filled['misses'] > 0
+        later = [step(), step()]
+        s = kernel_cache.stats()
+    assert s['misses'] == filled['misses'], (filled, s)
+    assert s['hits'] > filled['hits']
+    assert np.isfinite([first] + later).all()
+
+
 def test_distinct_shapes_and_attrs_miss():
     with dygraph.guard():
         a = dygraph.to_variable(np.ones((2, 2), np.float32))

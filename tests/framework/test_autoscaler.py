@@ -250,6 +250,94 @@ def test_autoscaler_ramp_up_and_down_zero_drops(lm):
                 pass
 
 
+def test_control_loop_thread_follows_a_closed_loop_load(lm):
+    """The control loop on its own thread, deciding from the REAL windowed
+    series (the test above scripts both): eight closed-loop clients on a
+    two-slot replica keep requests queued, a depth no CPU speed changes,
+    so the tier grows, the cold replica joining behind the warmup gate;
+    the clients stop and sustained low occupancy drains it back to one.
+    Every request of the run completes with the reference bytes, the
+    replica count stays within the cap, every decision names its trigger.
+    The deadlines are hang guards."""
+    from paddle_tpu.observability import distributed as dobs
+    dobs.reset_distributed()
+    # short signal windows, so the load's end shows inside the test
+    # (production default: 6 x 10 s)
+    for name in ('queue_depth', 'occupancy', 'ttft'):
+        dobs.series(name, window_s=1.0, windows=3)
+    lock = threading.RLock()
+    replicas, launched = {}, []
+
+    def launch():
+        rep = _InProcReplica(lm, lock, f'loop-{len(launched) + 1}',
+                             warm=False)
+        replicas[rep.url] = rep
+        launched.append(rep.url)
+        # cold start on a thread: the warmup gate holds traffic off it
+        threading.Thread(target=rep.engine.warmup, daemon=True).start()
+        return rep.url
+
+    def retire(url):
+        replicas.pop(url).shutdown()
+
+    seed = _InProcReplica(lm, lock, 'loop-0', warm=True)
+    replicas[seed.url] = seed
+    router = Router([seed.url], health_poll_s=0.25)
+    cfg = AutoscaleConfig(min_replicas=1, max_replicas=2, interval_s=0.2,
+                          up_queue=1.0, up_ttft_s=60.0, down_occupancy=0.25,
+                          cooldown_s=1.5, down_delay_s=2.0)
+    scaler = Autoscaler(router, CallableReplicaLauncher(launch, retire), cfg)
+
+    prompt = [5, 9, 2, 44]
+    ref = greedy_generate(lm, prompt, 4, pad_len=seed.engine.padded_context)
+    results, errors, sizes = [], [], []
+    stop = threading.Event()
+
+    def client():
+        while not stop.is_set():
+            try:
+                results.append(router.generate(prompt, max_new_tokens=4,
+                                               timeout=60))
+            except Exception as e:   # noqa: BLE001 — the drill counts drops
+                errors.append(e)
+            sizes.append(len(router.replicas))
+
+    clients = [threading.Thread(target=client) for _ in range(8)]
+    try:
+        for t in clients:
+            t.start()
+        # launch() returns before the scaler hands the url to the router
+        assert _wait_until(
+            lambda: any(r.url in launched and r.routable()
+                        for r in router.replicas), timeout=90), \
+            scaler.decisions
+        answered = len(results)
+        assert _wait_until(lambda: len(results) >= answered + 16, timeout=60)
+        stop.set()
+        for t in clients:
+            t.join(90)
+        assert not any(t.is_alive() for t in clients)
+        assert _wait_until(lambda: len(router.replicas) == 1, timeout=90), \
+            scaler.decisions
+    finally:
+        stop.set()
+        scaler.close()
+        router.close()
+        for rep in list(replicas.values()):
+            try:
+                rep.shutdown()
+            except Exception:
+                pass
+        dobs.reset_distributed()
+    assert not errors, errors[:3]
+    assert results and all(r['tokens'] == ref for r in results)
+    assert {r['replica'] for r in results} == {seed.url, launched[0]}
+    acts = [d['action'] for d in scaler.decisions]
+    assert 'up' in acts and 'down' in acts
+    assert all(d['trigger'] for d in scaler.decisions)
+    assert 1 < max(sizes) <= cfg.max_replicas
+
+
 def test_autoscaler_min_replicas_floor_spawns():
     """Below min_replicas the scaler launches unconditionally (cold tier
     bring-up), trigger recorded as min_replicas."""

@@ -249,12 +249,14 @@ def _run_recipe(main, start, loss, feed, fuse_on, steps=5):
     return out
 
 
-@pytest.mark.parametrize('recipe', ['mnist_mlp', 'resnet_block'])
+@pytest.mark.parametrize('recipe', ['mnist_mlp', 'deep_wide_mlp',
+                                    'resnet_block'])
 def test_bitwise_parity_pass_on_off(recipe, monkeypatch):
-    if recipe == 'mnist_mlp':
-        main, start, loss = _fleet_mlp(depth=3, width=32)
+    if recipe in ('mnist_mlp', 'deep_wide_mlp'):
+        depth, width = (3, 32) if recipe == 'mnist_mlp' else (4, 64)
+        main, start, loss = _fleet_mlp(depth=depth, width=width)
         rng = np.random.RandomState(0)
-        feed = {'x': rng.randn(16, 32).astype('float32'),
+        feed = {'x': rng.randn(16, width).astype('float32'),
                 'y': rng.randint(0, 10, (16, 1)).astype('int64')}
     else:
         main, start, loss = _fleet_resnet_block()

@@ -49,7 +49,7 @@ def test_handoff_parity_vs_colocated_and_reference(lm):
     eng_d, sched_d, worker = build_replica_stack(model=lm, disagg=True)
     refs = [greedy_generate(lm, p, 6, pad_len=eng_d.padded_context)
             for p in prompts]
-    h0 = _counter('disagg_handoffs')
+    h0, b0 = _counter('disagg_handoffs'), _counter('disagg_kv_bytes')
     try:
         outs = [sched_d.submit(p, max_new_tokens=6).result(120)
                 for p in prompts]
@@ -58,6 +58,7 @@ def test_handoff_parity_vs_colocated_and_reference(lm):
         worker.close()
     assert outs == refs
     assert _counter('disagg_handoffs') - h0 == len(prompts)
+    assert _counter('disagg_kv_bytes') - b0 > 0
     eng_c, sched_c, _ = build_replica_stack(model=lm, disagg=False)
     try:
         colocated = [sched_c.submit(p, max_new_tokens=6).result(120)

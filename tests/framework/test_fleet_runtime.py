@@ -2,8 +2,10 @@
 cross-host primitives (single-host degenerate forms), the poison-flag
 sentinel with its watchdog hook, partitioner-sharded checkpoints
 (forced-sharded on the single-process 8-device mesh), DataLoader per-host
-sharding, sync-BN parity, and the LARS large-batch pieces. The REAL
-multi-process behaviors are covered by test_fleet_crash_resume.py."""
+sharding, sync-BN parity, and the LARS large-batch pieces. One drill here
+starts REAL ``jax.distributed`` workers (1 and 2, through the executor
+spine); kill-and-resume and poison propagation across processes are in
+test_fleet_crash_resume.py."""
 import json
 import os
 
@@ -12,6 +14,71 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import layers as L
+from shared_programs import run_fleet_script
+
+
+# One trainer of a data-parallel fleet through the product spine: fleet env
+# bootstrap, fleet.distributed_optimizer, an Executor fed THIS host's rows of
+# the global batch (weak scaling: the per-host batch is fixed, so the global
+# batch grows with the fleet). Rank 0 writes what it saw.
+FLEET_WORKER = r'''
+import json, sys
+import numpy as np
+from paddle_tpu.fleet_runtime import bootstrap
+bootstrap()
+import jax
+import paddle_tpu as fluid
+from paddle_tpu import layers as L
+from paddle_tpu.parallel import DistributedStrategy, fleet
+
+result_path, per_host, steps = sys.argv[1], int(sys.argv[2]), int(sys.argv[3])
+n, rank = jax.process_count(), jax.process_index()
+global_batch = per_host * n
+
+fluid.seed(7)
+main, start = fluid.Program(), fluid.Program()
+with fluid.program_guard(main, start):
+    x = L.data('bx', [32], dtype='float32')
+    y = L.data('by', [1], dtype='float32')
+    h = L.fc(L.fc(x, size=32, act='relu'), size=32, act='relu')
+    loss = L.mean(L.square_error_cost(L.fc(h, size=1), y))
+    fleet.init()
+    fleet.distributed_optimizer(
+        fluid.optimizer.Momentum(0.01, momentum=0.9),
+        strategy=DistributedStrategy()).minimize(loss)
+
+exe = fluid.Executor()
+exe.run(start)
+rng = np.random.RandomState(0)
+X = rng.randn(global_batch, 32).astype('float32')
+Y = rng.randn(global_batch, 1).astype('float32')
+feed = {'bx': X[rank::n], 'by': Y[rank::n]}
+losses = [float(np.asarray(exe.run(main, feed=feed, fetch_list=[loss])[0]))
+          for _ in range(steps)]
+if rank == 0:
+    with open(result_path, 'w') as f:
+        json.dump({'nproc': n, 'global_batch': global_batch,
+                   'rows_fed_here': int(feed['bx'].shape[0]),
+                   'losses': losses}, f)
+'''
+
+
+@pytest.mark.parametrize('nproc', [1, 2])
+def test_real_distributed_workers_train_to_the_end(tmp_path, nproc):
+    """1 and 2 real ``jax.distributed`` CPU workers (one process a trainer,
+    gloo collectives, the full PADDLE_* env from ``local_fleet``) both run
+    the data-parallel loop to its end: every rank exits 0, the global
+    batch is the per-host batch times the fleet, and the loss falls."""
+    result = tmp_path / 'result.json'
+    rcs, output = run_fleet_script(tmp_path, nproc, FLEET_WORKER,
+                                   [result, 2048, 6])
+    assert rcs == [0] * nproc, output
+    got = json.loads(result.read_text())
+    assert got['nproc'] == nproc
+    assert got['global_batch'] == 2048 * nproc
+    assert got['rows_fed_here'] == 2048
+    assert np.isfinite(got['losses']).all()
+    assert got['losses'][-1] < got['losses'][0]
 
 
 # ---------------------------------------------------------------------------

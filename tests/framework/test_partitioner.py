@@ -167,7 +167,7 @@ def test_fsdp_spec_parity_with_module():
     mesh = make_mesh({'fsdp': 8})
     p = Partitioner(mesh=mesh)
     for shape in [(64, 32), (32, 64), (8,), (3, 5), (1,), (16, 16, 4),
-                  (24, 7), (8, 8)]:
+                  (24, 7), (7, 24), (8, 8)]:
         assert p.fsdp_spec(shape) == F.fsdp_spec(shape, mesh), shape
         assert p.param_spec('w', shape) == F.fsdp_spec(shape, mesh), shape
 
@@ -380,6 +380,43 @@ def test_spmd_step_dp_tp_composition():
                     if s['labels'].get('path') == 'spmd_step')
         assert calls == step.sync_calls_per_step * 5
     np.testing.assert_allclose(losses, ref_losses, rtol=5e-4, atol=1e-6)
+
+
+def _tp_loss(ps, bt):
+    x, y = bt[:, :-1], bt[:, -1:]
+    x = mp_copy(x, 'tp')
+    h = jnp.maximum(x @ ps['ffn1.w'], 0.0)
+    part = h @ ps['ffn2.w']
+    return jnp.mean(((mp_allreduce(part, 'tp') + ps['b']) - y) ** 2)
+
+
+@pytest.mark.parametrize('mesh_shape, loss_fn', [
+    ({'dp': 2, 'fsdp': 4}, _ref_loss), ({'dp': 2, 'tp': 4}, _tp_loss)],
+    ids=['dp_fsdp', 'dp_tp'])
+def test_spmd_step_composition_under_a_partitioner_of_its_own(mesh_shape,
+                                                              loss_fn):
+    """The two compositions through a ``Partitioner`` object handed to the
+    step (the process-global one left unconfigured): four SGD steps stay
+    within 1e-3 of the single-device reference, every gradient sync is
+    counted, and bucketing keeps the syncs of a step at 6 or fewer.
+    Both are red on this image's jax (ROADMAP D1) and kept so, in sight:
+    dp×fsdp runs and DIVERGES (0.633 vs 0.773 at step 2), a wrong answer;
+    dp×tp stops at the graduated ``shard_map``'s typing of ``psum`` over
+    ``('dp', 'tp')``."""
+    params, batch = _composition_fixture()
+    ref_losses, _ = _reference_sgd(_ref_loss, params, batch, 0.1, 4)
+    p = Partitioner(mesh_shape=mesh_shape)
+    with obs.telemetry_guard(True):
+        obs.reset()
+        step = SpmdTrainStep(loss_fn, params, partitioner=p, lr=0.1)
+        losses = [float(step(batch)) for _ in range(4)]
+        m = obs.registry.to_dict()
+    calls = sum(s['value'] for s in m['collective_sync_calls']['samples']
+                if s['labels'].get('path') == 'spmd_step')
+    assert calls == step.sync_calls_per_step * 4
+    assert step.sync_calls_per_step <= 6
+    rel = np.abs((np.asarray(losses) - ref_losses) / ref_losses)
+    assert rel.max() < 1e-3, (losses, ref_losses)
 
 
 def test_spmd_step_bucketed_replicated_grads():

@@ -23,6 +23,7 @@ from paddle_tpu.analysis.stage import (plan_staged_program,
 from paddle_tpu.core.scope import global_scope
 from paddle_tpu.partition.pipeline import (PP_SCHEDULES, pp_microbatches,
                                            pp_schedule)
+from shared_programs import build_bert_layer
 
 
 def _trajectory(schedule, monkeypatch, steps=5, n_micro=4):
@@ -336,6 +337,98 @@ def test_staged_planner_1f1b_peak_below_gpipe():
     # auto-cut candidates cover the boundary set the solver used
     cands = stage_cut_candidates(main, **kw)
     assert cuts[0] in cands and len(cands) >= 2
+
+
+def _compiled_temp_bytes(program, feed, fetch_names, scope):
+    """XLA's temp-buffer bytes for the step the executor compiles for
+    (program, feed, fetches): the same ``_lower``, donation included."""
+    import jax
+    from paddle_tpu import ir
+    from paddle_tpu.core.random import default_generator
+    from paddle_tpu.executor import _lower
+    state_names = sorted(v.name for v in program.list_vars()
+                         if v.persistable and scope.find(v.name) is not None)
+    opt_program, _ = ir.apply_pipeline(
+        program, fetch_names=fetch_names, feed_names=list(feed))
+    step = _lower(opt_program, list(feed), fetch_names, state_names,
+                  feed_shapes={n: v.shape for n, v in feed.items()})
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        {n: scope.find(n) for n in state_names}, {}, feed,
+        default_generator.base_key()).compile()
+    return int(compiled.memory_analysis().temp_size_in_bytes)
+
+
+def test_1f1b_holds_one_wave_planned_and_compiled(monkeypatch):
+    """An activation-heavy deep MLP auto-cut into 2 stages, GPipe against
+    1F1B at the same cut and 4 microbatches through the schedule knob: the
+    losses are bitwise (the same arithmetic, the backward reordered), and
+    1F1B's peak is not above GPipe's both as the staged planner PREDICTS
+    it and as XLA's ``memory_analysis`` of the compiled step MEASURES it,
+    so the prediction is held to the compiler."""
+    from paddle_tpu.core.random import default_generator
+    monkeypatch.delenv('PADDLE_TPU_PP_MICROBATCHES', raising=False)
+    monkeypatch.delenv('PADDLE_TPU_HBM_BUDGET_MB', raising=False)
+    width, depth, bs = 128, 8, 32
+    main, start = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, start):
+        x = layers.data('pp_x', [width], dtype='float32')
+        y = layers.data('pp_y', [1], dtype='float32')
+        h = x
+        for _ in range(depth):
+            h = layers.fc(h, size=width, act='relu')
+        loss = layers.reduce_mean(
+            layers.square_error_cost(layers.fc(h, size=1), y))
+        fluid.optimizer.PipelineOptimizer(
+            fluid.optimizer.SGD(learning_rate=1e-3), num_stages=2,
+            num_microbatches=4, schedule='gpipe').minimize(loss)
+    stamp = next(op.attrs['pipeline']
+                 for op in reversed(main.global_block().ops)
+                 if op.attrs.get('pipeline'))
+    cuts, m = list(stamp['cut_vars']), int(stamp['num_microbatches'])
+    rng = np.random.RandomState(0)
+    feeds = [{'pp_x': rng.randn(bs, width).astype(np.float32),
+              'pp_y': rng.randn(bs, 1).astype(np.float32)}
+             for _ in range(4)]
+    losses, predicted, measured = {}, {}, {}
+    for sched in ('gpipe', '1f1b'):
+        monkeypatch.setenv('PADDLE_TPU_PP_SCHEDULE', sched)
+        predicted[sched] = plan_staged_program(
+            main, cuts, m, schedule=sched, fetch_names=[loss.name],
+            feed_names=['pp_x', 'pp_y'],
+            feed_shapes={k: v.shape for k, v in feeds[0].items()}
+        ).host_peak_bytes
+        scope = fluid.Scope()
+        with fluid.scope_guard(scope):
+            default_generator.seed(42)
+            exe = fluid.Executor()
+            exe.run(start)
+            measured[sched] = _compiled_temp_bytes(main, feeds[0],
+                                                   [loss.name], scope)
+            losses[sched] = [
+                np.asarray(exe.run(main, feed=f, fetch_list=[loss])[0])
+                .tobytes() for f in feeds]
+    assert losses['1f1b'] == losses['gpipe']
+    assert 0 < predicted['1f1b'] <= predicted['gpipe']
+    assert 0 < measured['1f1b'] <= measured['gpipe']
+
+
+def test_auto_cut_within_5_percent_of_the_best_manual_cut():
+    """Every manual single cut of the BERT-layer recipe scored through the
+    staged planner (max per-stage flops + bytes): the cost-model auto-cut
+    lands within 5% of the best of them."""
+    program, _startup, make_feed, loss = build_bert_layer()
+    kw = dict(fetch_names=[loss.name], feed_names=sorted(make_feed()),
+              assume_dim=8)
+
+    def cut_cost(cuts):
+        splan = plan_staged_program(program, cuts, 2, schedule='1f1b', **kw)
+        return max(r.flops + r.bytes for r in splan.stages)
+
+    cands = stage_cut_candidates(program, **kw)
+    assert len(cands) >= 2
+    best_manual = min(cut_cost([c]) for c in cands)
+    auto_cuts, _report = solve_stage_cuts(program, 2, **kw)
+    assert 0 < best_manual <= cut_cost(auto_cuts) <= best_manual * 1.05
 
 
 def test_parallel_pipeline_shim_delegates():

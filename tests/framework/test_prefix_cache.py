@@ -9,6 +9,7 @@ from paddle_tpu.dygraph import guard
 from paddle_tpu.models.causal_lm import greedy_generate
 from paddle_tpu.serving import DecodeEngine, DecodeScheduler, PrefixCache
 from paddle_tpu.serving.tier.replica import build_tiny_lm
+from shared_programs import build_shared_prompt_work
 
 
 @pytest.fixture(scope='module')
@@ -97,6 +98,31 @@ def test_shared_system_prompt_different_suffixes(lm):
                 for p in prompts]
     assert outs == refs
     assert _counter('prefix_cache_hits') - h0 == len(prompts) - 1
+
+
+@pytest.mark.parametrize('enabled', [False, True], ids=['off', 'on'])
+def test_shared_prompt_mix_with_the_cache_on_and_off(lm, enabled):
+    """One 12-token system prompt under twelve short user suffixes,
+    submitted together: bitwise the uncached reference either way; with
+    the cache on the always-on metrics show a hit rate AND prefill tokens
+    saved above zero, with it off no lookup at all."""
+    work = build_shared_prompt_work(12)
+    eng = make_engine(lm, slots=4, max_blocks=256, prefix_cache=enabled)
+    refs = [greedy_generate(lm, p, m, pad_len=eng.padded_context)
+            for p, m in work]
+    names = ('prefix_cache_hits', 'prefix_cache_misses',
+             'prefix_cache_tokens_saved')
+    before = [_counter(n) for n in names]
+    with DecodeScheduler(eng, queue_depth=len(work) + 1) as sched:
+        streams = [sched.submit(p, max_new_tokens=m) for p, m in work]
+        outs = [s.result(300) for s in streams]
+    hits, misses, saved = (_counter(n) - b for n, b in zip(names, before))
+    assert outs == refs
+    if enabled:
+        assert hits > 0 and hits + misses == len(work)
+        assert saved > 0
+    else:
+        assert hits == misses == saved == 0
 
 
 def test_concurrent_mixed_workload_parity(lm):

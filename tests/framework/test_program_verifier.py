@@ -8,7 +8,6 @@ lattice, and regression tests for the latent defects the verifier
 surfaced (clone(for_test) dead vars, generated-layer dtype fallback,
 lstm/gru optional slots)."""
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -23,10 +22,8 @@ from paddle_tpu.compiler import BuildStrategy
 from paddle_tpu.ir.pass_base import Pass, PassContext, PassManager
 from paddle_tpu.ir import get_pass
 
-sys.path.insert(0, os.path.join(
-    os.path.dirname(__file__), '..', '..', 'tools'))
-from bench_passes import (build_bert_layer, build_mlp_adam,  # noqa: E402
-                          build_resnet_block)
+from shared_programs import (build_bert_layer, build_mlp_adam,
+                             build_resnet_block)
 
 _THIS_FILE = os.path.abspath(__file__)
 
@@ -423,7 +420,7 @@ _RECIPES = {
 
 
 def _from_builder(builder):
-    main, _startup, make_feed, fetch = builder(smoke=True)
+    main, _startup, make_feed, fetch = builder()
     feed = make_feed() if callable(make_feed) else make_feed
     return main, [fetch.name], sorted(feed)
 
@@ -556,6 +553,29 @@ def test_executor_full_mode_runs_clean_program(monkeypatch):
     out, = exe.run(main, feed={'x': np.ones((2, 4), np.float32)},
                    fetch_list=[h])
     assert out.shape == (2, 3)
+
+
+@pytest.mark.parametrize('level, ran', [('off', False), ('passes', True)])
+def test_executor_run_verifies_at_passes_level_only(monkeypatch, level, ran):
+    """The verifier rides the REAL Executor build: a cold ``exe.run`` of the
+    multi-param Adam recipe records ``program_verify_seconds`` beside its
+    ``executor_compile_seconds`` at level ``passes``, and nothing at
+    ``off``."""
+    from paddle_tpu import observability as obs
+    monkeypatch.setenv('PADDLE_TPU_VERIFY', level)
+    main, startup, make_feed, loss = build_mlp_adam()
+    with obs.telemetry_guard(True):
+        obs.reset()
+        exe = fluid.Executor()
+        exe.run(startup)
+        exe.run(main, feed=make_feed(), fetch_list=[loss])
+        d = obs.registry.to_dict()
+
+    def count(name):
+        return sum(x['count'] for x in d.get(name, {}).get('samples', []))
+
+    assert count('executor_compile_seconds') >= 1
+    assert (count('program_verify_seconds') > 0) is ran
 
 
 def test_trace_error_names_op_and_site(monkeypatch):

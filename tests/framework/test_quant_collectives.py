@@ -304,6 +304,61 @@ def test_dygraph_bundle_one_reduce_per_dtype():
         assert len(calls) == 2
 
 
+# One rank of a real cross-process dygraph bundle reduce: every rank holds
+# its own seeded gradient per parameter; after apply_collective_grads each
+# holds the sum over ranks.
+_BUNDLE_WORKER = r'''
+import json, os, sys
+import numpy as np
+from paddle_tpu.fleet_runtime import bootstrap
+bootstrap()
+import jax
+import jax.numpy as jnp
+from paddle_tpu import dygraph
+from paddle_tpu.dygraph.nn import Linear
+from paddle_tpu.dygraph.parallel import DataParallel
+
+result_path, comm = sys.argv[1], sys.argv[2]
+n, rank = jax.process_count(), jax.process_index()
+os.environ['PADDLE_TPU_COMM_DTYPE'] = comm
+with dygraph.guard():
+    model = Linear(16, 4)
+    dp = DataParallel(model)
+    rngs = [np.random.RandomState(100 + r) for r in range(n)]
+    want = {}
+    for p in model.parameters():
+        per_rank = [r.randn(*np.shape(p.value)).astype('float32')
+                    for r in rngs]
+        p.grad = jnp.asarray(per_rank[rank])
+        want[id(p)] = np.sum(per_rank, axis=0)
+    dp.apply_collective_grads()
+    err = max(float(np.abs(np.asarray(p.grad) - want[id(p)]).max())
+              for p in model.parameters())
+if rank == 0:
+    with open(result_path, 'w') as f:
+        json.dump({'nproc': n, 'max_err': err}, f)
+'''
+
+
+@pytest.mark.parametrize('comm, bound', [('f32', 0.0), ('int8', 0.5)])
+def test_bundled_reduce_across_two_real_processes(tmp_path, comm, bound):
+    """dygraph ``DataParallel.apply_collective_grads`` over two REAL
+    ``jax.distributed`` CPU processes (gloo): the bundled reduce leaves the
+    per-rank gradients' sum on every rank, exactly at f32 and within the
+    codec's bound at int8."""
+    import json
+    from shared_programs import run_fleet_script
+    result = tmp_path / 'result.json'
+    rcs, output = run_fleet_script(tmp_path, 2, _BUNDLE_WORKER,
+                                   [result, comm])
+    assert rcs == [0, 0], output
+    got = json.loads(result.read_text())
+    assert got['nproc'] == 2
+    assert got['max_err'] <= bound
+    if comm == 'int8':
+        assert got['max_err'] > 0            # it really quantized
+
+
 def test_static_c_allreduce_unbound_axis_is_identity():
     """The graph op lowers to identity outside a shard_map (single-replica
     semantics) — what fleet's inserted sync points do on the GSPMD
@@ -350,6 +405,103 @@ def test_collective_telemetry_counters():
         assert 0 < max(s['max'] for s in errs) < 0.05
     # axis size 1 moves zero bytes (passthrough is local)
     assert qc.wire_bytes(elems, 'int8', 1) == 0
+
+
+@pytest.mark.parametrize('comm', ['f32', 'bf16', 'int8'])
+def test_gradient_volume_allreduce_wire_bytes_and_error(mesh8, comm):
+    """One gradient-volume sync on the 8-device mesh, bytes counted by the
+    telemetry: f32 moves the f32-equivalent bytes and is bitwise the exact
+    ``lax.psum``; bf16 moves exactly half; int8 (payload + one f32 scale
+    per block) at least 3.5x fewer, with a small but nonzero error."""
+    elems = 1 << 16
+    X = np.random.RandomState(0).randn(8, elems).astype('float32')
+    want = np.asarray(compat.shard_map(
+        lambda v: lax.psum(v[0], 'dp')[None], mesh=mesh8,
+        in_specs=P('dp'), out_specs=P('dp'))(jnp.asarray(X)))[0]
+    got = _allreduce(X, mesh8, comm)[0]
+    with obs.telemetry_guard(True):
+        obs.reset()
+        qc.record_collective('testpath', elems, comm, 8)
+        m = obs.registry.to_dict()
+    wire = sum(s['value'] for s in m['collective_bytes_on_wire']['samples'])
+    f32eq = sum(s['value']
+                for s in m['collective_bytes_f32_equiv']['samples'])
+    rel = np.abs(got - want).max() / np.abs(want).max()
+    if comm == 'f32':
+        assert f32eq == wire and np.array_equal(got, want)
+    elif comm == 'bf16':
+        assert f32eq == 2 * wire and 0 < rel < 0.05
+    else:
+        assert f32eq / wire >= 3.5 and 0 < rel < 0.05
+
+
+def _mnist_like(rng, n, in_dim=784, classes=10):
+    """Prototype-digit corpus (the test_mnist_convergence recipe shape):
+    per-class fixed prototypes + pixel noise, learnable by an MLP."""
+    protos = rng.randint(0, 256, (classes, in_dim))
+    labels = rng.randint(0, classes, n)
+    imgs = np.clip(protos[labels] + rng.randint(-80, 80, (n, in_dim)),
+                   0, 255).astype(np.float32) / 255.0
+    return imgs, labels.astype(np.int32)[:, None]
+
+
+def _explicit_sync_dp_step(mesh, params, lr, comm_dtype):
+    """Jitted data-parallel step: batch sharded over 'dp', params
+    replicated, per-shard grads synced with ``qallreduce_mean`` at
+    `comm_dtype` (exact pmean at f32)."""
+    def loss_fn(p, x, y):
+        h = jnp.maximum(x @ p['w1'] + p['b1'], 0.0)
+        logp = jax.nn.log_softmax(h @ p['w2'] + p['b2'])
+        return -jnp.mean(jnp.take_along_axis(logp, y, axis=1))
+
+    def body(p, x, y):
+        loss, grads = jax.value_and_grad(loss_fn)(p, x, y)
+        grads = {k: compat.pcast(
+            qc.qallreduce_mean(g, 'dp', comm_dtype=comm_dtype),
+            'dp', to='varying') for k, g in grads.items()}
+        return ({k: v - lr * grads[k] for k, v in p.items()},
+                lax.pmean(loss, 'dp'))
+
+    pspec = {k: P() for k in params}
+    return jax.jit(compat.shard_map(
+        body, mesh=mesh, in_specs=(pspec, P('dp'), P('dp')),
+        out_specs=(pspec, P())), donate_argnums=(0,))
+
+
+def test_int8_gradient_sync_converges_as_f32_does(mesh8):
+    """The EQuARX quality claim at test scale: the MNIST-shaped MLP trained
+    twice on identical data and init, gradients synced at f32 and at int8;
+    both converge and the int8 run ends within 10% of the f32 run's loss
+    DECREASE (or 15% of its final value). Red on this image's jax (ROADMAP
+    D1) and kept so: ``pcast`` varying→varying is refused, and the step's
+    ``P()`` out_specs are no longer inferred replicated."""
+    from jax.sharding import NamedSharding
+    n, epochs, bs, hidden = 512, 4, 64, 64
+    X, Y = _mnist_like(np.random.RandomState(0), n)
+    data_sh = NamedSharding(mesh8, P('dp'))
+    final, first = {}, None
+    for comm in ('f32', 'int8'):
+        rng = np.random.RandomState(1)
+        params = {
+            'w1': jnp.asarray((rng.randn(784, hidden) * (2.0 / 784) ** 0.5)
+                              .astype(np.float32)),
+            'b1': jnp.zeros(hidden, jnp.float32),
+            'w2': jnp.asarray((rng.randn(hidden, 10)
+                               * (2.0 / hidden) ** 0.5).astype(np.float32)),
+            'b2': jnp.zeros(10, jnp.float32)}
+        step = _explicit_sync_dp_step(mesh8, params, 0.1, comm)
+        hist = []
+        for _ in range(epochs):
+            for i in range(0, n - bs + 1, bs):
+                xb = jax.device_put(jnp.asarray(X[i:i + bs]), data_sh)
+                yb = jax.device_put(jnp.asarray(Y[i:i + bs]), data_sh)
+                params, loss = step(params, xb, yb)
+                hist.append(float(loss))
+        final[comm] = float(np.mean(hist[-4:]))
+        first = hist[0] if first is None else first
+    assert final['f32'] < 0.5 * first and final['int8'] < 0.5 * first
+    tol = max(0.1 * (first - final['f32']), 0.15 * final['f32'])
+    assert abs(final['int8'] - final['f32']) <= tol, (final, tol)
 
 
 def test_local_sgd_records_sync_bytes(mesh8):

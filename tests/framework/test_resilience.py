@@ -295,7 +295,7 @@ def _static_batches(epoch, n=6):
              rng.randn(4, 1).astype(np.float32)) for _ in range(n)]
 
 
-def _run_static(total_steps, ckpt_dir=None, resume=False, every=3):
+def _run_static(total_steps, ckpt_dir=None, resume=False, every=3, **mgr_kw):
     losses = {}
     with unique_name.guard():
         fluid.seed(1234)
@@ -311,7 +311,7 @@ def _run_static(total_steps, ckpt_dir=None, resume=False, every=3):
                 lambda: iter(_static_batches(loader.epoch)))
             step, mgr = 0, None
             if ckpt_dir:
-                mgr = _mgr(ckpt_dir, every_n_steps=every, keep=2)
+                mgr = _mgr(ckpt_dir, every_n_steps=every, keep=2, **mgr_kw)
                 if resume:
                     got = mgr.restore()
                     if got is not None:
@@ -351,6 +351,22 @@ def test_executor_spine_bitwise_resume(tmp_path):
     assert sorted(second) == [7, 8, 9, 10]          # resumed from step 6
     assert all(second[k] == ref[k] for k in second), \
         'resumed loss trajectory is not bitwise-identical'
+
+
+@pytest.mark.parametrize('async_save', [True, False],
+                         ids=['async', 'blocking'])
+def test_checkpointing_observes_and_never_perturbs_the_losses(
+        tmp_path, async_save):
+    """One loop from one initial state, bare and checkpointing every 3
+    steps (the non-blocking donation-protected capture with the background
+    writer, and the blocking commit on the calling thread): the losses are
+    BITWISE the bare loop's, and the cadence's checkpoints are on disk."""
+    ref = _run_static(12)
+    d = str(tmp_path / 'ck')
+    got = _run_static(12, ckpt_dir=d, every=3, async_save=async_save)
+    assert got == ref, 'checkpointing changed the computation'
+    with _mgr(d) as mgr:
+        assert [c.step for c in mgr.all_checkpoints()] == [9, 12]  # keep=2
 
 
 def test_executor_snapshot_is_donation_protected_until_materialized():
@@ -451,26 +467,30 @@ def test_trainstep_bitwise_resume_through_checkpoint(tmp_path):
 # goodput / lost-work accounting
 # ---------------------------------------------------------------------------
 
-def test_goodput_books_lost_work_on_restart(tmp_path):
+@pytest.mark.parametrize('died_at, every', [(7, 5), (13, 5)])
+def test_goodput_books_lost_work_on_restart(tmp_path, died_at, every):
+    """A run that checkpoints every K steps and dies at step N restores
+    step K*(N//K) and books N mod K lost steps from the heartbeat."""
+    restored, lost = every * (died_at // every), died_at % every
     with obs.telemetry_guard(True):
         obs.reset()
-        mgr = _mgr(tmp_path, every_n_steps=5)
+        mgr = _mgr(tmp_path, every_n_steps=every, keep=2)
         state = {'w': np.ones(2)}
-        for s in range(1, 8):          # checkpoint at 5; heartbeat to 7
+        for s in range(1, died_at + 1):   # heartbeat runs past the last save
             mgr.end_of_step(s, lambda: (state, {}))
         mgr.wait()
         # "crash": a new manager (new incarnation) restores
-        mgr2 = _mgr(tmp_path, every_n_steps=5)
+        mgr2 = _mgr(tmp_path, every_n_steps=every, keep=2)
         arrays, meta = mgr2.restore()
-        assert meta['step'] == 5
-        assert mgr2.goodput.lost_steps == 2        # steps 6, 7 are replayed
+        assert meta['step'] == restored
+        assert mgr2.goodput.lost_steps == lost     # replayed after restore
         assert mgr2.goodput.restarts == 1
         m = obs.registry.to_dict()
         assert sum(s['value'] for s in m['restarts_total']['samples']) == 1
         assert sum(s['value']
-                   for s in m['restart_lost_steps']['samples']) == 2
+                   for s in m['restart_lost_steps']['samples']) == lost
         g = meta['goodput']
-        assert g['steps'] == 5 and g['productive_s'] >= 0
+        assert g['steps'] == restored and g['productive_s'] >= 0
         mgr.close()
         mgr2.close()
 

@@ -164,6 +164,91 @@ def test_e2e_concurrent_clients_bitwise_parity(saved_model, reference):
                 f'client {cid} row {i} not bitwise-equal to Predictor.run'
 
 
+def _hist_totals(name):
+    """(sum, count) over every sample of one registry histogram."""
+    from paddle_tpu.observability import registry
+    d = registry.to_dict().get(name)
+    samples = d['samples'] if d else []
+    return (sum(x['sum'] for x in samples),
+            sum(x['count'] for x in samples))
+
+
+def test_closed_loop_single_row_clients_coalesce(saved_model, reference):
+    """More closed-loop single-row clients than the row budget: the
+    batcher really coalesces (mean rows per device call well past one
+    request), every row is accounted in ``serving_batch_rows``, and the
+    padding-waste histogram stays a ratio in [0, 1). (That each response
+    is bitwise the serial row is the test above.)"""
+    X, _ = reference
+    eng = InferenceEngine(saved_model, max_batch_size=MAX_BATCH)
+    eng.warmup()
+    rows0, nb0 = _hist_totals('serving_batch_rows')
+    waste0, nw0 = _hist_totals('serving_padding_waste_ratio')
+    clients, per_client = 3 * MAX_BATCH, 10
+    errors = []
+
+    def client(cid):
+        try:
+            for i in range(per_client):
+                ridx = (cid * per_client + i) % len(X)
+                out, = batcher.predict({'x': X[ridx:ridx + 1]})
+                assert out.shape == (1, 4)
+        except Exception as e:          # pragma: no cover - fail loudly
+            errors.append(e)
+
+    with MicroBatcher(eng, batch_timeout_ms=20,
+                      queue_depth=4 * clients) as batcher:
+        threads = [threading.Thread(target=client, args=(c,))
+                   for c in range(clients)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert not any(t.is_alive() for t in threads)
+    assert not errors
+    rows1, nb1 = _hist_totals('serving_batch_rows')
+    waste1, nw1 = _hist_totals('serving_padding_waste_ratio')
+    assert rows1 - rows0 == clients * per_client    # every row accounted
+    assert (rows1 - rows0) / (nb1 - nb0) > 2
+    assert nw1 - nw0 == nb1 - nb0
+    assert 0 <= (waste1 - waste0) / (nw1 - nw0) < 1
+
+
+def test_open_loop_arrivals_all_accounted(saved_model, reference):
+    """Open-loop submission (arrivals never wait for completions, results
+    stamped by ``add_done_callback``): every offered request is either
+    answered or rejected with the typed ``Overloaded`` — none fails, none
+    is lost."""
+    X, _ = reference
+    eng = InferenceEngine(saved_model, max_batch_size=MAX_BATCH)
+    eng.warmup()
+    requests = 200
+    done, done_lock = [], threading.Lock()
+    rejected, pending = 0, []
+
+    def on_done(fut):
+        with done_lock:
+            done.append(fut)
+
+    with MicroBatcher(eng, batch_timeout_ms=2,
+                      queue_depth=4 * MAX_BATCH) as batcher:
+        for i in range(requests):
+            ridx = i % len(X)
+            try:
+                fut = batcher.submit({'x': X[ridx:ridx + 1]})
+            except Overloaded:
+                rejected += 1
+                continue
+            fut.add_done_callback(on_done)
+            pending.append(fut)
+        for fut in pending:
+            out, = fut.result(timeout=60)      # a failure raises here
+            assert out.shape == (1, 4)
+    assert len(pending) > 0
+    assert len(done) == len(pending)            # one callback per answer
+    assert len(pending) + rejected == requests
+
+
 class _StubEngine:
     """Duck-typed engine with controllable latency/failure — makes the
     robustness tests deterministic and device-free."""

@@ -16,6 +16,7 @@ from paddle_tpu.serving import (NoReplicaAvailable, Router, RouterServer,
                                 ServingServer)
 from paddle_tpu.serving.tier import knobs
 from paddle_tpu.serving.tier.replica import build_replica_stack, build_tiny_lm
+from shared_programs import build_shared_prompt_work
 
 
 @pytest.fixture(scope='module')
@@ -106,6 +107,67 @@ def test_routed_parity_and_result_metadata(lm, pair):
             assert fin['request_id']
             assert fin['retries'] == 0
         assert len({f['request_id'] for f in finals}) == 4   # unique ids
+
+
+def _fire_all(work, call, midway=lambda: None):
+    """Every request on a thread of its own, none waiting for another, with
+    `midway()` run once half of them are started; returns the final events
+    in request order (None where one raised) and what was raised."""
+    finals, errors = [None] * len(work), []
+
+    def fire(i, prompt, max_new):
+        try:
+            finals[i] = call(prompt, max_new)
+        except Exception as e:          # a drop: the caller asserts none
+            errors.append((i, e))
+
+    threads = [threading.Thread(target=fire, args=(i, p, m))
+               for i, (p, m) in enumerate(work)]
+    for t in threads[:len(threads) // 2]:
+        t.start()
+    midway()
+    for t in threads[len(threads) // 2:]:
+        t.start()
+    for t in threads:
+        t.join(180)
+    assert not any(t.is_alive() for t in threads)
+    return finals, errors
+
+
+@pytest.mark.parametrize('replicas', [1, 2])
+def test_concurrent_arrivals_complete_bitwise(lm, pair, replicas):
+    """The shared-system-prompt mix arriving all at once through the HTTP
+    router, against one replica and against two: every request completes
+    and every stream is the uncached reference's."""
+    work = build_shared_prompt_work(8)
+    refs = [greedy_generate(lm, p, m, pad_len=pair[0].engine.padded_context)
+            for p, m in work]
+    urls = [r.url for r in pair[:replicas]]
+    with Router(urls, health_poll_s=5) as router:
+        finals, errors = _fire_all(
+            work, lambda p, m: router.generate(p, max_new_tokens=m,
+                                               timeout=120))
+    assert not errors
+    assert [f['tokens'] for f in finals] == refs
+    assert {f['replica'] for f in finals} <= set(urls)
+
+
+def test_replica_dying_mid_run_drops_no_nonstreamed_request(lm, pair):
+    """One of two replicas stops abruptly with half of the requests in
+    flight and the other half not yet sent: a non-streamed generate is
+    idempotent (nothing reached the client), so the router retries it on
+    the survivor and ALL complete bitwise, zero dropped. (The kill -9
+    version over real processes: test_router_failover.py.)"""
+    work = build_shared_prompt_work(8)
+    refs = [greedy_generate(lm, p, m, pad_len=pair[0].engine.padded_context)
+            for p, m in work]
+    with Router([r.url for r in pair], health_poll_s=0.3) as router:
+        finals, errors = _fire_all(
+            work, lambda p, m: router.generate_nonstream(
+                p, max_new_tokens=m, timeout=120),
+            midway=lambda: pair[0].shutdown(drain=False))   # dies abruptly
+    assert not errors, errors
+    assert [f['tokens'] for f in finals] == refs
 
 
 def test_least_loaded_dispatch(lm, pair):

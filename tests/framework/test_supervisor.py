@@ -250,13 +250,16 @@ def _train_with_manager(tmp_path, poison_steps, total=12, **sup_kw):
     return events
 
 
+@pytest.mark.parametrize('poisoned', [8, 7])
 def test_rollback_restores_last_checkpoint_and_run_is_deterministic(
-        tmp_path):
-    a = _train_with_manager(tmp_path / 'a', poison_steps={8})
-    b = _train_with_manager(tmp_path / 'b', poison_steps={8})
+        tmp_path, poisoned):
+    """Checkpoints at 3 and 6: a NaN at 8, or at 7 while the step-6 write
+    may still be in flight, rolls back to the NEWEST committed one."""
+    a = _train_with_manager(tmp_path / 'a', poison_steps={poisoned})
+    b = _train_with_manager(tmp_path / 'b', poison_steps={poisoned})
     assert a == b, 'identically-faulted runs diverged'
     rollbacks = [e for e in a if e[0] == 'rollback']
-    assert rollbacks == [('rollback', 8, 6)]      # ckpts at 3, 6 → resume 6
+    assert rollbacks == [('rollback', poisoned, 6)]
     # the run completed past the fault with new (forward) data
     assert max(e[0] for e in a if isinstance(e[0], int)) == 12
     q = (tmp_path / 'a' / 'ck' / 'quarantine.jsonl').read_text()
@@ -290,6 +293,47 @@ def test_skip_boundary_never_checkpoints_the_poisoned_state(tmp_path):
     mgr.wait()
     assert len(mgr.all_checkpoints()) == 1        # no new checkpoint
     mgr.close()
+
+
+def test_supervised_healthy_loop_is_bitwise_the_bare_loop(tmp_path):
+    """Supervision observes a healthy run and never changes it: the same
+    feeds from the same initial state, bare and then supervised (rollback
+    policy attached to a manager, watchdog armed with the executor's
+    per-run lease and the supervisor's boundary lease), give BITWISE the
+    same losses, and every verdict is 'ok'."""
+    from paddle_tpu.resilience import watchdog as wdg
+    import jax.numpy as jnp
+    fluid.seed(5)
+    main, startup, loss = _build_net()
+    feeds = _feeds(12, seed=3)
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor()
+        exe.run(startup)
+        state0 = _scope_state(scope, main)
+
+        bare = [np.asarray(exe.run(main, feed=f, fetch_list=[loss])[0])
+                .tobytes() for f in feeds]
+
+        for name, value in state0.items():
+            scope.set(name, jnp.asarray(value))
+        wdg.enable(floor_s=60.0, abort=False)
+        try:
+            mgr = resilience.CheckpointManager(
+                str(tmp_path), keep=2, install_signal_handlers=False)
+            sup = TrainingSupervisor(policy='rollback', manager=mgr,
+                                     executor=exe, program=main, scope=scope)
+            supervised, verdicts = [], []
+            for step, f in enumerate(feeds, 1):
+                lv = exe.run(main, feed=f, fetch_list=[loss])[0]
+                verdicts.append(sup.end_of_step(step, lv).action)
+                supervised.append(np.asarray(lv).tobytes())
+            sup.close()
+            mgr.close()
+        finally:
+            wdg.disable()
+    assert verdicts == ['ok'] * len(feeds)
+    assert supervised == bare
 
 
 # ---------------------------------------------------------------------------

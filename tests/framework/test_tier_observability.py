@@ -260,24 +260,44 @@ def test_traced_failover_fleet_metrics_and_scrape_hardening(
 
 
 def test_trace_overhead_sampled_off_is_zero_span(tmp_path, monkeypatch):
-    """Satellite d at smoke size: the A/B harness must show a structurally
-    free disabled path — ZERO spans recorded with sampling off, spans
-    flowing with it on, bitwise-identical tokens either way. (The p50
-    numbers live in PERF.md §22; wall-clock ratios are not CI-stable.)"""
+    """The same serial request sweep through a router and one in-process
+    replica with ``PADDLE_TPU_TRACE_SAMPLE=0`` (the production default) and
+    then ``=1`` with span records on: a structurally free disabled path —
+    ZERO spans recorded with sampling off, spans flowing with it on,
+    bitwise-identical tokens either way."""
+    from shared_programs import build_shared_prompt_work
+    from paddle_tpu.serving import ServingServer
+    from paddle_tpu.serving.tier.replica import build_replica_stack
     monkeypatch.delenv(ENV_TRACE_DIR, raising=False)
     monkeypatch.delenv(ENV_TRACE_SAMPLE, raising=False)
-    import threading as _t
-
-    from tools.bench_router import build_shared_prompt_work
-    from tools.bench_router import measure_trace_overhead
+    spans, tokens = {}, {}
     with guard():
         model = build_tiny_lm()
         work = build_shared_prompt_work(4)
         pad = -(-(16 + 16) // 4) * 4
-        refs = [greedy_generate(model, p, m, pad_len=pad)
-                for p, m in work]
-        res = measure_trace_overhead(model, _t.RLock(), work, refs)
-    assert res['spans_off'] == 0             # disabled path does no work
-    assert res['spans_on'] > 0
-    assert res['bitwise_equal']
-    assert res['p50_on_ms'] < 60e3           # sane, not hung
+        refs = [greedy_generate(model, p, m, pad_len=pad) for p, m in work]
+        engine, scheduler, _ = build_replica_stack(
+            model=model, replica_id='trace-ab', slots=4, queue_depth=64)
+        engine.warmup()
+        server = ServingServer(None, port=0, generator=scheduler).start()
+        try:
+            with Router([f'http://127.0.0.1:{server.port}'],
+                        health_poll_s=0.3) as router:
+                for mode, env in (('off', {ENV_TRACE_SAMPLE: '0'}),
+                                  ('on', {ENV_TRACE_SAMPLE: '1',
+                                          ENV_TRACE_DIR: str(tmp_path)})):
+                    for k, v in env.items():
+                        monkeypatch.setenv(k, v)
+                    s0 = _counter('trace_spans_recorded')
+                    tokens[mode] = [
+                        router.generate(p, max_new_tokens=m,
+                                        timeout=120)['tokens']
+                        for p, m in work]
+                    spans[mode] = _counter('trace_spans_recorded') - s0
+        finally:
+            dobs.reset_distributed()      # drop the recorder bound to tmp
+            scheduler.close(drain=True, timeout=30)
+            server.shutdown(drain=True)
+    assert spans['off'] == 0                 # disabled path does no work
+    assert spans['on'] > 0
+    assert tokens['off'] == refs and tokens['on'] == refs

@@ -1,9 +1,10 @@
-"""On-chip microbench for the conv-efficiency levers (PERF.md §1 follow-up;
+"""On-chip microbench for the conv-efficiency levers (ROADMAP S5;
 run on a real TPU):
 
   python tools/bench_fused_conv.py
 
-Measures, slope method (the dispatch-robust timing PERF.md §3 established):
+Measures, slope method (dispatch-robust: the marginal time between an N-iter
+and a 3N-iter run):
 1. ResNet stem: plain 7×7/s2 conv vs space-to-depth 4×4/s1 re-layout.
 2. Bottleneck 1×1 conv + BN + relu: XLA (conv → affine) vs the pallas
    fused-epilogue kernel.

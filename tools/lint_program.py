@@ -37,9 +37,10 @@ def _build_recipe(name):
     """(main_program, fetch_names, feed_names) for one tier-1 recipe."""
     import paddle_tpu as fluid
     from paddle_tpu import layers as L
-    sys.path.insert(0, os.path.join(_REPO, 'tools'))
-    from bench_passes import (build_bert_layer, build_mlp_adam,
-                              build_resnet_block)
+    # the tier-1 recipe programs are the test suites' own
+    sys.path.insert(0, os.path.join(_REPO, 'tests', 'framework'))
+    from shared_programs import (build_bert_layer, build_mlp_adam,
+                                 build_resnet_block)
 
     if name == 'mnist_mlp':
         main, startup = fluid.Program(), fluid.Program()
@@ -57,8 +58,8 @@ def _build_recipe(name):
         builder = {'mlp_adam': build_mlp_adam,
                    'resnet_block': build_resnet_block,
                    'bert_layer': build_bert_layer}[name]
-        main, _startup, make_feed, fetch = builder(smoke=True)
-        feed = make_feed() if callable(make_feed) else make_feed
+        main, _startup, make_feed, fetch = builder()
+        feed = make_feed()
         return main, [fetch.name], sorted(feed)
     if name == 'fleet_dp':
         from paddle_tpu.parallel import DistributedStrategy, fleet

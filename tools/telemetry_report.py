@@ -144,7 +144,7 @@ def summarize(metrics, trace, steps, top=10):
         # host time NOT hidden by the pipeline = D2H materialization waits
         # + input starvation; the rest of the wall clock overlapped device
         # compute with host work — the quantity the K-in-flight window
-        # exists to maximize (PERF.md §12)
+        # exists to maximize
         blocked = mat_s + wait_total
         lines += [f"materializations:      {int(mat_n)} "
                   f"(total wait {mat_s:.4f}s, "
