@@ -12,10 +12,11 @@ repo's own means:
   train      ResNet-50 224x224 NHWC bs128, bf16 compute over f32 masters,
              Momentum, dygraph.guard() + dygraph.jit.TrainStep
   serve      TransformerLM(CausalLMConfig()) behind build_replica_stack +
-             ServingServer: 8 concurrent POST /generate, one streamed, one
+             ServingServer: 16 concurrent POST /generate, one streamed, one
              replayed by request_id; logits vs the uncached forward
-  kernels    fused_attention and paged_attention once each where their
-             pallas kernel applies, against a plain jax.numpy reference
+  kernels    fused_attention where its pallas kernel applies, and
+             paged_attention's decode read at the head sizes, row paddings
+             and pool dtypes no served cell has, against plain references
   static     models.lenet.build_static_lenet under
              fluid.Executor(fluid.TPUPlace(0)), fed from the DataLoader ring
   train_dp4  only with >= 4 devices: BERT-base S=128, 128 sequences per chip,
@@ -39,7 +40,6 @@ import copy
 import gc
 import http.client
 import importlib.metadata
-import inspect
 import itertools
 import json
 import math
@@ -267,13 +267,17 @@ def _logit_rows(engine, model, prompt, pad_len):
     return grabbed[0], np.array(rows[0]), ref[P - 1], ref[P]
 
 
-def serve_phase(counter, cfg=None, slots=8, block_size=16, max_blocks=256,
-                max_prompt_len=128, max_new_tokens_cap=64,
-                prompt_lens=(32, 48, 64, 80, 96, 112, 120, 128),
+def serve_phase(counter, cfg=None, slots=16, block_size=16, max_blocks=512,
+                max_prompt_len=128, max_new_tokens_cap=192,
+                prompt_lens=(32, 40, 48, 56, 64, 72, 80, 88, 96, 104, 112,
+                             116, 120, 124, 126, 128),
                 new_tokens=32, logit_tol=2e-2, seed=1234):
     """TransformerLM(cfg) (default: the class defaults, h=512, 6 layers, 8
     heads of 64, V=32,000) through build_replica_stack + ServingServer on a
-    thread of this process — the objects the replica CLI builds.
+    thread of this process — the objects the replica CLI builds. The block
+    tables (16 slots of 20 blocks) hold more than one chunk of the step's
+    read, so that "no array over every slot's padded context" says
+    something of it (`DecodeEngine.step_context_arrays`).
 
     logit_tol bounds max|cached - uncached| / max|uncached|. On a TPU it
     cannot be array_equal: f32 matmuls run as one bf16 pass by default
@@ -288,8 +292,7 @@ def serve_phase(counter, cfg=None, slots=8, block_size=16, max_blocks=256,
     from paddle_tpu import dygraph, profiler
     from paddle_tpu.core.random import default_generator
     from paddle_tpu.models.causal_lm import CausalLMConfig, TransformerLM
-    from paddle_tpu.ops.nn_ops import (flash_kernel_applies, paged_attention,
-                                       paged_kernel_applies)
+    from paddle_tpu.ops.nn_ops import flash_kernel_applies
     from paddle_tpu.serving.server import ServingServer
     from paddle_tpu.serving.tier.replica import build_replica_stack
 
@@ -400,6 +403,13 @@ def serve_phase(counter, cfg=None, slots=8, block_size=16, max_blocks=256,
                           engine.pool_moves(engine.prompt_buckets[-1])}
         assert not any(pool_moves.values()), \
             f'programs move the K/V pool: {pool_moves}'
+        # ... and the step's read builds no array over every slot's whole
+        # padded context: it walks the live blocks a chunk at a time
+        # (ops/nn_ops.py::paged_attention), on the one step executable of
+        # the warm-up at every live-block count the traffic above had
+        context_arrays = engine.step_context_arrays()
+        assert not context_arrays, \
+            f'the step holds per-slot dense contexts: {context_arrays}'
 
         # which attention path each rung took: the ops' own predicates, at
         # the shapes the engine dispatched
@@ -411,12 +421,6 @@ def serve_phase(counter, cfg=None, slots=8, block_size=16, max_blocks=256,
             qkv = shaped((1, heads, b, d_head), np.float32)
             prefill_paths[b] = 'pallas flash' \
                 if flash_kernel_applies(qkv, qkv) else 'XLA gather'
-        ppcb = inspect.signature(paged_attention).parameters[
-            'pages_per_compute_block'].default     # what CacheContext gets
-        decode_path = 'pallas paged' if paged_kernel_applies(
-            shaped((slots, heads, d_head), np.float32), k_pages,
-            shaped((slots, engine.pool.max_blocks_per_seq), np.int32),
-            ppcb) else 'XLA gather'
 
     say('serve', f"ok TransformerLM h={cfg.hidden_size} "
                  f"L={cfg.num_hidden_layers} heads={heads}x{d_head} V={V}; "
@@ -431,7 +435,9 @@ def serve_phase(counter, cfg=None, slots=8, block_size=16, max_blocks=256,
                  f"{'x'.join(map(str, k_pages.shape))} a layer's K or V, "
                  f"pool-sized copies in the compiled "
                  + ', '.join(f'{name}: {len(found)}'
-                             for name, found in pool_moves.items()))
+                             for name, found in pool_moves.items())
+                 + f"; arrays over {slots} slots' padded context in the "
+                 f"step: {len(context_arrays)}")
     say('serve', f"logits vs uncached forward at pad {engine.padded_context}"
                  f", as a share of max|logit| (tolerance {logit_tol:g}): "
                  + '; '.join(
@@ -441,7 +447,6 @@ def serve_phase(counter, cfg=None, slots=8, block_size=16, max_blocks=256,
                      for n, e in logit_err.items()))
     say('serve', 'paged_prefill_attention path by rung: ' + ', '.join(
         f'{b}:{p}' for b, p in prefill_paths.items()))
-    say('serve', f'paged_attention (single-query decode) path: {decode_path}')
     say('serve', f"informational: warm-up {warm_s:.1f} s "
                  f"({warm_compiles['compiles']} XLA compiles, "
                  f"{warm_compiles['compile_secs']:.1f} s in XLA; persistent "
@@ -452,32 +457,39 @@ def serve_phase(counter, cfg=None, slots=8, block_size=16, max_blocks=256,
             'warmup_phases': {k: round(v, 2) for k, v in timings.items()},
             'logit_err': logit_err,
             'pool_moves': {k: len(v) for k, v in pool_moves.items()},
-            'prefill_paths': prefill_paths, 'decode_path': decode_path}
+            'step_context_arrays': len(context_arrays),
+            'prefill_paths': prefill_paths}
 
 
 # -- kernels -----------------------------------------------------------------
 
-def kernels_phase(fused_shape=(8, 12, 512, 64), paged_slots=8, paged_heads=4,
-                  paged_head_dim=128, block_size=16, pages_per_seq=12,
-                  num_blocks=256, tol=2e-2):
-    """The two attention kernels the served model does not reach.
+def kernels_phase(fused_shape=(8, 12, 512, 64), paged_slots=32,
+                  block_size=16, pages_per_seq=12, num_blocks=512,
+                  paged_cases=((4, 128, 'f32'), (12, 64, 'bf16'),
+                               (12, 64, 'int8'), (5, 64, 'f32')),
+                  tol=2e-2, paged_tol=1e-4):
+    """The attention paths the served model does not reach.
 
     `fused_attention` is off TransformerLM's path (use_fused_attention is
     False): one bf16 forward+backward through dispatch_op compiles its TPU
-    branch. `paged_attention` takes the XLA formulation at the served
-    head_dim of 64 (paged_kernel_applies), so it is called once at the same
-    width split into 4 heads of 128, where its kernel applies (over the
-    pool's rows of one token, (blocks, block, heads·head_dim), which the op
-    hands the head-major kernel as a transposed view). Both are
-    checked against plain jax.numpy at `tol` of the output scale (bf16
-    inputs, or f32 matmuls run as one bf16 pass: a few 2^-8 roundings)."""
+    branch, checked against plain jax.numpy at `tol` of the output scale
+    (bf16 inputs: a few 2^-8 roundings).
+
+    `paged_attention`'s single-query read (one formulation, the walk over
+    the batch's live blocks) is served at 12 heads of 64 over an f32 pool;
+    `paged_cases` (heads, head_dim, pool dtype) run it where no cell does:
+    head_dim 128, bf16 and int8 pools, and 5 heads of 64, a 320-wide row in
+    384 lanes. Each over tables of more than one chunk of blocks, half the
+    slots at the full context, against a float64 numpy walk of the stored
+    values at `paged_tol` of the output scale: the read sums in float32
+    (its matmuls at precision HIGHEST); one bf16 pass would show 2e-3."""
     import jax
     import jax.numpy as jnp
 
     from paddle_tpu import dygraph
     from paddle_tpu.dygraph.tape import Tensor, dispatch_op
-    from paddle_tpu.ops.nn_ops import (flash_kernel_applies,
-                                       paged_kernel_applies)
+    from paddle_tpu.ops.nn_ops import flash_kernel_applies
+    from paddle_tpu.serving.decode.kv_cache import row_lanes
 
     rng = np.random.RandomState(7)
     B, H, S, D = fused_shape
@@ -514,36 +526,58 @@ def kernels_phase(fused_shape=(8, 12, 512, 64), paged_slots=8, paged_heads=4,
                    f"via dispatch_op: path {fused_path}; vs jax.numpy fwd "
                    f"{err:.2e} grad {gerr:.2e} of scale (tolerance {tol:g})")
 
-    Sl, Hp, Dp = paged_slots, paged_heads, paged_head_dim
-    pscale = 1.0 / math.sqrt(Dp)
-    qd = rng.randn(Sl, Hp, Dp).astype('float32')
-    kp = rng.randn(num_blocks, block_size, Hp * Dp).astype('float32')
-    vp = rng.randn(num_blocks, block_size, Hp * Dp).astype('float32')
-    tables = rng.randint(1, num_blocks, (Sl, pages_per_seq)).astype('int32')
+    Sl = paged_slots
+    tables = rng.permutation(np.arange(1, num_blocks))[
+        :Sl * pages_per_seq].reshape(Sl, pages_per_seq).astype('int32')
     lens = rng.randint(1, pages_per_seq * block_size + 1, Sl).astype('int32')
-    with dygraph.guard():
-        got = np.asarray(dispatch_op(
-            'paged_attention',
-            {'q': qd, 'k_pages': kp, 'v_pages': vp, 'block_tables': tables,
-             'context_lens': lens}, {'sm_scale': pscale}).numpy())
-    want = np.zeros_like(got)
-    for s in range(Sl):             # plain numpy, f64: the block walk itself
-        ks = kp[tables[s]].reshape(-1, Hp, Dp)[:lens[s]]
-        vs = vp[tables[s]].reshape(-1, Hp, Dp)[:lens[s]]
-        sc = np.einsum('hd,thd->ht', qd[s].astype('float64'), ks) * pscale
-        pr = np.exp(sc - sc.max(-1, keepdims=True))
-        want[s] = np.einsum('ht,thd->hd', pr / pr.sum(-1, keepdims=True), vs)
-    assert np.isfinite(got).all()
-    perr = float(np.abs(got - want).max() / np.abs(want).max())
-    assert perr <= tol, (perr, tol)
-    paged_path = 'pallas paged' if paged_kernel_applies(
-        jnp.asarray(qd), jnp.asarray(kp), jnp.asarray(tables), 4) \
-        else 'XLA gather'
-    say('kernels', f"ok paged_attention q{qd.shape} pool{kp.shape} f32 via "
-                   f"dispatch_op: path {paged_path}; vs numpy {perr:.2e} of "
-                   f"scale (tolerance {tol:g})")
-    return {'fused_attention': fused_path, 'paged_attention_d128': paged_path,
-            'err': {'fused_fwd': err, 'fused_grad': gerr, 'paged': perr}}
+    lens[::2] = pages_per_seq * block_size
+    paged_err = {}
+    for Hp, Dp, kv_dtype in paged_cases:
+        pscale = 1.0 / math.sqrt(Dp)
+        shape = (num_blocks, block_size, row_lanes(Hp * Dp))
+        qd = rng.randn(Sl, Hp, Dp).astype('float32')
+        inputs = {'q': qd, 'block_tables': tables, 'context_lens': lens}
+        stored = {}                 # the pool's values, decoded to float64
+        for name in ('k', 'v'):
+            if kv_dtype == 'int8':
+                pages = rng.randint(-127, 128, shape).astype('int8')
+                scales = rng.uniform(0.002, 0.02, shape[:2] + (Hp,)) \
+                    .astype('float32')
+                inputs[name + '_scales'] = scales
+                stored[name] = pages[..., :Hp * Dp].reshape(
+                    shape[:2] + (Hp, Dp)).astype('float64') \
+                    * scales[..., None]
+            else:
+                pages = np.asarray(jnp.asarray(
+                    rng.randn(*shape),
+                    jnp.bfloat16 if kv_dtype == 'bf16' else jnp.float32))
+                stored[name] = np.asarray(pages, 'float64')[
+                    ..., :Hp * Dp].reshape(shape[:2] + (Hp, Dp))
+            inputs[name + '_pages'] = pages
+        with dygraph.guard():
+            got = np.asarray(dispatch_op('paged_attention', inputs,
+                                         {'sm_scale': pscale}).numpy())
+        want = np.zeros_like(got)
+        for s in range(Sl):         # plain numpy, f64: the block walk itself
+            ks = stored['k'][tables[s]].reshape(-1, Hp, Dp)[:lens[s]]
+            vs = stored['v'][tables[s]].reshape(-1, Hp, Dp)[:lens[s]]
+            sc = np.einsum('hd,thd->ht', qd[s].astype('float64'),
+                           ks) * pscale
+            pr = np.exp(sc - sc.max(-1, keepdims=True))
+            want[s] = np.einsum('ht,thd->hd',
+                                pr / pr.sum(-1, keepdims=True), vs)
+        assert np.isfinite(got).all()
+        perr = float(np.abs(got - want).max() / np.abs(want).max())
+        assert perr <= paged_tol, (Hp, Dp, kv_dtype, perr, paged_tol)
+        paged_err[f'{Hp}x{Dp}_{kv_dtype}'] = perr
+    say('kernels', f"ok paged_attention, {Sl} slots of {pages_per_seq} "
+                   f"blocks of {block_size} via dispatch_op, vs numpy as a "
+                   f"share of scale (tolerance {paged_tol:g}): "
+                   + ', '.join(f'{name} {e:.2e}'
+                               for name, e in paged_err.items()))
+    return {'fused_attention': fused_path,
+            'err': {'fused_fwd': err, 'fused_grad': gerr,
+                    'paged': paged_err}}
 
 
 # -- static ------------------------------------------------------------------

@@ -480,8 +480,10 @@ def _pdim(info, i, assume):
 @cost_rule('paged_attention', 'paged_prefill_attention')
 def _c_paged_attention(ctx):
     # per query row against T_pad = num_blocks_per_seq × block_size keys:
-    # QK^T (2D) + softmax (~TRANS+2) + PV (2D) — the padded extent is the
-    # honest decode cost; masked positions still burn the lanes
+    # QK^T (2D) + softmax (~TRANS+2) + PV (2D). The padded extent is what
+    # the gathered reads do (masked positions still burn the lanes) and the
+    # bound of the single-query read, whose work follows the contexts'
+    # lengths, which a static rule cannot know
     # pages are (num_blocks, block_size, H·D) rows of one token; H and D
     # are q's: (S, H, D), (S, H, K, D) or prefill's (1, H, L, D)
     q = ctx.input('q')

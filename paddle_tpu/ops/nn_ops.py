@@ -629,28 +629,26 @@ def norm(x, *, axis=-1, epsilon=1e-10):
 # ---------------------------------------------------------------------------
 # Fused / paged attention: explicit kernel dispatch.
 #
-# Each attention op has a stock pallas TPU kernel and an XLA formulation.
-# Which one runs is a predicate on backend, rank, dtype and the kernel's own
-# shape rules, written beside the call. Where the predicate holds the kernel
-# runs and a compiler refusal is an error; where it does not, the XLA
-# formulation runs because the code says so. Nothing is caught: the op
-# bodies run at trace time inside the eager kernel-cache jit, so a Mosaic
-# refusal would surface at compile anyway, outside any handler here.
+# `fused_attention` and `paged_prefill_attention` have a stock pallas TPU
+# kernel (flash attention) and an XLA formulation. Which one runs is a
+# predicate on backend, rank, dtype and the kernel's own shape rules,
+# written beside the call. Where the predicate holds the kernel runs and a
+# compiler refusal is an error; where it does not, the XLA formulation runs
+# because the code says so. Nothing is caught: the op bodies run at trace
+# time inside the eager kernel-cache jit, so a Mosaic refusal would surface
+# at compile anyway, outside any handler here. `paged_attention` has no
+# kernel and no predicate: its single-query read is one XLA formulation for
+# every head_dim and pool dtype (:func:`_live_block_attention`).
 #
-# The rules below were established on a TPU v5e with jax 0.9.0 (PERF.md
-# "Bring-up"): the flash kernel lowers at head_dim 16/64/128 whenever both
-# sequence extents are multiples of its 128-row blocks; the paged kernel
-# lowers at head_dim 128 and is refused by the pallas TPU lowering at
-# head_dim 64 (its softmax-state outputs are blocked at head_dim lanes).
+# The flash rule was established on a TPU v5e with jax 0.9.0 (PERF.md
+# section 6, PR 21): the kernel lowers at head_dim 16/64/128 whenever both
+# sequence extents are multiples of its 128-row blocks.
 # ---------------------------------------------------------------------------
 
 # jax.experimental.pallas.ops.tpu.flash_attention BlockSizes.get_default:
 # every forward and backward block is 128 rows, and _verify_block raises
 # for a sequence that is shorter than its block or not a multiple of it
 _FLASH_BLOCK = 128
-# the pallas TPU lowering wants a block's last dimension to be a multiple of
-# the 128-lane tile (or the whole array dimension)
-_TPU_LANES = 128
 
 
 def flash_kernel_applies(q, k):
@@ -662,28 +660,6 @@ def flash_kernel_applies(q, k):
             and q.dtype in (jnp.float32, jnp.bfloat16)
             and q.shape[2] % _FLASH_BLOCK == 0
             and k.shape[2] % _FLASH_BLOCK == 0)
-
-
-def _pages_per_compute_block(requested, block_tables):
-    return max(min(int(requested), block_tables.shape[1]), 1)
-
-
-def paged_kernel_applies(q, k_pages, block_tables, pages_per_compute_block):
-    """True when `paged_attention` runs the pallas paged-attention kernel:
-    a TPU backend, single-query (S, H, D) ``q``, an f32 pool (bf16/int8
-    pools need the dequant-after-gather of the XLA formulation; the
-    multi-query (S, H, K, D) verify read has no stock kernel), head_dim a
-    multiple of the 128-lane tile, a pool row (NB, BS, Hkv·D) of whole
-    heads whose count divides q's, and the kernel's own
-    ``pages_per_sequence % pages_per_compute_block == 0`` rule."""
-    return (on_tpu() and len(q.shape) == 3
-            and k_pages.dtype == jnp.float32
-            and q.shape[2] % _TPU_LANES == 0
-            and k_pages.shape[2] % q.shape[2] == 0
-            and q.shape[1] % (k_pages.shape[2] // q.shape[2]) == 0
-            and block_tables.shape[1]
-            % _pages_per_compute_block(pages_per_compute_block,
-                                       block_tables) == 0)
 
 
 @register_op('fused_attention')
@@ -719,13 +695,61 @@ def fused_attention(q, k, v, bias=None, *, sm_scale=1.0, causal=False):
     return jnp.einsum('bhqk,bhkd->bhqd', probs, v)
 
 
+# Live blocks one chunk of the single-query decode read takes from the pool:
+# the work of a step is chunks × this, so the last chunk's padding (half a
+# chunk on average) is read for nothing, and each chunk costs a loop
+# iteration of small ops. At GPT-1's rows a chunk's K or V is
+# 256 × 16 × 768 f32 = 12.6 MB.
+LIVE_BLOCK_CHUNK = 256
+
+
+def live_block_chunk(table_entries):
+    """Blocks a chunk of :func:`paged_attention`'s read holds when the block
+    tables have ``table_entries`` = S × max_blocks entries: the whole list
+    where it is shorter than a chunk."""
+    return min(LIVE_BLOCK_CHUNK, int(table_entries))
+
+
+def live_block_list(block_tables, context_lens, block_size):
+    """The LIVE blocks of a decode batch as they lie in the pool, compacted
+    slot-major on the device: entry (s, j) of ``block_tables`` (S,
+    max_blocks) is live iff ``j < ceil(context_lens[s] / block_size)``.
+
+    Returns ``(block_id, slot, first_pos, n_live)``: three int32 arrays of
+    S × max_blocks entries rounded up to whole chunks
+    (:func:`live_block_chunk`), and the int32 count. Entry n < n_live is
+    block ``block_id[n]`` of the pool, holding positions ``first_pos[n]`` …
+    of slot ``slot[n]``; a slot's blocks are contiguous and in sequence
+    order. Entries past ``n_live`` name the scratch block (0) at
+    ``first_pos`` = the padded context, which no slot's context reaches:
+    a reader's own mask (position < context) gives them zero mass.
+
+    The list depends on the step's tables and lengths alone, so one list
+    serves every layer of a program (`CacheContext.live_blocks`)."""
+    tables = jnp.asarray(block_tables, jnp.int32)
+    s, mb = tables.shape
+    chunk = live_block_chunk(s * mb)
+    at = jnp.arange(-(-s * mb // chunk) * chunk, dtype=jnp.int32)
+    blocks = -(-jnp.asarray(context_lens, jnp.int32) // block_size)   # (S,)
+    ends = jnp.cumsum(blocks)
+    n_live = ends[-1]
+    # entry n belongs to the first slot whose blocks end past n
+    slot = jnp.minimum(jnp.sum(at[:, None] >= ends[None, :], axis=1,
+                               dtype=jnp.int32), s - 1)
+    j = at - (ends - blocks)[slot]
+    live = at < n_live
+    block_id = jnp.where(live, tables[slot, jnp.clip(j, 0, mb - 1)], 0)
+    first_pos = jnp.where(live, j * block_size, mb * block_size)
+    return block_id, slot, first_pos, n_live
+
+
 @register_op('paged_attention')
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
-                    k_scales=None, v_scales=None, *,
-                    sm_scale=1.0, pages_per_compute_block=4):
+                    k_scales=None, v_scales=None, live=None, *,
+                    sm_scale=1.0):
     """Single-token decode attention over a paged KV cache (the decode half
     of the serving decode engine — docs/SERVING.md "Stateful decode";
-    kernel blueprint: Ragged Paged Attention, PAPERS.md arxiv 2604.15464).
+    blueprint: Ragged Paged Attention, PAPERS.md arxiv 2604.15464).
 
     - ``q``: (S, H, D) — one query token per decode slot — or (S, H, K, D)
       for the MULTI-QUERY decode read speculative decoding verifies with
@@ -737,59 +761,45 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
       it).
     - ``block_tables``: (S, max_blocks_per_seq) int32 — each slot's cache
       blocks in sequence order; tail entries beyond the context are
-      arbitrary valid block ids (masked by ``context_lens``).
+      arbitrary valid block ids (never read, or masked by
+      ``context_lens``).
     - ``k_scales`` / ``v_scales``: optional (num_blocks, block_size, H)
       f32 — per-row dequant scales for int8 pools (PADDLE_TPU_KV_DTYPE=
-      int8). Dequantization happens AFTER the per-slot gather, so only the
-      slots' working set is ever materialized at f32; bf16 pools pass no
-      scales and simply cast after the gather. Scale-zero rows (unwritten,
-      incl. the scratch block) dequantize to exact zeros, preserving the
-      masking contract below at every dtype.
-    - ``context_lens``: (S,) int32 — tokens to attend per slot, INCLUDING
-      the token written at position context_len-1 this step. In the
-      multi-query form this is the extent of fed-token ROW 0; row j
+      int8). Only what a read takes from the pool is ever cast or scaled
+      to f32; bf16 pools pass no scales and simply cast. Scale-zero rows
+      (unwritten, incl. the scratch block) dequantize to exact zeros.
+    - ``context_lens``: (S,) int32 ≥ 1 — tokens to attend per slot,
+      INCLUDING the token written at position context_len-1 this step. In
+      the multi-query form this is the extent of fed-token ROW 0; row j
       attends ``context_lens + j`` keys (a causal staircase over the K
       fed positions — row j sees the prior context plus fed tokens 0..j).
+    - ``live``: optional, the single-query read's
+      :func:`live_block_list` of these tables and lengths, for a caller
+      that reads many layers through the same tables; made here otherwise.
 
-    Where :func:`paged_kernel_applies` holds this dispatches the pallas
-    paged-attention kernel (jax.experimental.pallas.ops.tpu.paged_attention
-    — ragged block walk, no dense gather; its pool is head-major,
-    (Hkv, num_blocks, block_size, D), so it is handed a transposed view:
-    a copy of the pool a call, ROADMAP D7); everywhere else the XLA
-    formulation gathers the slot's blocks into a dense (S, H, T, D) view
-    and runs the batched-matmul → mask → softmax → matmul sequence the
-    unfused MultiHeadAttention path uses.
-    Masked key positions get *exactly-zero* probability mass (the mask
-    value underflows exp), and `jnp.matmul` rows are extent-independent on
-    XLA CPU (measured; einsum dot_general is NOT), so a decode step is
-    bitwise-identical to the matching row of a whole-sequence forward at
-    the same padded key extent, and stale values in reused blocks can
-    never bleed (0.0 × finite == 0.0)."""
+    The single-query read has ONE formulation, for every head_dim and pool
+    dtype (:func:`_live_block_attention`): it walks the batch's live blocks
+    as they lie in the pool, in chunks, with a running softmax, so its work
+    follows the contexts' lengths and not the table's padded width, and no
+    per-slot dense copy of the context exists. It is exact softmax
+    attention in float32 over positions < context_lens, nothing dropped or
+    approximated; against a whole-sequence forward it differs by the
+    rounding of another order of summation (a few ulp; the tests state the
+    tolerance). Positions past a slot's context get *exactly-zero* mass
+    (masked after the exponential, and mostly never read), so stale values
+    in a reused or scratch block can never bleed (0.0 × finite == 0.0).
+
+    The multi-query (S, H, K, D) read still gathers each slot's whole
+    padded table dense (:func:`_gather_pages`) and runs matmul → mask →
+    softmax → matmul: no served cell runs it yet (ROADMAP S3)."""
     q = jnp.asarray(q)
     k_pages = jnp.asarray(k_pages)
     v_pages = jnp.asarray(v_pages)
     block_tables = jnp.asarray(block_tables, jnp.int32)
     context_lens = jnp.asarray(context_lens, jnp.int32)
-    if paged_kernel_applies(q, k_pages, block_tables,
-                            pages_per_compute_block):
-        from jax.experimental.pallas.ops.tpu.paged_attention import (
-            paged_attention as _tpu_paged_attention)
-        nb, bs, _ = k_pages.shape
-
-        def head_major(pages):
-            return pages.reshape(nb, bs, -1, q.shape[2]).transpose(2, 0, 1, 3)
-        return _tpu_paged_attention(
-            q * jnp.asarray(sm_scale, q.dtype),
-            head_major(k_pages), head_major(v_pages),
-            context_lens, block_tables,
-            pages_per_compute_block=_pages_per_compute_block(
-                pages_per_compute_block, block_tables))
     if q.ndim == 4:
-        # multi-query decode (speculative verify): K fed tokens per slot.
-        # Same matmul → mask → softmax → matmul sequence as the
-        # single-query path, so each row j is bitwise-identical to the
-        # (S, 1) step that would have read the same K/V at extent
-        # context_lens + j (the tests prove it across ragged extents).
+        # multi-query decode (speculative verify): K fed tokens per slot,
+        # row j at extent context_lens + j
         s, h, kq, d = q.shape
         k = _gather_pages(k_pages, block_tables, s, h, d, k_scales)
         v = _gather_pages(v_pages, block_tables, s, h, d, v_scales)
@@ -803,21 +813,78 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
         scores = jnp.where(valid, scores, jnp.finfo(scores.dtype).min)
         probs = jax.nn.softmax(scores, axis=-1)
         return jnp.matmul(probs, v)                        # (S, H, K, D)
+    if live is None:
+        live = live_block_list(block_tables, context_lens, k_pages.shape[1])
+    return _live_block_attention(q, k_pages, v_pages, context_lens, live,
+                                 k_scales, v_scales, sm_scale)
+
+
+def _live_block_attention(q, k_pages, v_pages, context_lens, live,
+                          k_scales, v_scales, sm_scale):
+    """q (S, H, D) against the pool's LIVE blocks (:func:`live_block_list`),
+    a chunk of C blocks at a time, only as many chunks as hold live blocks.
+
+    Everything stays token-major, rows of W = the pool's lanes, so nothing
+    has a minor dimension of head_dim: a chunk's rows are taken as stored,
+    (C, block, W), and cast to f32; scores are ``rows ⊙ q[slot]`` summed per
+    head by a matmul with the constant (W, H) head-indicator; an int8 pool's
+    row scales multiply the (C, block, H) scores and weights, never the
+    rows. The chunk is folded into per-slot running state m (S, H), l
+    (S, H), acc (S, W) with the running-softmax rescale, a slot's entries
+    found by the one-hot (S, C) of the chunk's ``slot``. The sums the
+    matmuls take are float32's (precision HIGHEST: the MXU's default would
+    round each product and weight to bf16)."""
+    f32, exact = jnp.float32, lax.Precision.HIGHEST
     s, h, d = q.shape
-    k = _gather_pages(k_pages, block_tables, s, h, d, k_scales)
-    v = _gather_pages(v_pages, block_tables, s, h, d, v_scales)
-    t_pad = k.shape[2]
-    # same op sequence as the unfused MHA path (matmul·α → mask → softmax
-    # → matmul), q extent 1: bitwise-equal to the whole-sequence rows
-    scores = jnp.matmul(q[:, :, None, :], jnp.swapaxes(k, -1, -2))
-    if sm_scale != 1.0:
-        scores = scores * jnp.asarray(sm_scale, scores.dtype)
-    valid = jnp.arange(t_pad, dtype=jnp.int32)[None, None, None, :] \
-        < context_lens[:, None, None, None]
-    scores = jnp.where(valid, scores, jnp.finfo(scores.dtype).min)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.matmul(probs, v)
-    return out.reshape(s, h, d)
+    bs, w = k_pages.shape[1:]
+    block_id, slot, first_pos, n_live = live
+    chunk = live_block_chunk(block_id.shape[0])
+    neg = jnp.finfo(f32).min
+    # lane x of a row belongs to head x // D; a row's padding lanes to none
+    head_of = (jnp.arange(w)[:, None] // d
+               == jnp.arange(h)[None, :]).astype(f32)            # (W, H)
+    lanes_of = head_of.T            # (H, W): a head's value to its lanes
+    q_rows = (q.astype(f32) * jnp.asarray(sm_scale, f32)).reshape(s, h * d)
+    q_rows = jnp.pad(q_rows, ((0, 0), (0, w - h * d)))            # (S, W)
+    offsets = jnp.arange(bs, dtype=jnp.int32)
+    slots = jnp.arange(s, dtype=jnp.int32)
+
+    def fold(i, state):
+        m, l, acc = state
+        ids, of, pos = (lax.dynamic_slice_in_dim(x, i * chunk, chunk)
+                        for x in (block_id, slot, first_pos))
+        seen = (pos[:, None] + offsets[None, :]
+                < context_lens[of][:, None])[..., None]          # (C, BS, 1)
+        mine = of[None, :] == slots[:, None]                      # (S, C)
+        to_slot = mine.astype(f32)
+        k = jnp.take(k_pages, ids, axis=0).astype(f32)            # (C, BS, W)
+        scores = jnp.matmul(
+            (k * q_rows[of][:, None, :]).reshape(chunk * bs, w), head_of,
+            precision=exact).reshape(chunk, bs, h)
+        if k_scales is not None:
+            scores = scores * jnp.take(k_scales, ids, axis=0)
+        scores = jnp.where(seen, scores, neg)
+        m_new = jnp.maximum(m, jnp.max(
+            jnp.where(mine[:, :, None], scores.max(1)[None], neg), axis=1))
+        p = jnp.where(seen, jnp.exp(scores - m_new[of][:, None, :]), 0.0)
+        rescale = jnp.exp(m - m_new)                              # (S, H)
+        l = l * rescale + jnp.matmul(to_slot, p.sum(1), precision=exact)
+        if v_scales is not None:
+            p = p * jnp.take(v_scales, ids, axis=0)
+        v = jnp.take(v_pages, ids, axis=0).astype(f32)
+        weighted = jnp.matmul(p.reshape(chunk * bs, h), lanes_of,
+                              precision=exact).reshape(chunk, bs, w) * v
+        acc = (acc * jnp.matmul(rescale, lanes_of, precision=exact)
+               + jnp.matmul(to_slot, weighted.sum(1), precision=exact))
+        return m_new, l, acc
+
+    m, l, acc = lax.fori_loop(
+        0, -(-n_live // chunk), fold,
+        (jnp.full((s, h), neg, f32), jnp.zeros((s, h), f32),
+         jnp.zeros((s, w), f32)))
+    out = acc[:, :h * d] / jnp.matmul(l, lanes_of[:, :h * d],
+                                      precision=exact)
+    return out.reshape(s, h, d).astype(q.dtype)
 
 
 def _gather_pages(pages, block_tables, s, h, d, scales=None):

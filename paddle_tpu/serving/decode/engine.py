@@ -33,8 +33,10 @@ Numerical contract with the uncached whole-sequence forward
 rows within a stated tolerance — a fused program and ~300 eager kernels
 round differently (one ulp seen on the CPU: 1.2e-7 at a logit scale of
 0.47). Exact equality holds between runs of the SAME program only: replay
-by request id, spill and reinject, a same-dtype handoff. See
-ops/nn_ops.py:paged_attention for why every read pads to ``padded_context``.
+by request id, spill and reinject, a same-dtype handoff. The lockstep
+step's read walks the batch's live blocks (ops/nn_ops.py:paged_attention);
+prefill rungs below 128 and the (S, K) step still read ``padded_context``
+positions a slot.
 
 The engine is single-threaded by design (one scheduler worker owns it);
 it holds no queueing or lifecycle logic — that is scheduler.py.
@@ -56,6 +58,7 @@ from ...observability import distributed as _dobs
 from ...core.compile_cache import setup_persistent_cache
 from ...dygraph.jit import _bind
 from ...dygraph.tape import Tensor, no_grad_guard
+from ...ops.nn_ops import live_block_chunk
 from ..engine import bucket_ladder
 from ..errors import InvalidRequest, UnsupportedCacheFeature
 from .kv_cache import (BlockTable, CacheContext, KVCachePool, decode_coords,
@@ -84,6 +87,24 @@ def _moves_of_size(hlo_text, sizes):
                 for dims in _HLO_DIMS.findall(made.group(1))):
             moves.append(line.strip())
     return moves
+
+
+def _arrays_spanning(hlo_text, positions):
+    """The instructions of a compiled program's text with an array in which
+    a run of adjacent dimensions multiplies to ``positions``: for S slots ×
+    their padded context, the dense per-slot copies and score arrays of a
+    read that pads every slot to the table's width, whatever their trailing
+    dimensions ((S·blocks, block, W), (S, T, H, D), (S, T, H), ...)."""
+    found = []
+    for line in hlo_text.splitlines():
+        for dims in _HLO_DIMS.findall(line):
+            dims = [int(d) for d in dims.split(',')]
+            if any(int(np.prod(dims[i:j])) == positions
+                   for i in range(len(dims))
+                   for j in range(i + 1, len(dims) + 1)):
+                found.append(line.strip())
+                break
+    return found
 
 
 class _Program:
@@ -228,10 +249,10 @@ class _CallClock:
     observation per phase into ``decode_engine_phase_seconds``, and with
     telemetry on the ``engine/<call>`` span and its ``engine/<call>/<phase>``
     children from the same stamps. The span's args carry the call's ``work``
-    (expert assignments, experts touched, context positions read: what the
-    counters were given for this call), so that a trace reader can set the
-    device time of a slice against the work of the calls in it. O(1) per
-    call."""
+    (expert assignments, experts touched, context positions and cache blocks
+    read: what the counters were given for this call), so that a trace
+    reader can set the device time of a slice against the work of the calls
+    in it. O(1) per call."""
 
     __slots__ = ('call', 'start', 'last', 'ends', 'work')
 
@@ -421,9 +442,10 @@ class DecodeEngine:
 
     @property
     def padded_context(self):
-        """The key extent every attention read pads to — run the uncached
-        reference (models/causal_lm.greedy_generate) at this pad_len for
-        identical tokens."""
+        """The positions a slot's block table spans, the key extent of the
+        reads that still gather it whole — run the uncached reference
+        (models/causal_lm.greedy_generate) at this pad_len for identical
+        tokens."""
         return self.pool.padded_context
 
     def validate(self, prompt_ids, max_new_tokens):
@@ -539,8 +561,21 @@ class DecodeEngine:
         layers, scales = self.pool.arrays()
         sizes = {int(a.size) for arrs in list(layers.values())
                  + list(scales.values()) for a in arrs}
-        return _moves_of_size(
-            self.lowered(bucket, sharding).compile().as_text(), sizes)
+        return _moves_of_size(self._compiled_text(bucket, sharding), sizes)
+
+    def _compiled_text(self, bucket=None, sharding=None):
+        return self.lowered(bucket, sharding).compile().as_text()
+
+    def step_context_arrays(self, sharding=None):
+        """The instructions of the lockstep step, compiled as `pool_moves`
+        compiles it, that hold an array over every slot's whole padded
+        context (S × ``padded_context`` positions). Must be empty for a K/V
+        pool: the step's read walks the live blocks a chunk at a time
+        (ops/nn_ops.py::paged_attention) and builds no per-slot dense copy.
+        It says so only where the tables hold more than one chunk of
+        blocks: a shorter table is one chunk, whole."""
+        return _arrays_spanning(self._compiled_text(None, sharding),
+                                self.slots * self.padded_context)
 
     def prefill(self, prompt, table, sampler=None):
         """Run the bucket-padded prompt once, writing K/V into ``table``'s
@@ -599,27 +634,45 @@ class DecodeEngine:
             tables[s].context_len = c + 1   # the fed token becomes cached
             ctx_lens.append(c + 1)
         coords = decode_coords(self.pool, tables, ctx_lens)
+        blocks = self._blocks_walked(ctx_lens)
         t0 = clock.end('pack')
         rows = self._run(clock, 'decode', ids, pos, coords)
         out = _first_max(rows)
         dt = clock.end('sample') - t0
-        self._account_step(clock, dt, tables)
+        self._account_step(clock, dt, tables, blocks)
         self._step_compiled = True
         if return_rows:
             return out, rows
         return out
 
-    def _account_step(self, clock, dt, tables):
+    def _blocks_walked(self, ctx_lens):
+        """Blocks a layer's read takes from the pool in a lockstep step over
+        ``ctx_lens`` (an idle slot's 1: the scratch block). A K/V pool's
+        read walks the live blocks in whole chunks (ops/nn_ops.py::
+        paged_attention); a latent pool's gathers every slot's whole
+        table."""
+        entries = self.slots * self.pool.max_blocks_per_seq
+        if self.cache_kind != 'kv':
+            return entries
+        chunk = live_block_chunk(entries)
+        live = sum(-(-int(c) // self.block_size) for c in ctx_lens)
+        return -(-live // chunk) * chunk
+
+    def _account_step(self, clock, dt, tables, blocks):
         """What every decode step books, lockstep or speculative, and the
-        call's record."""
+        call's record; ``blocks`` the cache blocks a layer's read took."""
         _m.decode_step_seconds.observe(dt)
         _m.decode_steps.inc()
         active = sum(t is not None for t in tables)
-        # the live context the step attended, the fed tokens included
-        positions = self.pool.num_layers * sum(
+        # the live context the step attended, the fed tokens included, and
+        # the blocks its reads took from the pool to attend it
+        layers = self.pool.num_layers
+        positions = layers * sum(
             t.context_len for t in tables if t is not None)
         _m.decode_context_positions_read.inc(positions)
+        _m.decode_kv_blocks_read.inc(layers * blocks)
         clock.work['context_positions'] = positions
+        clock.work['kv_blocks'] = layers * blocks
         clock.record()
         _m.decode_slots_active.set(active)
         _m.decode_slot_occupancy.observe(active / max(self.slots, 1))
@@ -673,7 +726,9 @@ class DecodeEngine:
         rows = self._run(clock, 'decode', ids, pos, coords)
         dt = clock.last - t0
         self._spec_compiled = True
-        self._account_step(clock, dt, tables)   # it IS the decode step
+        # it IS the decode step; its (S, K) read gathers every table whole
+        self._account_step(clock, dt, tables,
+                           S * self.pool.max_blocks_per_seq)
         _m.decode_spec_verify_seconds.observe(dt)
         _m.decode_spec_rounds.inc()
         return rows

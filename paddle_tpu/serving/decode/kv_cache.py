@@ -8,8 +8,9 @@ one array (num_blocks, block_size, row_lanes(width)), int8 row scales
 and a whole number of the TPU's 128-lane tiles (GPT-1: 768 = 6 × 128; any
 other width is padded up to one, :func:`row_lanes`), so row-major is the
 compact layout the compiler gives a program's arguments and results, and the
-paged scatter and the page gather work on it as it lies: no engine program
-relayouts the pool. The head-major (n_heads, num_blocks,
+paged scatter and the reads (the lockstep step's walk over the live blocks,
+the page gather) take its rows as they lie: no engine program relayouts the
+pool. The head-major (n_heads, num_blocks,
 block_size, head_dim) of the stock pallas paged kernel put head_dim 64 under
 the 128 lanes; the compiler then made the block axis minor and every engine
 program copied every layer's whole pool into the scatter's layout and back,
@@ -34,8 +35,8 @@ it) or by the first whole-block injection.
 
 Block 0 is the **scratch block**: never allocated, the padding target for
 inactive decode slots and short block tables. Writes to it are harmless
-(masked by context lengths — and masked probabilities are *exactly* zero in
-the XLA fallback, so stale block contents can never bleed between requests;
+(masked by context lengths — and masked probabilities are *exactly* zero,
+so stale block contents can never bleed between requests;
 tests/ops/test_paged_attention.py proves reuse-after-free is clean).
 
 Functional updates: jax arrays are immutable, so writes are scatters over a
@@ -60,10 +61,11 @@ sparse-push codec; KV rows and embedding rows are the same shape problem).
 Quantization happens AT THE WRITE (prefill block scatter, decode token
 scatter, speculative window, whole-block handoff injection) and
 dequantization AT THE READ inside `paged_attention` /
-`paged_prefill_attention`, after the per-slot gather — so the resident
-pool never exists at f32. The scratch-block masking contract survives
-every dtype: scales init to 0.0, so an unwritten int8 row dequantizes to
-exact zeros, and masked probabilities are exactly zero regardless.
+`paged_prefill_attention`, on what the read took from the pool (a chunk of
+live blocks, a gathered table) — so the resident pool never exists at f32.
+The scratch-block masking contract survives every dtype: scales init to
+0.0, so an unwritten int8 row dequantizes to exact zeros, and masked
+probabilities are exactly zero regardless.
 """
 from __future__ import annotations
 
@@ -277,9 +279,10 @@ class KVCachePool:
     """Per-layer paged K/V arrays + the shared allocator.
 
     ``max_blocks_per_seq`` fixes the batched block-table width — and with
-    it ``padded_context = max_blocks_per_seq * block_size``, the key extent
-    every attention read uses. The parity contract with whole-sequence
-    decode (engine.py) holds at exactly that padded length (see
+    it ``padded_context = max_blocks_per_seq * block_size``, the most a
+    slot can hold and the key extent of the reads that gather a table whole
+    (prefill rungs below 128, the (S, K) step, a latent pool's step); the
+    lockstep step over a K/V pool reads the live blocks alone (see
     ops/nn_ops.py).
     """
 
@@ -673,8 +676,9 @@ class CacheContext:
     the paged view (`paged_prefill_attention`).
 
     mode='decode': q/k/v are (S, H, K, D). K = 1 is the lockstep step, one
-    token per slot — K/V land at each slot's next position, attention reads
-    through the batched block tables (`paged_attention`) at fixed shape.
+    token per slot — K/V land at each slot's next position, attention walks
+    the batch's live blocks (`paged_attention` over :meth:`live_blocks`) at
+    fixed shape.
     K > 1 is the multi-token window :func:`decode_coords` describes.
     """
 
@@ -686,6 +690,7 @@ class CacheContext:
         # (traced); the rows past it are the rung's padding
         self.last = last
         self._layer = 0
+        self._live = None          # `live_blocks`, once a layer asked
         self.stats = {}            # name -> [what a layer noted], `note`
 
     def note(self, name, value):
@@ -703,6 +708,22 @@ class CacheContext:
         if self.mode == 'prefill':
             return jnp.arange(n, dtype=jnp.int32) <= self.last
         return jnp.asarray(self.coords['write_ids']) != SCRATCH_BLOCK
+
+    def live_blocks(self):
+        """The decode batch's live blocks as they lie in the pool,
+        ``[block_id, slot, first_pos, n_live]``
+        (ops/nn_ops.py::live_block_list), made on the device from this
+        program's block tables and context lengths the first time a layer
+        asks and shared by every layer after it: a read that walks it does
+        work in proportion to the contexts, not to the tables' padded
+        width. A program none of whose layers asks (a latent pool's, the
+        (S, K) step's) holds no such op."""
+        if self._live is None:
+            from ...ops.nn_ops import live_block_list
+            c = self.coords
+            self._live = list(live_block_list(
+                c['block_tables'], c['context_lens'], self.pool.block_size))
+        return self._live
 
     def _scale_inputs(self, layer):
         """Extra dispatch inputs for int8 pools ({} otherwise — the f32/bf16
@@ -773,5 +794,9 @@ class CacheContext:
             # q stays rank-4 for the multi-query paged_attention read
             return dispatch_op('paged_attention', dict(inputs, q=q), attrs)
         q3 = dispatch_op('reshape', {'x': q}, {'shape': [s, h, d]})
-        out = dispatch_op('paged_attention', dict(inputs, q=q3), attrs)
+        # the scope names the read's device ops in a profiler trace
+        with jax.named_scope('kv/decode_read'):
+            out = dispatch_op(
+                'paged_attention',
+                dict(inputs, q=q3, live=self.live_blocks()), attrs)
         return dispatch_op('reshape', {'x': out}, {'shape': [s, h, 1, d]})

@@ -231,6 +231,12 @@ decode_context_positions_read = _LazyMetric(
     'counter', 'decode_context_positions_read',
     'cached positions a decode step attends: the live context of every '
     'active slot, summed over layers and steps')
+decode_kv_blocks_read = _LazyMetric(
+    'counter', 'decode_kv_blocks_read',
+    'cache blocks a decode step\'s attention reads took from the pool, '
+    'summed over layers and steps: the live blocks the lockstep step '
+    'walked, its last chunk\'s padding included; S x max_blocks a layer '
+    'for a read that gathers every slot\'s whole table')
 decode_scheduler_phase_seconds = _LazyMetric(
     'histogram', 'decode_scheduler_phase_seconds',
     'wall seconds of the scheduler worker thread per loop iteration (label '

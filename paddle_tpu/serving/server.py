@@ -340,6 +340,20 @@ class _Handler(BaseHTTPRequestHandler):
         _m.http_responses.labels(code=200).inc()
 
 
+class Listener(ThreadingHTTPServer):
+    """The serving tier's HTTP listener: one daemon handler thread per
+    connection, and a listen backlog that holds a burst of connections.
+    socketserver's default backlog of 5 overflows when a replica's clients
+    connect together (128 at once in the benchmark's closed loop) while the
+    accept loop waits for the interpreter lock behind a busy scheduler
+    thread; the kernel then drops the SYNs, and a client retries after 1, 3,
+    7, 15, 31, 63 s: the first token of the last client came 4 to 62 s after
+    the burst, by chance (PERF.md section 6, PR 29). The kernel caps the
+    backlog at net.core.somaxconn."""
+    daemon_threads = True
+    request_queue_size = 1024
+
+
 class ServingServer:
     """Engine + batcher + ThreadingHTTPServer, wired and lifecycle-managed.
 
@@ -392,8 +406,7 @@ class ServingServer:
         self._shutdown_started = False
         self._shutdown_lock = threading.Lock()
         self._old_handlers = {}
-        self._httpd = ThreadingHTTPServer((host, port), _Handler)
-        self._httpd.daemon_threads = True
+        self._httpd = Listener((host, port), _Handler)
         self._httpd.serving = self
         self._thread = None
 

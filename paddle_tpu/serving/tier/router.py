@@ -53,10 +53,11 @@ import time
 import urllib.error
 import urllib.request
 import uuid
-from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
+from http.server import BaseHTTPRequestHandler
 
 from .. import metrics as _m
 from ..errors import InvalidRequest, NoReplicaAvailable
+from ..server import Listener
 from ...log_helper import get_logger
 from ...observability import distributed as _dobs
 from ...observability.trace_context import maybe_sample
@@ -777,8 +778,7 @@ class RouterServer:
             port = parse_int_env(ENV_ROUTER_PORT, 8180, minimum=0,
                                  maximum=65535)
         self.router = router
-        self._httpd = ThreadingHTTPServer((host, int(port)), _RouterHandler)
-        self._httpd.daemon_threads = True
+        self._httpd = Listener((host, int(port)), _RouterHandler)
         self._httpd.router = router
         self._thread = None
 

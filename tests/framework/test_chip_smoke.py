@@ -59,12 +59,19 @@ def test_serve_phase_tiny(counter):
     from paddle_tpu.models.causal_lm import CausalLMConfig
     # logit_tol: f32 on the CPU, where the paged and the dense read differ
     # by an ulp or two (ROADMAP D1); 1e-5 of the logit scale is far above it
+    # 5 slots of 52 blocks: 260 table entries, more than one chunk of the
+    # step's read, as the phase's own sizes have
     out = chip_smoke.serve_phase(
-        counter, cfg=CausalLMConfig.tiny(), slots=2, block_size=4,
-        max_blocks=64, max_prompt_len=16, max_new_tokens_cap=8,
-        prompt_lens=(5, 16), new_tokens=4, logit_tol=1e-5)
+        counter, cfg=CausalLMConfig(
+            vocab_size=128, hidden_size=32, num_hidden_layers=2,
+            num_attention_heads=2, intermediate_size=64,
+            max_position_embeddings=256),
+        slots=5, block_size=4, max_blocks=300, max_prompt_len=16,
+        max_new_tokens_cap=192, prompt_lens=(5, 16, 9, 12, 3), new_tokens=4,
+        logit_tol=1e-5)
     assert set(out['prefill_paths'].values()) == {'XLA gather'}
-    assert out['decode_path'] == 'XLA gather'
+    assert out['step_context_arrays'] == 0 \
+        and set(out['pool_moves'].values()) == {0}
 
 
 def test_static_phase_tiny(counter):
@@ -73,11 +80,15 @@ def test_static_phase_tiny(counter):
 
 
 def test_kernels_phase_tiny():
+    # the paged cases as the phase has them (head_dim 128, bf16, int8, a
+    # 320-wide row in 384 lanes), over 9 × 30 = 270 table entries
     out = chip_smoke.kernels_phase(
-        fused_shape=(1, 2, 128, 16), paged_slots=2, paged_heads=2,
-        paged_head_dim=128, block_size=4, pages_per_seq=4, num_blocks=16)
+        fused_shape=(1, 2, 128, 16), paged_slots=9, block_size=4,
+        pages_per_seq=30, num_blocks=300)
     assert out['fused_attention'] == 'XLA'
-    assert out['paged_attention_d128'] == 'XLA gather'
+    assert set(out['err']['paged']) == {'4x128_f32', '12x64_bf16',
+                                        '12x64_int8', '5x64_f32'}
+    assert max(out['err']['paged'].values()) < 1e-5
 
 
 def test_result_line_holds_exactly_the_keys_the_driver_reads():

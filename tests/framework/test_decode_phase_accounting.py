@@ -246,6 +246,54 @@ def test_one_engine_span_per_call_inside_its_cycle(lm):
     assert obs.tracer.snapshot()['otherData']['dropped_events'] == 0
 
 
+def test_blocks_read_counts_the_live_blocks_walked_in_whole_chunks(lm):
+    """`decode_kv_blocks_read` against a hand count, beside
+    `decode_context_positions_read`, and both in the `engine/step` span's
+    args. 10 slots of 32 blocks: 320 table entries, so a chunk of the
+    lockstep read is `LIVE_BLOCK_CHUNK` = 256 blocks."""
+    from paddle_tpu.ops.nn_ops import LIVE_BLOCK_CHUNK
+    assert LIVE_BLOCK_CHUNK == 256
+    engine = make_engine(lm, slots=10, max_blocks=400, max_prompt_len=16,
+                         max_new_tokens_cap=112, spec_decode=True, spec_k=2)
+    layers = lm.num_cache_layers
+    assert engine.slots * engine.pool.max_blocks_per_seq == 320
+    tables = [engine.reserve_table(16, 112) for _ in range(10)]
+
+    def step(contexts):
+        """One lockstep step with slot i at ``contexts[i]`` cached tokens
+        (None: idle); (blocks, positions) the counters gained."""
+        for t, c in zip(tables, contexts):
+            t.context_len = c or 0
+        before = (_counter('decode_kv_blocks_read'),
+                  _counter('decode_context_positions_read'))
+        engine.decode_step([1 if c else None for c in contexts],
+                           [t if c else None for t, c in zip(tables,
+                                                             contexts)])
+        return (_counter('decode_kv_blocks_read') - before[0],
+                _counter('decode_context_positions_read') - before[1])
+
+    with obs.telemetry_guard(True):
+        # the step feeds one token: contexts 4, 5 and 12 attend 5, 6 and 13
+        # positions in 2, 2 and 4 blocks; seven idle slots read the scratch
+        # block: 15 live blocks, one chunk of 256 walked
+        assert step([4, 5, 12] + [None] * 7) == (layers * 256,
+                                                 layers * (5 + 6 + 13))
+        # 7 slots of 32 blocks, one of 30, two idle: 256 live, one chunk
+        assert step([124] * 7 + [119] + [None] * 2)[0] == layers * 256
+        # the eighth at 31 blocks: 257 live, a block into the second chunk
+        assert step([124] * 7 + [123] + [None] * 2)[0] == layers * 512
+    spans = [e for e in _spans('engine/step') if e['name'] == 'engine/step']
+    assert [e['args']['kv_blocks'] for e in spans] == [
+        layers * 256, layers * 256, layers * 512]
+    assert spans[0]['args']['context_positions'] == layers * 24
+    # the (S, K) step's read gathers every table whole
+    for t in tables:
+        t.context_len = 4
+    before = _counter('decode_kv_blocks_read')
+    engine.spec_step([[1, 2]] * 10, tables)
+    assert _counter('decode_kv_blocks_read') - before == layers * 320
+
+
 def test_traced_requests_cost_the_per_slot_loop_no_child_contexts(
         lm, monkeypatch, tmp_path):
     """Per traced request per step: one append. The spans, with child

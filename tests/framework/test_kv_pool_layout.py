@@ -1,7 +1,8 @@
 """The K/V pool's storage layout (serving/decode/kv_cache.py): every pool
 array is rows of one token, (num_blocks, block_size, n_heads·head_dim in
-whole 128-lane tiles), so the paged writes and the page gather work on it as
-it lies; the wire format (read_blocks / write_whole_blocks) stays head-major
+whole 128-lane tiles), so the paged writes and the reads work on it as it
+lies, and the lockstep step's read holds no array over a slot's whole padded
+context; the wire format (read_blocks / write_whole_blocks) stays head-major
 and unpadded. Each case at every storage dtype."""
 import numpy as np
 import jax
@@ -10,8 +11,9 @@ import pytest
 
 from paddle_tpu import dygraph
 from paddle_tpu.models.causal_lm import CausalLMConfig, TransformerLM
-from paddle_tpu.ops.nn_ops import _gather_pages
-from paddle_tpu.serving.decode.engine import DecodeEngine, _moves_of_size
+from paddle_tpu.ops.nn_ops import LIVE_BLOCK_CHUNK, _gather_pages
+from paddle_tpu.serving.decode.engine import (DecodeEngine, _arrays_spanning,
+                                              _moves_of_size)
 from paddle_tpu.serving.decode.kv_cache import (KV_PAYLOAD_DTYPES,
                                                 KVCachePool, kv_row_bytes,
                                                 row_lanes)
@@ -180,18 +182,22 @@ def lm():
 
 
 def _engine(lm, kv_dtype):
-    # 37 blocks: no activation of the tiny model has a pool array's size
-    eng = DecodeEngine(lm, slots=2, block_size=4, max_blocks=37,
-                       max_prompt_len=16, max_new_tokens_cap=8,
+    # 9 slots of 31 blocks: 279 table entries, more than one chunk of the
+    # step's read, and 9 × 124 = 1,116 positions, a product no other run of
+    # the tiny model's dimensions has; 337 blocks: no activation has a pool
+    # array's size
+    eng = DecodeEngine(lm, slots=9, block_size=4, max_blocks=337,
+                       max_prompt_len=64, max_new_tokens_cap=60,
                        prefix_cache=False, kv_dtype=kv_dtype)
-    table = eng.reserve_table(5, 4)
+    assert eng.slots * eng.pool.max_blocks_per_seq > LIVE_BLOCK_CHUNK
+    table = eng.reserve_table(5, 60)
     eng.prefill([3, 5, 7, 9, 11], table)         # allocates the pool
-    return eng
+    return eng, table
 
 
 @pytest.mark.parametrize('kv_dtype', KV_DTYPES)
 def test_engine_programs_alias_the_pool_and_never_move_it(lm, kv_dtype):
-    eng = _engine(lm, kv_dtype)
+    eng, table = _engine(lm, kv_dtype)
     layers, scales = eng.pool.arrays()
     arrays = [a for arrs in list(layers.values()) + list(scales.values())
               for a in arrs]
@@ -202,6 +208,36 @@ def test_engine_programs_alias_the_pool_and_never_move_it(lm, kv_dtype):
         # donation held: every pool argument is aliased to a result
         assert lowered.as_text().count('tf.aliasing_output') == len(arrays)
         assert eng.pool_moves(bucket) == []
+    # the step's read builds no array over every slot's padded context ...
+    assert eng.step_context_arrays() == []
+    # ... and is ONE executable however many live blocks a step walks: 11
+    # blocks of one chunk here, two chunks' worth there
+    others = [eng.reserve_table(64, 60) for _ in range(8)]
+    for t in others:
+        t.context_len = 100
+    eng.decode_step([1] + [None] * 8, [table] + [None] * 8)
+    programs = eng.compiled_programs()
+    eng.decode_step([1] * 9, [table] + others)
+    assert eng.compiled_programs() == programs
+
+
+def test_the_detector_sees_the_dense_reads_the_step_had():
+    """Result types of the GPT-1 cell's step as the chip ran it before the
+    walk over live blocks (ledger, PR 28: 128 slots × 32 blocks of 16), and
+    what the step holds now."""
+    text = '''
+  %fusion.1 = f32[128,512,12,64]{3,1,2,0:T(8,128)} fusion(%copy.3), kind=kLoop
+  %fusion.2 = (f32[4096,16,768]{2,1,0:T(8,128)}, f32[4096,16,768]{2,1,0:T(8,128)}) fusion(%p.1, %p.2), kind=kLoop
+  %fusion.3 = f32[128,512,12]{1,2,0:T(8,128)} fusion(%a, %b), kind=kLoop
+  %fusion.4 = f32[4104,16,768]{2,1,0:T(8,128)} fusion(%p.1, %p.2), kind=kCustom
+  %fusion.5 = f32[256,16,768]{2,1,0:T(8,128)} fusion(%p.1, %ids), kind=kCustom
+  %fusion.6 = f32[4096,12]{1,0:T(8,128)} fusion(%x, %y), kind=kOutput
+  %fusion.7 = s32[4096]{0:T(1024)} fusion(%t, %l), kind=kLoop
+  %dot.8 = f32[128,40478]{1,0:T(8,128)} fusion(%h, %w), kind=kOutput
+'''
+    found = _arrays_spanning(text, 128 * 512)
+    assert [line.split(' = ')[0] for line in found] == [
+        '%fusion.1', '%fusion.2', '%fusion.3']
 
 
 def test_the_detector_sees_the_copies_the_head_major_pool_had():
@@ -260,14 +296,23 @@ def test_compiled_for_the_chip_no_program_moves_the_pool(v5e, kv_dtype):
     with dygraph.guard():
         model = TransformerLM(cfg)
         model.eval()
-        eng = DecodeEngine(model, slots=8, block_size=16, max_blocks=521,
-                           max_prompt_len=32, max_new_tokens_cap=16,
+        # 24 slots of 11 blocks: 264 table entries, more than one chunk
+        eng = DecodeEngine(model, slots=24, block_size=16, max_blocks=521,
+                           max_prompt_len=32, max_new_tokens_cap=144,
                            prefix_cache=False, kv_dtype=kv_dtype)
+        assert eng.slots * eng.pool.max_blocks_per_seq > LIVE_BLOCK_CHUNK
         eng.prefill([3, 5, 7], eng.reserve_table(3, 2))
         pool = eng.pool.arrays()[0][0][0]
         assert pool.shape == (521, 16, 768)
         for bucket in (None, 32):
             text = eng.lowered(bucket, v5e).compile().as_text()
-            # the pool's arguments lie row-major, and nothing moves them
+            # the pool's arguments lie row-major, and nothing moves them:
+            # not the scatters, and not the step's loop over the live
+            # blocks' chunks
             assert f'[521,16,768]{{2,1,0' in text
             assert _moves_of_size(text, {pool.size}) == []
+            if bucket is None:
+                # a chunk of rows as stored, and no per-slot dense context
+                assert '[256,16,768]' in text
+                assert _arrays_spanning(
+                    text, eng.slots * eng.padded_context) == []

@@ -468,3 +468,29 @@ def test_http_overload_maps_to_429(saved_model):
     with pytest.raises((urllib.error.URLError, ConnectionError, OSError)):
         urllib.request.urlopen(f'http://127.0.0.1:{srv.port}/healthz',
                                timeout=2)
+
+
+def test_listener_holds_a_burst_of_connections_in_its_backlog():
+    """A replica's clients connect together (128 at once in the benchmark's
+    closed loop). With socketserver's default backlog of 5 the kernel drops
+    the SYNs of all but the first few, and a dropped SYN is retried after
+    1 s, then 3, 7, 15: here nothing accepts at all (no serve_forever), and
+    every connection still completes at once."""
+    import socket
+    from http.server import BaseHTTPRequestHandler
+    from paddle_tpu.serving.server import Listener
+    assert Listener.daemon_threads and Listener.request_queue_size >= 1024
+    listener = Listener(('127.0.0.1', 0), BaseHTTPRequestHandler)
+    socks = []
+    try:
+        t0 = time.perf_counter()
+        for _ in range(200):
+            s = socket.socket()
+            s.settimeout(0.9)           # below the first SYN retry
+            s.connect(listener.server_address)
+            socks.append(s)
+        assert time.perf_counter() - t0 < 0.9
+    finally:
+        for s in socks:
+            s.close()
+        listener.server_close()
