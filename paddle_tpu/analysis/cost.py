@@ -584,6 +584,47 @@ def _c_mla_decode(ctx):
                                  + TRANSCENDENTAL_FLOPS + 2))
 
 
+@cost_rule('retention_gate')
+def _c_retention_gate(ctx):
+    groups = _pdim(ctx.input('w'), 1, ctx.assume_dim)
+    rows = ctx.in_elems('x') // max(1, _pdim(ctx.input('w'), 0,
+                                            ctx.assume_dim))
+    return 2 * ctx.in_elems('x') * groups \
+        + 2 * TRANSCENDENTAL_FLOPS * rows * groups
+
+
+def _retention_dims(ctx):
+    a = ctx.assume_dim
+    q, k = ctx.input('q'), ctx.input('k')
+    d = _pdim(q, 3, a)
+    return (_pdim(q, 0, a), _pdim(q, 1, a), _pdim(q, 2, a), _pdim(k, 2, a),
+            d, d * (d + 1) // 2)
+
+
+@cost_rule('power_retention_step')
+def _c_retention_step(ctx):
+    # per slot: φ of every head, one read of the state per query head
+    # (2·D·(d + 1)), and per key/value head the gate and the rank-one
+    # update of its D × (d + 1) state
+    slots, _, heads, groups, d, big = _retention_dims(ctx)
+    return slots * ((heads + groups) * 2 * big
+                    + heads * 2 * big * (d + 1)
+                    + groups * 3 * big * (d + 1))
+
+
+@cost_rule('power_retention_prefill')
+def _c_retention_prefill(ctx):
+    # as computed: per token and query head the scores and weighted sum
+    # over every key of the padded sequence (2(2d + 1) + the gates a key);
+    # per key/value head its φ(k) [v, 1]ᵀ into the next state
+    b, length, heads, groups, d, big = _retention_dims(ctx)
+    chunk = min(int(ctx.attr('chunk', 256)), length)
+    per_key = 2 * (2 * d + 1) + TRANSCENDENTAL_FLOPS + 3
+    per_query = -(-length // chunk) * chunk * per_key
+    return b * length * (heads * per_query
+                         + groups * (2 * big + 2 * big * (d + 1)))
+
+
 # ---------------------------------------------------------------------------
 # fallback coverage: every remaining op type with an INFER rule gets a
 # bytes-only cost rule so the registries stay coverage-aligned (the tier-1

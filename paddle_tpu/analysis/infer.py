@@ -1548,6 +1548,67 @@ def _mla_decode_attention(ctx):
     return _mla_out(ctx, 'mla_decode_attention')
 
 
+@infer_rule('retention_gate')
+def _retention_gate(ctx):
+    x, w = ctx.require('x'), ctx.require('w')
+    _contracts('retention_gate', _dim(x, -1), _dim(w, 0))
+    shape = None if x.shape is None else tuple(x.shape[:-1]) + (_dim(w, 1),)
+    return {'Out': VarInfo(shape, 'float32')}
+
+
+def _retention_heads(ctx, what):
+    """(tokens.., H·d) of a power-retention op and its (G, P, d) state
+    block: q (.., .., H, d) over k, v (.., .., G, d), H a multiple of G."""
+    q, k, v = ctx.require('q'), ctx.require('k'), ctx.require('v')
+    for name, info in (('q', q), ('k', k), ('v', v)):
+        if info.shape is not None and len(info.shape) != 4:
+            raise InferError(f'{what} expects {name} of rank 4, got rank '
+                             f'{len(info.shape)}')
+    heads, groups, d = _dim(q, 2), _dim(k, 2), _dim(q, 3)
+    if not (dims_agree(groups, _dim(v, 2)) and dims_agree(d, _dim(k, 3))
+            and dims_agree(d, _dim(v, 3))):
+        raise InferError(f'{what}: q, k and v disagree on heads or head '
+                         f'size: {q.shape}, {k.shape}, {v.shape}')
+    if known(heads) and known(groups) and (groups < 1 or heads % groups):
+        raise InferError(f'{what}: {heads} query heads do not divide over '
+                         f'{groups} key/value heads')
+    _contracts(f'{what} log_gate against key/value heads',
+               _dim(ctx.require('log_gate'), 2), groups)
+    block = None
+    if known(d):
+        from ..ops.llm_ops import retention_state_rows
+        if d % 2:
+            raise InferError(f'{what}: head size {d} is odd')
+        block = (groups, retention_state_rows(d)[2], d)
+    return (VarInfo((_dim(q, 0), _dim(q, 1), _mul_dims(heads, d)), q.dtype),
+            block)
+
+
+@infer_rule('power_retention_prefill')
+def _power_retention_prefill(ctx):
+    out, block = _retention_heads(ctx, 'power_retention_prefill')
+    if int(ctx.attr('chunk', 256)) < 1:
+        raise InferError('power_retention_prefill: chunk must be positive',
+                         kind='bad-attr')
+    state = None if block is None else (_dim(ctx.require('q'), 0),) + block
+    return {'Out': out, 'State': VarInfo(state, 'float32')}
+
+
+@infer_rule('power_retention_step')
+def _power_retention_step(ctx):
+    out, block = _retention_heads(ctx, 'power_retention_step')
+    state = ctx.require('state')
+    if state.shape is not None and block is not None and not (
+            len(state.shape) == 4 and all(
+                dims_agree(a, b) for a, b in zip(state.shape[1:], block))):
+        raise InferError(
+            f'power_retention_step: state rows of {state.shape[1:]} are '
+            f'not the block {block} of these heads')
+    _contracts('power_retention_step rows against slots',
+               _dim(ctx.require('rows'), 0), _dim(ctx.require('q'), 0))
+    return {'Out': out, 'State': VarInfo(state.shape, 'float32')}
+
+
 # ---------------------------------------------------------------------------
 # rules: framework-internal ops
 # ---------------------------------------------------------------------------

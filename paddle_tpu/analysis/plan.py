@@ -511,17 +511,20 @@ def _model_state_bytes(model):
 
 
 def _decode_kv_geometry(model):
-    """What the model caches per token per layer, asked of the model
-    (``model.kv_cache_spec()``: ``{'kind': 'kv', 'layers', 'heads',
-    'head_dim'}`` for K and V rows of every head, ``{'kind': 'latent',
-    'layers', 'row_width'}`` for one latent row). Raises a ValueError
+    """What the model caches, asked of the model
+    (``model.kv_cache_spec()``: per token per layer ``{'kind': 'kv',
+    'layers', 'heads', 'head_dim'}`` for K and V rows of every head or
+    ``{'kind': 'latent', 'layers', 'row_width'}`` for one latent row; per
+    REQUEST per layer ``{'kind': 'state', 'layers', 'heads', 'state_rows',
+    'head_dim'}`` for one recurrent state). Raises a ValueError
     naming what is missing — a budget solve over unknown geometry would
     silently size the pool wrong."""
     spec = getattr(model, 'kv_cache_spec', None)
     if spec is None:
         raise ValueError(
             'decode-pool budget solve needs model.kv_cache_spec() (the '
-            'models/causal_lm.py and models/latent_moe_lm.py contract); '
+            'models/causal_lm.py, latent_moe_lm.py and retention_lm.py '
+            'contract); '
             'pass an explicit max_blocks / PADDLE_TPU_DECODE_MAX_BLOCKS '
             'for models without it')
     return spec()
@@ -531,9 +534,16 @@ def decode_token_layer_bytes(model, kv_dtype='f32'):
     """HBM bytes ONE token's cached state costs in ONE layer: a K and a V
     row of every head, or one latent row, each in the lanes the pool gives
     it, priced by kv_cache.kv_row_bytes at the storage dtype (int8 rows
-    carry an f32 scale a head; a latent row has no int8 form)."""
+    carry an f32 scale a head; a latent row has no int8 form). A state
+    cache holds no row per token: 0, and its price is per slot
+    (:func:`decode_state_row_bytes`)."""
     from ..serving.decode.kv_cache import kv_row_bytes
     spec = _decode_kv_geometry(model)
+    if spec['kind'] == 'state':
+        if kv_dtype != 'f32':
+            raise ValueError('a state cache is float32: it has no '
+                             f'kv_dtype={kv_dtype} form')
+        return 0
     if spec['kind'] == 'latent':
         if kv_dtype == 'int8':
             raise ValueError('a latent KV cache has no int8 rows')
@@ -545,6 +555,36 @@ def decode_pool_block_bytes(model, block_size, kv_dtype='f32'):
     """HBM bytes ONE KV-cache block costs across every layer."""
     return (_decode_kv_geometry(model)['layers'] * int(block_size)
             * decode_token_layer_bytes(model, kv_dtype))
+
+
+def decode_state_row_bytes(model):
+    """HBM bytes ONE request's recurrent state costs across every layer: a
+    row of each state layer's array, ``heads`` blocks of ``state_rows`` ×
+    ``head_dim`` float32 values (ops/llm_ops.py::retention_state_rows),
+    whatever the request's context. What a slot costs a state cache."""
+    spec = _decode_kv_geometry(model)
+    if spec['kind'] != 'state':
+        raise ValueError(
+            f"a {spec['kind']} cache holds rows per token, no state row: "
+            f'price it by decode_pool_block_bytes')
+    return (spec['layers'] * spec['heads'] * spec['state_rows']
+            * spec['head_dim'] * 4)
+
+
+def solve_decode_state_slots(model, hbm_mb):
+    """Slots a budget covers for a state cache: (budget − model state) //
+    the bytes of one state row, less the scratch row of idle slots. Raises
+    when the budget does not cover the weights and one slot."""
+    budget = int(float(hbm_mb) * (1 << 20))
+    state = _model_state_bytes(model)
+    rows = (budget - state) // decode_state_row_bytes(model)
+    if rows < 2:
+        raise ValueError(
+            f'a budget of {hbm_mb} MiB ({budget} bytes) does not cover the '
+            f'model state ({state} bytes) and two state rows of '
+            f'{decode_state_row_bytes(model)} bytes (a slot and the '
+            f'scratch row)')
+    return int(rows) - 1
 
 
 def solve_decode_pool_blocks(model, hbm_mb, block_size, kv_dtype='f32',
@@ -563,6 +603,9 @@ def solve_decode_pool_blocks(model, hbm_mb, block_size, kv_dtype='f32',
             f'PADDLE_TPU_DECODE_HBM_MB={hbm_mb} ({budget} bytes) does not '
             f'cover the model state ({state} bytes); nothing left for the '
             f'KV pool')
+    if not block_bytes:
+        # a state cache: blocks book lengths and no HBM stands behind them
+        return int(min_blocks)
     return max(int(min_blocks), (budget - state) // block_bytes)
 
 
@@ -575,7 +618,12 @@ def decode_pool_report(model, hbm_mb, block_size, kv_dtype='f32',
     block_bytes = decode_pool_block_bytes(model, block_size, kv_dtype)
     blocks = solve_decode_pool_blocks(model, hbm_mb, block_size, kv_dtype,
                                       min_blocks)
+    extra = {}
+    if spec['kind'] == 'state':
+        extra = {'state_row_bytes': decode_state_row_bytes(model),
+                 'state_slots': solve_decode_state_slots(model, hbm_mb)}
     return {
+        **extra,
         'budget_mb': int(hbm_mb),
         'kv_dtype': kv_dtype,
         'block_size': int(block_size),

@@ -38,6 +38,33 @@ _ONLY = {'q_lora_rank': None, 'n_group': 1, 'topk_group': 1,
 _IGNORED = ('head_dim', 'model_type', 'qk_head_dim', 'num_key_value_heads')
 
 
+def check_published(owner, published, only, ignored):
+    """Refuse a published key ``owner`` has no equations for: one it does
+    not know, or a value other than the one its block assumes (``only``);
+    keys in ``ignored`` repeat another or describe nothing of the forward."""
+    for key, value in published.items():
+        if key in ignored:
+            continue
+        if key not in only:
+            raise ValueError(f'{owner}: unknown key {key!r}')
+        if value != only[key]:
+            raise ValueError(
+                f'{owner}: {key}={value!r} is not supported (only '
+                f'{only[key]!r}): the block has no equations for it')
+
+
+def from_published(cls, published, extras, only, ignored):
+    """``cls`` from a dict that holds the published `config.json` keys among
+    others (a benchmark configuration file): the keys ``cls`` knows are
+    taken, under their own names, and ``extras`` beside them."""
+    import inspect
+    known = {name for name, p in inspect.signature(
+        cls.__init__).parameters.items()
+        if p.kind is p.POSITIONAL_OR_KEYWORD} | set(only) | set(ignored)
+    return cls(**{**{k: v for k, v in published.items() if k in known},
+                  **extras})
+
+
 class LatentMoEConfig:
     def __init__(self, vocab_size, hidden_size, intermediate_size,
                  moe_intermediate_size, num_hidden_layers,
@@ -48,16 +75,7 @@ class LatentMoEConfig:
                  norm_topk_prob=True, rms_norm_eps=1e-6, rope_theta=10000.0,
                  max_position_embeddings=4096, initializer_range=0.02,
                  router_bias_scale=0.0, dtype='float32', **published):
-        for key, value in published.items():
-            if key in _IGNORED:
-                continue
-            if key not in _ONLY:
-                raise ValueError(f'LatentMoEConfig: unknown key {key!r}')
-            if value != _ONLY[key]:
-                raise ValueError(
-                    f'LatentMoEConfig: {key}={value!r} is not supported '
-                    f'(only {_ONLY[key]!r}): the block has no equations '
-                    f'for it')
+        check_published('LatentMoEConfig', published, _ONLY, _IGNORED)
         self.vocab_size = int(vocab_size)
         self.hidden_size = int(hidden_size)
         self.intermediate_size = int(intermediate_size)
@@ -88,12 +106,7 @@ class LatentMoEConfig:
         """From a dict that holds the published `config.json` keys among
         others (a benchmark configuration file): the keys this class knows
         are taken, under their own names, and ``extras`` beside them."""
-        import inspect
-        known = {name for name, p in inspect.signature(
-            cls.__init__).parameters.items()
-            if p.kind is p.POSITIONAL_OR_KEYWORD} | set(_ONLY) | set(_IGNORED)
-        return cls(**{**{k: v for k, v in published.items() if k in known},
-                      **extras})
+        return from_published(cls, published, extras, _ONLY, _IGNORED)
 
     @property
     def latent_row_width(self):

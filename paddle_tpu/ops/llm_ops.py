@@ -21,6 +21,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .registry import register_op
@@ -229,3 +230,256 @@ def mla_decode_attention(q, pages, block_tables, context_lens, w_kvb, *,
     out = jnp.einsum('skhr,rhd->skhd', u, w_uv,
                      preferred_element_type=_F32).astype(q.dtype)
     return out.reshape(s, kq, heads * v_dim)
+
+
+# ---------------------------------------------------------------------------
+# power retention (degree 2): linear attention over the symmetric degree-2
+# coordinates φ(x), φ(q)·φ(k) = (q·k)², with one sigmoid gate per key/value
+# head. Per head the recurrent state is S (D × d) and its normaliser z (D),
+# D = d(d+1)/2, both float32:
+#
+#     S_t = γ_t S_{t−1} + φ(k_t) v_tᵀ      z_t = γ_t z_{t−1} + φ(k_t)
+#     y_t = φ(q_t)ᵀ S_t / φ(q_t)ᵀ z_t
+#
+# A head's state is ONE (P, d) block, d values of v on the lanes: rows 0..D−1
+# are S, the next ⌈D/d⌉ hold z row-major (zero past D), and P rounds their
+# count up to the 8 sublanes, so that row-major is the TPU's compact layout
+# and the normaliser pads no axis from d + 1 to 2d (retention_state_rows).
+#
+# Precision: the state, the gates' cumulative logs and every sum over the
+# state are float32; q, k and v arrive as stored (bf16 on the served path)
+# and φ is formed in float32 from them (a product of two bf16 values is
+# exact there). Contractions with the float32 state run at _STATE_PRECISION:
+# one bf16 pass would round the state's 24 bits to 8 at every read.
+# ---------------------------------------------------------------------------
+
+_STATE_PRECISION = lax.Precision.HIGHEST
+
+
+def retention_state_rows(head_dim):
+    """(D, rows of z, P) of one head's state block at ``head_dim`` d."""
+    d = int(head_dim)
+    if d % 2:
+        raise ValueError(f'power retention needs an even head_dim, got {d}')
+    big = d * (d + 1) // 2
+    z_rows = -(-big // d)
+    return big, z_rows, -(-(big + z_rows) // 8) * 8
+
+
+def _phi(x):
+    """The symmetric degree-2 coordinates of x (..., d) in float32, (...,
+    d(d+1)/2): lane o·d + a holds c·x_a·x_{(a+o) mod d} for the cyclic
+    offsets o = 0 .. d/2 − 1 (c = 1 at o = 0, √2 after it), and the last
+    d/2 lanes hold √2·x_a·x_{a+d/2}: every unordered pair once, by rolls of
+    the lanes and no gather."""
+    x = jnp.asarray(x).astype(_F32)
+    d = x.shape[-1]
+    half = d // 2
+    root2 = jnp.sqrt(_F32(2.0))
+    parts = [x * x] + [root2 * x * jnp.roll(x, -o, -1)
+                       for o in range(1, half)]
+    parts.append(root2 * x[..., :half] * x[..., half:])
+    return jnp.concatenate(parts, -1)
+
+
+def _z_rows(z, n_rows, d):
+    """z (..., D) laid row-major in (..., n_rows, d), zero past D: the
+    block's rows under S."""
+    pad = ((0, 0),) * (z.ndim - 1) + ((0, n_rows * d - z.shape[-1]),)
+    return jnp.pad(z, pad).reshape(z.shape[:-1] + (n_rows, d))
+
+
+def _state_block(s, z, rows):
+    """S (G, D, d) and z (G, D) as the (G, P, d) block."""
+    big, d = s.shape[1:]
+    return jnp.concatenate([s, _z_rows(z, rows - big, d)], 1)
+
+
+def _state_parts(block):
+    """(S (.., D, d), z (.., D)) of a (.., P, d) block."""
+    d = block.shape[-1]
+    big, z_rows, _ = retention_state_rows(d)
+    z = block[..., big:big + z_rows, :]
+    return (block[..., :big, :],
+            z.reshape(z.shape[:-2] + (z_rows * d,))[..., :big])
+
+
+def retention_state_forms(block):
+    """A (..., P, d) state block as the quadratic forms it stands for,
+    (..., d, d, d + 1) float32: M[a, b] = Σ_i decay_i · k_{i,a} k_{i,b} ·
+    [v_i, 1], symmetric in (a, b), so that φ(q)ᵀ [S, z] = Σ_{a,b} q_a q_b
+    M[a, b]. It names `_phi`'s order of the pairs and the block's layout
+    once, for whoever holds a state to one computed otherwise (a test, the
+    benchmark's check)."""
+    s, z = _state_parts(jnp.asarray(block))
+    flat = jnp.concatenate([s, z[..., None]], -1)         # (.., D, d + 1)
+    big, d = s.shape[-2:]
+    half = d // 2
+    lane = np.arange(d)
+    a = np.concatenate([np.tile(lane, half), lane[:half]])
+    b = np.concatenate([(lane + o) % d for o in range(half)]
+                       + [lane[:half] + half])
+    where = np.zeros((d, d), np.int32)              # (a, b) -> its lane of φ
+    where[a, b] = where[b, a] = np.arange(big)
+    flat = flat / jnp.where(a == b, 1.0, jnp.sqrt(_F32(2.0)))[:, None]
+    return flat[..., where, :].astype(_F32)
+
+
+@register_op('retention_gate')
+def retention_gate(x, w, *, shift=0.0):
+    """log γ = log sigmoid(x · w + shift) in float32, one gate per key/value
+    head: x (..., h), w (h, G) -> (..., G)."""
+    return jax.nn.log_sigmoid(
+        jnp.matmul(jnp.asarray(x), jnp.asarray(w),
+                   preferred_element_type=_F32) + _F32(shift))
+
+
+def _retention_scan(q, k, v, log_gate, last, chunk):
+    """One sequence: q (L, G, R, d), k, v (L, G, d), log_gate (L, G); rows
+    past ``last`` are padding (gate 1, contribution 0). Returns y (L, G, R,
+    d) float32 and the (G, P, d) state after row ``last``.
+
+    A scan over chunks that builds the state a chunk at a time. A chunk's
+    outputs are the quadratic form over every key up to t: the state is
+    built for the steps that follow and never read here (no φ(q) is formed:
+    at d = 128 a read of the state costs a query as much as 8,288 keys)."""
+    length, g, rep, d = q.shape
+    big, _, rows = retention_state_rows(d)
+    chunk = min(int(chunk), length)
+    n = -(-length // chunk)
+    padded = n * chunk
+    live = jnp.arange(padded, dtype=jnp.int32) <= last
+
+    def rows_of(x):
+        # a dead row's k and v are zeroed by a select, not by a factor:
+        # whatever a padded row holds (a NaN too) then adds exactly nothing
+        x = jnp.pad(x, ((0, padded - length),) + ((0, 0),) * (x.ndim - 1))
+        return jnp.where(live.reshape((-1,) + (1,) * (x.ndim - 1)), x, 0)
+
+    def chunks(x):
+        return x.reshape((n, chunk) + x.shape[1:])
+
+    k_all, v_all = rows_of(k), rows_of(v)
+    a_all = rows_of(log_gate.astype(_F32))
+    xs = (jnp.arange(n),
+          chunks(jnp.pad(q, ((0, padded - length), (0, 0), (0, 0), (0, 0)))),
+          chunks(k_all), chunks(v_all), chunks(a_all))
+    position = jnp.arange(padded, dtype=jnp.int32)
+    cum_all = jnp.cumsum(a_all, 0)                        # (L, G)
+
+    def step(state, x):
+        idx, qc, kc, vc, ac = x
+        s_prev, z_prev = _state_parts(state)
+        b = jnp.cumsum(ac, 0)                             # (C, G), inclusive
+        here = idx * chunk + jnp.arange(chunk, dtype=jnp.int32)
+        # Σ_i a_{t,i} [v_i, 1] of the chunk's queries over the sequence's
+        # keys, a_{t,i} = (q_t·k_i)² exp(cum[t] − cum[i]) for i <= t
+        seen = here[:, None] >= position[None, :]
+        scores = jnp.einsum('tgrd,igd->grti', qc, k_all,
+                            preferred_element_type=_F32)
+        cum_q = lax.dynamic_slice_in_dim(cum_all, idx * chunk, chunk)
+        diff = cum_q.T[:, :, None] - cum_all.T[:, None, :]  # (G, t, i)
+        decay = jnp.where(seen, jnp.exp(jnp.where(seen, diff, 0.0)), 0.0)
+        weights = jnp.square(scores) * decay[:, None]     # (G, R, t, i)
+        num = jnp.einsum('grti,igd->tgrd', weights.astype(v_all.dtype),
+                         v_all, preferred_element_type=_F32)
+        den = weights.sum(-1).transpose(2, 0, 1)          # (t, G, R)
+        # the next state: S_prev decayed over the chunk, plus the chunk's
+        # own φ(k_i) [v_i, 1]ᵀ decayed from i to its end
+        phi_k = _phi(kc) * jnp.exp(b[-1][None] - b)[:, :, None]  # (C, G, D)
+        s_next = jnp.exp(b[-1])[:, None, None] * s_prev + jnp.einsum(
+            'igD,igd->gDd', phi_k, vc.astype(_F32),
+            precision=_STATE_PRECISION)
+        z_next = jnp.exp(b[-1])[:, None] * z_prev + phi_k.sum(0)
+        # a row of padding (q = 0) weighs nothing: its 0/0 reads 0, and its
+        # gradient stays finite
+        den = jnp.where(den > 0, den, 1.0)
+        return _state_block(s_next, z_next, rows), num / den[..., None]
+
+    state, y = lax.scan(step, jnp.zeros((g, rows, d), _F32), xs)
+    return y.reshape(padded, g, rep, d)[:length], state
+
+
+@register_op('power_retention_prefill', outputs=('Out', 'State'))
+def power_retention_prefill(q, k, v, log_gate, last=None, *, chunk=256):
+    """Power retention over whole sequences, as a scan over chunks that
+    builds the state (see above): a_{t,i} = (q_t·k_i)² Π_{s=i+1..t} γ_s.
+
+    q (B, L, H, d); k, v (B, L, G, d), query head j reads key/value head
+    j // (H/G); log_gate (B, L, G) float32, log γ; ``last`` () or (B,)
+    int32, the index of a sequence's last row (rows past it are a rung's
+    padding and never enter the state; None: every row is live). Returns
+    (B, L, H·d) in q's dtype and the (B, G, P, d) float32 states after row
+    ``last``."""
+    q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
+    b, length, heads, d = q.shape
+    g = k.shape[2]
+    if last is None:
+        last = length - 1
+    last = jnp.broadcast_to(jnp.asarray(last, jnp.int32), (b,))
+    y, state = jax.vmap(
+        lambda *x: _retention_scan(*x, chunk=chunk))(
+            q.reshape(b, length, g, heads // g, d), k, v,
+            jnp.asarray(log_gate), last)
+    return y.reshape(b, length, heads * d).astype(q.dtype), state
+
+
+@register_op('power_retention_step', outputs=('Out', 'State'))
+def power_retention_step(q, k, v, log_gate, state, rows):
+    """One token for each of S slots over their recurrent states: gate,
+    rank-one update, read.
+
+    q (S, 1, H, d); k, v (S, 1, G, d); log_gate (S, 1, G); state (rows, G,
+    P, d) float32, one row a request and row 0 for idle slots; rows (S,)
+    int32, each slot's row. Returns (S, 1, H·d) in q's dtype and the state
+    with every slot's row advanced.
+
+    Two walks over the slots, one row at a time, so that the program holds
+    no copy of S rows. The first advances each slot's row where it lies:
+    S ← γ S + φ(k) vᵀ is one pass that reads the row and writes it back (the
+    rank-one term is formed from φ(k) and v as it is added), z alike in its
+    few rows. The second reads each advanced row, φ(q)ᵀ S and φ(q)ᵀ z.
+    Writing and reading in one walk makes the compiler copy the whole array
+    (seen on the CPU backend) or a row at a time (on the TPU)."""
+    q, state = jnp.asarray(q), jnp.asarray(state)
+    slots, _, heads, d = q.shape
+    g = k.shape[2]
+    rep = heads // g
+    big, _, prow = retention_state_rows(d)
+    tail = prow - big                       # the rows of z, and the padding
+    rows = jnp.asarray(rows, jnp.int32)
+    phi_q = _phi(q[:, 0]).reshape(slots, g, rep, big)
+    phi_k = _phi(k[:, 0])                                   # (S, G, D)
+    vf = jnp.asarray(v)[:, 0].astype(_F32)                  # (S, G, d)
+    gamma = jnp.exp(jnp.asarray(log_gate)[:, 0].astype(_F32))
+    z_add = _z_rows(phi_k, tail, d)                         # (S, G, tail, d)
+
+    def advance(i, state):
+        row, gm = rows[i], gamma[i][None, :, None, None]
+        s_old = lax.dynamic_slice(state, (row, 0, 0, 0), (1, g, big, d))
+        state = lax.dynamic_update_slice(
+            state, gm * s_old + (phi_k[i][:, :, None]
+                                 * vf[i][:, None, :])[None], (row, 0, 0, 0))
+        z_old = lax.dynamic_slice(state, (row, 0, big, 0), (1, g, tail, d))
+        return lax.dynamic_update_slice(state, gm * z_old + z_add[i][None],
+                                        (row, 0, big, 0))
+
+    state = lax.fori_loop(0, slots, advance, state)
+
+    def read(i, carry):
+        num, den = carry
+        s_now = lax.dynamic_slice(state, (rows[i], 0, 0, 0),
+                                  (1, g, big, d))[0]
+        z_now = lax.dynamic_slice(state, (rows[i], 0, big, 0),
+                                  (1, g, tail, d))[0]
+        z_now = z_now.reshape(g, tail * d)[:, :big]
+        return (num.at[i].set(jnp.einsum('grD,gDd->grd', phi_q[i], s_now,
+                                         precision=_STATE_PRECISION)),
+                den.at[i].set(jnp.einsum('grD,gD->gr', phi_q[i], z_now,
+                                         precision=_STATE_PRECISION)))
+
+    num, den = lax.fori_loop(
+        0, slots, read, (jnp.zeros((slots, g, rep, d), _F32),
+                         jnp.zeros((slots, g, rep), _F32)))
+    out = (num / den[..., None]).reshape(slots, 1, heads * d)
+    return out.astype(q.dtype), state

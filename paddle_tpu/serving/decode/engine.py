@@ -1,8 +1,10 @@
 """DecodeEngine: phase-split stateful generation over a paged KV cache.
 
 Every engine call runs the model as ONE jitted XLA program (`_Program`):
-parameters, buffers and the pool's per-layer K/V arrays are its arguments
-(the pool DONATED, so the paged writes update it in place), everything about
+parameters, buffers and the pool's per-layer arrays are its arguments (K/V
+or latent rows per token; for a retention layer one recurrent state per
+request, kv_cache.py "Recurrent state"; all DONATED, so the paged writes and
+the state updates happen in place), everything about
 the request is a small traced array (token ids, positions, write
 coordinates, block tables, context lengths), and it returns the logits rows
 the host samples from plus the new pool arrays. The host's part of a call is
@@ -114,8 +116,8 @@ class _Program:
             ids, pos, coords, last) -> (rows, stats, layers, scales)
 
     ``mode`` ('prefill' | 'decode') and the pool's ``geometry`` are static;
-    ``layers`` / ``scales`` (the pool's arrays) are donated and come back
-    written; parameters are arguments, read from the model at each call, so
+    ``layers`` / ``scales`` (the pool's arrays, a state layer's among
+    ``layers``) are donated and come back written; parameters are arguments, read from the model at each call, so
     no weight is baked into an executable and a swapped weight is served.
     The trace binds them the way dygraph/jit.py::functionalize does, and
     the pool it writes is a `KVCachePool.over` the traced arrays: nothing
@@ -329,8 +331,9 @@ class DecodeEngine:
         # tape's no_grad flag is process-global). None = zero overhead.
         self._model_lock = model_lock
         self._program = _Program.of(model)
-        # what the model caches per token per layer: [k, v] rows per head
-        # (the default), or one latent row (models/latent_moe_lm.py)
+        # what the model caches: per token per layer [k, v] rows per head
+        # (the default) or one latent row (models/latent_moe_lm.py), or per
+        # REQUEST per layer one recurrent state (models/retention_lm.py)
         spec = getattr(model, 'kv_cache_spec', None)
         self.cache_kind = spec()['kind'] if spec else 'kv'
         # the last call's ``stats`` as its program returned them (device
@@ -355,10 +358,11 @@ class DecodeEngine:
                                         'f32')
         num_blocks = self._resolve_num_blocks(model, max_blocks, block_size,
                                               max_bps, kv_dtype)
-        self.pool = KVCachePool(block_size=block_size,
-                                num_blocks=num_blocks,
-                                max_blocks_per_seq=max_bps,
-                                kv_dtype=kv_dtype)
+        # a state cache: a row a slot, and the scratch row of idle slots
+        self.pool = KVCachePool(
+            block_size=block_size, num_blocks=num_blocks,
+            max_blocks_per_seq=max_bps, kv_dtype=kv_dtype,
+            state_rows=self.slots + 1 if self.cache_kind == 'state' else 0)
         if self.pool.allocator.capacity < max_bps:
             # an empty pool must always cover one maximal request, or the
             # scheduler's FIFO head could wait forever
@@ -370,6 +374,7 @@ class DecodeEngine:
         _m.decode_cache_blocks_total.set(self.pool.allocator.capacity)
         from .kv_cache import KV_DTYPE_CODES
         _m.kv_cache_dtype.set(KV_DTYPE_CODES[self.pool.kv_dtype])
+        self._set_state_gauges()
         self._prefill_compiled = set()
         self._step_compiled = False
         self._spec_compiled = False
@@ -409,6 +414,18 @@ class DecodeEngine:
                 ('kv_dtype=int8', kv_dtype == 'int8')) if on]
             if asked:
                 raise UnsupportedCacheFeature(asked, 'latent')
+        if self.cache_kind == 'state':
+            # nothing of a state can be shared, quantized or rolled back
+            # (docs/SERVING.md "Recurrent state"); the handoff is refused
+            # where its prefill role is built (serving/tier/disagg.py)
+            asked = [name for name, on in (
+                ('the prefix cache (and its spill and reinject)',
+                 self.prefix_cache is not None),
+                ('speculative decoding (its (S, K) verify step)',
+                 self.spec_enabled),
+                (f'kv_dtype={kv_dtype}', kv_dtype != 'f32')) if on]
+            if asked:
+                raise UnsupportedCacheFeature(asked, 'state')
 
     @staticmethod
     def _resolve_num_blocks(model, max_blocks, block_size, max_bps,
@@ -492,6 +509,16 @@ class DecodeEngine:
     def release_table(self, table):
         self.pool.free_table(table)
         _m.decode_cache_blocks_used.set(self.pool.allocator.used)
+        self._set_state_gauges()
+
+    def _set_state_gauges(self):
+        """The state cache's three gauges (a telemetry reset clears gauges,
+        so every call that changes one sets all three); nothing for a cache
+        of rows."""
+        if self.cache_kind == 'state':
+            _m.state_cache_bytes_in_hbm.set(self.pool.state_bytes_in_hbm())
+            _m.state_cache_rows_total.set(self.pool.state_rows.capacity)
+            _m.state_cache_rows_used.set(self.pool.state_rows.used)
 
     # -- phases ------------------------------------------------------------
     def _run(self, clock, mode, ids, pos, coords, last=None):
@@ -595,6 +622,10 @@ class DecodeEngine:
         _m.decode_prefill_seconds.observe(clock.last - t0)
         token = int(row.argmax() if sampler is None else sampler(row))
         clock.end('sample')
+        folded = P * self.pool.num_state_layers
+        if folded:
+            _m.decode_state_tokens_folded.inc(folded)
+            clock.work['state_tokens_folded'] = folded
         clock.record(prompt_len=P, bucket=bucket)
         if bucket not in self._prefill_compiled:
             self._prefill_compiled.add(bucket)
@@ -602,6 +633,7 @@ class DecodeEngine:
         _m.decode_cache_blocks_used.set(self.pool.allocator.used)
         _m.kv_cache_bytes_in_hbm.set(self.pool.bytes_in_hbm())
         _m.kv_cache_row_bytes.set(self.pool.row_bytes())
+        self._set_state_gauges()
         return token
 
     def decode_step(self, tokens, tables, return_rows=False):
@@ -650,8 +682,10 @@ class DecodeEngine:
         ``ctx_lens`` (an idle slot's 1: the scratch block). A K/V pool's
         read walks the live blocks in whole chunks (ops/nn_ops.py::
         paged_attention); a latent pool's gathers every slot's whole
-        table."""
+        table; a state layer reads no block at all."""
         entries = self.slots * self.pool.max_blocks_per_seq
+        if self.cache_kind == 'state':
+            return 0
         if self.cache_kind != 'kv':
             return entries
         chunk = live_block_chunk(entries)
@@ -665,14 +699,20 @@ class DecodeEngine:
         _m.decode_steps.inc()
         active = sum(t is not None for t in tables)
         # the live context the step attended, the fed tokens included, and
-        # the blocks its reads took from the pool to attend it
-        layers = self.pool.num_layers
+        # the blocks its reads took from the pool to attend it: both over
+        # the layers that cache rows (a state layer attends no position and
+        # reads no block: it advances one state a live slot)
+        layers = self.pool.num_row_layers
         positions = layers * sum(
             t.context_len for t in tables if t is not None)
         _m.decode_context_positions_read.inc(positions)
         _m.decode_kv_blocks_read.inc(layers * blocks)
         clock.work['context_positions'] = positions
         clock.work['kv_blocks'] = layers * blocks
+        updates = self.pool.num_state_layers * active
+        if updates:
+            _m.decode_state_updates.inc(updates)
+            clock.work['state_updates'] = updates
         clock.record()
         _m.decode_slots_active.set(active)
         _m.decode_slot_occupancy.observe(active / max(self.slots, 1))

@@ -51,6 +51,19 @@ serves every request of its shape. Between engine calls `write_whole_blocks`
 (handoff, reinjection) scatters eagerly through the small jitted kernels
 below.
 
+Recurrent state (docs/SERVING.md "Recurrent state"): a retention layer
+(models/retention_lm.py) caches no row per token but ONE fixed-size float32
+state per request, whatever its context: a state layer is one array
+(state_rows, G, P, d), a row a request, ``state_rows = slots + 1`` with row 0
+the scratch row of idle slots. A row is G blocks of (P, d) with d values on
+the lanes (ops/llm_ops.py::retention_state_rows), so row-major is the compact
+layout here too. It is not paged: a table carries its row
+(`BlockTable.state_row`, taken and returned with the table by a free list of
+its own, :class:`StateRows`), a prefill overwrites the whole row and every
+step advances it in place, inside the same donated programs. The block
+allocator still books the request's lengths; no HBM stands behind a block of
+a model whose every layer is a state layer.
+
 Quantized storage (``kv_dtype``, docs/SERVING.md "Tiered KV cache"): the
 pools hold payload at ``f32`` (exact, the default), ``bf16`` (half the
 bytes; decode reads cast back to f32 — an exact roundtrip for every
@@ -76,9 +89,11 @@ import threading
 import jax
 import numpy as np
 
-from ..errors import InvalidRequest, OutOfBlocks, UnsupportedCacheFeature
+from ..errors import (InvalidRequest, OutOfBlocks, OutOfStateRows,
+                      UnsupportedCacheFeature)
 
 __all__ = ['BlockAllocator', 'BlockTable', 'KVCachePool', 'CacheContext',
+           'StateRows',
            'prefill_coords', 'decode_coords', 'DEFAULT_SLOTS',
            'DEFAULT_BLOCK_SIZE', 'DEFAULT_MAX_BLOCKS', 'SCRATCH_BLOCK',
            'KV_PAYLOAD_DTYPES', 'KV_DTYPE_CODES', 'kv_row_bytes',
@@ -154,6 +169,45 @@ def _scatter_blocks(pages, block_ids, vals):
 def _scatter_tokens(pages, block_ids, offsets, vals):
     """pages (NB, BS, W) ← vals (S, W) at (block_ids, offsets) (S,)."""
     return pages.at[block_ids, offsets].set(vals)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _put_row(states, row, block):
+    """states (R, G, P, d) ← block (G, P, d) at row () int32, whole."""
+    return jax.lax.dynamic_update_index_in_dim(states, block, row, 0)
+
+
+class StateRows:
+    """Free list of the state layers' rows (one row of EVERY state layer
+    belongs to one request). Row 0, the scratch row of idle slots, is never
+    handed out; a row is owned by one table and shared by nobody."""
+
+    def __init__(self, num_rows):
+        self.num_rows = int(num_rows)
+        self._free = list(range(self.num_rows - 1, 0, -1))    # pop() -> 1..
+        self._lock = threading.Lock()
+
+    @property
+    def capacity(self):
+        return max(self.num_rows - 1, 0)
+
+    @property
+    def used(self):
+        with self._lock:
+            return self.capacity - len(self._free)
+
+    def take(self):
+        with self._lock:
+            if not self._free:
+                raise OutOfStateRows(self.capacity)
+            return self._free.pop()
+
+    def give_back(self, row):
+        row = int(row)
+        with self._lock:
+            if not 0 < row < self.num_rows or row in self._free:
+                raise ValueError(f'state row {row} is not in use')
+            self._free.append(row)
 
 
 class BlockAllocator:
@@ -240,10 +294,13 @@ class BlockTable:
     """One request's cache blocks, in sequence order. ``context_len`` is the
     number of cached tokens (prompt + generated so far)."""
 
-    __slots__ = ('blocks', 'block_size', 'context_len', 'cached_len')
+    __slots__ = ('blocks', 'block_size', 'context_len', 'cached_len',
+                 'state_row')
 
-    def __init__(self, blocks, block_size, cached_len=0):
+    def __init__(self, blocks, block_size, cached_len=0, state_row=0):
         self.blocks = list(blocks)
+        # the request's row of the state layers (0: none, the scratch row)
+        self.state_row = int(state_row)
         self.block_size = int(block_size)
         self.context_len = 0
         # tokens at the FRONT of the table already filled by shared
@@ -287,7 +344,7 @@ class KVCachePool:
     """
 
     def __init__(self, block_size=None, num_blocks=None,
-                 max_blocks_per_seq=None, kv_dtype=None):
+                 max_blocks_per_seq=None, kv_dtype=None, state_rows=0):
         self.block_size = int(block_size or DEFAULT_BLOCK_SIZE)
         self.num_blocks = int(num_blocks or DEFAULT_MAX_BLOCKS)
         self.max_blocks_per_seq = int(max_blocks_per_seq or 8)
@@ -299,9 +356,13 @@ class KVCachePool:
         self.kv_dtype = kv_dtype
         self.dtype = KV_PAYLOAD_DTYPES[kv_dtype]
         self.allocator = BlockAllocator(self.num_blocks)
+        # rows of the state layers' arrays (0: the model has none), row 0
+        # scratch, and who holds which
+        self.state_rows = StateRows(state_rows)
         # layer idx -> [k_pages, v_pages], each (NB, BS, lanes of H·D), or
         # for a latent (MLA) layer -> [rows] of (NB, BS, lanes of W): one
-        # row a token
+        # row a token; for a state layer -> [states] of (state rows, G, P,
+        # d) float32: one row a request
         self._layers = {}
         self._scales = {}          # int8 only: layer -> [k_scales, v_scales]
         # layer idx -> (H, D), how a K/V row splits into heads: what the
@@ -322,7 +383,7 @@ class KVCachePool:
         the constructor's arguments: hashable, so it can key a compiled
         program, and engines of equal geometry share executables."""
         return (self.block_size, self.num_blocks, self.max_blocks_per_seq,
-                self.kv_dtype)
+                self.kv_dtype, self.state_rows.num_rows)
 
     @classmethod
     def over(cls, geometry, layers, scales):
@@ -361,29 +422,54 @@ class KVCachePool:
                 store[layer] = [jnp.zeros(a.shape, a.dtype) for a in arrs]
         self.heads.update(heads)
 
+    @staticmethod
+    def _is_state(arrs):
+        return len(arrs) == 1 and arrs[0].ndim == 4
+
+    @property
+    def num_row_layers(self):
+        """Layers that cache a row per token (K/V or latent)."""
+        return sum(not self._is_state(a) for a in self._layers.values())
+
+    @property
+    def num_state_layers(self):
+        """Layers that cache one recurrent state per request."""
+        return sum(self._is_state(a) for a in self._layers.values())
+
     def row_bytes(self):
-        """Resident bytes of one token's cached state in one layer (a
+        """Resident bytes of one token's cached rows in one layer (a
         layer's arrays over the positions they hold): the
-        kv_cache_row_bytes gauge."""
-        if not self._layers:
+        kv_cache_row_bytes gauge. 0 where no layer caches rows."""
+        if not self.num_row_layers:
             return 0
         return self.bytes_in_hbm() // (
-            len(self._layers) * self.num_blocks * self.block_size)
+            self.num_row_layers * self.num_blocks * self.block_size)
 
     def new_table(self, total_tokens):
-        """Allocate a table holding ``total_tokens`` (prompt + budget).
-        Raises OutOfBlocks when the pool cannot cover it right now."""
+        """Allocate a table holding ``total_tokens`` (prompt + budget), and
+        with state layers its state row. Raises OutOfBlocks (OutOfStateRows
+        is one) when the pool cannot cover it right now: nothing is kept."""
         nb = -(-int(total_tokens) // self.block_size)
         if nb > self.max_blocks_per_seq:
             raise InvalidRequest(
                 f'{total_tokens} tokens need {nb} blocks > '
                 f'max_blocks_per_seq={self.max_blocks_per_seq}')
-        return BlockTable(self.allocator.allocate(nb), self.block_size)
+        row = self.state_rows.take() if self.state_rows.num_rows else 0
+        try:
+            blocks = self.allocator.allocate(nb)
+        except OutOfBlocks:
+            if row:
+                self.state_rows.give_back(row)
+            raise
+        return BlockTable(blocks, self.block_size, state_row=row)
 
     def free_table(self, table):
         if table.blocks:
             self.allocator.free(table.blocks)
             table.blocks = []
+        if table.state_row:
+            self.state_rows.give_back(table.state_row)
+            table.state_row = 0
 
     def ensure_layer(self, layer, n_heads, head_dim):
         """The layer's arrays, made on first use: the one place that decides
@@ -437,14 +523,43 @@ class KVCachePool:
         return rowwise_quantize(vals)
 
     def bytes_in_hbm(self):
-        """Resident pool bytes across all allocated layers: payload arrays
+        """Resident bytes of the layers that cache rows: payload arrays
         plus (int8) their scale arrays — the kv_cache_bytes_in_hbm gauge."""
         total = 0
         for arrs in self._layers.values():
-            total += sum(int(a.nbytes) for a in arrs)
+            if not self._is_state(arrs):
+                total += sum(int(a.nbytes) for a in arrs)
         for arrs in self._scales.values():
             total += sum(int(a.nbytes) for a in arrs)
         return total
+
+    def state_bytes_in_hbm(self):
+        """Resident bytes of the state layers, every row of every layer —
+        the state_cache_bytes_in_hbm gauge."""
+        return sum(int(arrs[0].nbytes) for arrs in self._layers.values()
+                   if self._is_state(arrs))
+
+    # -- state layers: one row a request, whole ----------------------------
+    def ensure_state(self, layer, block_shape):
+        """The state layer's one array, (state rows, *block_shape) float32
+        zeros, made on first use; ``block_shape`` (G, P, d) is what the
+        layer's op returned for one request."""
+        if layer not in self._layers:
+            import jax.numpy as jnp
+            if not self.state_rows.num_rows:
+                raise ValueError(
+                    'a state layer over a pool built with state_rows=0: '
+                    'the model must say kv_cache_spec() kind "state"')
+            self._layers[layer] = [jnp.zeros(
+                (self.state_rows.num_rows,) + tuple(block_shape),
+                'float32')]
+        return self._layers[layer]
+
+    def write_state(self, layer, row, block):
+        """A prefill's final state (G, P, d) over the whole of ``row``: a
+        row reused after a free carries nothing over."""
+        states = self.ensure_state(layer, block.shape)
+        states[0] = _put_row(states[0], row, block.astype('float32'))
 
     def write_prefill(self, layer, block_ids, k, v):
         """Write the prompt's K/V rows. ``k``/``v``: (H, L, D) — the bucket-
@@ -616,11 +731,15 @@ def prefill_coords(pool, table, bucket):
     bs = pool.block_size
     nb = -(-int(bucket) // bs)
     nb_w = min(-(-table.context_len // bs), len(table.blocks), nb)
-    return {'block_tables': np.asarray(
-                [table.padded(pool.max_blocks_per_seq)], np.int32),
-            'write_ids': np.asarray(
-                table.blocks[:nb_w] + [SCRATCH_BLOCK] * (nb - nb_w),
-                np.int32)}
+    coords = {'block_tables': np.asarray(
+                  [table.padded(pool.max_blocks_per_seq)], np.int32),
+              'write_ids': np.asarray(
+                  table.blocks[:nb_w] + [SCRATCH_BLOCK] * (nb - nb_w),
+                  np.int32)}
+    if pool.state_rows.num_rows:
+        # (1,): the request's row of the state layers
+        coords['state_rows'] = np.asarray([table.state_row], np.int32)
+    return coords
 
 
 def decode_coords(pool, tables, context_lens, fed_counts=None, window=1):
@@ -656,11 +775,17 @@ def decode_coords(pool, tables, context_lens, fed_counts=None, window=1):
             ids.append(b)
             offs.append(o)
         padded.append(t.padded(pool.max_blocks_per_seq))
-    return {'block_tables': np.asarray(padded, np.int32),
-            'write_ids': np.asarray(ids, np.int32),
-            'write_offs': np.asarray(offs, np.int32),
-            'context_lens': np.asarray(
-                [max(int(c), 1) for c in context_lens], np.int32)}
+    coords = {'block_tables': np.asarray(padded, np.int32),
+              'write_ids': np.asarray(ids, np.int32),
+              'write_offs': np.asarray(offs, np.int32),
+              'context_lens': np.asarray(
+                  [max(int(c), 1) for c in context_lens], np.int32)}
+    if pool.state_rows.num_rows:
+        # (S,): each slot's row of the state layers, an idle slot's the
+        # scratch row
+        coords['state_rows'] = np.asarray(
+            [0 if t is None else t.state_row for t in tables], np.int32)
+    return coords
 
 
 class CacheContext:
@@ -760,6 +885,41 @@ class CacheContext:
                 'block_tables': c['block_tables'],
                 'context_lens': c['context_lens'],
                 'w_kvb': inputs['w_kvb']}, attrs)
+
+    def attend_retention(self, inputs, attrs):
+        """A power-retention layer through its recurrent state. ``inputs``:
+        q (B, L, H, d), k and v (B, L, G, d), ``log_gate`` (B, L, G);
+        ``attrs`` those of `power_retention_prefill` (ops/llm_ops.py).
+        Prefill scans the bucket (rows past ``last`` never enter the state)
+        and writes the final state over the request's whole row; a decode
+        step advances every slot's row in place and reads it, idle slots on
+        the scratch row. The scopes name the two ops' device ops in a
+        profiler trace."""
+        from ...dygraph.tape import dispatch_op
+        layer = self._layer
+        self._layer += 1
+        rows = self.coords['state_rows']
+        if self.mode == 'prefill':
+            with jax.named_scope('retention/prefill_scan'):
+                out, state = dispatch_op(
+                    'power_retention_prefill',
+                    dict(inputs, last=self.last), attrs)
+            self.pool.write_state(layer, rows[0], state.value[0])
+            return out
+        if inputs['q'].shape[1] != 1:
+            raise UnsupportedCacheFeature(
+                ['a decode window of more than one token (speculation, '
+                 'chunked suffix fill)'], 'state')
+        from ...ops.llm_ops import retention_state_rows
+        _, _, groups, d = inputs['k'].shape
+        states = self.pool.ensure_state(
+            layer, (groups, retention_state_rows(d)[2], d))
+        with jax.named_scope('retention/decode_update'):
+            out, state = dispatch_op(
+                'power_retention_step',
+                dict(inputs, state=states[0], rows=rows), {})
+        states[0] = state.value
+        return out
 
     def attend(self, q, k, v, sm_scale=1.0):
         from ...dygraph.tape import Tensor, dispatch_op

@@ -11,7 +11,8 @@ from __future__ import annotations
 
 __all__ = ['ServingError', 'InvalidRequest', 'Overloaded', 'DeadlineExceeded',
            'EngineClosed', 'EngineUnhealthy', 'OutOfBlocks',
-           'NoReplicaAvailable', 'UnsupportedCacheFeature']
+           'OutOfStateRows', 'NoReplicaAvailable',
+           'UnsupportedCacheFeature']
 
 
 class ServingError(RuntimeError):
@@ -94,18 +95,44 @@ class OutOfBlocks(ServingError):
         self.available = available
 
 
+class OutOfStateRows(OutOfBlocks):
+    """Every row of the recurrent-state layers is held by a live request
+    (docs/SERVING.md "Recurrent state"). The same WAIT signal to the decode
+    scheduler as :class:`OutOfBlocks`, which it is: the request stays queued
+    until a finishing slot returns its row."""
+
+    def __init__(self, capacity):
+        ServingError.__init__(
+            self, f'state cache exhausted: all {capacity} state rows are '
+            f'held by live requests (one row a slot; lower concurrency)')
+        self.requested = 1
+        self.available = 0
+
+
 class UnsupportedCacheFeature(ServingError, ValueError):
     """A cache feature was asked of a model whose cached state it cannot
     hold. Raised when the engine (or the prefill role beside it) is built,
-    never under traffic: the prefix cache with its host spill and reinject,
+    never under traffic. The prefix cache with its host spill and reinject,
     the disaggregated handoff and int8 rows all move ``[k, v]`` pairs of
-    per-head rows, and a latent (MLA) layer caches ONE array of rows with
-    no head axis."""
+    per-head rows: a latent (MLA) layer caches ONE array of rows with no
+    head axis (``kind`` 'latent'), and a retention layer no row per token at
+    all but one recurrent state per request (``kind`` 'state'), which has no
+    prefix to share, no blocks to hand off, no rollback for a speculative
+    window and no storage type but float32."""
+
+    _WHY = {
+        'latent': ('they read and write [k, v] pairs of per-head rows',
+                   'Latent pool'),
+        'state': ('the state cache holds one float32 recurrent state per '
+                  'request, advanced in place: no row per token to share, '
+                  'hand off, quantize or roll back', 'Recurrent state')}
 
     def __init__(self, features, kind):
         features = list(features)
+        why, section = self._WHY.get(kind, self._WHY['latent'])
+        what = 'the state cache' if kind == 'state' else f'a {kind} KV cache'
         super().__init__(
-            f'{", ".join(features)} cannot be used with a {kind} KV cache: '
-            f'they read and write [k, v] pairs of per-head rows '
-            f'(docs/SERVING.md "Latent pool")')
+            f'{", ".join(features)} cannot be used with {what}: {why} '
+            f'(docs/SERVING.md "{section}")')
         self.features = features
+        self.kind = kind

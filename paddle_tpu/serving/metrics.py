@@ -237,6 +237,14 @@ decode_kv_blocks_read = _LazyMetric(
     'summed over layers and steps: the live blocks the lockstep step '
     'walked, its last chunk\'s padding included; S x max_blocks a layer '
     'for a read that gathers every slot\'s whole table')
+decode_state_updates = _LazyMetric(
+    'counter', 'decode_state_updates',
+    'recurrent states a decode step advanced: live slots x state layers, '
+    'summed over steps (idle slots advance the scratch row, not counted)')
+decode_state_tokens_folded = _LazyMetric(
+    'counter', 'decode_state_tokens_folded',
+    'prompt tokens a prefill folded into a recurrent state: prompt length '
+    'x state layers, summed over prefills (a rung\'s padding not counted)')
 decode_scheduler_phase_seconds = _LazyMetric(
     'histogram', 'decode_scheduler_phase_seconds',
     'wall seconds of the scheduler worker thread per loop iteration (label '
@@ -355,6 +363,17 @@ kv_cache_row_bytes = _LazyMetric(
     'gauge', 'kv_cache_row_bytes',
     'resident bytes of one token\'s cached state in one layer (K and V '
     'rows of every head, or one latent row)')
+state_cache_bytes_in_hbm = _LazyMetric(
+    'gauge', 'state_cache_bytes_in_hbm',
+    'resident bytes of the recurrent-state layers: every row (one a slot '
+    'and the scratch row) of every state layer, float32')
+state_cache_rows_total = _LazyMetric(
+    'gauge', 'state_cache_rows_total',
+    'state rows a request can hold (one a slot; the scratch row not '
+    'counted)')
+state_cache_rows_used = _LazyMetric(
+    'gauge', 'state_cache_rows_used',
+    'state rows held by live requests')
 kv_cache_bytes_spilled = _LazyMetric(
     'counter', 'kv_cache_bytes_spilled',
     'serialized KV payload bytes moved from HBM to the host spill tier')

@@ -316,3 +316,55 @@ def test_compiled_for_the_chip_no_program_moves_the_pool(v5e, kv_dtype):
                 assert '[256,16,768]' in text
                 assert _arrays_spanning(
                     text, eng.slots * eng.padded_context) == []
+
+
+def test_compiled_for_the_chip_no_program_moves_a_state_array(v5e):
+    """A retention layer's state at the published head size (8 key/value
+    heads of 128: a row is 8 blocks of (8328, 128) float32, 128 values on
+    the lanes) lies row-major in the step and in a prefill rung compiled
+    for the chip, is updated where it lies, and no program holds a copy of
+    the array or of all S rows (compiled here: no chip, no time). Shapes
+    alone: the parameters and the states are described, not made."""
+    from paddle_tpu.core.random import default_generator
+    from paddle_tpu.models.retention_lm import RetentionLM, RetentionLMConfig
+    from paddle_tpu.serving.decode.kv_cache import (BlockTable,
+                                                    prefill_coords)
+    made = {}
+
+    def init(key):
+        with default_generator.bind_base(key):
+            made['model'] = RetentionLM(RetentionLMConfig(
+                vocab_size=512, hidden_size=256, intermediate_size=512,
+                num_hidden_layers=2, num_attention_heads=40,
+                num_key_value_heads=8, head_dim=128, rope_theta=1e6,
+                gate_shift=6.0, dtype='bfloat16'))
+        return {n: p.value for n, p in made['model'].named_parameters()}
+
+    with dygraph.guard():
+        default_generator.seed(3)
+        shapes = jax.eval_shape(init, default_generator.base_key())
+        model = made['model']
+        model.eval()
+        for name, p in model.named_parameters():
+            p.value = shapes[name]
+        eng = DecodeEngine(model, slots=6, block_size=16, max_blocks=64,
+                           max_prompt_len=256, max_new_tokens_cap=64,
+                           prompt_buckets=[256], prefix_cache=False)
+        pool, prog = eng.pool, eng._program
+        out = jax.eval_shape(
+            lambda pv, *rest: prog.jitted('prefill', pool.geometry, pv, {},
+                                          {}, {}, *rest),
+            {n: p.value for n, p in prog._params.items()},
+            np.zeros((1, 256), np.int64), None,
+            prefill_coords(pool, BlockTable([], 16), 256), np.int32(0))
+        pool.adopt({k: list(v) for k, v in out[2].items()}, {})
+        state = pool.arrays()[0][0][0]
+        assert state.shape == (7, 8, 8328, 128)
+        assert pool.num_state_layers == 2 and pool.num_row_layers == 0
+        for bucket in (None, 256):
+            text = eng.lowered(bucket, v5e).compile().as_text()
+            assert 'f32[7,8,8328,128]{3,2,1,0' in text
+            assert _moves_of_size(text, {int(np.prod(state.shape))}) == []
+            # no gathered copy of the S slots' rows either
+            assert 'f32[6,8,8328,128]' not in text
+            assert 'f32[6,8,8256,128]' not in text

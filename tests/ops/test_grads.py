@@ -323,6 +323,17 @@ GRAD_SPECS = {
                    f32(r.standard_normal((8, 2 * 7)) * 0.5)],
         diff=(0, 1, 2),
         attrs={'qk_nope_dim': 4, 'v_dim': 3, 'sm_scale': 0.4}),
+    'retention_gate': S(lambda r: [f32(r.standard_normal((1, 3, 5))),
+                                   f32(r.standard_normal((5, 2)))],
+                        diff=(0, 1), attrs={'shift': 1.5}),
+    # autodiff through the chunked scan (two chunks and a padded third);
+    # no backward kernel of its own (ROADMAP R7)
+    'power_retention_prefill': S(
+        lambda r: [f32(r.standard_normal((1, 5, 2, 4))),
+                   f32(r.standard_normal((1, 5, 1, 4))),
+                   f32(r.standard_normal((1, 5, 1, 4))),
+                   -pos(r, (1, 5, 1), 0.05, 0.5)],
+        diff=(0, 1, 2, 3), attrs={'chunk': 2}),
     'instance_norm': S(lambda r: [f32(r.standard_normal((2, 3, 4, 4))),
                                   pos(r, (3,)),
                                   f32(r.standard_normal((3,)))],
@@ -676,6 +687,11 @@ NONDIFF = {
         '(serving/decode/); training gradients flow through '
         'mla_prefill_attention, parity tested in tests/framework/'
         'test_latent_moe_lm.py',
+    'power_retention_step':
+        'inference-only update-and-read of the recurrent state cache '
+        '(serving/decode/); training gradients flow through '
+        'power_retention_prefill, parity tested in tests/ops/'
+        'test_power_retention.py',
     'paged_prefill_attention':
         'inference-only prefill-phase cache read (serving/decode/); '
         'parity tested in tests/ops/test_paged_attention.py',
