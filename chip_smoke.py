@@ -244,8 +244,12 @@ def _post(port, body, timeout=300):
 def _logit_rows(engine, model, prompt, pad_len):
     """(cached prefill row, cached decode row, uncached rows) for one prompt:
     the prefill's last row and one decode step through the engine's own
-    phases, and the same two rows of the uncached whole-sequence forward."""
+    phases, and the same two rows of the uncached whole-sequence forward.
+    Also holds the step's pick to the device's: the ids are numpy's argmax
+    of the rows of the same call, in every slot, and a greedy step copies
+    its S ids to the host and nothing else."""
     from paddle_tpu.dygraph.tape import Tensor, no_grad_guard
+    from paddle_tpu.serving.metrics import decode_logits_bytes_copied
     P = len(prompt)
     grabbed = []
 
@@ -257,7 +261,12 @@ def _logit_rows(engine, model, prompt, pad_len):
     tok = engine.prefill(prompt, table, sampler=grab)
     tokens = [tok] + [None] * (engine.slots - 1)
     tables = [table] + [None] * (engine.slots - 1)
-    _, rows = engine.decode_step(tokens, tables, return_rows=True)
+    ids, rows = engine.decode_step(tokens, tables, return_rows=True)
+    assert np.array_equal(ids, rows.argmax(-1)), (ids, rows.argmax(-1))
+    copied = decode_logits_bytes_copied.value
+    engine.decode_step([int(ids[0])] + tokens[1:], tables)
+    copied = decode_logits_bytes_copied.value - copied
+    assert copied == engine.slots * 4, (copied, engine.slots)
     engine.release_table(table)
     buf = np.zeros((1, pad_len), np.int64)
     buf[0, :P] = prompt

@@ -6,19 +6,21 @@ or latent rows per token; for a retention layer one recurrent state per
 request, kv_cache.py "Recurrent state"; all DONATED, so the paged writes and
 the state updates happen in place), everything about
 the request is a small traced array (token ids, positions, write
-coordinates, block tables, context lengths), and it returns the logits rows
-the host samples from plus the new pool arrays. The host's part of a call is
-packing those arrays, one dispatch, one wait, one copy of the rows, and the
-argmax or the request's sampler.
+coordinates, block tables, context lengths), and it returns the logits rows,
+their greedy pick (the rows' argmax, taken on the device) and the new pool
+arrays. The host's part of a call is packing those arrays, one dispatch, one
+wait and one copy of the ids: 4 bytes a slot. The rows cross only where the
+call asks for them (a request with a sampler, the speculative accept loop, a
+check that reads logits), and the host then runs the request's sampler.
 
 The compile-count story (the whole point — models/transformer.py's original
 decode re-compiled per generated length):
 
 - **prefill** runs the prompt once at a bucket-ladder shape (reusing
   serving.engine.bucket_ladder — powers of two up to ``max_prompt_len``),
-  writing its K/V into cache blocks, and hands the host the row of the
-  prompt's last token, sliced on the device: one program per rung,
-  ``len(prompt_buckets)`` compiles, ever.
+  writing its K/V into cache blocks, and hands the host the pick of the
+  prompt's last row, sliced on the device (and the row, to a sampler): one
+  program per rung, ``len(prompt_buckets)`` compiles, ever.
 - **decode** steps all S slots in lockstep at ONE fixed shape
   ((S, 1) tokens + (S, max_blocks_per_seq) tables + (S,) context lengths):
   exactly one program, regardless of how long any sequence runs (one more,
@@ -113,7 +115,7 @@ class _Program:
     """``model`` as the pure function every engine call runs, jitted:
 
         run(mode, geometry, params, buffers, layers, scales,
-            ids, pos, coords, last) -> (rows, stats, layers, scales)
+            ids, pos, coords, last) -> (rows, picks, stats, layers, scales)
 
     ``mode`` ('prefill' | 'decode') and the pool's ``geometry`` are static;
     ``layers`` / ``scales`` (the pool's arrays, a state layer's among
@@ -121,9 +123,15 @@ class _Program:
     no weight is baked into an executable and a swapped weight is served.
     The trace binds them the way dygraph/jit.py::functionalize does, and
     the pool it writes is a `KVCachePool.over` the traced arrays: nothing
-    traced outlives the trace. ``rows`` is what the host needs and no more:
-    prefill (1, L) -> row ``last`` (V,); decode (S, 1) -> (S, V); decode
-    (S, K) -> (S, K, V). ``stats`` is what the forward noted on its
+    traced outlives the trace. ``picks`` is what the host needs and no more:
+    the int32 argmax of ``rows`` over the vocabulary, of the very numbers
+    ``rows`` returns (the first of equal maxima; a row with a NaN gives its
+    first NaN's index: numpy's argmax on the same rows). ``rows``, with
+    ``picks`` in brackets: prefill (1, L) -> row ``last`` (V,) [()]; decode
+    (S, 1) -> (S, V) [(S,)]; decode (S, K) -> (S, K, V) [(S, K)]. The rows
+    stay an output of the one program, the head's matmul result, for the
+    calls that ask for them: an output nobody reads is never copied.
+    ``stats`` is what the forward noted on its
     `CacheContext` for the host, stacked per name and otherwise untouched
     (a model with routed experts: the rows each expert was given and the
     experts of the scored rows; else empty).
@@ -177,7 +185,8 @@ class _Program:
             stats = {name: jax.numpy.stack(values)
                      for name, values in ctx.stats.items()}
             heads.update(pool.heads)
-            return (rows, stats) + pool.arrays()
+            picks = jax.numpy.argmax(rows, -1).astype(jax.numpy.int32)
+            return (rows, picks, stats) + pool.arrays()
 
         self.jitted = jax.jit(run, static_argnums=(0, 1),
                               donate_argnums=(4, 5))
@@ -189,22 +198,22 @@ class _Program:
                 {n: b.value for n, b in self._buffers.items()})
 
     def __call__(self, pool, mode, ids, pos, coords, last=None):
-        """Run one engine call's program over ``pool`` and return its rows
-        and the forward's ``stats`` (for a model with routed experts
-        ``expert_counts`` (layers, E) and ``expert_ids`` (layers, scored
-        rows, k), else empty), all device arrays: the call is enqueued, not
-        finished."""
+        """Run one engine call's program over ``pool`` and return its rows,
+        their picks and the forward's ``stats`` (for a model with routed
+        experts ``expert_counts`` (layers, E) and ``expert_ids`` (layers,
+        scored rows, k), else empty), all device arrays: the call is
+        enqueued, not finished."""
         fn = functools.partial(self.jitted, *self._head(pool, mode))
         if not pool.num_layers:
             # the pool allocates here, before the first trace that takes its
             # arrays as arguments: an abstract trace over an empty pool
             # returns the arrays `ensure_layer` would make
             pool.allocate(*jax.eval_shape(fn, {}, {}, ids, pos, coords,
-                                          last)[2:], self._heads)
-        rows, stats, layers, scales = fn(*pool.arrays(), ids, pos, coords,
-                                         last)
+                                          last)[3:], self._heads)
+        rows, picks, stats, layers, scales = fn(*pool.arrays(), ids, pos,
+                                                coords, last)
         pool.adopt(layers, scales)
-        return rows, stats
+        return rows, picks, stats
 
     def lower(self, pool, mode, ids, pos, coords, last=None, sharding=None):
         """The program `__call__` would run over ``pool`` (allocated),
@@ -220,21 +229,6 @@ class _Program:
         return self.jitted.lower(*head[:2], *args)
 
 
-def _first_max(rows):
-    """``rows.argmax(-1)``, NaN rows included (the first NaN's index).
-    Written as a max and a compare because on the rows ``np.asarray`` hands
-    back from the device (read-only, 16-byte aligned) numpy's float argmax
-    takes a path ten times slower: 80 ms against 7.5 for (128, 128,256)
-    float32 on the v5e's host, 3 ms on a copy that numpy allocated (PERF.md
-    section 6, PR 26)."""
-    top = rows.max(-1, keepdims=True)
-    out = (rows == top).argmax(-1)
-    nan = np.isnan(top[..., 0])
-    if nan.any():       # a NaN equals nothing, its row's maximum included
-        out[nan] = rows[nan].argmax(-1)
-    return out
-
-
 class _CallClock:
     """perf_counter stamps at the phase boundaries of one engine call
     (``call``: prefill | step | spec_step). Each phase runs from the stamp
@@ -242,19 +236,21 @@ class _CallClock:
     arrays the program reads), forward (the dispatch of the call's ONE
     program: arguments handed over, the program enqueued; its trace and
     compile too, the first time a shape is seen), device_wait
-    (``block_until_ready`` on the rows: host idle, the device running the
-    program — the device's time for the call), logits_copy (the rows,
-    device to host, and the few kB of counts the counters read), sample
-    (host argmax or the request's sampler).
+    (``block_until_ready`` on the picks: host idle, the device running the
+    program — the device's time for the call), logits_copy (what crosses,
+    device to host: the picks, the few kB of counts the counters read, and
+    the rows where the call asked for them), sample (what the host still
+    does for the pick: an ``int()``, or the request's sampler on its row).
 
     ``record`` is the one place the stamps are read: always one
     observation per phase into ``decode_engine_phase_seconds``, and with
     telemetry on the ``engine/<call>`` span and its ``engine/<call>/<phase>``
     children from the same stamps. The span's args carry the call's ``work``
     (expert assignments, experts touched, context positions and cache blocks
-    read: what the counters were given for this call), so that a trace
-    reader can set the device time of a slice against the work of the calls
-    in it. O(1) per call."""
+    read: what the counters were given for this call; ``rows_fetched`` 1
+    where the rows crossed to the host, 0 where the picks alone did), so
+    that a trace reader can set the device time of a slice against the work
+    of the calls in it. O(1) per call."""
 
     __slots__ = ('call', 'start', 'last', 'ends', 'work')
 
@@ -269,18 +265,22 @@ class _CallClock:
         self.ends.append((phase, self.last))
         return self.last
 
-    def fetch(self, rows, counts=None):
-        """The rows on the host, with the wait for the device and the copy
-        stamped apart (``np.asarray`` alone is both at once); ``counts``,
-        a small array of the same program, is copied in the same phase."""
-        rows.block_until_ready()
+    def fetch(self, picks, counts=None, rows=None):
+        """The picks on the host, with the wait for the device and the copy
+        stamped apart (``device_get`` alone is both at once); ``counts`` (a
+        small array of the same program) and ``rows`` (given only by a call
+        that needs them on the host) are copied in the same phase.
+        ``decode_logits_bytes_copied`` takes what crossed for the pick:
+        4 bytes a pick, and the rows' bytes where they were asked for."""
+        picks.block_until_ready()
         self.end('device_wait')
-        host = np.asarray(rows)
-        if counts is not None:
-            counts = np.asarray(counts)
+        # one round of transfers, started together (None stays None)
+        picks, counts, rows = jax.device_get((picks, counts, rows))
         self.end('logits_copy')
-        _m.decode_logits_bytes_copied.inc(host.nbytes)
-        return host, counts
+        _m.decode_logits_bytes_copied.inc(
+            picks.nbytes + (0 if rows is None else rows.nbytes))
+        self.work['rows_fetched'] = int(rows is not None)
+        return picks, counts, rows
 
     def record(self, **args):
         hist = _m.decode_engine_phase_seconds
@@ -521,21 +521,25 @@ class DecodeEngine:
             _m.state_cache_rows_used.set(self.pool.state_rows.used)
 
     # -- phases ------------------------------------------------------------
-    def _run(self, clock, mode, ids, pos, coords, last=None):
-        """The call's one program, from dispatch to its rows on the host,
-        stamping forward, device_wait and logits_copy on ``clock``. The
-        model lock is held throughout: a first call of a shape traces the
-        model with its parameters bound to tracers, which a second engine
-        over the same model (serving/tier/disagg.py) must not see."""
+    def _run(self, clock, mode, ids, pos, coords, last=None,
+             fetch_rows=False):
+        """The call's one program, from dispatch to its picks on the host
+        (and, with ``fetch_rows``, the rows they are the argmax of; else
+        None), stamping forward, device_wait and logits_copy on ``clock``.
+        The model lock is held throughout: a first call of a shape traces
+        the model with its parameters bound to tracers, which a second
+        engine over the same model (serving/tier/disagg.py) must not see."""
         with self._model_lock or _NULL_LOCK:
-            rows, stats = self._program(self.pool, mode, ids, pos, coords,
-                                        last)
+            rows, picks, stats = self._program(self.pool, mode, ids, pos,
+                                               coords, last)
             clock.end('forward')
-            host, counts = clock.fetch(rows, stats.get('expert_counts'))
+            picks, counts, rows = clock.fetch(
+                picks, stats.get('expert_counts'),
+                rows if fetch_rows else None)
         self.last_stats = stats
         if counts is not None:
             clock.work.update(self._account_experts(clock.call, counts))
-        return host
+        return picks, rows
 
     @staticmethod
     def _account_experts(call, counts):
@@ -606,9 +610,10 @@ class DecodeEngine:
 
     def prefill(self, prompt, table, sampler=None):
         """Run the bucket-padded prompt once, writing K/V into ``table``'s
-        blocks, and return the FIRST generated token — greedy, or drawn by
-        ``sampler(logits_row)`` for sampled requests. Sets
-        ``table.context_len = len(prompt)``."""
+        blocks, and return the FIRST generated token — greedy (the row's
+        argmax, taken on the device: 4 bytes reach the host), or drawn by
+        ``sampler(logits_row)`` for sampled requests (the row is copied to
+        the host for it). Sets ``table.context_len = len(prompt)``."""
         clock = _CallClock('prefill')
         P = len(prompt)
         bucket = next(b for b in self.prompt_buckets if P <= b)
@@ -617,10 +622,11 @@ class DecodeEngine:
         table.context_len = P
         coords = prefill_coords(self.pool, table, bucket)
         t0 = clock.end('pack')
-        row = self._run(clock, 'prefill', ids, None, coords,
-                        np.int32(P - 1))
+        pick, row = self._run(clock, 'prefill', ids, None, coords,
+                              np.int32(P - 1),
+                              fetch_rows=sampler is not None)
         _m.decode_prefill_seconds.observe(clock.last - t0)
-        token = int(row.argmax() if sampler is None else sampler(row))
+        token = int(pick if sampler is None else sampler(row))
         clock.end('sample')
         folded = P * self.pool.num_state_layers
         if folded:
@@ -644,12 +650,13 @@ class DecodeEngine:
         inactive). For an active slot with context c, the fed token is the
         one at position c (it was sampled from the previous step/prefill
         but not yet cached); its K/V are written and attended this step.
-        Returns (S,) next-token ids (greedy; garbage on inactive slots) and
-        advances each active table's context_len by 1. With
-        ``return_rows=True`` the raw (S, V) logits rows come back too
-        (``(ids, rows)``) so the scheduler can sample non-greedy slots —
-        the greedy ids are the argmax of those same rows, so requesting
-        rows changes no bits."""
+        Returns (S,) next-token ids (greedy: the rows' argmax, taken on the
+        device, so S × 4 bytes reach the host; garbage on inactive slots)
+        and advances each active table's context_len by 1. With
+        ``return_rows=True`` the raw (S, V) logits rows are copied to the
+        host too (``(ids, rows)``) so the scheduler can sample non-greedy
+        slots — the same executable runs and the ids are the argmax of
+        those same rows, so requesting rows changes no bits."""
         clock = _CallClock('step')
         S = self.slots
         assert len(tokens) == S and len(tables) == S
@@ -668,8 +675,8 @@ class DecodeEngine:
         coords = decode_coords(self.pool, tables, ctx_lens)
         blocks = self._blocks_walked(ctx_lens)
         t0 = clock.end('pack')
-        rows = self._run(clock, 'decode', ids, pos, coords)
-        out = _first_max(rows)
+        out, rows = self._run(clock, 'decode', ids, pos, coords,
+                              fetch_rows=return_rows)
         dt = clock.end('sample') - t0
         self._account_step(clock, dt, tables, blocks)
         self._step_compiled = True
@@ -763,7 +770,9 @@ class DecodeEngine:
         coords = decode_coords(self.pool, tables, ctx_lens,
                                fed_counts=fed_counts, window=K)
         t0 = clock.end('pack')
-        rows = self._run(clock, 'decode', ids, pos, coords)
+        # the accept loop reads the rows on the host (scheduler._spec_step)
+        _, rows = self._run(clock, 'decode', ids, pos, coords,
+                            fetch_rows=True)
         dt = clock.last - t0
         self._spec_compiled = True
         # it IS the decode step; its (S, K) read gathers every table whole

@@ -524,9 +524,11 @@ class DecodeScheduler:
             _m.decode_requests_failed.inc(failed)
 
     def _pick_token(self, req, row):
-        """Next token from a logits row: the request's deterministic
-        sampler (indexed by tokens generated so far — the replay contract)
-        or exact greedy argmax."""
+        """Next token from a logits row the host was handed: the request's
+        deterministic sampler (indexed by tokens generated so far — the
+        replay contract) or exact greedy argmax (the speculative accept
+        loop's rows alone: a prefill's and a lockstep step's greedy picks
+        are the engine program's own)."""
         if req.sampler is not None:
             tok = req.sampler.sample(row, req.generated)
             _m.decode_tokens_sampled.inc()

@@ -213,7 +213,8 @@ decode_engine_phase_seconds = _LazyMetric(
     'XLA program, device_wait the device running it')
 decode_logits_bytes_copied = _LazyMetric(
     'counter', 'decode_logits_bytes_copied',
-    'bytes of logits copied from the device to the host by engine calls')
+    'bytes engine calls copied from the device to the host for the pick: '
+    'the int32 ids, and the logits rows where a call asked for them')
 decode_expert_assignments = _LazyMetric(
     'counter', 'decode_expert_assignments',
     'token-to-expert assignments of the calls\' live tokens, over every '

@@ -357,7 +357,7 @@ def test_compiled_for_the_chip_no_program_moves_a_state_array(v5e):
             {n: p.value for n, p in prog._params.items()},
             np.zeros((1, 256), np.int64), None,
             prefill_coords(pool, BlockTable([], 16), 256), np.int32(0))
-        pool.adopt({k: list(v) for k, v in out[2].items()}, {})
+        pool.adopt({k: list(v) for k, v in out[3].items()}, {})
         state = pool.arrays()[0][0][0]
         assert state.shape == (7, 8, 8328, 128)
         assert pool.num_state_layers == 2 and pool.num_row_layers == 0

@@ -207,31 +207,6 @@ def test_the_counts_are_copied_before_the_sample_phase(lm, monkeypatch):
             'pack', 'forward', 'device_wait', 'logits_copy', 'sample']
 
 
-@pytest.mark.parametrize('case', ['plain', 'ties', 'nan', 'all_nan',
-                                  'infinite', 'from_device'])
-def test_the_steps_pick_is_numpys_argmax(case):
-    """`_first_max` is `argmax(-1)` on every row: the first of equal maxima,
-    and a NaN's index where a row holds one."""
-    from paddle_tpu.serving.decode.engine import _first_max
-    rng = np.random.RandomState(3)
-    rows = rng.randn(6, 50).astype(np.float32)
-    if case == 'ties':
-        rows[:, 7] = rows[:, 31] = rows.max() + 1
-        rows[2] = 0.0
-    elif case == 'nan':
-        rows[1, 9] = rows[1, 20] = rows[4, 0] = np.nan
-    elif case == 'all_nan':
-        rows[:] = np.nan
-    elif case == 'infinite':
-        rows[0] = -np.inf
-        rows[3, 5] = rows[3, 6] = np.inf
-    elif case == 'from_device':      # read-only, as the engine's rows are
-        import jax.numpy as jnp
-        rows = np.asarray(jnp.asarray(rows))
-        assert not rows.flags.writeable
-    np.testing.assert_array_equal(_first_max(rows), rows.argmax(-1))
-
-
 def test_absorbed_decode_is_expanded_attention_on_the_same_weights():
     """`mla_decode_attention` over a paged pool holding a sequence's latent
     rows gives, for the last K positions, the rows `mla_prefill_attention`
