@@ -527,6 +527,12 @@ def _c_lm_head(ctx):
     return 2 * ctx.in_elems('x') * _pdim(ctx.input('w'), 1, ctx.assume_dim)
 
 
+@cost_rule('diffusion_pick')
+def _c_diffusion_pick(ctx):
+    # per logit a compare for the max, an exponential and an add
+    return (TRANSCENDENTAL_FLOPS + 2) * ctx.in_elems('rows')
+
+
 @cost_rule('swiglu_ffn')
 def _c_swiglu_ffn(ctx):
     f = _pdim(ctx.input('w_gate'), 1, ctx.assume_dim)

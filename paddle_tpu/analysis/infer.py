@@ -1409,7 +1409,25 @@ def _paged_attention(ctx):
             f'paged_attention expects q of rank 3 (decode) or 4 '
             f'(multi-query verify), got rank {len(q.shape)}')
     _check_kv_scales(ctx)
+    if ctx.attr('block_window', False):
+        # a window model's block read: (S, H, K, D) over rows of
+        # kv_heads <= H heads, every row at the same extent
+        if q.shape is not None and len(q.shape) != 4:
+            raise InferError('paged_attention block_window expects q of '
+                             f'rank 4 (S, H, K, D), got rank {len(q.shape)}')
+        if ctx.input('k_scales') is not None:
+            raise InferError('paged_attention block_window has no int8 '
+                             'form: it takes no row scales')
+        _grouped_heads('paged_attention', _dim(q, 1),
+                       ctx.attr('kv_heads', None))
     return {'Out': VarInfo(q.shape, q.dtype)}
+
+
+def _grouped_heads(what, heads, kv_heads):
+    if kv_heads is not None and known(heads) and (
+            int(kv_heads) < 1 or heads % int(kv_heads)):
+        raise InferError(f'{what}: {heads} query heads do not divide over '
+                         f'kv_heads={kv_heads}', kind='bad-attr')
 
 
 @infer_rule('paged_prefill_attention')
@@ -1420,6 +1438,16 @@ def _paged_prefill_attention(ctx):
             f'paged_prefill_attention expects q of rank 4 (1, H, L, D), '
             f'got rank {len(q.shape)}')
     _check_kv_scales(ctx)
+    block_len = int(ctx.attr('block_len', 0))
+    if block_len < 0:
+        raise InferError(f'paged_prefill_attention block_len={block_len} is '
+                         f'negative', kind='bad-attr')
+    k = ctx.input('k')
+    if block_len and k is not None and k.shape is not None \
+            and len(k.shape) == 4:
+        # the block mask takes grouped heads: k, v (1, G, L, D)
+        _grouped_heads('paged_prefill_attention', _dim(q, 1), _dim(k, 1)
+                       if known(_dim(k, 1)) else None)
     return {'Out': VarInfo(q.shape, q.dtype)}
 
 
@@ -1475,6 +1503,20 @@ def _lm_head(ctx):
     return {'Out': VarInfo(shape, 'float32')}
 
 
+@infer_rule('diffusion_pick')
+def _diffusion_pick(ctx):
+    rows = ctx.require('rows')
+    if rows.shape is not None and len(rows.shape) < 1:
+        raise InferError('diffusion_pick expects rows of rank >= 1 (..., V)')
+    vocab, mask = _dim(rows, -1), int(ctx.attr('mask_token_id', -1))
+    if known(vocab) and mask >= vocab:
+        raise InferError(f'diffusion_pick mask_token_id={mask} is not a '
+                         f'column of {vocab}', kind='bad-attr')
+    shape = None if rows.shape is None else tuple(rows.shape[:-1])
+    return {'Ids': VarInfo(shape, 'int32'),
+            'Confidence': VarInfo(shape, 'float32')}
+
+
 @infer_rule('swiglu_ffn')
 def _swiglu_ffn(ctx):
     x = ctx.require('x')
@@ -1490,8 +1532,14 @@ def _swiglu_ffn(ctx):
 def _moe_router(ctx):
     x, w = ctx.require('x'), ctx.require('w_gate')
     _contracts('moe_router', _dim(x, -1), _dim(w, 0))
-    _contracts('moe_router bias against experts', _dim(ctx.require('bias'), 0),
-               _dim(w, 1))
+    bias = ctx.input('bias')
+    if bias is not None:
+        _contracts('moe_router bias against experts', _dim(bias, 0),
+                   _dim(w, 1))
+    scoring = ctx.attr('scoring_func', 'sigmoid')
+    if scoring not in ('sigmoid', 'softmax'):
+        raise InferError(f'moe_router scoring_func={scoring!r} is neither '
+                         f"'sigmoid' nor 'softmax'", kind='bad-attr')
     k = int(ctx.require_attr('top_k'))
     if known(_dim(w, 1)) and not 0 < k <= _dim(w, 1):
         raise InferError(f'moe_router top_k={k} of {_dim(w, 1)} experts',

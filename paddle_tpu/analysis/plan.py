@@ -513,7 +513,9 @@ def _model_state_bytes(model):
 def _decode_kv_geometry(model):
     """What the model caches, asked of the model
     (``model.kv_cache_spec()``: per token per layer ``{'kind': 'kv',
-    'layers', 'heads', 'head_dim'}`` for K and V rows of every head or
+    'layers', 'heads', 'head_dim'}`` for K and V rows of every head the
+    model caches (its key/value heads; with ``'window'`` B where a step
+    feeds B rows a slot, :func:`decode_step_rows`) or
     ``{'kind': 'latent', 'layers', 'row_width'}`` for one latent row; per
     REQUEST per layer ``{'kind': 'state', 'layers', 'heads', 'state_rows',
     'head_dim'}`` for one recurrent state). Raises a ValueError
@@ -549,6 +551,14 @@ def decode_token_layer_bytes(model, kv_dtype='f32'):
             raise ValueError('a latent KV cache has no int8 rows')
         return kv_row_bytes(1, spec['row_width'], kv_dtype)
     return 2 * kv_row_bytes(spec['heads'], spec['head_dim'], kv_dtype)
+
+
+def decode_step_rows(model, slots):
+    """Rows the lockstep decode step feeds the model: one a slot, or for a
+    WINDOW model (``kv_cache_spec()['window']`` B: block diffusion feeds a
+    slot's whole block every forward) B a slot. What a step's matmuls, its
+    router and its head are priced over."""
+    return int(slots) * int(_decode_kv_geometry(model).get('window', 1))
 
 
 def decode_pool_block_bytes(model, block_size, kv_dtype='f32'):
@@ -630,6 +640,7 @@ def decode_pool_report(model, hbm_mb, block_size, kv_dtype='f32',
         'model_state_bytes': state,
         'kv_layers': spec['layers'],
         'kv_cache': spec,
+        'step_rows_per_slot': int(spec.get('window', 1)),
         'row_bytes': decode_token_layer_bytes(model, kv_dtype),
         'block_bytes': block_bytes,
         'num_blocks': int(blocks),

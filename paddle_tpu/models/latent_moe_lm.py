@@ -161,37 +161,47 @@ class GatedFFN(Layer):
 
 
 class RoutedExperts(Layer):
-    """``n_routed_experts`` gated feed-forwards behind a sigmoid router,
-    plus one shared feed-forward of ``n_shared_experts`` expert widths."""
+    """``n_routed_experts`` gated feed-forwards behind a router, plus one
+    shared feed-forward of ``n_shared_experts`` expert widths (none where
+    that is 0). The router scores by ``cfg.scoring_func``: 'sigmoid' with a
+    selection bias (the default: `deepseek_v3`) or 'softmax' with none
+    (`sdar_moe`, models/block_diffusion_lm.py)."""
 
     def __init__(self, cfg):
         super().__init__()
         e, h, f = (cfg.n_routed_experts, cfg.hidden_size,
                    cfg.moe_intermediate_size)
         normal = NormalInitializer(0.0, cfg.initializer_range)
+        scoring = getattr(cfg, 'scoring_func', 'sigmoid')
         self.router = _linear(cfg, h, e)
-        self.router_bias = self.create_parameter(
-            [e], None, 'float32', default_initializer=NormalInitializer(
-                0.0, cfg.router_bias_scale))
+        self.router_bias = None
+        if scoring == 'sigmoid':
+            self.router_bias = self.create_parameter(
+                [e], None, 'float32', default_initializer=NormalInitializer(
+                    0.0, cfg.router_bias_scale))
         self.experts_gate = self.create_parameter(
             [e, h, f], None, cfg.dtype, default_initializer=normal)
         self.experts_up = self.create_parameter(
             [e, h, f], None, cfg.dtype, default_initializer=normal)
         self.experts_down = self.create_parameter(
             [e, f, h], None, cfg.dtype, default_initializer=normal)
-        self.shared = GatedFFN(cfg, cfg.n_shared_experts * f)
+        self.shared = GatedFFN(cfg, cfg.n_shared_experts * f) \
+            if cfg.n_shared_experts else None
         self._route = {'top_k': cfg.num_experts_per_tok,
                        'routed_scaling_factor': cfg.routed_scaling_factor,
                        'norm_topk_prob': cfg.norm_topk_prob}
+        if scoring != 'sigmoid':    # the sigmoid dispatch stays as it was
+            self._route['scoring_func'] = scoring
 
     def forward(self, x, cache=None):
         b, s, h = x.shape
         flat = dispatch_op('reshape', {'x': x}, {'shape': [b * s, h]})
         # the scopes name the device ops of each part in a profiler trace
         with jax.named_scope('moe/route'):
-            ids, weights = dispatch_op('moe_router', {
-                'x': flat, 'w_gate': self.router.weight,
-                'bias': self.router_bias}, self._route)
+            router = {'x': flat, 'w_gate': self.router.weight}
+            if self.router_bias is not None:
+                router['bias'] = self.router_bias
+            ids, weights = dispatch_op('moe_router', router, self._route)
         with jax.named_scope('moe/experts'):
             routed, counts = dispatch_op('moe_experts', {
                 'x': flat, 'ids': ids, 'weights': weights,
@@ -208,10 +218,10 @@ class RoutedExperts(Layer):
             cache.note('expert_counts', (chosen & live[:, None, None]).sum(
                 (0, 1), dtype=jnp.int32))
             cache.note('expert_ids', _scored_rows(cache, ids.value, 0))
-        with jax.named_scope('moe/shared'):
-            shared = self.shared(flat)
-        return dispatch_op('reshape', {'x': routed + shared},
-                           {'shape': [b, s, h]})
+        if self.shared is not None:
+            with jax.named_scope('moe/shared'):
+                routed = routed + self.shared(flat)
+        return dispatch_op('reshape', {'x': routed}, {'shape': [b, s, h]})
 
 
 def _scored_rows(cache, x, axis):

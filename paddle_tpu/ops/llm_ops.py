@@ -1,7 +1,9 @@
 """The modern decoder block as ops (ROADMAP R2): RMSNorm, rotary positions,
-the gated feed-forward, a sigmoid router with bias-corrected top-k, a grouped
-expert feed-forward that drops no token, and latent (MLA) attention in its
-two forms: expanded over a prompt, absorbed over the paged latent cache.
+the gated feed-forward, a router (sigmoid scores with bias-corrected top-k,
+or softmax scores) and a grouped expert feed-forward that drops no token,
+latent (MLA) attention in its two forms (expanded over a prompt, absorbed
+over the paged latent cache), power retention, and a block-diffusion
+forward's pick (a token and the confidence in it per row).
 
 Precision rule, the same in every op: matmuls take their operands as they
 are stored (bf16 weights and activations on the served path) and accumulate
@@ -76,6 +78,23 @@ def lm_head(x, w):
                       preferred_element_type=_F32)
 
 
+@register_op('diffusion_pick', outputs=('Ids', 'Confidence'))
+def diffusion_pick(rows, *, mask_token_id=-1):
+    """What a denoising forward of a block-diffusion model hands the host of
+    its logits ``rows`` (..., V): per row the most likely token and the
+    model's confidence in it, the softmax probability of that token, with
+    column ``mask_token_id`` (the `MASK` token: an input, never an answer)
+    at −∞ in both. Float32 whatever the rows are; ids int32, the first of
+    equal maxima."""
+    rows = jnp.asarray(rows).astype(_F32)
+    if mask_token_id >= 0:
+        rows = jnp.where(jnp.arange(rows.shape[-1]) == int(mask_token_id),
+                         -jnp.inf, rows)
+    top = rows.max(-1, keepdims=True)
+    confidence = 1.0 / jnp.exp(rows - top).sum(-1)
+    return jnp.argmax(rows, -1).astype(jnp.int32), confidence
+
+
 @register_op('swiglu_ffn')
 def swiglu_ffn(x, w_gate, w_up, w_down):
     """w_down(silu(x · w_gate) ⊙ (x · w_up)); weights (h, f), (h, f),
@@ -87,21 +106,36 @@ def swiglu_ffn(x, w_gate, w_up, w_down):
 
 
 @register_op('moe_router', outputs=('Ids', 'Weights'))
-def moe_router(x, w_gate, bias, *, top_k, routed_scaling_factor=1.0,
-               norm_topk_prob=True):
-    """Sigmoid router with a selection bias (`noaux_tc`, one group): scores
-    s = sigmoid(x · w_gate) over E experts; the ``top_k`` largest s + bias
-    are chosen; their weights are the UNBIASED scores, normalised over the
-    chosen (``norm_topk_prob``) and scaled. All float32.
+def moe_router(x, w_gate, bias=None, *, top_k, routed_scaling_factor=1.0,
+               norm_topk_prob=True, scoring_func='sigmoid'):
+    """A router's choice of ``top_k`` of E experts and their weights, all
+    float32. ``scoring_func``:
 
-    x (T, h), w_gate (h, E), bias (E,) -> ids (T, k) int32, weights (T, k)
-    float32."""
+    - ``'sigmoid'`` with a selection ``bias`` (`noaux_tc`, one group):
+      scores s = sigmoid(x · w_gate); the ``top_k`` largest s + bias are
+      chosen; their weights are the UNBIASED scores.
+    - ``'softmax'`` (Qwen3-MoE, `sdar_moe`): scores p = softmax(x · w_gate)
+      over the E experts; the ``top_k`` largest are chosen (``bias`` None:
+      the family has none) and weigh by their p.
+
+    The weights are normalised over the chosen (``norm_topk_prob``) and
+    scaled. Of equal scores the lower expert is chosen first.
+
+    x (T, h), w_gate (h, E), bias (E,) or None -> ids (T, k) int32, weights
+    (T, k) float32."""
     logits = jnp.matmul(jnp.asarray(x).astype(_F32),
                         jnp.asarray(w_gate).astype(_F32),
                         precision=lax.Precision.HIGHEST)
-    scores = jax.nn.sigmoid(logits)
-    _, ids = lax.top_k(scores + jnp.asarray(bias).astype(_F32),
-                       int(top_k))
+    if scoring_func == 'softmax':
+        scores = jax.nn.softmax(logits, -1)
+    elif scoring_func == 'sigmoid':
+        scores = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f'moe_router: scoring_func={scoring_func!r} is '
+                         "neither 'sigmoid' nor 'softmax'")
+    biased = scores if bias is None \
+        else scores + jnp.asarray(bias).astype(_F32)
+    _, ids = lax.top_k(biased, int(top_k))
     weights = jnp.take_along_axis(scores, ids, -1)
     if norm_topk_prob:
         weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)

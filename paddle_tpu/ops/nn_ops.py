@@ -743,10 +743,68 @@ def live_block_list(block_tables, context_lens, block_size):
     return block_id, slot, first_pos, n_live
 
 
+# The multi-query BLOCK read (a window model's step: B rows a slot, every row
+# at the same extent) walks the live context in GROUPS of whole cache blocks,
+# 128 keys a group where the table is that wide: a group's K or V is one
+# (128, heads·head_dim) tile per key/value head, so that a group's scores are
+# one (query rows, head_dim) x (head_dim, 128) matmul a key/value head, not
+# eight of 16 keys. A chunk of the walk holds this many groups (at SDAR's
+# rows a chunk's K is 128 x 128 x 512 bf16 = 16.8 MB); fewer, larger chunks
+# rewrite the per-slot running state less often, and the last chunk's
+# padding (half a chunk on average) names the scratch block.
+LIVE_GROUP_KEYS = 128
+LIVE_GROUP_CHUNK = 128
+
+
+def live_group_blocks(block_size, max_blocks):
+    """Cache blocks a group of the block read holds: ``LIVE_GROUP_KEYS``
+    keys' worth, at least one, at most a slot's whole table."""
+    return max(1, min(LIVE_GROUP_KEYS // int(block_size), int(max_blocks)))
+
+
+def live_group_chunk(slots, block_size, max_blocks):
+    """(groups a slot's table holds, groups a chunk of the walk holds)."""
+    per_slot = -(-int(max_blocks) // live_group_blocks(block_size, max_blocks))
+    return per_slot, min(LIVE_GROUP_CHUNK, int(slots) * per_slot)
+
+
+def live_group_list(block_tables, context_lens, block_size):
+    """The LIVE groups of a decode batch, compacted slot-major on the
+    device: :func:`live_block_list` at the grain of
+    :func:`live_group_blocks` blocks. Group j of slot s is live iff
+    ``j·keys < context_lens[s]``, keys = blocks a group × ``block_size``.
+
+    Returns ``(block_ids, slot, first_pos, n_live)``: (N, blocks a group),
+    (N,), (N,) int32 over N = S × groups a slot rounded up to whole chunks,
+    and the int32 count. A live group's trailing blocks past the slot's
+    context are whatever its table holds there (the scratch block, or
+    reserved blocks not yet written) and entries past ``n_live`` name the
+    scratch block at ``first_pos`` = the padded context: a reader's own
+    mask (position < context) gives both zero mass."""
+    tables = jnp.asarray(block_tables, jnp.int32)
+    s, mb = tables.shape
+    m = live_group_blocks(block_size, mb)
+    per_slot, chunk = live_group_chunk(s, block_size, mb)
+    tables = jnp.pad(tables, ((0, 0), (0, per_slot * m - mb))).reshape(
+        s, per_slot, m)
+    at = jnp.arange(-(-s * per_slot // chunk) * chunk, dtype=jnp.int32)
+    groups = -(-jnp.asarray(context_lens, jnp.int32) // (m * block_size))
+    ends = jnp.cumsum(groups)
+    n_live = ends[-1]
+    slot = jnp.minimum(jnp.sum(at[:, None] >= ends[None, :], axis=1,
+                               dtype=jnp.int32), s - 1)
+    j = at - (ends - groups)[slot]
+    live = at < n_live
+    block_ids = jnp.where(live[:, None],
+                          tables[slot, jnp.clip(j, 0, per_slot - 1)], 0)
+    first_pos = jnp.where(live, j * (m * block_size), mb * block_size)
+    return block_ids, slot, first_pos, n_live
+
+
 @register_op('paged_attention')
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                     k_scales=None, v_scales=None, live=None, *,
-                    sm_scale=1.0):
+                    sm_scale=1.0, block_window=False, kv_heads=None):
     """Single-token decode attention over a paged KV cache (the decode half
     of the serving decode engine — docs/SERVING.md "Stateful decode";
     blueprint: Ragged Paged Attention, PAPERS.md arxiv 2604.15464).
@@ -774,8 +832,17 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
       attends ``context_lens + j`` keys (a causal staircase over the K
       fed positions — row j sees the prior context plus fed tokens 0..j).
     - ``live``: optional, the single-query read's
-      :func:`live_block_list` of these tables and lengths, for a caller
-      that reads many layers through the same tables; made here otherwise.
+      :func:`live_block_list` of these tables and lengths (the block read's
+      :func:`live_group_list`), for a caller that reads many layers through
+      the same tables; made here otherwise.
+    - ``block_window`` (multi-query only): the BLOCK read of a window model
+      (block diffusion: the K fed rows of a slot are one block, bidirectional
+      inside it). ``context_lens`` is then the extent of EVERY row, the K
+      rows just written included, not the staircase; ``kv_heads`` G ≤ H is
+      the heads a pool row holds (query head i reads key/value head
+      i // (H/G)); the read walks the live context
+      (:func:`_live_group_attention`) and gathers no table whole. int8
+      pools have no block read.
 
     The single-query read has ONE formulation, for every head_dim and pool
     dtype (:func:`_live_block_attention`): it walks the batch's live blocks
@@ -789,14 +856,23 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
     (masked after the exponential, and mostly never read), so stale values
     in a reused or scratch block can never bleed (0.0 × finite == 0.0).
 
-    The multi-query (S, H, K, D) read still gathers each slot's whole
-    padded table dense (:func:`_gather_pages`) and runs matmul → mask →
-    softmax → matmul: no served cell runs it yet (ROADMAP S3)."""
+    The multi-query (S, H, K, D) STAIRCASE read still gathers each slot's
+    whole padded table dense (:func:`_gather_pages`) and runs matmul → mask
+    → softmax → matmul: no served cell runs it yet (ROADMAP S3)."""
     q = jnp.asarray(q)
     k_pages = jnp.asarray(k_pages)
     v_pages = jnp.asarray(v_pages)
     block_tables = jnp.asarray(block_tables, jnp.int32)
     context_lens = jnp.asarray(context_lens, jnp.int32)
+    if q.ndim == 4 and block_window:
+        if k_scales is not None:
+            raise ValueError('paged_attention: an int8 pool has no block '
+                             'read')
+        if live is None:
+            live = live_group_list(block_tables, context_lens,
+                                   k_pages.shape[1])
+        return _live_group_attention(q, k_pages, v_pages, context_lens, live,
+                                     int(kv_heads or q.shape[1]), sm_scale)
     if q.ndim == 4:
         # multi-query decode (speculative verify): K fed tokens per slot,
         # row j at extent context_lens + j
@@ -887,6 +963,79 @@ def _live_block_attention(q, k_pages, v_pages, context_lens, live,
     return out.reshape(s, h, d).astype(q.dtype)
 
 
+def _live_group_attention(q, k_pages, v_pages, context_lens, live, kv_heads,
+                          sm_scale):
+    """q (S, H, K, D) against the pool's LIVE groups
+    (:func:`live_group_list`), a chunk of C groups at a time, only as many
+    chunks as hold live groups; every row of a slot sees positions <
+    ``context_lens[slot]``.
+
+    Query head i reads key/value head i // (H/G), so a slot's H·K query
+    vectors are R = (H/G)·K rows for each of the G key/value heads: a
+    group's scores are one (R, D) x (D, keys) matmul a head and its weighted
+    sum one (R, keys) x (keys, D), on the rows as the pool stores them
+    (their dtype the operands', float32 the accumulation; probabilities
+    float32 until the second matmul takes them in V's dtype). A chunk is
+    folded into per-slot running state m, l (S, G, R) and acc (S, G, R, D)
+    with the running-softmax rescale, a slot's groups found by the one-hot
+    (S, C) of the chunk's ``slot`` as in :func:`_live_block_attention`.
+    Masked positions get exactly-zero mass."""
+    f32, exact = jnp.float32, lax.Precision.HIGHEST
+    s, h, kq, d = q.shape
+    g = int(kv_heads)
+    rep = h // g
+    r = rep * kq
+    bs = k_pages.shape[1]
+    block_ids, slot, first_pos, n_live = live
+    m_blocks = block_ids.shape[1]
+    keys = m_blocks * bs
+    chunk = min(LIVE_GROUP_CHUNK, block_ids.shape[0])
+    neg = jnp.finfo(f32).min
+    qg = q.reshape(s, g, r, d)
+    scale = jnp.asarray(sm_scale, f32)
+    offsets = jnp.arange(keys, dtype=jnp.int32)
+    slots = jnp.arange(s, dtype=jnp.int32)
+
+    def rows_of(pages, ids):
+        got = jnp.take(pages, ids.reshape(-1), axis=0)[..., :g * d]
+        if got.dtype != q.dtype:
+            got = got.astype(q.dtype)
+        return got.reshape(chunk, keys, g, d)
+
+    def fold(i, state):
+        m, l, acc = state
+        ids, of, pos = (lax.dynamic_slice_in_dim(x, i * chunk, chunk)
+                        for x in (block_ids, slot, first_pos))
+        seen = (pos[:, None] + offsets[None, :]
+                < context_lens[of][:, None])[:, None, None, :]   # (C,1,1,T)
+        mine = of[None, :] == slots[:, None]                      # (S, C)
+        to_slot = mine.astype(f32)
+        scores = jnp.einsum('cgrd,ctgd->cgrt', qg[of], rows_of(k_pages, ids),
+                            preferred_element_type=f32) * scale
+        scores = jnp.where(seen, scores, neg)
+        m_new = jnp.maximum(m, jnp.max(
+            jnp.where(mine[:, :, None, None], scores.max(-1)[None], neg),
+            axis=1))
+        p = jnp.where(seen, jnp.exp(scores - m_new[of][..., None]), 0.0)
+        rescale = jnp.exp(m - m_new)                          # (S, G, R)
+        l = l * rescale + jnp.matmul(
+            to_slot, p.sum(-1).reshape(chunk, g * r),
+            precision=exact).reshape(s, g, r)
+        v = rows_of(v_pages, ids)
+        weighted = jnp.einsum('cgrt,ctgd->cgrd', p.astype(v.dtype), v,
+                              preferred_element_type=f32)
+        acc = acc * rescale[..., None] + jnp.matmul(
+            to_slot, weighted.reshape(chunk, g * r * d),
+            precision=exact).reshape(s, g, r, d)
+        return m_new, l, acc
+
+    m, l, acc = lax.fori_loop(
+        0, -(-n_live // chunk), fold,
+        (jnp.full((s, g, r), neg, f32), jnp.zeros((s, g, r), f32),
+         jnp.zeros((s, g, r, d), f32)))
+    return (acc / l[..., None]).reshape(s, h, kq, d).astype(q.dtype)
+
+
 def _gather_pages(pages, block_tables, s, h, d, scales=None):
     """(NB, BS, W ≥ H·D) cache pool + (S, nbs) tables → dense (S, H, nbs·BS, D)
     per-slot key/value view (the XLA stand-in for the kernel's block walk):
@@ -917,7 +1066,7 @@ def _gather_pages(pages, block_tables, s, h, d, scales=None):
 @register_op('paged_prefill_attention')
 def paged_prefill_attention(q, k, v, k_pages, v_pages, block_tables,
                             k_scales=None, v_scales=None, *,
-                            sm_scale=1.0):
+                            sm_scale=1.0, block_len=0):
     """Prefill-phase attention for the decode engine: causal whole-prompt
     attention whose KEY EXTENT is the paged-cache view, so prefill rows are
     bitwise-identical to the decode steps (and to a whole-sequence forward
@@ -940,8 +1089,19 @@ def paged_prefill_attention(q, k, v, k_pages, v_pages, block_tables,
     path on every backend (the raw-k/v TPU kernel would attend the
     UN-quantized projections — bitwise-different from the decode steps that
     later read the quantized cache, breaking the prefill/decode parity the
-    engine is built on)."""
+    engine is built on).
+
+    ``block_len`` B > 0 is the BLOCK mask of a window model (block
+    diffusion): key j is visible to row i iff j // B <= i // B, causal
+    across blocks and bidirectional inside one, and ``k``/``v`` may hold
+    G ≤ H heads (query head i reads key/value head i // (H/G)). Such a
+    prefill attends the raw projections it was handed, the prompt itself,
+    in chunks of query rows (:func:`_block_prefill_attention`): the pool
+    holds the same values (a quantized pool is refused by the engine), and
+    nothing of a table is gathered."""
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
+    if block_len:
+        return _block_prefill_attention(q, k, v, int(block_len), sm_scale)
     if (flash_kernel_applies(q, k)
             and jnp.asarray(k_pages).dtype == jnp.float32):
         from jax.experimental.pallas.ops.tpu.flash_attention import (
@@ -964,3 +1124,35 @@ def paged_prefill_attention(q, k, v, k_pages, v_pages, block_tables,
     scores = jnp.where(causal, scores, jnp.finfo(scores.dtype).min)
     probs = jax.nn.softmax(scores, axis=-1)
     return jnp.matmul(probs, vd)
+
+
+# query rows of a prompt attended at a time under the block mask, each chunk
+# against the keys up to its own last block: a 2,048-token rung holds at
+# most (heads, 512, 2048) scores, and computes 10 of the 16 chunk pairs
+_BLOCK_PREFILL_QUERY_CHUNK = 512
+
+
+def _block_prefill_attention(q, k, v, block_len, sm_scale):
+    """q (B, H, L, D) over k, v (B, G, L, D) under the block mask (key j
+    visible to row i iff j // block_len <= i // block_len), grouped heads,
+    operands as stored, float32 scores and softmax."""
+    f32 = jnp.float32
+    b, h, length, d = q.shape
+    g = k.shape[1]
+    qg = q.reshape(b, g, h // g, length, d)
+    chunk = min(length, _BLOCK_PREFILL_QUERY_CHUNK)
+    if length % chunk or chunk % block_len:
+        chunk = length
+    block_of = jnp.arange(length, dtype=jnp.int32) // block_len
+    out = []
+    for start in range(0, length, chunk):
+        stop = start + chunk
+        s = jnp.einsum('bgrqd,bgkd->bgrqk', qg[:, :, :, start:stop],
+                       k[:, :, :stop], preferred_element_type=f32) \
+            * jnp.asarray(sm_scale, f32)
+        seen = block_of[None, :stop] <= block_of[start:stop, None]
+        s = jnp.where(seen, s, jnp.finfo(f32).min)
+        p = jax.nn.softmax(s, -1).astype(v.dtype)
+        out.append(jnp.einsum('bgrqk,bgkd->bgrqd', p, v[:, :, :stop],
+                              preferred_element_type=f32).astype(q.dtype))
+    return jnp.concatenate(out, 3).reshape(b, h, length, d)

@@ -62,9 +62,12 @@ from ...observability import distributed as _dobs
 from ...core.compile_cache import setup_persistent_cache
 from ...dygraph.jit import _bind
 from ...dygraph.tape import Tensor, no_grad_guard
-from ...ops.nn_ops import live_block_chunk
+from ...ops.llm_ops import diffusion_pick
+from ...ops.nn_ops import (live_block_chunk, live_group_blocks,
+                           live_group_chunk)
 from ..engine import bucket_ladder
 from ..errors import InvalidRequest, UnsupportedCacheFeature
+from .diffusion import unmask_most_confident
 from .kv_cache import (BlockTable, CacheContext, KVCachePool, decode_coords,
                        prefill_coords, DEFAULT_BLOCK_SIZE,
                        DEFAULT_MAX_BLOCKS, DEFAULT_SLOTS)
@@ -128,7 +131,12 @@ class _Program:
     ``rows`` returns (the first of equal maxima; a row with a NaN gives its
     first NaN's index: numpy's argmax on the same rows). ``rows``, with
     ``picks`` in brackets: prefill (1, L) -> row ``last`` (V,) [()]; decode
-    (S, 1) -> (S, V) [(S,)]; decode (S, K) -> (S, K, V) [(S, K)]. The rows
+    (S, 1) -> (S, V) [(S,)]; decode (S, K) -> (S, K, V) [(S, K)]. A WINDOW
+    model (``model.decode_window`` B > 1, block diffusion) steps (S, B) ->
+    (S, B, V) [a pair: (S, B) int32 ids with the `MASK` column left out,
+    and (S, B) float32 softmax probabilities of those ids,
+    ops/llm_ops.py::diffusion_pick], and its prefill returns neither rows
+    nor picks: it writes the prompt's K/V and scores nothing. The rows
     stay an output of the one program, the head's matmul result, for the
     calls that ask for them: an output nobody reads is never copied.
     ``stats`` is what the forward noted on its
@@ -161,6 +169,11 @@ class _Program:
         # `KVCachePool.allocate` is told beside the arrays' shapes, which do
         # not say it
         heads = self._heads = {}
+        # a window model (models/block_diffusion_lm.py) feeds a step
+        # ``decode_window`` rows a slot and hands the host, per row, a pick
+        # and the confidence in it; its prefill scores nothing
+        window = int(getattr(model, 'decode_window', 1))
+        pick = {'mask_token_id': int(getattr(model, 'mask_token_id', -1))}
 
         def run(mode, geometry, pvals, bvals, layers, scales, ids, pos,
                 coords, last):
@@ -171,8 +184,11 @@ class _Program:
             with _bind(params, pvals), _bind(buffers, bvals), \
                     no_grad_guard():
                 logits = model_ref()(Tensor(ids, stop_gradient=True),
-                                     pos_ids=pos, cache=ctx).value
-            if mode == 'prefill':
+                                     pos_ids=pos, cache=ctx)
+                logits = None if logits is None else logits.value
+            if logits is None:
+                rows = None         # a window model's prefill: K/V alone
+            elif mode == 'prefill':
                 # a model may have scored row `last` alone: (1, 1, V)
                 rows = jax.lax.dynamic_index_in_dim(
                     logits[0], jax.numpy.minimum(last, logits.shape[1] - 1),
@@ -185,7 +201,15 @@ class _Program:
             stats = {name: jax.numpy.stack(values)
                      for name, values in ctx.stats.items()}
             heads.update(pool.heads)
-            picks = jax.numpy.argmax(rows, -1).astype(jax.numpy.int32)
+            if rows is None:
+                picks = None
+            elif window > 1:
+                # (ids, confidences), each (S, B): the scope names the
+                # pick's device ops in a profiler trace
+                with jax.named_scope('diffusion/pick'):
+                    picks = diffusion_pick(rows, **pick)
+            else:
+                picks = jax.numpy.argmax(rows, -1).astype(jax.numpy.int32)
             return (rows, picks, stats) + pool.arrays()
 
         self.jitted = jax.jit(run, static_argnums=(0, 1),
@@ -270,15 +294,20 @@ class _CallClock:
         stamped apart (``device_get`` alone is both at once); ``counts`` (a
         small array of the same program) and ``rows`` (given only by a call
         that needs them on the host) are copied in the same phase.
+        ``picks`` may be a tuple of arrays (a window model's ids and
+        confidences) or None (its prefill: the caller has waited for the
+        program by the pool's arrays).
         ``decode_logits_bytes_copied`` takes what crossed for the pick:
-        4 bytes a pick, and the rows' bytes where they were asked for."""
-        picks.block_until_ready()
+        4 bytes a pick (and 4 a confidence), and the rows' bytes where they
+        were asked for."""
+        jax.block_until_ready(picks)
         self.end('device_wait')
         # one round of transfers, started together (None stays None)
         picks, counts, rows = jax.device_get((picks, counts, rows))
         self.end('logits_copy')
         _m.decode_logits_bytes_copied.inc(
-            picks.nbytes + (0 if rows is None else rows.nbytes))
+            sum(x.nbytes for x in jax.tree_util.tree_leaves(picks))
+            + (0 if rows is None else rows.nbytes))
         self.work['rows_fetched'] = int(rows is not None)
         return picks, counts, rows
 
@@ -336,6 +365,9 @@ class DecodeEngine:
         # REQUEST per layer one recurrent state (models/retention_lm.py)
         spec = getattr(model, 'kv_cache_spec', None)
         self.cache_kind = spec()['kind'] if spec else 'kv'
+        # rows a slot feeds the lockstep step: 1 for every model but a
+        # WINDOW model (block diffusion), whose step is `window_step`
+        self.window = int(getattr(model, 'decode_window', 1))
         # the last call's ``stats`` as its program returned them (device
         # arrays, read by whoever asks: nothing is copied on the served
         # path): ``expert_ids`` says which experts made the rows just read
@@ -347,6 +379,11 @@ class DecodeEngine:
         self.prompt_buckets = bucket_ladder(self.max_prompt_len,
                                             prompt_buckets)
         block_size = int(block_size or DEFAULT_BLOCK_SIZE)
+        if block_size % self.window:
+            raise ValueError(
+                f'block_size={block_size} is no multiple of the model\'s '
+                f'window of {self.window}: a block of the model must never '
+                f'straddle two cache blocks')
         max_total = self.max_prompt_len + self.max_new_tokens_cap
         max_bps = -(-max_total // block_size)
         # KV storage dtype: arg wins, else the strict-parsed
@@ -426,6 +463,18 @@ class DecodeEngine:
                 (f'kv_dtype={kv_dtype}', kv_dtype != 'f32')) if on]
             if asked:
                 raise UnsupportedCacheFeature(asked, 'state')
+        if self.window > 1:
+            # each needs a path under the block mask that is not written
+            # yet (docs/SERVING.md "Window models"); the handoff is refused
+            # where its prefill role is built (serving/tier/disagg.py)
+            asked = [name for name, on in (
+                ('the prefix cache (and its spill and reinject)',
+                 self.prefix_cache is not None),
+                ('speculative decoding (its (S, K) verify step)',
+                 self.spec_enabled),
+                ('kv_dtype=int8', kv_dtype == 'int8')) if on]
+            if asked:
+                raise UnsupportedCacheFeature(asked, 'window')
 
     @staticmethod
     def _resolve_num_blocks(model, max_blocks, block_size, max_bps,
@@ -533,6 +582,10 @@ class DecodeEngine:
             rows, picks, stats = self._program(self.pool, mode, ids, pos,
                                                coords, last)
             clock.end('forward')
+            if picks is None:
+                # a window model's prefill picks nothing: the program is
+                # done when the pool it wrote is
+                jax.block_until_ready(self.pool.arrays())
             picks, counts, rows = clock.fetch(
                 picks, stats.get('expert_counts'),
                 rows if fetch_rows else None)
@@ -571,10 +624,11 @@ class DecodeEngine:
         chip_smoke.py, the tests."""
         pool = self.pool
         if bucket is None:
-            feed = np.zeros((self.slots, 1), np.int64)
+            feed = np.zeros((self.slots, self.window), np.int64)
             return self._program.lower(
                 pool, 'decode', feed, feed,
-                decode_coords(pool, [None] * self.slots, [1] * self.slots),
+                decode_coords(pool, [None] * self.slots, [1] * self.slots,
+                              window=self.window, block=self.window > 1),
                 sharding=sharding)
         return self._program.lower(
             pool, 'prefill', np.zeros((1, bucket), np.int64), None,
@@ -614,6 +668,8 @@ class DecodeEngine:
         argmax, taken on the device: 4 bytes reach the host), or drawn by
         ``sampler(logits_row)`` for sampled requests (the row is copied to
         the host for it). Sets ``table.context_len = len(prompt)``."""
+        if self.window > 1:
+            return self._prefill_window(prompt, table, sampler)
         clock = _CallClock('prefill')
         P = len(prompt)
         bucket = next(b for b in self.prompt_buckets if P <= b)
@@ -633,6 +689,10 @@ class DecodeEngine:
             _m.decode_state_tokens_folded.inc(folded)
             clock.work['state_tokens_folded'] = folded
         clock.record(prompt_len=P, bucket=bucket)
+        self._after_prefill(bucket)
+        return token
+
+    def _after_prefill(self, bucket):
         if bucket not in self._prefill_compiled:
             self._prefill_compiled.add(bucket)
             _m.decode_prefill_compiles.inc()
@@ -640,7 +700,35 @@ class DecodeEngine:
         _m.kv_cache_bytes_in_hbm.set(self.pool.bytes_in_hbm())
         _m.kv_cache_row_bytes.set(self.pool.row_bytes())
         self._set_state_gauges()
-        return token
+
+    def _prefill_window(self, prompt, table, sampler=None):
+        """A window model's prefill: the prompt's ⌊P/B⌋ WHOLE blocks run
+        once at the rung that holds them, under the block mask, and their
+        K/V are written; ``table.context_len`` = ⌊P/B⌋·B. Nothing is scored
+        or picked and None is returned: the prompt's last P mod B tokens
+        open the first block beside its masks, and the first denoising
+        forward (:meth:`window_step`) reads them. A prompt shorter than a
+        block runs no program at all."""
+        if sampler is not None:
+            raise InvalidRequest(
+                'a window model picks by confidence and takes no sampler')
+        clock = _CallClock('prefill')
+        P = len(prompt)
+        n = P // self.window * self.window
+        table.context_len = n
+        if not n:
+            return None
+        bucket = next(b for b in self.prompt_buckets if n <= b)
+        ids = np.zeros((1, bucket), np.int64)
+        ids[0, :n] = prompt[:n]
+        coords = prefill_coords(self.pool, table, bucket)
+        t0 = clock.end('pack')
+        self._run(clock, 'prefill', ids, None, coords, np.int32(n - 1))
+        _m.decode_prefill_seconds.observe(clock.last - t0)
+        clock.end('sample')
+        clock.record(prompt_len=P, bucket=bucket)
+        self._after_prefill(bucket)
+        return None
 
     def decode_step(self, tokens, tables, return_rows=False):
         """One lockstep step over all S slots at fixed shape.
@@ -695,13 +783,26 @@ class DecodeEngine:
             return 0
         if self.cache_kind != 'kv':
             return entries
+        if self.window > 1:
+            # the block read walks whole groups of blocks, in whole chunks
+            # of groups (ops/nn_ops.py::live_group_list)
+            per_group = live_group_blocks(self.block_size,
+                                          self.pool.max_blocks_per_seq)
+            _, chunk = live_group_chunk(self.slots, self.block_size,
+                                        self.pool.max_blocks_per_seq)
+            live = sum(-(-int(c) // (per_group * self.block_size))
+                       for c in ctx_lens)
+            return -(-live // chunk) * chunk * per_group
         chunk = live_block_chunk(entries)
         live = sum(-(-int(c) // self.block_size) for c in ctx_lens)
         return -(-live // chunk) * chunk
 
-    def _account_step(self, clock, dt, tables, blocks):
+    def _account_step(self, clock, dt, tables, blocks, attended=None):
         """What every decode step books, lockstep or speculative, and the
-        call's record; ``blocks`` the cache blocks a layer's read took."""
+        call's record; ``blocks`` the cache blocks a layer's read took,
+        ``attended`` the positions a layer's read attended where that is
+        not the tables' contexts (a window step reads a block it may not
+        keep)."""
         _m.decode_step_seconds.observe(dt)
         _m.decode_steps.inc()
         active = sum(t is not None for t in tables)
@@ -710,8 +811,8 @@ class DecodeEngine:
         # the layers that cache rows (a state layer attends no position and
         # reads no block: it advances one state a live slot)
         layers = self.pool.num_row_layers
-        positions = layers * sum(
-            t.context_len for t in tables if t is not None)
+        positions = layers * (attended if attended is not None else sum(
+            t.context_len for t in tables if t is not None))
         _m.decode_context_positions_read.inc(positions)
         _m.decode_kv_blocks_read.inc(layers * blocks)
         clock.work['context_positions'] = positions
@@ -726,6 +827,69 @@ class DecodeEngine:
         # sliding-window views for /healthz slo + fleet snapshots
         _dobs.series('occupancy').observe(active / max(self.slots, 1))
         _dobs.series('decode_step').observe(dt)
+
+    def window_step(self, blocks, masked, quota, tables, commits,
+                    return_rows=False):
+        """One lockstep step of a WINDOW model (block diffusion): every live
+        slot feeds its whole block of B = ``window`` tokens over its cache.
+        It IS the engine's step: the same clock, histograms and span as
+        :meth:`decode_step`, one program, one shape.
+
+        ``blocks`` (S, B) int and ``masked`` (S, B) bool are the caller's
+        host arrays of every slot's block: its token ids, the model's `MASK`
+        id where a position is still masked, and which positions those are.
+        ``tables``: length-S list, None = inactive. ``commits``: length-S
+        flags. For a live slot with context c the block is fed at positions
+        c .. c+B-1, every row attending positions < c+B (the block mask);
+        its K/V are written there, PROVISIONALLY: ``context_len`` moves to
+        c+B where ``commits[s]`` says so (a commit forward: the block is
+        finished, its K/V are kept) and not at all otherwise (a denoising
+        forward: the next forward of the block writes the same positions
+        again, and no later read sees them before that: rollback is one
+        integer never stored).
+
+        The host is handed (S, B) ids and (S, B) float32 confidences
+        (ops/llm_ops.py::diffusion_pick), S × B × 8 bytes, never a logits
+        row; in the call's ``sample`` phase the schedule
+        (diffusion.py::unmask_most_confident) fixes, IN PLACE in ``blocks``
+        and ``masked``, the ``quota[s]`` most confident masked positions of
+        each denoising slot to their picks. Returns ``(ids, confidences)``
+        (garbage on inactive slots; of a commit forward nobody's concern),
+        and with ``return_rows=True`` the (S, B, V) rows too, for a check."""
+        clock = _CallClock('step')
+        S, B = self.slots, self.window
+        assert B > 1 and len(tables) == S and len(commits) == S
+        live = np.asarray([t is not None for t in tables])
+        commits = live & np.asarray(commits, bool)
+        contexts = np.asarray([t.context_len if t is not None else 0
+                               for t in tables], np.int64)
+        ids = np.where(live[:, None], blocks, 0)
+        pos = np.where(live[:, None], contexts[:, None] + np.arange(B), 0)
+        # an idle slot reads the scratch block, masked and ignored
+        extents = np.where(live, contexts + B, 1)
+        coords = decode_coords(self.pool, tables, extents, live * B, B,
+                               block=True)
+        walked = self._blocks_walked(extents)
+        t0 = clock.end('pack')
+        (picks, conf), rows = self._run(clock, 'decode', ids, pos, coords,
+                                        fetch_rows=return_rows)
+        for s in np.flatnonzero(commits):
+            tables[s].context_len += B      # the block's K/V are kept
+        unmasked = unmask_most_confident(
+            blocks, masked, picks, conf,
+            np.where(live & ~commits, np.asarray(quota), 0))
+        dt = clock.end('sample') - t0
+        forwards, committed = int(live.sum()), int(commits.sum())
+        _m.decode_diffusion_denoise_forwards.inc(forwards - committed)
+        _m.decode_diffusion_commit_forwards.inc(committed)
+        clock.work.update(window=B, slot_forwards=forwards,
+                          commits=committed, rows_unmasked=unmasked)
+        self._account_step(clock, dt, tables, walked,
+                           attended=int(extents[live].sum()))
+        self._step_compiled = True
+        if return_rows:
+            return picks, conf, rows
+        return picks, conf
 
     def spec_step(self, token_lists, tables):
         """One batched (S, k) speculative/multi-token step.
@@ -826,7 +990,13 @@ class DecodeEngine:
         return (self._step_compiled
                 and (self._spec_compiled or not self.spec_enabled)
                 and all(b in self._prefill_compiled
-                        for b in self.prompt_buckets))
+                        for b in self._rungs_run()))
+
+    def _rungs_run(self):
+        """The ladder's rungs a prefill can run at: all of them, but for a
+        window model those that hold a whole block (a shorter prompt runs
+        no prefill program: its tokens open the first block)."""
+        return [b for b in self.prompt_buckets if b >= self.window]
 
     def warmup(self):
         """Precompile the prefill ladder + the decode-step shape (+ the
@@ -842,11 +1012,12 @@ class DecodeEngine:
         TokenSampler(SamplingParams(temperature=1.0), 'warmup').sample(
             np.zeros(2, np.float32), 0)
         timings['sampler'] = time.perf_counter() - t0
-        for bucket in self.prompt_buckets:
+        for bucket in self._rungs_run():
             # reserve spec_k headroom so the warmup spec_step below can
-            # write its window without outgrowing the throwaway table
+            # write its window without outgrowing the throwaway table (a
+            # window model's step writes one block)
             table = self.reserve_table(bucket, self.spec_k
-                                       if self.spec_enabled else 1)
+                                       if self.spec_enabled else self.window)
             t0 = time.perf_counter()
             tok = self.prefill([1] * bucket, table)
             timings[f'prefill_{bucket}'] = time.perf_counter() - t0
@@ -854,7 +1025,15 @@ class DecodeEngine:
             tokens = [tok] + [None] * (self.slots - 1)
             tables = [table] + [None] * (self.slots - 1)
             t0 = time.perf_counter()
-            self.decode_step(tokens, tables)
+            if self.window > 1:
+                # a denoising forward: the table keeps nothing of it
+                self.window_step(
+                    np.ones((self.slots, self.window), np.int64),
+                    np.ones((self.slots, self.window), bool),
+                    np.ones(self.slots, np.int64), tables,
+                    [False] * self.slots)
+            else:
+                self.decode_step(tokens, tables)
             timings.setdefault('decode_step',
                                time.perf_counter() - t0)
             if self.spec_enabled and not self._spec_compiled:

@@ -742,7 +742,8 @@ def prefill_coords(pool, table, bucket):
     return coords
 
 
-def decode_coords(pool, tables, context_lens, fed_counts=None, window=1):
+def decode_coords(pool, tables, context_lens, fed_counts=None, window=1,
+                  block=False):
     """What a decode program reads of the S slots, as host arrays whose
     shapes depend on S, ``window`` and the pool's geometry alone:
     ``block_tables`` (S, max_blocks_per_seq), ``context_lens`` (S,) and the
@@ -756,7 +757,12 @@ def decode_coords(pool, tables, context_lens, fed_counts=None, window=1):
     write to the scratch block (harmless by the masking contract above).
     ``context_lens[s]`` stays the extent of fed ROW 0; `paged_attention`'s
     multi-query form gives row j the causal staircase extent
-    context_lens + j."""
+    context_lens + j.
+
+    ``block`` is a window model's step (block diffusion): every live slot
+    feeds one whole block of ``window`` tokens, and ``context_lens[s]`` is
+    the extent EVERY row of it sees, the block included: the block is
+    written at positions context_lens[s]-window .. context_lens[s]-1."""
     if fed_counts is None:
         fed_counts = [1 if t is not None else 0 for t in tables]
     ids, offs, padded = [], [], []
@@ -766,7 +772,8 @@ def decode_coords(pool, tables, context_lens, fed_counts=None, window=1):
             offs.extend([0] * window)
             padded.append([SCRATCH_BLOCK] * pool.max_blocks_per_seq)
             continue
-        base = int(c) - 1          # first token written this step
+        # first token written this step
+        base = int(c) - (window if block else 1)
         for j in range(window):
             if j < int(f):
                 b, o = t.slot_for(base + j)
@@ -805,6 +812,16 @@ class CacheContext:
     the batch's live blocks (`paged_attention` over :meth:`live_blocks`) at
     fixed shape.
     K > 1 is the multi-token window :func:`decode_coords` describes.
+
+    A window model (block diffusion, models/block_diffusion_lm.py) passes
+    ``attend(..., block_len=B)``: k/v hold the model's key/value heads (G ≤
+    q's H; a pool row holds G heads), a prefill attends under the block
+    mask, and a decode step's K = B rows of a slot are one block, all at
+    the extent ``context_lens`` says (`paged_attention` with
+    ``block_window`` over :meth:`live_groups`). What such a step writes is
+    provisional: it is kept only if the engine moves the table's
+    ``context_len`` past it (engine.py::window_step), and the next forward
+    of the block writes the same positions again.
     """
 
     def __init__(self, pool, mode, coords, last=None):
@@ -816,6 +833,7 @@ class CacheContext:
         self.last = last
         self._layer = 0
         self._live = None          # `live_blocks`, once a layer asked
+        self._groups = None        # `live_groups`, once a layer asked
         self.stats = {}            # name -> [what a layer noted], `note`
 
     def note(self, name, value):
@@ -849,6 +867,17 @@ class CacheContext:
             self._live = list(live_block_list(
                 c['block_tables'], c['context_lens'], self.pool.block_size))
         return self._live
+
+    def live_groups(self):
+        """:meth:`live_blocks` for a window model's block read: the live
+        context in groups of whole blocks (ops/nn_ops.py::live_group_list),
+        made once a program and shared by its layers."""
+        if self._groups is None:
+            from ...ops.nn_ops import live_group_list
+            c = self.coords
+            self._groups = list(live_group_list(
+                c['block_tables'], c['context_lens'], self.pool.block_size))
+        return self._groups
 
     def _scale_inputs(self, layer):
         """Extra dispatch inputs for int8 pools ({} otherwise — the f32/bf16
@@ -921,7 +950,7 @@ class CacheContext:
         states[0] = state.value
         return out
 
-    def attend(self, q, k, v, sm_scale=1.0):
+    def attend(self, q, k, v, sm_scale=1.0, block_len=0):
         from ...dygraph.tape import Tensor, dispatch_op
         layer = self._layer
         self._layer += 1
@@ -935,8 +964,10 @@ class CacheContext:
             inputs = {'q': q, 'k': k, 'v': v, 'k_pages': k_pages,
                       'v_pages': v_pages, 'block_tables': c['block_tables']}
             inputs.update(self._scale_inputs(layer))
-            return dispatch_op('paged_prefill_attention', inputs,
-                               {'sm_scale': float(sm_scale)})
+            attrs = {'sm_scale': float(sm_scale)}
+            if block_len:
+                attrs['block_len'] = int(block_len)
+            return dispatch_op('paged_prefill_attention', inputs, attrs)
         s, h, k_w, d = kv.shape
         # (S, H, K, D) -> (H, S·K, D), slot-major, matching the flattened
         # write coordinates
@@ -950,6 +981,16 @@ class CacheContext:
                   'context_lens': c['context_lens']}
         inputs.update(self._scale_inputs(layer))
         attrs = {'sm_scale': float(sm_scale)}
+        if block_len:
+            if k_w != block_len:
+                raise ValueError(f'a window model steps whole blocks of '
+                                 f'{block_len} rows a slot, got {k_w}')
+            # the scope names the block read's device ops in a trace
+            with jax.named_scope('kv/block_read'):
+                return dispatch_op(
+                    'paged_attention',
+                    dict(inputs, q=q, live=self.live_groups()),
+                    dict(attrs, block_window=True, kv_heads=int(h)))
         if k_w > 1:
             # q stays rank-4 for the multi-query paged_attention read
             return dispatch_op('paged_attention', dict(inputs, q=q), attrs)

@@ -26,6 +26,16 @@ when the pool can't cover the next waiting request the scheduler simply
 keeps stepping until a finishing slot frees blocks (FIFO admission — no
 starvation of big requests behind small ones).
 
+**Window models** (block diffusion, ``engine.window`` B > 1: docs/SERVING.md
+"Window models"): a slot holds a BLOCK, not a next token: B ids, which of
+them are still masked, the positions a forward unmasks (``_blocks``,
+``_masked``, ``_quota``, one row a slot). A step (``_window_step``, the
+engine's ``window_step``) denoises the slots with masked positions and
+commits the others; a commit emits the block's tokens in position order
+(0 or up to B tokens a slot a step), then opens the next block or retires
+the request at the exact length asked for, cutting its last block. A model
+of window 1 takes none of this.
+
 Deadlines bound WAITING only: once a request holds a slot it runs to
 completion (aborting mid-generation would waste the prefill — the
 ROADMAP's preemption item is about checkpointed resume, not dropping
@@ -41,6 +51,8 @@ import threading
 import time
 import uuid
 
+import numpy as np
+
 from .. import metrics as _m
 from ... import observability as _obs
 from ...observability import distributed as _dobs
@@ -48,6 +60,7 @@ from ..breaker import CircuitBreaker
 from ..errors import (DeadlineExceeded, EngineClosed, EngineUnhealthy,
                       InvalidRequest, Overloaded, OutOfBlocks, ServingError)
 from ..batcher import DEFAULT_QUEUE_DEPTH
+from .diffusion import denoise_quota, validate_denoising_steps
 from .sampling import SamplingParams, TokenSampler
 
 __all__ = ['DecodeScheduler', 'GenerationStream']
@@ -156,11 +169,11 @@ class _Request:
                  'enqueued_at', 'table', 'next_token', 'generated',
                  'pending_prompt', 'prefilling', 'handoff_pending',
                  'sampling', 'sampler', 'history', 'trace', 'enqueued_perf',
-                 'handoff_t0')
+                 'handoff_t0', 'denoising_steps', 'block_from', 'block_t0')
 
     def __init__(self, prompt, max_new_tokens, eos_id, deadline,
                  replica_id=None, sampling=None, request_id=None,
-                 trace=None):
+                 trace=None, denoising_steps=None):
         self.prompt = prompt
         self.max_new_tokens = max_new_tokens
         self.eos_id = eos_id
@@ -175,6 +188,13 @@ class _Request:
         self.enqueued_at = time.monotonic()
         self.enqueued_perf = time.perf_counter()
         self.handoff_t0 = None
+        # a window model's request: denoising forwards a block; the first
+        # position of the open block that is the answer's (the prompt's
+        # tail comes before it in the first block); when the open block's
+        # first forward began (None: not yet)
+        self.denoising_steps = denoising_steps
+        self.block_from = 0
+        self.block_t0 = None
         self.table = None
         self.next_token = None        # sampled but not yet cached/emitted?
         self.generated = 0
@@ -212,11 +232,29 @@ class DecodeScheduler:
     def __init__(self, engine, queue_depth=DEFAULT_QUEUE_DEPTH,
                  admission='continuous', default_timeout_ms=None,
                  breaker_failures=None, breaker_reset_s=None, start=True,
-                 replica_id=None, disagg=None, drafter=None):
+                 replica_id=None, disagg=None, drafter=None,
+                 denoising_steps=None):
         if admission not in ('continuous', 'drain'):
             raise ValueError(f"admission must be 'continuous' or 'drain', "
                              f"got {admission!r}")
         self.engine = engine
+        # a window model (block diffusion): every slot's open block on the
+        # host, and the replica's default denoising steps a block (a
+        # request may ask for its own, 1..B; default B: one position a
+        # forward)
+        self._window = int(getattr(engine, 'window', 1))
+        self.denoising_steps = None
+        if self._window > 1:
+            self.denoising_steps = validate_denoising_steps(
+                self._window if denoising_steps is None else denoising_steps,
+                self._window)
+            self._blocks = np.zeros((engine.slots, self._window), np.int64)
+            self._masked = np.zeros((engine.slots, self._window), bool)
+            self._quota = np.zeros(engine.slots, np.int64)
+        elif denoising_steps is not None:
+            raise ValueError(
+                'denoising_steps is a window model\'s (block diffusion); '
+                'this engine\'s model generates one token a step')
         # speculative decoding (engine.spec_enabled): the engine owns the
         # batched (S, k) verify step; the scheduler owns the DRAFTER —
         # proposals are host-side policy. ``drafter`` may be a name
@@ -271,7 +309,8 @@ class DecodeScheduler:
 
     # -- client side -------------------------------------------------------
     def submit(self, prompt_ids, max_new_tokens=16, eos_id=None,
-               timeout_ms=None, sampling=None, request_id=None, trace=None):
+               timeout_ms=None, sampling=None, request_id=None, trace=None,
+               denoising_steps=None):
         """Validate and enqueue one generation; returns its
         :class:`GenerationStream`. Raises InvalidRequest / Overloaded /
         EngineUnhealthy (breaker open) / EngineClosed (all pre-enqueue).
@@ -283,7 +322,10 @@ class DecodeScheduler:
         exact token sequence (after a restart, on another replica, ...).
         ``trace``: optional :class:`observability.TraceContext` carried in
         from the HTTP front end — queue-wait/prefill/per-token spans of
-        this generation are recorded under it (docs/OBSERVABILITY.md)."""
+        this generation are recorded under it (docs/OBSERVABILITY.md).
+        ``denoising_steps``: a window model's alone (block diffusion): the
+        denoising forwards a block of this request takes, 1..B, the
+        replica's default where None; typed validation pre-enqueue."""
         if not self.breaker.allow():
             raise EngineUnhealthy('decode engine',
                                   self.breaker.consecutive_failures)
@@ -291,6 +333,8 @@ class DecodeScheduler:
             prompt, max_new = self.engine.validate(prompt_ids,
                                                    max_new_tokens)
             params = SamplingParams.validate(sampling)
+            denoising_steps = self._validate_denoising(denoising_steps,
+                                                       params)
             if request_id is not None:
                 request_id = str(request_id)
                 if not 0 < len(request_id) <= 128 or any(
@@ -308,7 +352,8 @@ class DecodeScheduler:
         req = _Request(prompt, max_new,
                        self.engine.eos_id if eos_id is None else eos_id,
                        deadline, replica_id=self.replica_id,
-                       sampling=params, request_id=request_id, trace=trace)
+                       sampling=params, request_id=request_id, trace=trace,
+                       denoising_steps=denoising_steps)
         if req.trace is not None:
             _m.trace_requests_sampled.inc()
         with self._cv:
@@ -323,6 +368,23 @@ class DecodeScheduler:
             _dobs.series('queue_depth').observe(len(self._waiting))
             self._cv.notify()
         return req.stream
+
+    def _validate_denoising(self, denoising_steps, params):
+        """A request's denoising steps (None on a model of window 1)."""
+        if self._window == 1:
+            if denoising_steps is not None:
+                raise InvalidRequest(
+                    'denoising_steps is a window model\'s (block '
+                    'diffusion); this replica\'s model generates one token '
+                    'a step')
+            return None
+        if not params.greedy:
+            raise InvalidRequest(
+                'a window model unmasks by confidence: temperature, top_k '
+                'and top_p have no meaning for it')
+        if denoising_steps is None:
+            return self.denoising_steps
+        return validate_denoising_steps(denoising_steps, self._window)
 
     def generate(self, prompt_ids, max_new_tokens=16, eos_id=None,
                  timeout_ms=None, result_timeout=120.0):
@@ -447,6 +509,8 @@ class DecodeScheduler:
             req.handoff_t0 = time.perf_counter()
             self.disagg.submit(req, req.prompt, req.max_new_tokens)
             return
+        if self._window > 1:
+            return self._prefill_window(req)
         t0 = time.perf_counter()
         try:
             if req.sampler is None:     # kwarg-free call: duck-typed
@@ -468,6 +532,40 @@ class DecodeScheduler:
         self._publish(req)
         self._emit_token(req, first)
         self._phase('emit', t1, time.perf_counter(), span=True)
+
+    def _prefill_window(self, req):
+        """A window model's admission: the prompt's whole blocks are
+        prefilled (nothing is picked) and the first block is opened with
+        the prompt's tail fixed in its first positions."""
+        t0 = time.perf_counter()
+        try:
+            self.engine.prefill(req.prompt, req.table)
+        except Exception as e:
+            self._fail_request(req, e)
+            self._record_engine_failure()
+            return
+        t1 = time.perf_counter()
+        self._engine_s += t1 - t0
+        self._trace_span(req, 'replica/prefill', t0, t1,
+                         prompt_len=len(req.prompt))
+        self._record_spans()
+        self.breaker.record_success()
+        tail = req.prompt[req.table.context_len:]
+        self._open_block(self._slots.index(req), req, tail)
+
+    def _open_block(self, slot, req, fixed=()):
+        """Slot ``slot``'s next block: ``fixed`` tokens (a prompt's tail, in
+        the first block alone) then masks, and the positions a denoising
+        forward of it unmasks."""
+        n = len(fixed)
+        self._blocks[slot, :n] = fixed
+        self._blocks[slot, n:] = self.engine.model.mask_token_id
+        self._masked[slot, :n] = False
+        self._masked[slot, n:] = True
+        self._quota[slot] = denoise_quota(self._window - n,
+                                          req.denoising_steps)
+        req.block_from = n
+        req.block_t0 = None
 
     def _drain_handoffs(self, timeout=0.0):
         """Apply finished prefill handoffs: inject the KV payload into the
@@ -630,6 +728,54 @@ class DecodeScheduler:
         self._phase('emit', t1, time.perf_counter(), span=True)
         return True
 
+    def _window_step(self):
+        """One lockstep step of a window model over the current slots: a
+        slot whose block still has masked positions denoises (the engine
+        unmasks its most confident ones in place), a slot whose block is
+        finished commits. A commit emits the block's answer tokens in
+        position order, up to the length asked for, and opens the next
+        block or retires the request; a denoising forward emits nothing."""
+        active = [r for r in self._slots if r is not None]
+        if not active:
+            return False
+        tables = [None if r is None else r.table for r in self._slots]
+        commits = [r is not None and not self._masked[i].any()
+                   for i, r in enumerate(self._slots)]
+        t0 = time.perf_counter()
+        for req in active:
+            if req.block_t0 is None:
+                req.block_t0 = t0
+        try:
+            self.engine.window_step(self._blocks, self._masked, self._quota,
+                                    tables, commits)
+        except Exception as e:
+            for req in active:      # isolate: fail the batch, keep serving
+                self._fail_request(req, e)
+            self._record_engine_failure()
+            return True
+        t1 = time.perf_counter()
+        self._engine_s += t1 - t0
+        self.breaker.record_success()
+        self._record_spans()
+        for i, req in enumerate(self._slots):
+            if req is None or not commits[i]:
+                continue
+            _m.decode_block_seconds.observe(t1 - req.block_t0)
+            first = req.generated
+            for token in self._blocks[i, req.block_from:].tolist():
+                self._emit_token(req, token)
+                if req.table is None:
+                    break             # retired (eos / budget) mid-block
+            _m.decode_diffusion_tokens_committed.inc(req.generated - first)
+            if req.trace is not None:
+                self._trace_span(req, 'replica/token', t0, t1,
+                                 index=req.generated - 1,
+                                 emitted=req.generated - first)
+            if req.table is not None:
+                self._open_block(i, req)
+        self._phase('emit', t1, time.perf_counter(), span=True)
+        return True
+
     def _spec_step(self):
         """One speculative (S, k) verify round (engine.spec_enabled).
 
@@ -788,7 +934,9 @@ class DecodeScheduler:
                             and all(r is None or r.handoff_pending
                                     for r in self._slots))
             self._drain_handoffs(0.01 if only_pending else 0.0)
-            if getattr(self.engine, 'spec_enabled', False):
+            if self._window > 1:
+                stepped = self._window_step()
+            elif getattr(self.engine, 'spec_enabled', False):
                 stepped = self._spec_step()
             else:
                 stepped = self._step()

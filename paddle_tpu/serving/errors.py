@@ -118,19 +118,29 @@ class UnsupportedCacheFeature(ServingError, ValueError):
     head axis (``kind`` 'latent'), and a retention layer no row per token at
     all but one recurrent state per request (``kind`` 'state'), which has no
     prefix to share, no blocks to hand off, no rollback for a speculative
-    window and no storage type but float32."""
+    window and no storage type but float32. A WINDOW model (``kind``
+    'window': block diffusion, models/block_diffusion_lm.py) caches [k, v]
+    rows, but reads them under the block mask and keeps a block's rows only
+    at its commit forward: the features that fill, share or verify rows
+    outside that step have no such path yet."""
 
     _WHY = {
         'latent': ('they read and write [k, v] pairs of per-head rows',
                    'Latent pool'),
         'state': ('the state cache holds one float32 recurrent state per '
                   'request, advanced in place: no row per token to share, '
-                  'hand off, quantize or roll back', 'Recurrent state')}
+                  'hand off, quantize or roll back', 'Recurrent state'),
+        'window': ('a window model reads its rows under the block mask and '
+                   'keeps a block\'s rows only at its commit forward, and '
+                   'these have no path under that mask yet',
+                   'Window models')}
 
     def __init__(self, features, kind):
         features = list(features)
         why, section = self._WHY.get(kind, self._WHY['latent'])
-        what = 'the state cache' if kind == 'state' else f'a {kind} KV cache'
+        what = {'state': 'the state cache',
+                'window': 'a window model\'s KV cache'}.get(
+                    kind, f'a {kind} KV cache')
         super().__init__(
             f'{", ".join(features)} cannot be used with {what}: {why} '
             f'(docs/SERVING.md "{section}")')

@@ -246,6 +246,25 @@ decode_state_tokens_folded = _LazyMetric(
     'counter', 'decode_state_tokens_folded',
     'prompt tokens a prefill folded into a recurrent state: prompt length '
     'x state layers, summed over prefills (a rung\'s padding not counted)')
+# block diffusion (a window model: serving/decode/engine.py::window_step)
+decode_diffusion_denoise_forwards = _LazyMetric(
+    'counter', 'decode_diffusion_denoise_forwards',
+    'live slot-forwards of a window model that denoised a block: the '
+    'block\'s B rows fed over the cache, positions unmasked from their '
+    'confidences, the K/V written not kept (idle slots not counted)')
+decode_diffusion_commit_forwards = _LazyMetric(
+    'counter', 'decode_diffusion_commit_forwards',
+    'live slot-forwards of a window model that committed a block: the '
+    'finished block fed once more, its K/V kept, its rows\' picks unused')
+decode_diffusion_tokens_committed = _LazyMetric(
+    'counter', 'decode_diffusion_tokens_committed',
+    'answer tokens emitted at a block\'s commit: the block\'s positions '
+    'less a prompt\'s tail in the first block and what the asked length '
+    'cuts off the last')
+decode_block_seconds = _LazyMetric(
+    'histogram', 'decode_block_seconds',
+    'wall seconds of one block of a window model, from the start of its '
+    'first denoising forward to the end of its commit forward')
 decode_scheduler_phase_seconds = _LazyMetric(
     'histogram', 'decode_scheduler_phase_seconds',
     'wall seconds of the scheduler worker thread per loop iteration (label '

@@ -64,7 +64,8 @@ _STATUS_BY_ERROR = ((InvalidRequest, 400), (Overloaded, 429),
 # client believes it set temperature)
 _SAMPLING_KEYS = frozenset(('temperature', 'top_k', 'top_p', 'seed'))
 _GENERATE_KEYS = frozenset(('prompt', 'max_new_tokens', 'eos_id', 'stream',
-                            'timeout_ms', 'request_id')) | _SAMPLING_KEYS
+                            'timeout_ms', 'request_id',
+                            'denoising_steps')) | _SAMPLING_KEYS
 
 
 class _Handler(BaseHTTPRequestHandler):
@@ -233,7 +234,13 @@ class _Handler(BaseHTTPRequestHandler):
             {"prompt": [token ids], "max_new_tokens": 16,
              "eos_id": optional, "stream": true, "timeout_ms": optional,
              "temperature": 0.0, "top_k": 0, "top_p": 1.0,
-             "seed": optional, "request_id": optional}
+             "seed": optional, "request_id": optional,
+             "denoising_steps": optional}
+
+        ``denoising_steps`` is a window model's alone (block diffusion,
+        docs/SERVING.md "Window models"): the denoising forwards a block of
+        this request takes, 1..B, the replica's default where absent; on
+        any other model it is a 400.
 
         Sampling keys are validated typed (serving/decode/sampling.py):
         a bad value OR an unknown body key is a 400 naming the field —
@@ -277,6 +284,10 @@ class _Handler(BaseHTTPRequestHandler):
         except ValueError as e:
             return self._error(400, InvalidRequest(str(e)))
         t0 = time.perf_counter()
+        # handed on only where the body has it: a generator that is not a
+        # DecodeScheduler need not know the key
+        extra = {'denoising_steps': payload['denoising_steps']} \
+            if 'denoising_steps' in payload else {}
         try:
             stream = srv.generator.submit(
                 prompt,
@@ -285,7 +296,7 @@ class _Handler(BaseHTTPRequestHandler):
                 timeout_ms=payload.get('timeout_ms'),
                 sampling=sampling or None,
                 request_id=payload.get('request_id'),
-                trace=trace)
+                trace=trace, **extra)
         except tuple(e for e, _ in _STATUS_BY_ERROR) as e:
             for etype, code in _STATUS_BY_ERROR:
                 if isinstance(e, etype):

@@ -132,9 +132,10 @@ class PrefillReplica:
     then free. One worker thread owns it (``LocalPrefillWorker``)."""
 
     def __init__(self, engine):
-        if engine.cache_kind != 'kv':
-            raise UnsupportedCacheFeature(['the disaggregated handoff'],
-                                          engine.cache_kind)
+        if engine.cache_kind != 'kv' or getattr(engine, 'window', 1) > 1:
+            raise UnsupportedCacheFeature(
+                ['the disaggregated handoff'],
+                engine.cache_kind if engine.cache_kind != 'kv' else 'window')
         self.engine = engine
 
     def prefill_to_payload(self, prompt, max_new_tokens=0):

@@ -60,10 +60,14 @@ def build_replica_stack(model=None, seed=DEFAULT_SEED, slots=2, block_size=4,
                         max_new_tokens_cap=16, prompt_buckets=None,
                         prefix_cache=None, disagg=None, queue_depth=64,
                         replica_id=None, model_lock=None, spec_decode=None,
-                        spec_k=None, drafter=None, kv_dtype=None):
+                        spec_k=None, drafter=None, kv_dtype=None,
+                        denoising_steps=None):
     """(engine, scheduler, prefill_worker|None) — the replica's serving
     stack minus the HTTP listener. ``prefix_cache``/``disagg`` default to
-    their env knobs, ``kv_dtype`` (handed to the engines) to its. Used by the CLI below and, in-process, by
+    their env knobs, ``kv_dtype`` (handed to the engines) to its.
+    ``denoising_steps`` is a window model's alone (block diffusion): the
+    replica's default denoising forwards a block, which a request may
+    override (docs/SERVING.md "Window models"). Used by the CLI below and, in-process, by
     tests/framework/test_serving_tier.py and benchmark/runners/
     (in-process multi-replica setups pass ONE shared ``model_lock`` so
     concurrent scheduler workers serialize their model calls)."""
@@ -98,7 +102,8 @@ def build_replica_stack(model=None, seed=DEFAULT_SEED, slots=2, block_size=4,
         worker = LocalPrefillWorker([PrefillReplica(prefill_engine)])
     scheduler = DecodeScheduler(engine, queue_depth=queue_depth,
                                 replica_id=replica_id, disagg=worker,
-                                drafter=drafter)
+                                drafter=drafter,
+                                denoising_steps=denoising_steps)
     return engine, scheduler, worker
 
 

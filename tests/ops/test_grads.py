@@ -682,6 +682,10 @@ NONDIFF = {
         'the weights\' gradient belongs to the training of the block '
         '(ROADMAP R5); forward tested in tests/framework/'
         'test_latent_moe_lm.py',
+    'diffusion_pick':
+        'integer token ids and the probability of an argmax, read by the '
+        'host\'s unmasking schedule (serving/decode/diffusion.py); '
+        'inference-only; forward tested in tests/ops/test_block_read.py',
     'mla_decode_attention':
         'inference-only absorbed read of the paged latent cache '
         '(serving/decode/); training gradients flow through '
