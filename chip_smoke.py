@@ -472,12 +472,93 @@ def serve_phase(counter, cfg=None, slots=16, block_size=16, max_blocks=512,
 
 # -- kernels -----------------------------------------------------------------
 
+def experts_check(experts, calls, tol, seed=11):
+    """`moe_experts` at the routed cells' sizes (no routed model is served
+    here): ``experts`` (E, h, f) in bf16, and per ``calls`` (tokens, top_k)
+    the op under the caller's scope `moe/experts`, compiled for this
+    backend and run, against a per-expert float32 numpy loop at ``tol`` of
+    the output scale (bf16 operands, the hidden activations and the result
+    rounded to bf16). Where the op's predicate holds (a TPU) the compiled
+    program must hold no `ragged-dot` custom call and two Mosaic custom
+    calls, both with the scope in their `op_name` (ops/pallas_moe.py: gate
+    and up in one pass, then down). Returns (path, {call: error})."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import llm_ops
+    from paddle_tpu.ops.pallas_moe import kernel_op_names
+
+    n_experts, h, f = experts
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    gate, up, down = (
+        (jax.random.normal(key, shape, jnp.float32) * 0.05).astype(
+            jnp.bfloat16)
+        for key, shape in zip(keys, ((n_experts, h, f), (n_experts, h, f),
+                                     (n_experts, f, h))))
+    gate32, up32, down32 = (np.asarray(w, np.float32)
+                            for w in (gate, up, down))
+
+    # as a model's forward reaches the op: a jit of its own (every dispatch
+    # is one) under the caller's scope, inside the program's jit. Bound in
+    # the outermost jit itself, a pallas_call is lowered under the bare name
+    # `pallas_call` once core/compile_cache.py has turned jax's full
+    # tracebacks in locations off (PERF.md section 6, PR 33)
+    op = jax.jit(llm_ops.moe_experts)
+
+    def scoped(*args):
+        with jax.named_scope('moe/experts'):
+            return op(*args)
+
+    rng = np.random.RandomState(seed)
+    kernel = llm_ops.experts_kernel_applies(gate, gate)
+    errs = {}
+    for tokens, top_k in calls:
+        x = jnp.asarray(rng.randn(tokens, h), jnp.bfloat16)
+        # a router's imbalance: some experts drawn several times as often
+        p = np.exp(0.45 * rng.randn(n_experts))
+        ids = np.stack([rng.choice(n_experts, top_k, replace=False,
+                                   p=p / p.sum()) for _ in range(tokens)])
+        weights = rng.rand(tokens, top_k).astype('float32')
+        args = (x, jnp.asarray(ids, jnp.int32), jnp.asarray(weights), gate,
+                up, down)
+        compiled = jax.jit(scoped).lower(*args).compile()
+        if kernel:
+            text = compiled.as_text()
+            assert 'ragged-dot' not in text and 'ragged_dot' not in text
+            names = kernel_op_names(text)
+            assert len(names) == 2 and all('/moe/experts/' in n
+                                           for n in names), names
+        out, counts = compiled(*args)
+        counts = np.asarray(counts)
+        assert counts.tolist() == np.bincount(
+            ids.ravel(), minlength=n_experts).tolist()
+        x32 = np.asarray(x, np.float32)
+        want = np.zeros((tokens, h), np.float32)
+        for e in np.flatnonzero(counts):
+            rows, slot = np.nonzero(ids == e)
+            g, u = x32[rows] @ gate32[e], x32[rows] @ up32[e]
+            want[rows] += weights[rows, slot][:, None] * (
+                (g / (1.0 + np.exp(-g)) * u) @ down32[e])
+        got = np.asarray(out, np.float32)
+        assert got.shape == (tokens, h) and np.isfinite(got).all()
+        err = float(np.abs(got - want).max() / np.abs(want).max())
+        assert err <= tol, (tokens, top_k, err, tol)
+        errs[f'{tokens}x{top_k}'] = err
+    return 'pallas grouped matmul' if kernel else 'ragged_dot', errs
+
+
 def kernels_phase(fused_shape=(8, 12, 512, 64), paged_slots=32,
                   block_size=16, pages_per_seq=12, num_blocks=512,
                   paged_cases=((4, 128, 'f32'), (12, 64, 'bf16'),
                                (12, 64, 'int8'), (5, 64, 'f32')),
+                  experts=(128, 2048, 768),
+                  expert_calls=((128, 6), (512, 8), (1024, 6)),
                   tol=2e-2, paged_tol=1e-4):
-    """The attention paths the served model does not reach.
+    """The kernels and attention paths the served model does not reach.
+
+    `moe_experts` at the two routed cells' decode steps (128 tokens of
+    top-6, 512 of top-8) and a prefill rung's 6,144 assignments, over 128
+    experts of 2,048 × 768: :func:`experts_check`.
 
     `fused_attention` is off TransformerLM's path (use_fused_attention is
     False): one bf16 forward+backward through dispatch_op compiles its TPU
@@ -584,9 +665,16 @@ def kernels_phase(fused_shape=(8, 12, 512, 64), paged_slots=32,
                    f"share of scale (tolerance {paged_tol:g}): "
                    + ', '.join(f'{name} {e:.2e}'
                                for name, e in paged_err.items()))
-    return {'fused_attention': fused_path,
+    experts_path, experts_err = experts_check(experts, expert_calls, tol)
+    say('kernels', f"ok moe_experts, {experts[0]} experts of {experts[1]} x "
+                   f"{experts[2]} bf16, tokens x top_k: path {experts_path}"
+                   "; vs a per-expert numpy loop as a share of scale "
+                   f"(tolerance {tol:g}): "
+                   + ', '.join(f'{name} {e:.2e}'
+                               for name, e in experts_err.items()))
+    return {'fused_attention': fused_path, 'moe_experts': experts_path,
             'err': {'fused_fwd': err, 'fused_grad': gerr,
-                    'paged': paged_err}}
+                    'paged': paged_err, 'experts': experts_err}}
 
 
 # -- static ------------------------------------------------------------------

@@ -1,6 +1,8 @@
 """The modern decoder block as ops (ROADMAP R2): RMSNorm, rotary positions,
 the gated feed-forward, a router (sigmoid scores with bias-corrected top-k,
-or softmax scores) and a grouped expert feed-forward that drops no token,
+or softmax scores) and a grouped expert feed-forward that drops no token
+(on a TPU the pallas grouped matmul of ops/pallas_moe.py, the one op here
+with a kernel and a predicate beside its call, `experts_kernel_applies`),
 latent (MLA) attention in its two forms (expanded over a prompt, absorbed
 over the paged latent cache), power retention, and a block-diffusion
 forward's pick (a token and the confidence in it per row).
@@ -26,6 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..core.places import on_tpu
+from .pallas_moe import expert_ffn
 from .registry import register_op
 
 _F32 = jnp.float32
@@ -142,13 +146,32 @@ def moe_router(x, w_gate, bias=None, *, top_k, routed_scaling_factor=1.0,
     return ids.astype(jnp.int32), weights * routed_scaling_factor
 
 
+def experts_kernel_applies(x, w_gate):
+    """True when `moe_experts` runs the pallas grouped matmul
+    (ops/pallas_moe.py) for ``x`` and the experts' weights (arrays or
+    ShapeDtypeStructs): a TPU backend, and bf16 or float32 operands of one
+    dtype. The repo's one convention (ops/nn_ops.py, "explicit kernel
+    dispatch"): where it holds the kernel runs, at every row count (the
+    sorted rows are padded to whole tiles), and a Mosaic refusal is an
+    error; elsewhere the `lax.ragged_dot` formulation runs because the code
+    says so (the CPU tests, the gradients)."""
+    return (on_tpu() and x.dtype == w_gate.dtype
+            and x.dtype in (jnp.bfloat16, jnp.float32))
+
+
 @register_op('moe_experts', outputs=('Out', 'Counts'))
 def moe_experts(x, ids, weights, w_gate, w_up, w_down):
     """Σ_k weights[t, k] · E_ids[t, k](x[t]), each expert a gated
-    feed-forward, as ONE grouped matmul per projection over the T·k
-    assignments sorted by expert (`lax.ragged_dot`: the TPU's ragged-dot
-    kernel reads an expert's weights once, for the rows routed to it). No
-    capacity: every assignment is computed.
+    feed-forward, as grouped matmuls over the T·k assignments sorted by
+    expert. No capacity: every assignment is computed.
+
+    On a TPU (`experts_kernel_applies`) the grouped matmuls are the pallas
+    kernel of ops/pallas_moe.py, gate and up in one pass with `silu(g) · u`
+    in its epilogue, then down: an expert's weights are read once and whole
+    for the rows routed to it. Elsewhere one `lax.ragged_dot` a projection.
+    The same precision in both: operands as stored, float32 accumulation,
+    `silu(g) · u` in float32 and cast to x's dtype, the weighted sum in
+    float32.
 
     x (T, h); ids (T, k) int32; weights (T, k) float32; w_gate, w_up
     (E, h, f); w_down (E, f, h). Returns the (T, h) sum and the (E,) int32
@@ -160,11 +183,14 @@ def moe_experts(x, ids, weights, w_gate, w_up, w_down):
     counts = (flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype)
               ).sum(0, dtype=jnp.int32)
     order = jnp.argsort(flat, stable=True)       # assignments by expert
-    rows = x[order // k]                         # (T·k, h)
-    g = lax.ragged_dot(rows, w_gate, counts, preferred_element_type=_F32)
-    u = lax.ragged_dot(rows, w_up, counts, preferred_element_type=_F32)
-    y = lax.ragged_dot((jax.nn.silu(g) * u).astype(x.dtype), w_down,
-                       counts, preferred_element_type=_F32)
+    if experts_kernel_applies(x, w_gate):
+        y = expert_ffn(x, order // k, counts, w_gate, w_up, w_down)
+    else:
+        rows = x[order // k]                     # (T·k, h)
+        g = lax.ragged_dot(rows, w_gate, counts, preferred_element_type=_F32)
+        u = lax.ragged_dot(rows, w_up, counts, preferred_element_type=_F32)
+        y = lax.ragged_dot((jax.nn.silu(g) * u).astype(x.dtype), w_down,
+                           counts, preferred_element_type=_F32)
     y = y[jnp.argsort(order)].reshape(t, k, -1)  # back to token order
     out = (y * weights[..., None]).sum(1)
     return out.astype(x.dtype), counts

@@ -84,8 +84,11 @@ def test_kernels_phase_tiny():
     # 320-wide row in 384 lanes), over 9 × 30 = 270 table entries
     out = chip_smoke.kernels_phase(
         fused_shape=(1, 2, 128, 16), paged_slots=9, block_size=4,
-        pages_per_seq=30, num_blocks=300)
+        pages_per_seq=30, num_blocks=300, experts=(8, 32, 16),
+        expert_calls=((16, 2), (40, 3)))
     assert out['fused_attention'] == 'XLA'
+    assert out['moe_experts'] == 'ragged_dot'
+    assert set(out['err']['experts']) == {'16x2', '40x3'}
     assert set(out['err']['paged']) == {'4x128_f32', '12x64_bf16',
                                         '12x64_int8', '5x64_f32'}
     assert max(out['err']['paged'].values()) < 1e-5

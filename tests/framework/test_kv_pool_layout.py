@@ -368,3 +368,58 @@ def test_compiled_for_the_chip_no_program_moves_a_state_array(v5e):
             # no gathered copy of the S slots' rows either
             assert 'f32[6,8,8328,128]' not in text
             assert 'f32[6,8,8256,128]' not in text
+
+
+def test_compiled_for_the_chip_the_experts_run_the_grouped_kernel(
+        v5e, monkeypatch):
+    """A routed model's step and a prefill rung, compiled for the chip with
+    `moe_experts`'s predicate holding as it does there: no `ragged-dot`
+    custom call is left, and each expert layer holds the kernel's two
+    Mosaic custom calls (gate and up in one pass, then down:
+    ops/pallas_moe.py) with the caller's scope `moe/experts` in their
+    `op_name`, which benchmark/lib/scoped_ops.py sums device time by. The
+    step's 4 × 2 assignments and the rung's 128 × 2 are padded to whole
+    row tiles (compiled here: no chip, no time). Shapes alone."""
+    from paddle_tpu.core.random import default_generator
+    from paddle_tpu.models.latent_moe_lm import LatentMoEConfig, LatentMoELM
+    from paddle_tpu.ops import llm_ops
+    from paddle_tpu.ops.pallas_moe import kernel_op_names
+    from paddle_tpu.serving.decode.kv_cache import (BlockTable,
+                                                    prefill_coords)
+    monkeypatch.setattr(llm_ops, 'on_tpu', lambda: True)
+    made = {}
+
+    def init(key):
+        with default_generator.bind_base(key):
+            made['model'] = LatentMoELM(LatentMoEConfig.tiny(
+                hidden_size=256, moe_intermediate_size=128,
+                max_position_embeddings=256, dtype='bfloat16'))
+        return {n: p.value for n, p in made['model'].named_parameters()}
+
+    with dygraph.guard():
+        default_generator.seed(3)
+        shapes = jax.eval_shape(init, default_generator.base_key())
+        model = made['model']
+        model.eval()
+        for name, p in model.named_parameters():
+            p.value = shapes[name]
+        eng = DecodeEngine(model, slots=4, block_size=16, max_blocks=64,
+                           max_prompt_len=128, max_new_tokens_cap=64,
+                           prompt_buckets=[128], prefix_cache=False,
+                           kv_dtype='bf16')
+        pool, prog = eng.pool, eng._program
+        out = jax.eval_shape(
+            lambda pv, *rest: prog.jitted('prefill', pool.geometry, pv, {},
+                                          {}, {}, *rest),
+            {n: p.value for n, p in prog._params.items()},
+            np.zeros((1, 128), np.int64), None,
+            prefill_coords(pool, BlockTable([], 16), 128), np.int32(0))
+        pool.adopt({k: list(v) for k, v in out[3].items()}, {})
+        expert_layers = model.cfg.num_hidden_layers \
+            - model.cfg.first_k_dense_replace
+        for bucket in (None, 128):
+            text = eng.lowered(bucket, v5e).compile().as_text()
+            assert 'ragged-dot' not in text and 'ragged_dot' not in text
+            kernels = kernel_op_names(text)
+            assert len(kernels) == 2 * expert_layers, kernels
+            assert all('/moe/experts/' in name for name in kernels), kernels

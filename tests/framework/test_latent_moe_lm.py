@@ -622,8 +622,11 @@ def test_the_scopes_reach_the_compiled_programs_op_names(lm):
         None).compile().as_text()
     engine.release_table(table)
     names = set(re.findall(r'op_name="([^"]*)"', text))
-    # (the CPU lowers ragged_dot to a masked product: its sort is the witness;
-    # compiled for the TPU the three `ragged-dot` custom calls carry the scope)
+    # (the CPU runs the op's ragged_dot formulation, lowered to a masked
+    # product: its sort is the witness; compiled for the TPU the experts are
+    # two pallas custom calls a layer that carry the scope:
+    # test_kv_pool_layout.py::
+    # test_compiled_for_the_chip_the_experts_run_the_grouped_kernel)
     for scope, op in (('moe/experts', 'sort'),
                       ('moe/shared', 'dot_general'),
                       ('moe/route', 'top_k'),
