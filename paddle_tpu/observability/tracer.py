@@ -8,13 +8,27 @@ the tree. Unlike profiler.start_profiler this does not touch jax.profiler:
 it works on any backend, costs two perf_counter() calls per span, and the
 output is a single self-contained JSON file.
 
-The event buffer is bounded (PADDLE_TPU_TRACE_MAX_EVENTS, default 100000);
-past the bound new events are dropped and counted, never silently lost.
+The event buffer is bounded (PADDLE_TPU_TRACE_MAX_EVENTS, a count of
+events, default 100000); past the bound new events are dropped and counted,
+never silently lost (`dropped`, `otherData.dropped_events` of a snapshot: a
+trace with a count above 0 covers the time before the buffer filled, not the
+run). An event is kept as a compact record, the tuple
+``(name, start, end, tid, args)`` of the caller's own perf_counter stamps
+and its args dict as handed over, and becomes the chrome-trace dict in
+`snapshot()` only: recording builds no dict and converts no arg. A record
+takes about 170 bytes without args, 380 with one and 520 with six (the
+tuple, two floats, the thread id, the args dict; names are interned;
+tracemalloc over 100,000 events), so a full buffer at the default bound
+holds 17-52 MB. A traced decode server records a few thousand events a
+second (one `replica/token` a traced request and token): the default bound
+holds about half a minute of that, and a longer traced window needs the
+bound raised (the benchmark's harness sets 2,000,000).
 """
 from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 
@@ -42,7 +56,7 @@ class Span:
     def __exit__(self, exc_type, exc, tb):
         end = time.perf_counter()
         self.duration = end - self.start
-        self._tracer._exit(self, exc_type)
+        self._tracer._exit(self, end, exc_type)
         return False
 
 
@@ -84,63 +98,44 @@ class StepTracer:
         self._local.depth = depth + 1
         return depth
 
-    def _exit(self, span, exc_type):
+    def _exit(self, span, end, exc_type):
         self._local.depth = span._depth
-        ev = {
-            'name': span.name,
-            'ph': 'X',
-            'ts': (span.start - self._epoch) * 1e6,      # µs, trace-relative
-            'dur': span.duration * 1e6,
-            'pid': os.getpid(),
-            'tid': threading.get_ident(),
-        }
         args = span.args
         if exc_type is not None:
             args = dict(args or {}, error=exc_type.__name__)
-        if args:
-            ev['args'] = {k: _jsonable(v) for k, v in args.items()}
+        self._keep(span.name, span.start, end, args)
+
+    def _keep(self, name, start, end, args):
+        """One compact record (``end`` None: an instant) into the bounded
+        buffer, on the calling thread, which is the thread it describes."""
+        record = (sys.intern(name), start, end, threading.get_ident(),
+                  args or None)
         with self._lock:
             if len(self._events) >= self.max_events:
                 self.dropped += 1
             else:
-                self._events.append(ev)
+                self._events.append(record)
 
     def complete(self, name, start_perf, end_perf, **args):
         """Append one already-measured complete event (ph 'X') from
         explicit perf_counter stamps — distributed trace spans are often
         measured retroactively (queue wait is known only at admission),
         so they can't ride the context-manager path."""
-        ev = {'name': name, 'ph': 'X',
-              'ts': (start_perf - self._epoch) * 1e6,
-              'dur': max(0.0, end_perf - start_perf) * 1e6,
-              'pid': os.getpid(), 'tid': threading.get_ident()}
-        if args:
-            ev['args'] = {k: _jsonable(v) for k, v in args.items()}
-        with self._lock:
-            if len(self._events) >= self.max_events:
-                self.dropped += 1
-            else:
-                self._events.append(ev)
+        self._keep(name, start_perf, max(start_perf, end_perf), args)
 
     def instant(self, name, **args):
         """Zero-duration marker (ph 'i') — e.g. a nonfinite detection."""
-        ev = {'name': name, 'ph': 'i', 's': 't',
-              'ts': (time.perf_counter() - self._epoch) * 1e6,
-              'pid': os.getpid(), 'tid': threading.get_ident()}
-        if args:
-            ev['args'] = {k: _jsonable(v) for k, v in args.items()}
-        with self._lock:
-            if len(self._events) >= self.max_events:
-                self.dropped += 1
-            else:
-                self._events.append(ev)
+        self._keep(name, time.perf_counter(), None, args)
 
     # -- export ------------------------------------------------------------
     def snapshot(self):
         with self._lock:
-            events = list(self._events)
+            records = list(self._events)
             dropped = self.dropped
-        return {'traceEvents': events, 'displayTimeUnit': 'ms',
+            epoch = self._epoch
+        pid = os.getpid()
+        return {'traceEvents': [_event(r, epoch, pid) for r in records],
+                'displayTimeUnit': 'ms',
                 'otherData': {'producer': 'paddle_tpu.observability',
                               'dropped_events': dropped}}
 
@@ -163,6 +158,21 @@ class StepTracer:
     def __len__(self):
         with self._lock:
             return len(self._events)
+
+
+def _event(record, epoch, pid):
+    """The chrome-trace dict of one compact record."""
+    name, start, end, tid, args = record
+    ts = (start - epoch) * 1e6                  # µs, trace-relative
+    if end is None:
+        ev = {'name': name, 'ph': 'i', 's': 't', 'ts': ts,
+              'pid': pid, 'tid': tid}
+    else:
+        ev = {'name': name, 'ph': 'X', 'ts': ts, 'dur': (end - start) * 1e6,
+              'pid': pid, 'tid': tid}
+    if args:
+        ev['args'] = {k: _jsonable(v) for k, v in args.items()}
+    return ev
 
 
 def _jsonable(v):

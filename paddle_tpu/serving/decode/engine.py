@@ -266,27 +266,46 @@ class _CallClock:
     the rows where the call asked for them), sample (what the host still
     does for the pick: an ``int()``, or the request's sampler on its row).
 
-    ``record`` is the one place the stamps are read: always one
-    observation per phase into ``decode_engine_phase_seconds``, and with
-    telemetry on the ``engine/<call>`` span and its ``engine/<call>/<phase>``
-    children from the same stamps. The span's args carry the call's ``work``
-    (expert assignments, experts touched, context positions and cache blocks
-    read: what the counters were given for this call; ``rows_fetched`` 1
-    where the rows crossed to the host, 0 where the picks alone did), so
-    that a trace reader can set the device time of a slice against the work
-    of the calls in it. O(1) per call."""
+    Beside every perf_counter stamp stands one of the thread-CPU clock
+    (``time.thread_time``, which counts the calling thread only while it
+    runs): a phase's wall less its CPU is the time the thread was off the
+    CPU in it, waiting for the interpreter lock, for the device or in a
+    blocking call. In ``pack`` and ``sample``, which never block of
+    themselves, that is the wait for the interpreter lock.
 
-    __slots__ = ('call', 'start', 'last', 'ends', 'work')
+    ``record`` is the one place the stamps are read: always one
+    observation per phase into ``decode_engine_phase_seconds`` and its CPU
+    seconds into ``decode_engine_phase_cpu_seconds``, and with telemetry on
+    the ``engine/<call>`` span and its ``engine/<call>/<phase>`` children
+    from the wall stamps (the CPU seconds are read as sums over a window,
+    never call by call: where the host's thread clock advances in ticks of
+    10 ms one call's reading is 0 or a tick). The span's args carry the call's
+    ``work`` (expert assignments, experts touched, context positions and
+    cache blocks read: what the counters were given for this call;
+    ``rows_fetched`` 1 where the rows crossed to the host, 0 where the picks
+    alone did), so that a trace reader can set the device time of a slice
+    against the work of the calls in it. O(1) per call.
+
+    The engine keeps its newest clock as ``DecodeEngine.last_call``: the
+    scheduler reads where the call's phases began and ended from it, and
+    books what lies between them and its own as ``book``."""
+
+    __slots__ = ('call', 'start', 'start_cpu', 'last', 'last_cpu', 'ends',
+                 'cpu_ends', 'work')
 
     def __init__(self, call):
         self.call = call
         self.ends = []                  # [(phase, perf_counter at its end)]
+        self.cpu_ends = []              # [thread_time at its end], beside
         self.work = {}                  # args of the call's span: its work
         self.start = self.last = time.perf_counter()
+        self.start_cpu = self.last_cpu = time.thread_time()
 
     def end(self, phase):
         self.last = time.perf_counter()
+        self.last_cpu = time.thread_time()
         self.ends.append((phase, self.last))
+        self.cpu_ends.append(self.last_cpu)
         return self.last
 
     def fetch(self, picks, counts=None, rows=None):
@@ -313,14 +332,16 @@ class _CallClock:
 
     def record(self, **args):
         hist = _m.decode_engine_phase_seconds
+        cpu_s = _m.decode_engine_phase_cpu_seconds
         spans = _obs._ENABLED
         name = 'engine/' + self.call
-        t = self.start
-        for phase, end in self.ends:
+        t, c = self.start, self.start_cpu
+        for (phase, end), cpu in zip(self.ends, self.cpu_ends):
             hist.labels(call=self.call, phase=phase).observe(end - t)
+            cpu_s.labels(call=self.call, phase=phase).inc(cpu - c)
             if spans:
                 _obs.tracer.complete(f'{name}/{phase}', t, end)
-            t = end
+            t, c = end, cpu
         if spans:
             _obs.tracer.complete(name, self.start, self.last, **self.work,
                                  **args)
@@ -372,6 +393,9 @@ class DecodeEngine:
         # arrays, read by whoever asks: nothing is copied on the served
         # path): ``expert_ids`` says which experts made the rows just read
         self.last_stats = {}
+        # the newest call's clock (`_CallClock`): where its phases began and
+        # ended, wall and thread-CPU, for the scheduler's bookkeeping leaf
+        self.last_call = None
         self.slots = int(slots or DEFAULT_SLOTS)
         self.max_prompt_len = int(max_prompt_len)
         self.max_new_tokens_cap = int(max_new_tokens_cap)
@@ -670,7 +694,7 @@ class DecodeEngine:
         the host for it). Sets ``table.context_len = len(prompt)``."""
         if self.window > 1:
             return self._prefill_window(prompt, table, sampler)
-        clock = _CallClock('prefill')
+        clock = self.last_call = _CallClock('prefill')
         P = len(prompt)
         bucket = next(b for b in self.prompt_buckets if P <= b)
         ids = np.zeros((1, bucket), np.int64)
@@ -712,7 +736,7 @@ class DecodeEngine:
         if sampler is not None:
             raise InvalidRequest(
                 'a window model picks by confidence and takes no sampler')
-        clock = _CallClock('prefill')
+        clock = self.last_call = _CallClock('prefill')
         P = len(prompt)
         n = P // self.window * self.window
         table.context_len = n
@@ -745,7 +769,7 @@ class DecodeEngine:
         host too (``(ids, rows)``) so the scheduler can sample non-greedy
         slots — the same executable runs and the ids are the argmax of
         those same rows, so requesting rows changes no bits."""
-        clock = _CallClock('step')
+        clock = self.last_call = _CallClock('step')
         S = self.slots
         assert len(tokens) == S and len(tables) == S
         ids = np.zeros((S, 1), np.int64)
@@ -856,7 +880,7 @@ class DecodeEngine:
         each denoising slot to their picks. Returns ``(ids, confidences)``
         (garbage on inactive slots; of a commit forward nobody's concern),
         and with ``return_rows=True`` the (S, B, V) rows too, for a check."""
-        clock = _CallClock('step')
+        clock = self.last_call = _CallClock('step')
         S, B = self.slots, self.window
         assert B > 1 and len(tables) == S and len(commits) == S
         live = np.asarray([t is not None for t in tables])
@@ -911,7 +935,7 @@ class DecodeEngine:
         tests/framework/test_spec_decode.py asserts equal token streams
         across ragged accept lengths). Padded lanes (j >= f)
         are garbage on scratch reads and must be ignored."""
-        clock = _CallClock('spec_step')
+        clock = self.last_call = _CallClock('spec_step')
         S, K = self.slots, self.spec_k
         assert len(token_lists) == S and len(tables) == S
         ids = np.zeros((S, K), np.int64)

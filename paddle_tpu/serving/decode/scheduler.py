@@ -220,6 +220,12 @@ class _Request:
         return self.deadline is not None and now > self.deadline
 
 
+def _now():
+    """(perf_counter, thread_time): the wall clock and, beside it, the
+    calling thread's CPU clock, which runs only while the thread does."""
+    return time.perf_counter(), time.thread_time()
+
+
 class DecodeScheduler:
     """Continuous-batching front end over a :class:`DecodeEngine`.
 
@@ -293,10 +299,15 @@ class DecodeScheduler:
         self._waiting = collections.deque()
         self._slots = [None] * engine.slots      # _Request | None
         # worker-owned: spans of traced requests since the last batch
-        # (_trace_span / _record_spans), seconds inside engine calls this
-        # loop iteration (_worker_loop)
+        # (_trace_span / _record_spans); wall and thread-CPU seconds inside
+        # engine calls this loop iteration (_run_cycles); the stamps (wall,
+        # CPU) at which the last leaf of the thread's time ended, and the
+        # iteration's stretches between leaves, (start, end, CPU seconds):
+        # its bookkeeping (_leaf_begins)
         self._spans = []
-        self._engine_s = 0.0
+        self._engine_s = self._engine_cpu = 0.0
+        self._leaf = (0.0, 0.0)          # the worker stamps it as it starts
+        self._book = []
         self._cv = threading.Condition()
         self._closing = False
         self._abort = False
@@ -473,14 +484,70 @@ class DecodeScheduler:
                    replica_id=self.replica_id))
              for req, name, start, end, args in noted])
 
-    def _phase(self, phase, start, end, span=False, **args):
-        """One observation of a worker-thread phase and, where ``span`` says
-        so and telemetry is on, its ``scheduler/<phase>`` span from the same
+    def _phase(self, phase, wall, cpu, start=None, **args):
+        """One observation of a worker-thread phase, wall and thread-CPU
+        seconds, and, where ``start`` (the stamp it began at) is given and
+        telemetry is on, its ``scheduler/<phase>`` span from the wall
         stamps."""
-        _m.decode_scheduler_phase_seconds.labels(phase=phase).observe(
-            end - start)
-        if span and _obs._ENABLED:
-            _obs.tracer.complete('scheduler/' + phase, start, end, **args)
+        _m.decode_scheduler_phase_seconds.labels(phase=phase).observe(wall)
+        _m.decode_scheduler_phase_cpu_seconds.labels(phase=phase).inc(cpu)
+        if start is not None and _obs._ENABLED:
+            _obs.tracer.complete('scheduler/' + phase, start, start + wall,
+                                 **args)
+
+    def _leaf_begins(self, wall, cpu):
+        """A leaf of the worker thread's time (admit, an engine call's
+        phases, emit, a wait) begins at these stamps: what lies between the
+        last leaf's end and it is the thread's bookkeeping, one stretch of
+        the iteration's ``book``."""
+        last, last_cpu = self._leaf
+        if wall > last:
+            self._book.append((last, wall, cpu - last_cpu))
+
+    def _engine_returned(self, t0):
+        """An engine call that began at ``t0`` has returned: its seconds go
+        to the iteration's ``engine`` time, and the thread's last leaf now
+        ends here, where an ``emit`` begins (`_emitted`). The call's own
+        phases began after ``t0`` and ended before now
+        (``engine.last_call``): the entry before them and the call's record
+        and gauges after them are bookkeeping. The thread-CPU clock is not
+        read before the call (the call's own clock reads it a few
+        microseconds later, and a read is a system call): the ``engine`` CPU
+        seconds run from the call's first stamp. A duck-typed engine, or a
+        call that kept no clock, is one leaf whole from ``t0``, its CPU
+        counted from the last leaf's end. Returns now."""
+        t1, cpu1 = _now()
+        clock = getattr(self.engine, 'last_call', None)
+        if clock is None or clock.start < t0:
+            cpu0 = self._leaf[1]
+            self._leaf_begins(t0, cpu0)
+        else:
+            cpu0 = clock.start_cpu
+            self._leaf_begins(clock.start, cpu0)
+            self._leaf = (clock.last, clock.last_cpu)
+            self._leaf_begins(t1, cpu1)
+        self._engine_s += t1 - t0
+        self._engine_cpu += cpu1 - cpu0
+        self._leaf = (t1, cpu1)
+        return t1
+
+    def _emitted(self):
+        """Closes the ``emit`` that began where the last engine call
+        returned."""
+        t1, cpu1 = self._leaf
+        self._leaf = _now()
+        self._phase('emit', self._leaf[0] - t1, self._leaf[1] - cpu1,
+                    start=t1)
+
+    def _blocked(self, wait, *args):
+        """``wait(*args)``, a blocking call of the idle worker, as its
+        ``wait`` leaf."""
+        t0, cpu0 = _now()
+        self._leaf_begins(t0, cpu0)
+        out = wait(*args)
+        self._leaf = _now()
+        self._phase('wait', self._leaf[0] - t0, self._leaf[1] - cpu0)
+        return out
 
     def _prefill(self, req):
         now = time.perf_counter()
@@ -523,15 +590,14 @@ class DecodeScheduler:
             self._fail_request(req, e)
             self._record_engine_failure()
             return
-        t1 = time.perf_counter()
-        self._engine_s += t1 - t0
+        t1 = self._engine_returned(t0)
         self._trace_span(req, 'replica/prefill', t0, t1,
                          prompt_len=len(req.prompt))
         self._record_spans()
         self.breaker.record_success()
         self._publish(req)
         self._emit_token(req, first)
-        self._phase('emit', t1, time.perf_counter(), span=True)
+        self._emitted()
 
     def _prefill_window(self, req):
         """A window model's admission: the prompt's whole blocks are
@@ -544,8 +610,7 @@ class DecodeScheduler:
             self._fail_request(req, e)
             self._record_engine_failure()
             return
-        t1 = time.perf_counter()
-        self._engine_s += t1 - t0
+        t1 = self._engine_returned(t0)
         self._trace_span(req, 'replica/prefill', t0, t1,
                          prompt_len=len(req.prompt))
         self._record_spans()
@@ -574,10 +639,10 @@ class DecodeScheduler:
         dropped (their table is gone)."""
         if self.disagg is None:
             return
-        t0 = time.perf_counter()
-        completed = self.disagg.drain_completed(timeout)
         if timeout:
-            self._phase('wait', t0, time.perf_counter())
+            completed = self._blocked(self.disagg.drain_completed, timeout)
+        else:
+            completed = self.disagg.drain_completed(timeout)
         for req, payload, exc in completed:
             if req not in self._slots or req.table is None:
                 continue              # failed or closed while in flight
@@ -593,8 +658,7 @@ class DecodeScheduler:
                 self._fail_request(req, e)
                 self._record_engine_failure()
                 continue
-            t1 = time.perf_counter()
-            self._engine_s += t1 - t0
+            t1 = self._engine_returned(t0)
             if req.handoff_t0 is not None:
                 self._trace_span(req, 'replica/handoff_wait',
                                  req.handoff_t0, t1,
@@ -602,7 +666,7 @@ class DecodeScheduler:
             self.breaker.record_success()
             self._publish(req)
             self._emit_token(req, first)
-            self._phase('emit', t1, time.perf_counter(), span=True)
+            self._emitted()
 
     def _record_engine_failure(self):
         """Book one engine-failure batch with the breaker; on a trip, fail
@@ -703,8 +767,7 @@ class DecodeScheduler:
                 self._fail_request(req, e)
             self._record_engine_failure()
             return True
-        t1 = time.perf_counter()
-        self._engine_s += t1 - t0
+        t1 = self._engine_returned(t0)
         self.breaker.record_success()
         self._record_spans()
         for i, req in enumerate(self._slots):
@@ -725,7 +788,7 @@ class DecodeScheduler:
             if req.trace is not None:
                 self._trace_span(req, 'replica/token', t0, t1,
                                  index=req.generated - 1)
-        self._phase('emit', t1, time.perf_counter(), span=True)
+        self._emitted()
         return True
 
     def _window_step(self):
@@ -753,8 +816,7 @@ class DecodeScheduler:
                 self._fail_request(req, e)
             self._record_engine_failure()
             return True
-        t1 = time.perf_counter()
-        self._engine_s += t1 - t0
+        t1 = self._engine_returned(t0)
         self.breaker.record_success()
         self._record_spans()
         for i, req in enumerate(self._slots):
@@ -773,7 +835,7 @@ class DecodeScheduler:
                                  emitted=req.generated - first)
             if req.table is not None:
                 self._open_block(i, req)
-        self._phase('emit', t1, time.perf_counter(), span=True)
+        self._emitted()
         return True
 
     def _spec_step(self):
@@ -834,8 +896,7 @@ class DecodeScheduler:
                 self._fail_request(req, e)
             self._record_engine_failure()
             return True
-        t1 = time.perf_counter()
-        self._engine_s += t1 - t0
+        t1 = self._engine_returned(t0)
         self.breaker.record_success()
         self._record_spans()
         for i, req in enumerate(self._slots):
@@ -878,7 +939,7 @@ class DecodeScheduler:
                     _m.decode_spec_accepted_tokens.inc(emitted - 1)
                 _m.decode_spec_acceptance.set(
                     self._spec_accepted / max(self._spec_drafted, 1))
-        self._phase('emit', t1, time.perf_counter(), span=True)
+        self._emitted()
         return True
 
     def _fail_all_locked(self):
@@ -906,23 +967,33 @@ class DecodeScheduler:
 
     def _run_cycles(self):
         """The worker thread's life, one iteration (``cycle``) after another:
-        each is observed whole and by phase (``_phase``), so the thread's
-        self time is cycle - wait - engine; iterations that admitted or ran
-        an engine call also leave ``scheduler/cycle|admit|emit`` spans, which
-        contain the engine's own (one thread: containment is the tree).
-        ``emit`` is what follows an engine call that returned tokens, once
-        per call; with ``admit`` and the engine's phases it tiles the busy
-        part of a cycle."""
+        each is observed whole and by phase (``_phase``), wall and
+        thread-CPU seconds, so the thread's self time is cycle - wait -
+        engine; iterations that admitted or ran an engine call also leave
+        ``scheduler/cycle|admit|emit|book`` spans, which contain the
+        engine's own (one thread: containment is the tree). ``emit`` is what
+        follows an engine call that returned tokens, once per call; ``book``
+        is every stretch between two leaves (`_leaf_begins`), observed as
+        one sum an iteration and as one span a stretch. The leaves tile the
+        cycle: cycle = admit + the engine calls' phases + emit + book +
+        wait. A cycle runs from the loop's top to the stamp before its own
+        ``book`` and ``cycle`` observations, as it did before there was a
+        ``book``: what lies between two cycles (those observations and the
+        iteration's span writes) is one more ``scheduler/book`` span, under
+        no cycle and in no phase's sum, so that the leaves' spans tile the
+        thread's life."""
         cycle = 0
+        self._leaf = _now()
         while True:
-            t0 = time.perf_counter()
+            between = self._leaf[0]
+            t0, cpu0 = self._leaf = _now()
             with self._cv:
                 if self._closing and self._abort:
                     self._fail_all_locked()
                     break
                 self._expire_waiting(time.monotonic())
                 admitted = self._admit_locked()
-            t_admit = time.perf_counter()
+            t_admit, cpu_admit = self._leaf = _now()
             for req in admitted:
                 self._prefill(req)
             # finished prefill handoffs join before the step; when ONLY
@@ -949,17 +1020,26 @@ class DecodeScheduler:
                         if not self._waiting:
                             break
                     else:
-                        w0 = time.perf_counter()
-                        self._cv.wait(timeout=0.05)
-                        self._phase('wait', w0, time.perf_counter())
+                        self._blocked(self._cv.wait, 0.05)
             engine_s, self._engine_s = self._engine_s, 0.0
+            engine_cpu, self._engine_cpu = self._engine_cpu, 0.0
             worked = bool(admitted) or engine_s > 0
             cycle += worked
-            self._phase('admit', t0, t_admit, span=worked)
+            self._phase('admit', t_admit - t0, cpu_admit - cpu0,
+                        start=t0 if worked else None)
             if engine_s:
-                _m.decode_scheduler_phase_seconds.labels(
-                    phase='engine').observe(engine_s)
-            self._phase('cycle', t0, time.perf_counter(), span=worked,
+                self._phase('engine', engine_s, engine_cpu)
+            t_end, cpu_end = _now()
+            self._leaf_begins(t_end, cpu_end)   # the iteration's tail
+            self._leaf = (t_end, cpu_end)
+            book, self._book = self._book, []
+            self._phase('book', sum(b - a for a, b, _ in book),
+                        sum(cpu for _, _, cpu in book))
+            if worked and _obs._ENABLED:
+                for a, b in [(between, t0)] + [s[:2] for s in book]:
+                    _obs.tracer.complete('scheduler/book', a, b)
+            self._phase('cycle', t_end - t0, cpu_end - cpu0,
+                        start=t0 if worked else None,
                         cycle=cycle, admitted=len(admitted),
                         slots_active=sum(r is not None for r in self._slots))
 

@@ -122,6 +122,14 @@ bucket_compile_seconds = _LazyMetric(
 http_responses = _LazyMetric(
     'counter', 'serving_http_responses',
     'HTTP front-end responses by status code')
+http_handler_cpu_seconds = _LazyMetric(
+    'counter', 'http_handler_cpu_seconds',
+    'thread-CPU seconds (time.thread_time) the HTTP handler threads spent '
+    'on POST /generate, from entry to the last byte written: one increment '
+    'a request, nothing per token. Its rate is the share of one core, and '
+    'so at most of the one interpreter, that the handlers take (socket '
+    'calls run without the interpreter lock: an upper bound on the lock '
+    'held)')
 
 # -- circuit breaker (serving/breaker.py) ----------------------------------
 # state encoding: 0 = closed, 1 = half-open (probing), 2 = open (tripped)
@@ -211,6 +219,13 @@ decode_engine_phase_seconds = _LazyMetric(
     'spec_step, phase=pack|forward|device_wait|logits_copy|sample); the '
     'phases of a call tile it: forward is the dispatch of the call\'s one '
     'XLA program, device_wait the device running it')
+decode_engine_phase_cpu_seconds = _LazyMetric(
+    'counter', 'decode_engine_phase_cpu_seconds',
+    'thread-CPU seconds (time.thread_time, which counts the calling thread '
+    'only while it runs) per phase of the engine calls, same labels and '
+    'stamps as decode_engine_phase_seconds: that histogram\'s sum less '
+    'this is the time the thread was OFF the CPU in the phase: waiting for '
+    'the interpreter lock, for the device, or in a blocking call')
 decode_logits_bytes_copied = _LazyMetric(
     'counter', 'decode_logits_bytes_copied',
     'bytes engine calls copied from the device to the host for the pick: '
@@ -271,8 +286,18 @@ decode_scheduler_phase_seconds = _LazyMetric(
     'phase: cycle = the whole iteration; inside it admit = the locked '
     'expire-and-admit pass, engine = its engine calls, emit = what follows '
     'an engine call that returned tokens, once per call: the per-slot loop '
-    'after a step, the first token after a prefill; wait = blocked idle); '
-    'self time = cycle - wait - engine')
+    'after a step, the first token after a prefill; wait = blocked idle; '
+    'book = the thread\'s bookkeeping between those, every stretch under '
+    'neither admit, an engine call\'s phases, emit nor wait, summed per '
+    'iteration); self time = cycle - wait - engine; cycle = admit + the '
+    'engine calls\' phases + emit + book + wait')
+decode_scheduler_phase_cpu_seconds = _LazyMetric(
+    'counter', 'decode_scheduler_phase_cpu_seconds',
+    'thread-CPU seconds (time.thread_time) of the scheduler worker thread '
+    'per phase, same labels and stamps as decode_scheduler_phase_seconds: '
+    'wall less CPU of a phase is the time the thread was off the CPU in '
+    'it, which in admit, emit and book (pure interpreter work) is the wait '
+    'for the interpreter lock')
 decode_queue_wait_seconds = _LazyMetric(
     'histogram', 'decode_queue_wait_seconds',
     'accepted by submit -> admitted to a slot, per generation (every '

@@ -43,6 +43,7 @@ from .batcher import (DEFAULT_BATCH_TIMEOUT_MS, DEFAULT_QUEUE_DEPTH,
 from .engine import InferenceEngine
 from .errors import (DeadlineExceeded, EngineClosed, EngineUnhealthy,
                      InvalidRequest, Overloaded)
+from .. import observability as _obs
 from ..log_helper import get_logger
 from ..observability import TraceContext
 from ..observability import distributed as _dobs
@@ -257,7 +258,28 @@ class _Handler(BaseHTTPRequestHandler):
         ``stream=false`` blocks and returns the whole generation as one
         JSON reply. Pre-admission failures map like /predict:
         InvalidRequest→400, Overloaded→429, DeadlineExceeded→504,
-        EngineClosed→503."""
+        EngineClosed→503.
+
+        The handler thread's own CPU seconds for the request, entry to last
+        byte written, go to ``http_handler_cpu_seconds``: once a request,
+        nothing per token (the per-token wake is the contended place). With
+        telemetry on an answered generation leaves one ``http/generate``
+        span on this thread's ``tid``, from its submit to here."""
+        cpu0 = time.thread_time()
+        answered = self._generate()
+        cpu = time.thread_time() - cpu0
+        _m.http_handler_cpu_seconds.inc(cpu)
+        if answered is not None and _obs._ENABLED:
+            t0, stream = answered
+            _obs.tracer.complete(
+                'http/generate', t0, time.perf_counter(),
+                request_id=stream.request_id, tokens=len(stream.tokens),
+                cpu_us=cpu * 1e6)
+
+    def _generate(self):
+        """`_do_generate`'s request, parsed, submitted and answered;
+        ``(perf_counter at its submit, its stream)`` once the generation
+        was answered with a 200, else None."""
         srv = self.server.serving
         if srv.generator is None:
             return self._reply(404, {
@@ -318,10 +340,11 @@ class _Handler(BaseHTTPRequestHandler):
                 _logger.error('generate failed: %s: %s',
                               type(e).__name__, e)
                 return self._error(500, e)
-            return self._reply(200, {
+            self._reply(200, {
                 'tokens': toks, 'finish_reason': stream.finish_reason,
                 'latency_ms': round((time.perf_counter() - t0) * 1e3, 3),
                 **stream.meta})
+            return t0, stream
 
         # chunked per-token streaming
         self.send_response(200)
@@ -349,6 +372,7 @@ class _Handler(BaseHTTPRequestHandler):
         except (BrokenPipeError, ConnectionResetError):
             pass                      # generation continues server-side
         _m.http_responses.labels(code=200).inc()
+        return t0, stream
 
 
 class Listener(ThreadingHTTPServer):
