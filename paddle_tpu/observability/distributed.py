@@ -453,20 +453,23 @@ class WindowedSeries(object):
             self._cur = {'start': self._cur['end'], 'count': 0,
                          'total': 0.0, 'samples': []}
 
-    def observe(self, value, now=None):
+    def observe(self, value, now=None, times=1):
+        """``times`` observations of ``value`` at one stamp, as that many
+        calls would leave them (a step's tokens, booked together)."""
         now = time.monotonic() if now is None else now
         with self._lock:
             self._roll_locked(now)
             cur = self._cur
-            cur['count'] += 1
-            cur['total'] += value
-            if len(cur['samples']) < self._max_samples:
-                cur['samples'].append(value)
-            else:
-                # deterministic decimation: keep every k-th overflow so
-                # the tail is still represented without unbounded memory
-                k = cur['count'] % self._max_samples
-                cur['samples'][k] = value
+            for _ in range(times):
+                cur['count'] += 1
+                cur['total'] += value
+                if len(cur['samples']) < self._max_samples:
+                    cur['samples'].append(value)
+                else:
+                    # deterministic decimation: keep every k-th overflow so
+                    # the tail is still represented without unbounded memory
+                    k = cur['count'] % self._max_samples
+                    cur['samples'][k] = value
 
     def _windows_locked(self, now):
         self._roll_locked(now)

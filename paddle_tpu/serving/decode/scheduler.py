@@ -9,7 +9,9 @@ The scheduling model is S fixed decode slots stepped in lockstep:
                                                       3. ONE decode step,
                                                          all S slots
                                                       4. emit tokens, free
-                                                         finished slots
+                                                         finished slots,
+                                                         hand the touched
+                                                         streams over once
                                                       ▼
                                           per-request GenerationStream
 
@@ -46,7 +48,6 @@ from __future__ import annotations
 import collections
 import contextlib
 import os
-import queue
 import threading
 import time
 import uuid
@@ -64,8 +65,6 @@ from .diffusion import denoise_quota, validate_denoising_steps
 from .sampling import SamplingParams, TokenSampler
 
 __all__ = ['DecodeScheduler', 'GenerationStream']
-
-_END = object()
 
 
 class GenerationStream:
@@ -86,7 +85,14 @@ class GenerationStream:
     a replica restart or a router failover never collide and clients can
     correlate the attempts of one logical request across replicas. For
     SAMPLED requests the request_id is also the stream seed (sampling.py):
-    replaying the same id + params reproduces the token stream bitwise."""
+    replaying the same id + params reproduces the token stream bitwise.
+
+    The tokens are one append-only list, and a consumer is a cursor into it:
+    an in-process iterator (``iter_tokens``) sleeps on the stream's condition
+    and is woken a token; a consumer that serves many streams (the HTTP
+    server's stream writer) takes ``tokens_since(cursor)`` when the
+    scheduler's hand-off names the stream (``DecodeScheduler.stream_sink``)
+    and no thread is woken for it a token."""
 
     def __init__(self, prompt_len, max_new_tokens, replica_id=None,
                  request_id=None, trace_id=None):
@@ -95,8 +101,8 @@ class GenerationStream:
         self.replica_id = replica_id
         self.request_id = request_id or uuid.uuid4().hex[:16]
         self.trace_id = trace_id
-        self._q = queue.Queue()
         self._tokens = []
+        self._grew = threading.Condition()     # iter_tokens' sleepers
         self._done = threading.Event()
         self._exc = None
         self.finish_reason = None
@@ -117,20 +123,42 @@ class GenerationStream:
 
     def iter_tokens(self, timeout=None):
         """Yield token ids as they decode. ``timeout`` bounds the wait for
-        EACH token (TimeoutError) — the HTTP handler uses it so a stuck
-        stream cannot pin a connection thread forever."""
+        EACH token (TimeoutError), so a stuck stream cannot pin its
+        consumer forever."""
+        cursor = 0
         while True:
-            try:
-                item = self._q.get(timeout=timeout)
-            except queue.Empty:
-                raise TimeoutError(
-                    f'no token within {timeout}s (generated '
-                    f'{len(self._tokens)} so far)')
-            if item is _END:
+            with self._grew:
+                while True:
+                    # done is read before the length: a stream is done
+                    # after its last token
+                    done = self._done.is_set()
+                    if done or len(self._tokens) > cursor:
+                        break
+                    if not self._grew.wait(timeout):
+                        raise TimeoutError(self.stalled_message(timeout))
+            new = self._tokens[cursor:]
+            cursor += len(new)
+            yield from new
+            if done:
                 if self._exc is not None:
                     raise self._exc
                 return
-            yield item
+
+    def stalled_message(self, timeout):
+        """What the TimeoutError of a stream says that yielded no token for
+        ``timeout`` seconds."""
+        return (f'no token within {timeout}s (generated '
+                f'{len(self._tokens)} so far)')
+
+    def tokens_since(self, cursor):
+        """The tokens emitted after the first ``cursor``, without blocking:
+        a consumer that keeps its own cursor reads each token once. Read
+        ``done()`` BEFORE it: a stream is done after its last token."""
+        return self._tokens[cursor:]
+
+    def exception(self):
+        """The request's failure, None while it runs or if it finished."""
+        return self._exc
 
     def result(self, timeout=None):
         """All generated token ids; raises the request's failure."""
@@ -151,17 +179,21 @@ class GenerationStream:
     # -- scheduler side ----------------------------------------------------
     def _emit(self, token):
         self._tokens.append(int(token))
-        self._q.put(int(token))
+        self._wake()
 
     def _finish(self, reason):
         self.finish_reason = reason
         self._done.set()
-        self._q.put(_END)
+        self._wake()
 
     def _fail(self, exc):
         self._exc = exc
         self._done.set()
-        self._q.put(_END)
+        self._wake()
+
+    def _wake(self):
+        with self._grew:
+            self._grew.notify_all()
 
 
 class _Request:
@@ -306,6 +338,16 @@ class DecodeScheduler:
         # its bookkeeping (_leaf_begins)
         self._spans = []
         self._engine_s = self._engine_cpu = 0.0
+        # who else serves the streams' consumers: the HTTP server's stream
+        # writer sets it (serving/stream_writer.py). Called on the worker
+        # thread with the streams touched since the last call (a token, a
+        # finish, a failure), ONE call an emit whatever the slot count
+        # (`_hand_off`); None: a stream's own iterators are its consumers.
+        # Worker-owned beside it: the streams touched and the tokens emitted
+        # and not yet booked
+        self.stream_sink = None
+        self._touched = []
+        self._unbooked = 0
         self._leaf = (0.0, 0.0)          # the worker stamps it as it starts
         self._book = []
         self._cv = threading.Condition()
@@ -417,7 +459,7 @@ class DecodeScheduler:
         for req in self._waiting:
             if req.expired(now):
                 _m.decode_requests_deadline_missed.inc()
-                req.stream._fail(DeadlineExceeded(
+                self._fail_stream(req.stream, DeadlineExceeded(
                     f'deadline expired after {now - req.enqueued_at:.3f}s '
                     f'waiting for a decode slot'))
             else:
@@ -468,12 +510,12 @@ class DecodeScheduler:
         contexts, dicts, the JSONL lines and the mirror into the chrome
         buffer cost the worker thread one pass, not one per slot. It runs
         when an engine call has just returned and before the call's tokens
-        are emitted: the HTTP threads are then idle, so a batch that yields
+        are emitted: the HTTP side is then idle, so a batch that yields
         the interpreter (an id draw, the file write) hands it to nobody.
-        Emitted tokens wake 128 of them, and whatever yields between there
-        and the next engine call waits for all of them (PERF.md, PR 24):
-        per-slot spans there are what made a traced run admit earlier than
-        an untraced one."""
+        The emit's hand-off wakes the stream writer (a thread a connection
+        until PR 35), and whatever yields between there and the next engine
+        call may wait for it (PERF.md, PR 24): per-slot spans there are what
+        made a traced run admit earlier than an untraced one."""
         noted, self._spans = self._spans, []
         if not noted:
             return
@@ -531,9 +573,42 @@ class DecodeScheduler:
         self._leaf = (t1, cpu1)
         return t1
 
+    def _touch(self, stream):
+        """Notes a stream for the next hand-off, once however many tokens a
+        step gave it (they come one after the other)."""
+        if not self._touched or self._touched[-1] is not stream:
+            self._touched.append(stream)
+
+    def _fail_stream(self, stream, exc):
+        self._book_tokens()
+        stream._fail(exc)
+        self._touch(stream)
+
+    def _book_tokens(self):
+        """Books the tokens emitted since the last call: once a step and
+        before any stream ends, so whoever sees a stream done reads a
+        counter that holds its tokens."""
+        n, self._unbooked = self._unbooked, 0
+        if n:
+            _m.decode_tokens_generated.inc(n)
+            _dobs.series('tokens').observe(1.0, times=n)
+
+    def _hand_off(self):
+        """Gives the streams touched since the last call to ``stream_sink``
+        in ONE call: one put and one wake for a step's tokens, where every
+        token used to wake its connection's thread (PERF.md section 6, PR
+        35). Every path that touches a stream ends here: an ``emit``
+        (`_emitted`), a loop iteration's failures and expiries
+        (`_run_cycles`), the fail-fast close (`_fail_all_locked`)."""
+        self._book_tokens()
+        touched, self._touched = self._touched, []
+        if touched and self.stream_sink is not None:
+            self.stream_sink(touched)
+
     def _emitted(self):
         """Closes the ``emit`` that began where the last engine call
-        returned."""
+        returned, with its hand-off."""
+        self._hand_off()
         t1, cpu1 = self._leaf
         self._leaf = _now()
         self._phase('emit', self._leaf[0] - t1, self._leaf[1] - cpu1,
@@ -680,7 +755,7 @@ class DecodeScheduler:
         with self._cv:
             failed = len(self._waiting)
             while self._waiting:
-                self._waiting.popleft().stream._fail(exc)
+                self._fail_stream(self._waiting.popleft().stream, exc)
             _m.decode_queue_depth.set(0)
         if failed:
             _m.decode_requests_failed.inc(failed)
@@ -704,8 +779,8 @@ class DecodeScheduler:
         req.generated += 1
         req.history.append(int(token))
         req.stream._emit(token)
-        _m.decode_tokens_generated.inc()
-        _dobs.series('tokens').observe(1.0)
+        self._touch(req.stream)
+        self._unbooked += 1
         if req.generated == 1:
             ttft = time.perf_counter() - req.enqueued_perf
             _m.decode_ttft_seconds.observe(ttft)
@@ -721,7 +796,9 @@ class DecodeScheduler:
         self.engine.release_table(req.table)
         req.table = None
         self._slots[self._slots.index(req)] = None
+        self._book_tokens()
         req.stream._finish(reason)
+        self._touch(req.stream)
         _m.decode_requests_completed.inc()
 
     def _fail_request(self, req, exc):
@@ -731,10 +808,10 @@ class DecodeScheduler:
         if req in self._slots:
             self._slots[self._slots.index(req)] = None
         _m.decode_requests_failed.inc()
-        req.stream._fail(exc if isinstance(exc, ServingError)
-                         else ServingError(
-                             f'generation failed: '
-                             f'{type(exc).__name__}: {exc}'))
+        self._fail_stream(req.stream, exc if isinstance(exc, ServingError)
+                          else ServingError(
+                              f'generation failed: '
+                              f'{type(exc).__name__}: {exc}'))
 
     def _step(self):
         """One lockstep decode step over the current slots. Handoff-pending
@@ -947,7 +1024,7 @@ class DecodeScheduler:
         Runs on the WORKER thread (slot state is worker-owned; the close()
         caller only raises the abort flag), so no step can race a release."""
         while self._waiting:
-            self._waiting.popleft().stream._fail(EngineClosed(
+            self._fail_stream(self._waiting.popleft().stream, EngineClosed(
                 'decode scheduler shut down before this request ran'))
         _m.decode_queue_depth.set(0)
         for i, req in enumerate(self._slots):
@@ -955,9 +1032,10 @@ class DecodeScheduler:
                 self.engine.release_table(req.table)
                 req.table = None
                 self._slots[i] = None
-                req.stream._fail(EngineClosed(
+                self._fail_stream(req.stream, EngineClosed(
                     'decode scheduler shut down mid-generation'))
         _m.decode_slots_active.set(0)
+        self._hand_off()
 
     def _worker_loop(self):
         try:
@@ -1011,6 +1089,7 @@ class DecodeScheduler:
                 stepped = self._spec_step()
             else:
                 stepped = self._step()
+            self._hand_off()            # what failed or expired outside an emit
             if not stepped and not admitted:
                 self._record_spans()    # idle: nothing else will
                 with self._cv:

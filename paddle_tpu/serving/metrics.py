@@ -124,12 +124,28 @@ http_responses = _LazyMetric(
     'HTTP front-end responses by status code')
 http_handler_cpu_seconds = _LazyMetric(
     'counter', 'http_handler_cpu_seconds',
-    'thread-CPU seconds (time.thread_time) the HTTP handler threads spent '
-    'on POST /generate, from entry to the last byte written: one increment '
-    'a request, nothing per token. Its rate is the share of one core, and '
-    'so at most of the one interpreter, that the handlers take (socket '
-    'calls run without the interpreter lock: an upper bound on the lock '
-    'held)')
+    'thread-CPU seconds (time.thread_time) of the HTTP side of POST '
+    '/generate: each handler thread\'s from entry to its reply\'s last '
+    'byte (one increment a request; a streamed reply\'s bytes are the '
+    'stream writer\'s, the handler sleeps through them) and the stream '
+    'writer thread\'s own, one increment a turn of its loop, nothing per '
+    'token. Its rate is the share of one core, and so at most of the one '
+    'interpreter, that the HTTP side takes beside the scheduler\'s worker '
+    '(socket calls run without the interpreter lock: an upper bound on '
+    'the lock held)')
+http_stream_writer_wakes = _LazyMetric(
+    'counter', 'http_stream_writer_wakes',
+    'hand-offs the stream writer took from the decode scheduler: one put '
+    'and one wake each, whatever the number of streams a step touched')
+http_stream_writer_tokens = _LazyMetric(
+    'counter', 'http_stream_writer_tokens',
+    'token lines the stream writer formatted for its connections; over '
+    'http_stream_writer_wakes, the tokens one wake delivers (a thread a '
+    'connection was woken once a token)')
+http_stream_writer_sends = _LazyMetric(
+    'counter', 'http_stream_writer_sends',
+    'non-blocking send calls the stream writer made: one a connection a '
+    'hand-off, more only where a socket did not take its bytes at once')
 
 # -- circuit breaker (serving/breaker.py) ----------------------------------
 # state encoding: 0 = closed, 1 = half-open (probing), 2 = open (tripped)

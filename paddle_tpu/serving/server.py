@@ -1,8 +1,11 @@
 """Stdlib HTTP front end for the serving subsystem.
 
-A ``ThreadingHTTPServer`` (one handler thread per connection — the handler
-threads only parse JSON and block on futures; all device work stays on the
-single batcher worker) exposing:
+A ``ThreadingHTTPServer`` (one handler thread per connection — a handler
+thread parses a request, submits it, and sleeps until its reply is ready: on a
+future for ``/predict``, on the ONE stream writer thread for a streamed
+``/generate``, which writes every connection's token lines, serving/
+stream_writer.py; all device work stays on the batcher's and the decode
+scheduler's single workers) exposing:
 
 - ``POST /predict`` — body ``{"inputs": {name: nested-list}, "timeout_ms":
   optional}`` (or inputs as a list in feed order). Reply ``{"outputs":
@@ -43,6 +46,7 @@ from .batcher import (DEFAULT_BATCH_TIMEOUT_MS, DEFAULT_QUEUE_DEPTH,
 from .engine import InferenceEngine
 from .errors import (DeadlineExceeded, EngineClosed, EngineUnhealthy,
                      InvalidRequest, Overloaded)
+from .stream_writer import StreamWriter
 from .. import observability as _obs
 from ..log_helper import get_logger
 from ..observability import TraceContext
@@ -170,12 +174,6 @@ class _Handler(BaseHTTPRequestHandler):
             return None
         return payload
 
-    def _write_chunk(self, obj):
-        """One chunked-transfer NDJSON line."""
-        data = json.dumps(obj).encode() + b'\n'
-        self.wfile.write(b'%x\r\n' % len(data) + data + b'\r\n')
-        self.wfile.flush()
-
     def do_POST(self):
         if self.path == '/generate':
             return self._do_generate()
@@ -262,7 +260,8 @@ class _Handler(BaseHTTPRequestHandler):
 
         The handler thread's own CPU seconds for the request, entry to last
         byte written, go to ``http_handler_cpu_seconds``: once a request,
-        nothing per token (the per-token wake is the contended place). With
+        nothing per token (a streamed reply's lines are the stream writer's,
+        which books its own CPU seconds there a turn of its loop). With
         telemetry on an answered generation leaves one ``http/generate``
         span on this thread's ``tid``, from its submit to here."""
         cpu0 = time.thread_time()
@@ -346,38 +345,31 @@ class _Handler(BaseHTTPRequestHandler):
                 **stream.meta})
             return t0, stream
 
-        # chunked per-token streaming
+        # chunked per-token streaming: the token lines, the done (or error)
+        # line and the last chunk are the stream writer's; this thread
+        # sleeps until they are out (serving/stream_writer.py)
         self.send_response(200)
         self.send_header('Content-Type', 'application/x-ndjson')
         self.send_header('Transfer-Encoding', 'chunked')
-        self.end_headers()
         try:
-            try:
-                for i, tok in enumerate(
-                        stream.iter_tokens(srv.request_timeout)):
-                    self._write_chunk({'token': int(tok), 'index': i})
-                self._write_chunk({
-                    'done': True, 'finish_reason': stream.finish_reason,
-                    'tokens': stream.tokens,
-                    'latency_ms': round((time.perf_counter() - t0) * 1e3,
-                                        3),
-                    **stream.meta})
-            except (BrokenPipeError, ConnectionResetError):
-                raise                 # client went away: just stop
-            except Exception as e:    # failure mid-stream: error line
-                self._write_chunk({'error': type(e).__name__,
-                                   'message': str(e)})
-            self.wfile.write(b'0\r\n\r\n')
-            self.wfile.flush()
+            self.end_headers()
         except (BrokenPipeError, ConnectionResetError):
-            pass                      # generation continues server-side
+            self.close_connection = True
+        else:
+            if not srv.stream_writer.serve(self.connection, stream, t0):
+                # the client went away (generation continues server-side)
+                # or the server is stopping: not a connection to keep
+                self.close_connection = True
         _m.http_responses.labels(code=200).inc()
         return t0, stream
 
 
 class Listener(ThreadingHTTPServer):
     """The serving tier's HTTP listener: one daemon handler thread per
-    connection, and a listen backlog that holds a burst of connections.
+    connection (it wants the interpreter twice a request, to parse and to
+    book: a streamed reply's tokens are written by the server's one stream
+    writer while it sleeps), and a listen backlog that holds a burst of
+    connections.
     socketserver's default backlog of 5 overflows when a replica's clients
     connect together (128 at once in the benchmark's closed loop) while the
     accept loop waits for the interpreter lock behind a busy scheduler
@@ -428,6 +420,17 @@ class ServingServer:
                              else queue_depth),
                 default_timeout_ms=default_timeout_ms)
         self.generator = generator
+        # the one thread that writes every streamed /generate reply; the
+        # scheduler hands it the streams a step touched in one call where
+        # it offers the hook (a generator that does not is looked at on a
+        # timer)
+        self.stream_writer = None
+        if generator is not None:
+            hooked = hasattr(generator, 'stream_sink')
+            self.stream_writer = StreamWriter(request_timeout,
+                                              polled=not hooked)
+            if hooked:
+                generator.stream_sink = self.stream_writer.touched
         if generator is not None and warmup:
             timings = generator.engine.warmup()
             _logger.info('warmed decode engine: %s',
@@ -495,6 +498,8 @@ class ServingServer:
 
     def start(self):
         """Serve in a background thread; returns self."""
+        if self.stream_writer is not None:
+            self.stream_writer.start()
         self._thread = threading.Thread(target=self._httpd.serve_forever,
                                         name='paddle-tpu-serving-http',
                                         daemon=True)
@@ -515,6 +520,8 @@ class ServingServer:
             self.install_signal_handlers()
         except ValueError:
             pass                       # not the main thread: Ctrl-C only
+        if self.stream_writer is not None:
+            self.stream_writer.start()
         try:
             self._httpd.serve_forever()
         except KeyboardInterrupt:
@@ -581,6 +588,12 @@ class ServingServer:
                     'drain timeout (%.1fs) exceeded; failing remaining '
                     'queued work fast', timeout)
                 comp.close(drain=False, timeout=5)
+        if self.stream_writer is not None:
+            # every stream has ended by now (drained or failed): the writer
+            # takes the hand-offs it still holds, then lets the handlers go
+            self.stream_writer.stop()
+            if hasattr(self.generator, 'stream_sink'):
+                self.generator.stream_sink = None
         self._httpd.shutdown()
         self._httpd.server_close()
         if self._thread is not None and \

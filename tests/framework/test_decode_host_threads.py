@@ -371,21 +371,31 @@ def _post(port, body):
 class _Recorder:
     def __init__(self):
         self.incs = []
+        self.writer = None          # the stream writer thread's ident
 
     def inc(self, amount=1.0):
         self.incs.append((threading.get_ident(), amount))
 
+    def handlers(self):
+        return [(tid, cpu) for tid, cpu in self.incs if tid != self.writer]
+
     def settled(self, n):
-        """len(incs), once it is `n` or a second has passed: the handler
-        books its CPU after the client has read the reply's last byte."""
+        """The handler threads' increments, once they are `n` or a second
+        has passed: a handler books its CPU after the client has read the
+        reply's last byte."""
         until = time.perf_counter() + 1.0
-        while len(self.incs) < n and time.perf_counter() < until:
+        while len(self.handlers()) < n and time.perf_counter() < until:
             time.sleep(0.005)
         time.sleep(0.02)            # what a per-token increment would use
-        return len(self.incs)
+        return len(self.handlers())
 
 
-def test_handler_cpu_moves_once_a_request_and_not_per_token(lm, monkeypatch):
+def test_handler_cpu_moves_once_a_request_and_the_writers_once_a_turn(
+        lm, monkeypatch):
+    """A handler thread books its CPU once a request and sleeps through a
+    streamed reply; the stream writer thread, which writes the reply, books
+    its own into the same counter once a turn of its loop (a hand-off or a
+    tick), on its own clock."""
     engine = make_engine(lm)
     seen = _Recorder()
     monkeypatch.setattr(_m, 'http_handler_cpu_seconds', seen)
@@ -393,6 +403,7 @@ def test_handler_cpu_moves_once_a_request_and_not_per_token(lm, monkeypatch):
         server = ServingServer(None, host='127.0.0.1', port=0,
                                generator=sched)
         server.start()
+        seen.writer = server.stream_writer._thread.ident
         try:
             status, data = _post(server.port, {'prompt': [3, 5, 7],
                                                'max_new_tokens': 2})
@@ -411,7 +422,12 @@ def test_handler_cpu_moves_once_a_request_and_not_per_token(lm, monkeypatch):
         finally:
             server.shutdown(drain=False)
     me = threading.get_ident()
-    assert all(tid != me and 0 < cpu < 5.0 for tid, cpu in seen.incs)
+    assert all(tid != me and 0 < cpu < 5.0 for tid, cpu in seen.handlers())
+    writers = [cpu for tid, cpu in seen.incs if tid == seen.writer]
+    # the two streamed replies crossed in at most a hand-off a token (one
+    # slot was live) and two registrations; the rest are the loop's ticks
+    assert 2 <= len(writers) and all(0 <= cpu < 5.0 for cpu in writers)
+    assert sum(writers) > 0
 
 
 def test_http_generate_span_on_the_handlers_own_thread(lm):

@@ -132,6 +132,22 @@ def test_windowed_series_slides_old_data_out():
     assert s.count(now=20.5) == 1
 
 
+def test_windowed_series_books_a_steps_tokens_as_that_many_calls_would():
+    """`observe(value, times=n)` (a decode step's tokens, booked once a
+    step) leaves what n calls at the same stamp leave: count, total, the
+    retained samples and their decimation past `max_samples`."""
+    one = dobs.WindowedSeries('one', window_s=1.0, windows=2)
+    many = dobs.WindowedSeries('many', window_s=1.0, windows=2)
+    one._max_samples = many._max_samples = 8
+    for step, (value, n) in enumerate([(1.0, 5), (2.0, 7), (3.0, 1)]):
+        now = 50.0 + 0.1 * step
+        for _ in range(n):
+            one.observe(value, now=now)
+        many.observe(value, now=now, times=n)
+    assert many._cur == one._cur and many._cur['count'] == 13
+    assert many.snapshot(now=50.5) == one.snapshot(now=50.5)
+
+
 def test_series_registry_shared_and_reset():
     dobs.series('shared').observe(3.0)
     assert dobs.series('shared').count() == 1
