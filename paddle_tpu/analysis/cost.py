@@ -495,6 +495,14 @@ def _c_paged_attention(ctx):
     head_dim = _pdim(q, -1, a)
     seqs = _pdim(bt, 0, a)
     t_pad = _pdim(bt, 1, a) * block_size
+    if ctx.attr('kv_heads', None) is not None and ctx.op.type == \
+            'paged_prefill_attention':
+        # the causal grouped form attends the rung itself: half its square
+        t_pad = max(1, _pdim(q, 2, a) // 2)
+    span = int(ctx.attr('span', 0))
+    if span:
+        # a sliding layer: no row sees more than its span
+        t_pad = min(t_pad, span)
     queries = max(1, ctx.out_elems() // max(1, head_dim))
     flops = queries * t_pad * (4 * head_dim + TRANSCENDENTAL_FLOPS + 2)
     if ctx.input('k_scales') is not None:
@@ -541,6 +549,11 @@ def _c_swiglu_ffn(ctx):
     return 6 * ctx.in_elems('x') * f + (TRANSCENDENTAL_FLOPS + 1) * rows * f
 
 
+@cost_rule('sigmoid_gate')
+def _c_sigmoid_gate(ctx):
+    return (TRANSCENDENTAL_FLOPS + 1) * ctx.in_elems('x')
+
+
 @cost_rule('moe_router')
 def _c_moe_router(ctx):
     experts = _pdim(ctx.input('w_gate'), 1, ctx.assume_dim)
@@ -555,6 +568,9 @@ def _c_moe_experts(ctx):
     gate = ctx.input('w_gate')
     h, f = _pdim(gate, 1, a), _pdim(gate, 2, a)
     assignments = ctx.in_elems('ids')
+    # with experts_held (a share of the router's experts) this is the
+    # bound: which assignments fall on the held experts a static rule
+    # cannot know
     return assignments * (6 * h * f + (TRANSCENDENTAL_FLOPS + 1) * f + 2 * h)
 
 

@@ -1420,7 +1420,28 @@ def _paged_attention(ctx):
                              'form: it takes no row scales')
         _grouped_heads('paged_attention', _dim(q, 1),
                        ctx.attr('kv_heads', None))
+    elif ctx.attr('kv_heads', None) is not None:
+        # the grouped single-query read, and with span its sliding form
+        if q.shape is not None and len(q.shape) != 3:
+            raise InferError('paged_attention kv_heads without block_window '
+                             'expects q of rank 3 (S, H, D), got rank '
+                             f'{len(q.shape)}')
+        if ctx.input('k_scales') is not None:
+            raise InferError('paged_attention kv_heads has no int8 form: '
+                             'it takes no row scales')
+        _grouped_heads('paged_attention', _dim(q, 1), ctx.attr('kv_heads'))
+    _span_attr(ctx, 'paged_attention')
     return {'Out': VarInfo(q.shape, q.dtype)}
+
+
+def _span_attr(ctx, what):
+    span = int(ctx.attr('span', 0))
+    if span < 0:
+        raise InferError(f'{what} span={span} is negative', kind='bad-attr')
+    if span and ctx.attr('kv_heads', None) is None:
+        raise InferError(f'{what} span={span} needs kv_heads: a sliding '
+                         f'layer is read in the grouped form',
+                         kind='bad-attr')
 
 
 def _grouped_heads(what, heads, kv_heads):
@@ -1448,6 +1469,18 @@ def _paged_prefill_attention(ctx):
         # the block mask takes grouped heads: k, v (1, G, L, D)
         _grouped_heads('paged_prefill_attention', _dim(q, 1), _dim(k, 1)
                        if known(_dim(k, 1)) else None)
+    kv_heads = ctx.attr('kv_heads', None)
+    if kv_heads is not None:
+        # the causal grouped form: k, v (1, G, L, D)
+        if block_len:
+            raise InferError('paged_prefill_attention takes block_len or '
+                             'kv_heads, not both', kind='bad-attr')
+        _grouped_heads('paged_prefill_attention', _dim(q, 1), kv_heads)
+        if k is not None and k.shape is not None and len(k.shape) == 4 \
+                and not dims_agree(_dim(k, 1), int(kv_heads)):
+            raise InferError(f'paged_prefill_attention kv_heads={kv_heads} '
+                             f'but k holds {_dim(k, 1)} heads')
+    _span_attr(ctx, 'paged_prefill_attention')
     return {'Out': VarInfo(q.shape, q.dtype)}
 
 
@@ -1528,6 +1561,17 @@ def _swiglu_ffn(ctx):
     return {'Out': VarInfo(x.shape, x.dtype)}
 
 
+@infer_rule('sigmoid_gate')
+def _sigmoid_gate(ctx):
+    x, gate = ctx.require('x'), ctx.require('gate')
+    if x.shape is not None and gate.shape is not None and (
+            len(x.shape) != len(gate.shape) or not all(
+                dims_agree(a, b) for a, b in zip(x.shape, gate.shape))):
+        raise InferError(f'sigmoid_gate: x {tuple(x.shape)} and gate '
+                         f'{tuple(gate.shape)} differ in shape')
+    return {'Out': VarInfo(x.shape, x.dtype)}
+
+
 @infer_rule('moe_router')
 def _moe_router(ctx):
     x, w = ctx.require('x'), ctx.require('w_gate')
@@ -1557,6 +1601,13 @@ def _moe_experts(ctx):
     _contracts('moe_experts x against w_gate', _dim(x, -1), _dim(gate, 1))
     _contracts('moe_experts w_down against x', _dim(ctx.require('w_down'), 2),
                _dim(x, -1))
+    held = ctx.attr('experts_held', None)
+    if held is not None:
+        first, count = (int(n) for n in held)
+        if first < 0 or count < 1 or not dims_agree(_dim(gate, 0), count):
+            raise InferError(
+                f'moe_experts experts_held={tuple(held)!r}: the weights are '
+                f'of {_dim(gate, 0)} experts', kind='bad-attr')
     return {'Out': VarInfo(x.shape, x.dtype),
             'Counts': VarInfo((_dim(gate, 0),), 'int32')}
 

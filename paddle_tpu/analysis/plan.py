@@ -515,7 +515,9 @@ def _decode_kv_geometry(model):
     (``model.kv_cache_spec()``: per token per layer ``{'kind': 'kv',
     'layers', 'heads', 'head_dim'}`` for K and V rows of every head the
     model caches (its key/value heads; with ``'window'`` B where a step
-    feeds B rows a slot, :func:`decode_step_rows`) or
+    feeds B rows a slot, :func:`decode_step_rows`; with ``'layer_spans'``
+    where its layers are of two classes, each layer's span and 0 a full
+    layer, :func:`decode_layer_classes`) or
     ``{'kind': 'latent', 'layers', 'row_width'}`` for one latent row; per
     REQUEST per layer ``{'kind': 'state', 'layers', 'heads', 'state_rows',
     'head_dim'}`` for one recurrent state). Raises a ValueError
@@ -553,6 +555,43 @@ def decode_token_layer_bytes(model, kv_dtype='f32'):
     return 2 * kv_row_bytes(spec['heads'], spec['head_dim'], kv_dtype)
 
 
+def decode_layer_classes(model):
+    """(full layers, sliding layers, span) of a K/V model
+    (``kv_cache_spec()['layer_spans']``; a model that names no classes has
+    full layers alone, span 0): a full layer holds every position of a
+    context, a sliding layer ``min(context, span)`` of them, in a ring of
+    ``span / block + 1`` blocks a request (serving/decode/kv_cache.py
+    "Layer classes")."""
+    spec = _decode_kv_geometry(model)
+    spans = spec.get('layer_spans')
+    if spans is None:
+        return spec['layers'], 0, 0
+    sliding = [s for s in spans if s]
+    return len(spans) - len(sliding), len(sliding), max(sliding, default=0)
+
+
+def decode_context_bytes(model, context, kv_dtype='f32'):
+    """HBM bytes the cached rows of ONE context of ``context`` positions
+    take across every layer: each class priced by what it holds,
+    ``context`` a full layer and ``min(context, span)`` a sliding one."""
+    full, sliding, span = decode_layer_classes(model)
+    return decode_token_layer_bytes(model, kv_dtype) * (
+        full * int(context) + sliding * min(int(context), span))
+
+
+def decode_sliding_class_bytes(model, slots, block_size, kv_dtype='f32'):
+    """HBM bytes of the SLIDING class's arrays: its depth is derived (a
+    ring a slot and the spare, serving/decode/engine.py), so it is a fixed
+    cost beside the weights, whatever the budget. 0 for a model without."""
+    _, sliding, span = decode_layer_classes(model)
+    if not sliding:
+        return 0
+    from ..serving.decode.engine import SLIDING_SPARE_BLOCKS
+    ring = -(-span // int(block_size)) + 1
+    return (sliding * (int(slots) * ring + SLIDING_SPARE_BLOCKS)
+            * int(block_size) * decode_token_layer_bytes(model, kv_dtype))
+
+
 def decode_step_rows(model, slots):
     """Rows the lockstep decode step feeds the model: one a slot, or for a
     WINDOW model (``kv_cache_spec()['window']`` B: block diffusion feeds a
@@ -562,9 +601,15 @@ def decode_step_rows(model, slots):
 
 
 def decode_pool_block_bytes(model, block_size, kv_dtype='f32'):
-    """HBM bytes ONE KV-cache block costs across every layer."""
-    return (_decode_kv_geometry(model)['layers'] * int(block_size)
-            * decode_token_layer_bytes(model, kv_dtype))
+    """HBM bytes ONE KV-cache block costs across every layer that holds it:
+    all of them, or for a model with layer classes the FULL layers (the
+    block count a budget buys is the full class's; the sliding class is
+    :func:`decode_sliding_class_bytes`)."""
+    spec = _decode_kv_geometry(model)
+    layers = decode_layer_classes(model)[0] if spec['kind'] == 'kv' \
+        else spec['layers']
+    return layers * int(block_size) * decode_token_layer_bytes(model,
+                                                               kv_dtype)
 
 
 def decode_state_row_bytes(model):
@@ -598,15 +643,26 @@ def solve_decode_state_slots(model, hbm_mb):
 
 
 def solve_decode_pool_blocks(model, hbm_mb, block_size, kv_dtype='f32',
-                             min_blocks=2):
+                             min_blocks=2, slots=None):
     """The ``PADDLE_TPU_DECODE_HBM_MB`` budget solve: blocks =
     (budget − model state) // per-block KV bytes, floored at
     ``min_blocks`` (the engine passes max_blocks_per_seq + 1 so an empty
     pool always covers one maximal request). Raises when the budget does
     not even cover the model's resident state — a silent floor there
-    would hide that the budget is fiction."""
+    would hide that the budget is fiction. A model with a sliding class of
+    layer needs ``slots``: that class's arrays (a ring a slot) come off
+    the budget first, and the blocks solved for are the full class's."""
     budget = int(hbm_mb) << 20
     state = _model_state_bytes(model)
+    if _decode_kv_geometry(model)['kind'] == 'kv' \
+            and decode_layer_classes(model)[1]:
+        if slots is None:
+            raise ValueError(
+                'a model with a sliding class of layer is sized per class: '
+                'solve_decode_pool_blocks needs slots (the sliding class '
+                'holds a ring a slot)')
+        state += decode_sliding_class_bytes(model, slots, block_size,
+                                            kv_dtype)
     block_bytes = decode_pool_block_bytes(model, block_size, kv_dtype)
     if budget <= state:
         raise ValueError(

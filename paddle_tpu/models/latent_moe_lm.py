@@ -165,12 +165,24 @@ class RoutedExperts(Layer):
     shared feed-forward of ``n_shared_experts`` expert widths (none where
     that is 0). The router scores by ``cfg.scoring_func``: 'sigmoid' with a
     selection bias (the default: `deepseek_v3`) or 'softmax' with none
-    (`sdar_moe`, models/block_diffusion_lm.py)."""
+    (`sdar_moe`, models/block_diffusion_lm.py).
+
+    ``cfg.experts_held`` = (first, count) makes the layer one chip's SHARE
+    of a layer whose experts are spread over several (models/
+    sliding_moe_lm.py): the router keeps its ``n_routed_experts`` outputs
+    and its top-k, the layer holds the weights of experts [first, first +
+    count) alone, and it returns Shared(x) + the part of the routed sum
+    that its own experts give. The shares of a layer, with the shared
+    expert counted once, add up to the whole layer. Nothing here stands in
+    for the other chips or their exchange. None (the default): every
+    expert is held, and the layer is what it was."""
 
     def __init__(self, cfg):
         super().__init__()
         e, h, f = (cfg.n_routed_experts, cfg.hidden_size,
                    cfg.moe_intermediate_size)
+        self.held = getattr(cfg, 'experts_held', None)
+        held = e if self.held is None else self.held[1]
         normal = NormalInitializer(0.0, cfg.initializer_range)
         scoring = getattr(cfg, 'scoring_func', 'sigmoid')
         self.router = _linear(cfg, h, e)
@@ -180,11 +192,11 @@ class RoutedExperts(Layer):
                 [e], None, 'float32', default_initializer=NormalInitializer(
                     0.0, cfg.router_bias_scale))
         self.experts_gate = self.create_parameter(
-            [e, h, f], None, cfg.dtype, default_initializer=normal)
+            [held, h, f], None, cfg.dtype, default_initializer=normal)
         self.experts_up = self.create_parameter(
-            [e, h, f], None, cfg.dtype, default_initializer=normal)
+            [held, h, f], None, cfg.dtype, default_initializer=normal)
         self.experts_down = self.create_parameter(
-            [e, f, h], None, cfg.dtype, default_initializer=normal)
+            [held, f, h], None, cfg.dtype, default_initializer=normal)
         self.shared = GatedFFN(cfg, cfg.n_shared_experts * f) \
             if cfg.n_shared_experts else None
         self._route = {'top_k': cfg.num_experts_per_tok,
@@ -206,17 +218,23 @@ class RoutedExperts(Layer):
             routed, counts = dispatch_op('moe_experts', {
                 'x': flat, 'ids': ids, 'weights': weights,
                 'w_gate': self.experts_gate, 'w_up': self.experts_up,
-                'w_down': self.experts_down}, {})
+                'w_down': self.experts_down},
+                {} if self.held is None else {'experts_held': self.held})
         if cache is not None:
             # for the host's counters: the rows each expert was given of the
             # call's LIVE tokens (the work the mathematics needs; `counts`
             # holds a rung's padding and idle slots too), and the experts
             # behind the rows the host reads
             live = cache.live_rows(b * s)
-            chosen = ids.value[..., None] == jnp.arange(
+            first = 0 if self.held is None else self.held[0]
+            chosen = (ids.value - first)[..., None] == jnp.arange(
                 counts.shape[0], dtype=jnp.int32)
             cache.note('expert_counts', (chosen & live[:, None, None]).sum(
                 (0, 1), dtype=jnp.int32))
+            if self.held is not None:
+                # all the live rows' assignments, held here or elsewhere
+                cache.note('expert_assignments', live.sum(
+                    dtype=jnp.int32) * ids.shape[-1])
             cache.note('expert_ids', _scored_rows(cache, ids.value, 0))
         if self.shared is not None:
             with jax.named_scope('moe/shared'):

@@ -109,6 +109,16 @@ def swiglu_ffn(x, w_gate, w_up, w_down):
     return _dot((jax.nn.silu(g) * u).astype(x.dtype), w_down)
 
 
+@register_op('sigmoid_gate')
+def sigmoid_gate(x, gate):
+    """x ⊙ sigmoid(gate), lane by lane: the gate on an attention layer's
+    output (before its output projection), the sigmoid and the product in
+    float32, x's dtype out."""
+    x = jnp.asarray(x)
+    return (x.astype(_F32) * jax.nn.sigmoid(jnp.asarray(gate).astype(_F32))
+            ).astype(x.dtype)
+
+
 @register_op('moe_router', outputs=('Ids', 'Weights'))
 def moe_router(x, w_gate, bias=None, *, top_k, routed_scaling_factor=1.0,
                norm_topk_prob=True, scoring_func='sigmoid'):
@@ -160,7 +170,7 @@ def experts_kernel_applies(x, w_gate):
 
 
 @register_op('moe_experts', outputs=('Out', 'Counts'))
-def moe_experts(x, ids, weights, w_gate, w_up, w_down):
+def moe_experts(x, ids, weights, w_gate, w_up, w_down, *, experts_held=None):
     """Σ_k weights[t, k] · E_ids[t, k](x[t]), each expert a gated
     feed-forward, as grouped matmuls over the T·k assignments sorted by
     expert. No capacity: every assignment is computed.
@@ -173,13 +183,31 @@ def moe_experts(x, ids, weights, w_gate, w_up, w_down):
     `silu(g) · u` in float32 and cast to x's dtype, the weighted sum in
     float32.
 
+    ``experts_held`` = (first, count): the layer holds experts [first,
+    first + count) of the router's (one chip's share under expert
+    parallelism) and ``w_*`` are theirs alone, E = count. ``ids`` still
+    range over all the router's experts: an assignment to an expert held
+    elsewhere sorts behind the held ones as nobody's row, is never
+    multiplied, and adds nothing here. The result is the held experts' part
+    of the sum, and ``Counts`` their rows.
+
     x (T, h); ids (T, k) int32; weights (T, k) float32; w_gate, w_up
     (E, h, f); w_down (E, f, h). Returns the (T, h) sum and the (E,) int32
-    rows each expert was given (they add up to T·k)."""
+    rows each expert was given (they add up to T·k, or to the held
+    assignments)."""
     x = jnp.asarray(x)
     t, k = ids.shape
     n_experts = w_gate.shape[0]
     flat = ids.reshape(-1)
+    held = None
+    if experts_held is not None:
+        first, count = (int(n) for n in experts_held)
+        if count != n_experts:
+            raise ValueError(f'moe_experts: experts_held={experts_held!r} '
+                             f'but the weights are of {n_experts} experts')
+        flat = flat - first
+        held = (flat >= 0) & (flat < count)
+        flat = jnp.where(held, flat, count)      # nobody's: behind the held
     counts = (flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype)
               ).sum(0, dtype=jnp.int32)
     order = jnp.argsort(flat, stable=True)       # assignments by expert
@@ -191,7 +219,11 @@ def moe_experts(x, ids, weights, w_gate, w_up, w_down):
         u = lax.ragged_dot(rows, w_up, counts, preferred_element_type=_F32)
         y = lax.ragged_dot((jax.nn.silu(g) * u).astype(x.dtype), w_down,
                            counts, preferred_element_type=_F32)
-    y = y[jnp.argsort(order)].reshape(t, k, -1)  # back to token order
+    y = y[jnp.argsort(order)]                    # back to token order
+    if held is not None:
+        # a row of nobody is whatever the kernel left there: dropped
+        y = jnp.where(held[:, None], y, 0.0)
+    y = y.reshape(t, k, -1)
     out = (y * weights[..., None]).sum(1)
     return out.astype(x.dtype), counts
 

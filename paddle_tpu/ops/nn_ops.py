@@ -10,6 +10,7 @@ Layouts: Paddle default NCHW is honored; NHWC supported via data_format attr
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -636,7 +637,9 @@ def norm(x, *, axis=-1, epsilon=1e-10):
 # compiler refusal is an error; where it does not, the XLA formulation runs
 # because the code says so. Nothing is caught: the op bodies run at trace
 # time inside the eager kernel-cache jit, so a Mosaic refusal would surface
-# at compile anyway, outside any handler here. `paged_attention` has no
+# at compile anyway, outside any handler here. The causal grouped form of
+# `paged_prefill_attention` (a model with layer classes) has the stock
+# splash-attention kernel and `causal_kernel_applies`. `paged_attention` has no
 # kernel and no predicate: its single-query read is one XLA formulation for
 # every head_dim and pool dtype (:func:`_live_block_attention`).
 #
@@ -801,10 +804,54 @@ def live_group_list(block_tables, context_lens, block_size):
     return block_ids, slot, first_pos, n_live
 
 
+def live_ring_group_chunk(slots, block_size, ring, span):
+    """(blocks a group holds, groups a span can touch in a slot's ring,
+    groups a chunk of the walk holds) of the sliding class's read."""
+    m = live_group_blocks(block_size, ring)
+    per_slot = -(-int(span) // (m * int(block_size))) + 1
+    return m, per_slot, min(LIVE_GROUP_CHUNK, int(slots) * per_slot)
+
+
+def live_ring_group_list(ring_tables, context_lens, block_size, span):
+    """:func:`live_group_list` of the SLIDING class: ``ring_tables`` (S, R)
+    hold each slot's ring, position p in ring block ``(p // block_size) mod
+    R``, and of a slot at context c the positions [max(0, c - span), c) are
+    live. Group j (``keys`` positions, as :func:`live_group_blocks` cuts
+    them, at the ring's width) of a slot is live iff it holds one of them.
+
+    Returns ``(block_ids, slot, first_pos, n_live)`` as that function does,
+    over N = S × (span / keys + 2) entries rounded up to whole chunks;
+    ``first_pos`` is the group's first POSITION in the sequence, not its
+    place in the ring, so that a reader masks by position: a block of a
+    live group that the ring has since given to a later position, or not
+    yet to this one, holds keys outside [c - span, c) by that count and
+    gets zero mass. Entries past ``n_live`` name the sliding class's
+    scratch block at a position no context reaches."""
+    tables = jnp.asarray(ring_tables, jnp.int32)
+    s, ring = tables.shape
+    m, per_slot, chunk = live_ring_group_chunk(s, block_size, ring, span)
+    keys = m * block_size
+    at = jnp.arange(-(-s * per_slot // chunk) * chunk, dtype=jnp.int32)
+    ctx = jnp.asarray(context_lens, jnp.int32)
+    first = jnp.maximum(ctx - int(span), 0) // keys        # (S,) first group
+    groups = (ctx - 1) // keys - first + 1
+    ends = jnp.cumsum(groups)
+    n_live = ends[-1]
+    slot = jnp.minimum(jnp.sum(at[:, None] >= ends[None, :], axis=1,
+                               dtype=jnp.int32), s - 1)
+    j = first[slot] + at - (ends - groups)[slot]           # group of the seq
+    live = at < n_live
+    blocks = (j[:, None] * m + jnp.arange(m, dtype=jnp.int32)[None, :]) % ring
+    block_ids = jnp.where(live[:, None], tables[slot[:, None], blocks], 0)
+    first_pos = jnp.where(live, j * keys, jnp.iinfo(jnp.int32).max // 2)
+    return block_ids, slot, first_pos, n_live
+
+
 @register_op('paged_attention')
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                     k_scales=None, v_scales=None, live=None, *,
-                    sm_scale=1.0, block_window=False, kv_heads=None):
+                    sm_scale=1.0, block_window=False, kv_heads=None,
+                    span=0):
     """Single-token decode attention over a paged KV cache (the decode half
     of the serving decode engine — docs/SERVING.md "Stateful decode";
     blueprint: Ragged Paged Attention, PAPERS.md arxiv 2604.15464).
@@ -843,8 +890,16 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
       i // (H/G)); the read walks the live context
       (:func:`_live_group_attention`) and gathers no table whole. int8
       pools have no block read.
+    - ``kv_heads`` with a single query (q of rank 3): the GROUPED read of a
+      model whose pool rows hold G ≤ H key/value heads. It is the block
+      read's walk with one row a slot (:func:`_live_group_attention`: a
+      group of 128 keys is one matmul a key/value head for its H/G query
+      heads). ``span`` S > 0 makes it a SLIDING layer's read:
+      ``block_tables`` are the slots' rings
+      (:func:`live_ring_group_list`) and a key at position p counts iff
+      context - S <= p < context. int8 pools have neither.
 
-    The single-query read has ONE formulation, for every head_dim and pool
+    The single-query read of a pool of q's own heads has ONE formulation, for every head_dim and pool
     dtype (:func:`_live_block_attention`): it walks the batch's live blocks
     as they lie in the pool, in chunks, with a running softmax, so its work
     follows the contexts' lengths and not the table's padded width, and no
@@ -873,6 +928,21 @@ def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                                    k_pages.shape[1])
         return _live_group_attention(q, k_pages, v_pages, context_lens, live,
                                      int(kv_heads or q.shape[1]), sm_scale)
+    if q.ndim == 3 and kv_heads is not None:
+        if k_scales is not None:
+            raise ValueError('paged_attention: an int8 pool has no grouped '
+                             'read')
+        if live is None:
+            live = live_ring_group_list(
+                block_tables, context_lens, k_pages.shape[1], span) \
+                if span else live_group_list(block_tables, context_lens,
+                                             k_pages.shape[1])
+        return _live_group_attention(
+            q[:, :, None, :], k_pages, v_pages, context_lens, live,
+            int(kv_heads), sm_scale, int(span))[:, :, 0]
+    if span:
+        raise ValueError('paged_attention: span is the grouped '
+                         'single-query read\'s (kv_heads)')
     if q.ndim == 4:
         # multi-query decode (speculative verify): K fed tokens per slot,
         # row j at extent context_lens + j
@@ -964,7 +1034,7 @@ def _live_block_attention(q, k_pages, v_pages, context_lens, live,
 
 
 def _live_group_attention(q, k_pages, v_pages, context_lens, live, kv_heads,
-                          sm_scale):
+                          sm_scale, span=0):
     """q (S, H, K, D) against the pool's LIVE groups
     (:func:`live_group_list`), a chunk of C groups at a time, only as many
     chunks as hold live groups; every row of a slot sees positions <
@@ -979,7 +1049,9 @@ def _live_group_attention(q, k_pages, v_pages, context_lens, live, kv_heads,
     folded into per-slot running state m, l (S, G, R) and acc (S, G, R, D)
     with the running-softmax rescale, a slot's groups found by the one-hot
     (S, C) of the chunk's ``slot`` as in :func:`_live_block_attention`.
-    Masked positions get exactly-zero mass."""
+    Masked positions get exactly-zero mass. ``span`` S > 0: a position p
+    counts only if context - S <= p as well (``live``'s ``first_pos`` are
+    then positions of the sequence, :func:`live_ring_group_list`)."""
     f32, exact = jnp.float32, lax.Precision.HIGHEST
     s, h, kq, d = q.shape
     g = int(kv_heads)
@@ -1006,8 +1078,11 @@ def _live_group_attention(q, k_pages, v_pages, context_lens, live, kv_heads,
         m, l, acc = state
         ids, of, pos = (lax.dynamic_slice_in_dim(x, i * chunk, chunk)
                         for x in (block_ids, slot, first_pos))
-        seen = (pos[:, None] + offsets[None, :]
-                < context_lens[of][:, None])[:, None, None, :]   # (C,1,1,T)
+        at = pos[:, None] + offsets[None, :]                      # (C, T)
+        seen = at < context_lens[of][:, None]
+        if span:
+            seen = seen & (at >= context_lens[of][:, None] - span)
+        seen = seen[:, None, None, :]                             # (C,1,1,T)
         mine = of[None, :] == slots[:, None]                      # (S, C)
         to_slot = mine.astype(f32)
         scores = jnp.einsum('cgrd,ctgd->cgrt', qg[of], rows_of(k_pages, ids),
@@ -1066,7 +1141,8 @@ def _gather_pages(pages, block_tables, s, h, d, scales=None):
 @register_op('paged_prefill_attention')
 def paged_prefill_attention(q, k, v, k_pages, v_pages, block_tables,
                             k_scales=None, v_scales=None, *,
-                            sm_scale=1.0, block_len=0):
+                            sm_scale=1.0, block_len=0, kv_heads=None,
+                            span=0):
     """Prefill-phase attention for the decode engine: causal whole-prompt
     attention whose KEY EXTENT is the paged-cache view, so prefill rows are
     bitwise-identical to the decode steps (and to a whole-sequence forward
@@ -1098,10 +1174,28 @@ def paged_prefill_attention(q, k, v, k_pages, v_pages, block_tables,
     prefill attends the raw projections it was handed, the prompt itself,
     in chunks of query rows (:func:`_block_prefill_attention`): the pool
     holds the same values (a quantized pool is refused by the engine), and
-    nothing of a table is gathered."""
+    nothing of a table is gathered.
+
+    ``kv_heads`` G (the heads ``k``/``v`` hold, G ≤ H) is the CAUSAL
+    grouped-head form of a model with layer classes: row i sees key j iff
+    j <= i, and with ``span`` S > 0 iff 0 <= i - j < S as well (a sliding
+    layer). It too attends the raw projections: where
+    :func:`causal_kernel_applies` holds, the stock pallas splash-attention
+    kernel under a causal or a local mask, a key/value head's H/G query
+    heads at a time (:func:`_splash_prefill_attention`); elsewhere in
+    chunks of query rows against chunks of keys with a running softmax
+    (:func:`_causal_prefill_attention`). In neither does an array grow
+    with the square of the rung."""
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
     if block_len:
         return _block_prefill_attention(q, k, v, int(block_len), sm_scale)
+    if kv_heads is not None:
+        if k.shape[1] != int(kv_heads):
+            raise ValueError(f'paged_prefill_attention: kv_heads={kv_heads} '
+                             f'but k holds {k.shape[1]} heads')
+        if causal_kernel_applies(q):
+            return _splash_prefill_attention(q, k, v, int(span), sm_scale)
+        return _causal_prefill_attention(q, k, v, int(span), sm_scale)
     if (flash_kernel_applies(q, k)
             and jnp.asarray(k_pages).dtype == jnp.float32):
         from jax.experimental.pallas.ops.tpu.flash_attention import (
@@ -1156,3 +1250,128 @@ def _block_prefill_attention(q, k, v, block_len, sm_scale):
         out.append(jnp.einsum('bgrqk,bgkd->bgrqd', p, v[:, :, :stop],
                               preferred_element_type=f32).astype(q.dtype))
     return jnp.concatenate(out, 3).reshape(b, h, length, d)
+
+
+# query rows and keys a step of the causal grouped prefill holds at once:
+# (G, H/G · rows, keys) float32 scores, 100 MB at 48 heads (a 16,384-row
+# rung would hold 51 GB of (48, L, L) scores whole)
+_CAUSAL_PREFILL_QUERY_CHUNK = 512
+_CAUSAL_PREFILL_KEY_CHUNK = 1024
+
+
+def _causal_prefill_attention(q, k, v, span, sm_scale):
+    """q (1, H, L, D) over k, v (1, G, L, D), causal (key j visible to row i
+    iff j <= i) and, ``span`` S > 0, only while i - j < S; grouped heads
+    (query head i reads key/value head i // (H/G)), operands as stored,
+    float32 scores and softmax.
+
+    Query rows go a chunk at a time (`lax.map`), each against the key chunks
+    that hold a key it may see, [max(0, start - S + 1), stop), folded with
+    the running-softmax rescale: the work is the mask's, to a chunk, and
+    the largest array is one chunk pair's scores. A rung shorter than a
+    chunk is one pair."""
+    f32 = jnp.float32
+    _, h, length, d = q.shape
+    g = k.shape[1]
+    r = h // g
+    cq = min(length, _CAUSAL_PREFILL_QUERY_CHUNK)
+    ck = min(length, _CAUSAL_PREFILL_KEY_CHUNK)
+    if length % cq or length % ck:
+        cq = ck = length
+    qg = q[0].reshape(g, r, length, d)
+    kg, vg = k[0], v[0]                                    # (G, L, D)
+    scale = jnp.asarray(sm_scale, f32)
+    neg = jnp.finfo(f32).min
+    rows = jnp.arange(cq, dtype=jnp.int32)
+    cols = jnp.arange(ck, dtype=jnp.int32)
+
+    def one(i):
+        start = i * cq
+        qi = lax.dynamic_slice_in_dim(qg, start, cq, 2).reshape(g, r * cq, d)
+        at_q = jnp.tile(start + rows, r)                   # (R·cq,) positions
+
+        def fold(j, state):
+            m, l, acc = state
+            kj = lax.dynamic_slice_in_dim(kg, j * ck, ck, 1)
+            vj = lax.dynamic_slice_in_dim(vg, j * ck, ck, 1)
+            at_k = j * ck + cols
+            seen = at_k[None, :] <= at_q[:, None]
+            if span:
+                seen = seen & (at_q[:, None] - at_k[None, :] < span)
+            s = jnp.einsum('gqd,gkd->gqk', qi, kj,
+                           preferred_element_type=f32) * scale
+            s = jnp.where(seen[None], s, neg)
+            m_new = jnp.maximum(m, s.max(-1))
+            p = jnp.where(seen[None], jnp.exp(s - m_new[..., None]), 0.0)
+            rescale = jnp.exp(m - m_new)
+            l = l * rescale + p.sum(-1)
+            acc = acc * rescale[..., None] + jnp.einsum(
+                'gqk,gkd->gqd', p.astype(vj.dtype), vj,
+                preferred_element_type=f32)
+            return m_new, l, acc
+
+        lo = jnp.maximum(start - span + 1, 0) // ck if span else 0
+        hi = (start + cq - 1) // ck + 1
+        m, l, acc = lax.fori_loop(
+            lo, hi, fold,
+            (jnp.full((g, r * cq), neg, f32), jnp.zeros((g, r * cq), f32),
+             jnp.zeros((g, r * cq, d), f32)))
+        return (acc / l[..., None]).astype(q.dtype).reshape(g, r, cq, d)
+
+    out = lax.map(one, jnp.arange(length // cq, dtype=jnp.int32))
+    # (chunks, G, R, cq, D) -> (1, H, L, D)
+    return out.transpose(1, 2, 0, 3, 4).reshape(1, h, length, d)
+
+
+# rows and keys a block of the splash kernel holds (its scores stay in VMEM:
+# 512 x 512 float32 a query head)
+_SPLASH_BLOCK = 512
+
+
+def causal_kernel_applies(q):
+    """True when the causal grouped prefill runs the pallas splash-attention
+    kernel for (1, H, L, D) ``q`` (an array or a ShapeDtypeStruct): a TPU
+    backend, f32 or bf16, and a rung of whole kernel blocks. The repo's one
+    convention ("explicit kernel dispatch", above): where it holds the
+    kernel runs and a Mosaic refusal is an error; elsewhere the XLA
+    formulation runs because the code says so (the CPU tests, a rung
+    shorter than a block)."""
+    return (on_tpu() and len(q.shape) == 4
+            and q.dtype in (jnp.float32, jnp.bfloat16)
+            and q.shape[2] % _SPLASH_BLOCK == 0)
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(length, span, heads, interpret=False):
+    """The splash-attention MQA kernel for ``heads`` query heads over ONE
+    key/value head, causal over ``length`` positions and, ``span`` S > 0,
+    local to the last S: row i sees key j iff 0 <= i - j < S. Its mask is
+    worked out here once a (rung, span) from the block structure alone (no
+    (L, L) array exists); forward only."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as kernel, splash_attention_mask as masks)
+    shape = (length, length)
+    one = masks.LocalMask(shape, (span - 1, 0), 0) if span \
+        else masks.CausalMask(shape)
+    block = min(_SPLASH_BLOCK, length)
+    return kernel.make_splash_mqa_single_device(
+        masks.MultiHeadMask([one] * heads),
+        block_sizes=kernel.BlockSizes(block_q=block, block_kv=block,
+                                      block_kv_compute=block),
+        interpret=interpret)
+
+
+def _splash_prefill_attention(q, k, v, span, sm_scale, interpret=False):
+    """:func:`_causal_prefill_attention`'s mathematics through the stock
+    pallas splash-attention kernel: q (1, H, L, D) over k, v (1, G, L, D),
+    each key/value head with its H/G query heads as one multi-query call
+    (`jax.vmap` over G), operands as stored, float32 scores, softmax and
+    accumulation inside the kernel, whose blocks of scores never reach HBM.
+    The kernel takes no scale: q is scaled first, in float32."""
+    _, h, length, d = q.shape
+    g = k.shape[1]
+    kernel = _splash_kernel(length, span, h // g, interpret)
+    qg = (q[0].astype(jnp.float32) * jnp.asarray(sm_scale, jnp.float32)
+          ).astype(q.dtype).reshape(g, h // g, length, d)
+    out = jax.vmap(kernel)(qg, k[0], v[0])              # (G, H/G, L, D)
+    return out.reshape(1, h, length, d).astype(q.dtype)

@@ -14,9 +14,16 @@ rows once, however many experts share it.
 
 What is this repo's own:
 
-- a block is the expert's WHOLE matrix, (h, f) or (f, h): no grid axis over
-  the contraction or the width, no accumulator, and one DMA of 3.1 MB a
-  weight where the published sizes are 2,048 × 768 in bf16;
+- a block spans the expert's whole CONTRACTION, (h, ·) or (f, ·): no
+  contraction split, so no accumulator. Where the matrix is at most
+  `WEIGHT_BLOCK_BYTES` the block is the whole matrix and the grid is the
+  visits alone: one DMA of 3.1 MB a weight where the published sizes are
+  2,048 × 768 in bf16. A wider expert (3,072 × 3,072: 18.9 MB a matrix) is
+  cut along its OUTPUT width into blocks of at most that size, and the
+  width is the grid's OUTER axis: for each width block the visits are
+  walked whole, so an expert's weights still cross once, an output tile is
+  still revisited only by consecutive visits, and what crosses again is
+  the rows, once a width block;
 - gate and up run in ONE pass over the rows (`expert_ffn`): two weight
   blocks a visit, `silu(g) · u` in float32 in the kernel, a result in the
   rows' dtype: the two float32 (m, f) intermediates never reach HBM;
@@ -31,6 +38,7 @@ worse (PERF.md §6, PR 33): the tile is a constant.
 """
 from __future__ import annotations
 
+import functools
 import re
 
 import jax
@@ -43,6 +51,10 @@ _F32 = jnp.float32
 
 # rows a visit multiplies: the MXU's own tile on a v5e
 ROW_TILE = 128
+# the most one weight block holds: the DMA of a 12 MB block was the limit
+# found at PR 33 (PERF.md section 6); 6 MiB is 3,072 × 1,024 in bf16, and
+# two such blocks a visit (gate and up), double-buffered, are 25 MB of VMEM
+WEIGHT_BLOCK_BYTES = 6 << 20
 
 
 def group_visits(counts, m):
@@ -71,12 +83,25 @@ def group_visits(counts, m):
         tiles.sum()
 
 
-def _visit(offsets, group_ids, tile_ids, x_ref, *refs):
+def width_block(k, n, itemsize, limit=None):
+    """Columns of an expert's (k, n) matrix one block holds: all n where the
+    matrix is within ``limit`` bytes (`WEIGHT_BLOCK_BYTES`), else the
+    largest multiple of 128 that divides n and keeps the block within it
+    (128 where none does)."""
+    limit = WEIGHT_BLOCK_BYTES if limit is None else int(limit)
+    if k * n * itemsize <= limit:
+        return n
+    fits = [w for w in range(128, n, 128)
+            if n % w == 0 and k * w * itemsize <= limit]
+    return max(fits) if fits else (128 if n % 128 == 0 else n)
+
+
+def _visit(visit_axis, offsets, group_ids, tile_ids, x_ref, *refs):
     """One visit: the tile times the expert's block(s), float32
     accumulation; with two blocks `silu(x · w0) · (x · w1)` in float32. The
     expert's rows are stored, the tile's other rows stay as they are."""
     *w_refs, o_ref = refs
-    visit = pl.program_id(0)
+    visit = pl.program_id(visit_axis)
     group = group_ids[visit]
     x = x_ref[...]
     y = [jnp.dot(x, w[...], preferred_element_type=_F32) for w in w_refs]
@@ -87,50 +112,75 @@ def _visit(offsets, group_ids, tile_ids, x_ref, *refs):
     o_ref[...] = jnp.where(mine, y.astype(o_ref.dtype), o_ref[...])
 
 
-def grouped_matmul(rows, weights, visits, *, out_dtype, interpret=False):
+def grouped_matmul(rows, weights, visits, *, out_dtype, interpret=False,
+                   block_bytes=None):
     """rows (m, k) sorted by expert × each of ``weights`` (E, k, n), over
     ``visits`` = `group_visits(counts, m)`: (m, n) in ``out_dtype``. One
     weight: the product. Two: `silu(rows · w0) ⊙ (rows · w1)`. A row of no
-    expert (the padding) comes back undefined."""
+    expert (the padding) comes back undefined. ``block_bytes`` (the tests')
+    stands in for `WEIGHT_BLOCK_BYTES`."""
     (offsets, group_ids, tile_ids), n_visits = visits
     m, k = rows.shape
     n = weights[0].shape[2]
     tm = ROW_TILE
+    tn = width_block(k, n, max(w.dtype.itemsize for w in weights),
+                     block_bytes)
 
-    def tile(visit, offsets, group_ids, tile_ids):
-        return tile_ids[visit], 0
+    if tn == n:
+        # the whole matrix a block: the grid is the visits
+        grid, visit_axis = (n_visits,), 0
 
-    def block(visit, offsets, group_ids, tile_ids):
-        return group_ids[visit], 0, 0
+        def tile(visit, offsets, group_ids, tile_ids):
+            return tile_ids[visit], 0
+
+        def block(visit, offsets, group_ids, tile_ids):
+            return group_ids[visit], 0, 0
+
+        out_tile = tile
+    else:
+        # the width outermost: every visit for one block of columns, then
+        # the next block
+        grid, visit_axis = (n // tn, n_visits), 1
+
+        def tile(j, visit, offsets, group_ids, tile_ids):
+            return tile_ids[visit], 0
+
+        def block(j, visit, offsets, group_ids, tile_ids):
+            return group_ids[visit], 0, j
+
+        def out_tile(j, visit, offsets, group_ids, tile_ids):
+            return tile_ids[visit], j
 
     out_item = jnp.dtype(out_dtype).itemsize
-    resident = (tm * k * rows.dtype.itemsize + tm * n * out_item
-                + sum(k * n * w.dtype.itemsize for w in weights))
+    resident = (tm * k * rows.dtype.itemsize + tm * tn * out_item
+                + sum(k * tn * w.dtype.itemsize for w in weights))
     return pl.pallas_call(
-        _visit,
+        functools.partial(_visit, visit_axis),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             in_specs=[pl.BlockSpec((tm, k), tile)]
-            + [pl.BlockSpec((None, k, n), block) for _ in weights],
-            out_specs=pl.BlockSpec((tm, n), tile),
-            grid=(n_visits,)),
+            + [pl.BlockSpec((None, k, tn), block) for _ in weights],
+            out_specs=pl.BlockSpec((tm, tn), out_tile),
+            grid=grid),
         # an output tile is revisited by consecutive visits: in order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=('arbitrary',),
+            dimension_semantics=('arbitrary',) * len(grid),
             # every block double-buffered, the float32 products beside them
-            vmem_limit_bytes=2 * resident + len(weights) * tm * n * 4
+            vmem_limit_bytes=2 * resident + len(weights) * tm * tn * 4
             + (8 << 20)),
         cost_estimate=pl.CostEstimate(
             flops=2 * m * k * n * len(weights),
             transcendentals=m * n * (len(weights) - 1),
-            bytes_accessed=m * k * rows.dtype.itemsize + m * n * out_item
+            bytes_accessed=(n // tn) * m * k * rows.dtype.itemsize
+            + m * n * out_item
             + sum(w.size * w.dtype.itemsize for w in weights)),
         interpret=interpret,
     )(offsets, group_ids, tile_ids, rows, *weights)
 
 
-def expert_ffn(x, source, counts, w_gate, w_up, w_down, *, interpret=False):
+def expert_ffn(x, source, counts, w_gate, w_up, w_down, *, interpret=False,
+               block_bytes=None):
     """w_down,e(silu(x_s · w_gate,e) ⊙ (x_s · w_up,e)) for the assignments
     sorted by expert: ``source`` (m,) the row of x (T, h) behind each,
     ``counts`` (E,) how many each expert owns. Returns (≥ m, h) float32:
@@ -142,16 +192,18 @@ def expert_ffn(x, source, counts, w_gate, w_up, w_down, *, interpret=False):
     rows = x[jnp.pad(source, (0, padded - m))]
     visits = group_visits(counts, padded)
     hidden = grouped_matmul(rows, (w_gate, w_up), visits, out_dtype=x.dtype,
-                            interpret=interpret)
+                            interpret=interpret, block_bytes=block_bytes)
     return grouped_matmul(hidden, (w_down,), visits, out_dtype=_F32,
-                          interpret=interpret)
+                          interpret=interpret, block_bytes=block_bytes)
 
 
 def kernel_op_names(hlo_text):
     """The `op_name` of every Mosaic custom call in a compiled program's
     text: what says the kernel is there and under which scope a profiler
     trace will book it (chip_smoke.py, tests/framework/
-    test_kv_pool_layout.py)."""
-    return [re.search(r'op_name="([^"]*)"', line).group(1)
-            for line in hlo_text.splitlines()
-            if 'custom_call_target="tpu_custom_call"' in line]
+    test_kv_pool_layout.py). '' for a call the compiler left without one
+    (the stock splash-attention kernel's, ops/nn_ops.py)."""
+    names = (re.search(r'op_name="([^"]*)"', line)
+             for line in hlo_text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line)
+    return [found.group(1) if found else '' for found in names]

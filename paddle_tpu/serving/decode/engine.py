@@ -64,7 +64,7 @@ from ...dygraph.jit import _bind
 from ...dygraph.tape import Tensor, no_grad_guard
 from ...ops.llm_ops import diffusion_pick
 from ...ops.nn_ops import (live_block_chunk, live_group_blocks,
-                           live_group_chunk)
+                           live_group_chunk, live_ring_group_chunk)
 from ..engine import bucket_ladder
 from ..errors import InvalidRequest, UnsupportedCacheFeature
 from .diffusion import unmask_most_confident
@@ -72,7 +72,12 @@ from .kv_cache import (BlockTable, CacheContext, KVCachePool, decode_coords,
                        prefill_coords, DEFAULT_BLOCK_SIZE,
                        DEFAULT_MAX_BLOCKS, DEFAULT_SLOTS)
 
-__all__ = ['DecodeEngine']
+__all__ = ['DecodeEngine', 'SLIDING_SPARE_BLOCKS']
+
+# blocks the sliding class's arrays hold beyond every slot's ring: the
+# class's scratch block and a few spare, as a K/V pool sized by its slots
+# holds (`slots × blocks a slot + 8`)
+SLIDING_SPARE_BLOCKS = 8
 
 _NULL_LOCK = contextlib.nullcontext()
 
@@ -385,7 +390,22 @@ class DecodeEngine:
         # (the default) or one latent row (models/latent_moe_lm.py), or per
         # REQUEST per layer one recurrent state (models/retention_lm.py)
         spec = getattr(model, 'kv_cache_spec', None)
-        self.cache_kind = spec()['kind'] if spec else 'kv'
+        spec = spec() if spec else {'kind': 'kv'}
+        self.cache_kind = spec['kind']
+        # the K/V layers' classes (kv_cache.py "Layer classes"): each
+        # layer's span, 0 a full layer; None where the model names none
+        # (one class, full). The sliding class's span is the one they share
+        self.layer_spans = spec.get('layer_spans')
+        spans = {s for s in self.layer_spans or () if s}
+        if len(spans) > 1:
+            raise ValueError(
+                f'the model\'s sliding layers span {sorted(spans)}: the '
+                f'pool holds one sliding class, of one span')
+        self.span = int(spans.pop()) if spans else 0
+        # (full layers, sliding layers) of a model that names its classes
+        n_sliding = sum(bool(s) for s in self.layer_spans or ())
+        self._class_layers = (len(self.layer_spans or ()) - n_sliding,
+                              n_sliding)
         # rows a slot feeds the lockstep step: 1 for every model but a
         # WINDOW model (block diffusion), whose step is `window_step`
         self.window = int(getattr(model, 'decode_window', 1))
@@ -418,12 +438,18 @@ class DecodeEngine:
             kv_dtype = parse_choice_env(ENV_KV_DTYPE, KV_DTYPE_CHOICES,
                                         'f32')
         num_blocks = self._resolve_num_blocks(model, max_blocks, block_size,
-                                              max_bps, kv_dtype)
-        # a state cache: a row a slot, and the scratch row of idle slots
+                                              max_bps, kv_dtype, self.slots)
+        # a state cache: a row a slot, and the scratch row of idle slots.
+        # The sliding class's depth is DERIVED, never asked for: a ring a
+        # slot and the spare, since a slot never holds more of it
+        ring = -(-self.span // block_size) + 1 if self.span else 0
         self.pool = KVCachePool(
             block_size=block_size, num_blocks=num_blocks,
             max_blocks_per_seq=max_bps, kv_dtype=kv_dtype,
-            state_rows=self.slots + 1 if self.cache_kind == 'state' else 0)
+            state_rows=self.slots + 1 if self.cache_kind == 'state' else 0,
+            span=self.span,
+            sliding_blocks=self.slots * ring + SLIDING_SPARE_BLOCKS
+            if self.span else 0)
         if self.pool.allocator.capacity < max_bps:
             # an empty pool must always cover one maximal request, or the
             # scheduler's FIFO head could wait forever
@@ -487,6 +513,20 @@ class DecodeEngine:
                 (f'kv_dtype={kv_dtype}', kv_dtype != 'f32')) if on]
             if asked:
                 raise UnsupportedCacheFeature(asked, 'state')
+        if self.span:
+            # none has a path over a ring yet (docs/SERVING.md "Layer
+            # classes"); the handoff is refused where its prefill role is
+            # built (serving/tier/disagg.py)
+            asked = [name for name, on in (
+                ('the prefix cache (and its spill and reinject)',
+                 self.prefix_cache is not None),
+                ('speculative decoding (its (S, K) verify step)',
+                 self.spec_enabled),
+                ('kv_dtype=int8', kv_dtype == 'int8'),
+                ('a window model\'s block step', self.window > 1))
+                if on]
+            if asked:
+                raise UnsupportedCacheFeature(asked, 'sliding')
         if self.window > 1:
             # each needs a path under the block mask that is not written
             # yet (docs/SERVING.md "Window models"); the handoff is refused
@@ -502,7 +542,7 @@ class DecodeEngine:
 
     @staticmethod
     def _resolve_num_blocks(model, max_blocks, block_size, max_bps,
-                            kv_dtype):
+                            kv_dtype, slots):
         """Pool-size precedence (docs/SERVING.md "Tiered KV cache"): an
         explicit ``max_blocks=`` arg wins, then an explicitly-SET
         ``PADDLE_TPU_DECODE_MAX_BLOCKS`` env (checked live, not the
@@ -522,7 +562,7 @@ class DecodeEngine:
             from ...analysis.plan import solve_decode_pool_blocks
             return solve_decode_pool_blocks(
                 model, hbm_mb, block_size=block_size, kv_dtype=kv_dtype,
-                min_blocks=max_bps + 1)
+                min_blocks=max_bps + 1, slots=slots)
         return DEFAULT_MAX_BLOCKS
 
     # -- geometry ----------------------------------------------------------
@@ -581,8 +621,19 @@ class DecodeEngine:
 
     def release_table(self, table):
         self.pool.free_table(table)
-        _m.decode_cache_blocks_used.set(self.pool.allocator.used)
+        self._set_block_gauges()
         self._set_state_gauges()
+
+    def _set_block_gauges(self):
+        """Blocks held by live requests: the full class's (the one gauge a
+        pool of one class has), and where the model has layer classes each
+        class's own."""
+        used = self.pool.allocator.used
+        _m.decode_cache_blocks_used.set(used)
+        if self.layer_spans is not None:
+            _m.decode_full_blocks_held.set(used)
+            _m.decode_sliding_blocks_held.set(
+                self.pool.sliding.used if self.span else 0)
 
     def _set_state_gauges(self):
         """The state cache's three gauges (a telemetry reset clears gauges,
@@ -610,9 +661,12 @@ class DecodeEngine:
                 # a window model's prefill picks nothing: the program is
                 # done when the pool it wrote is
                 jax.block_until_ready(self.pool.arrays())
-            picks, counts, rows = clock.fetch(
-                picks, stats.get('expert_counts'),
-                rows if fetch_rows else None)
+            counts = stats.get('expert_counts')
+            if 'expert_assignments' in stats:
+                # a share of the experts: all the assignments beside it
+                counts = (counts, stats['expert_assignments'])
+            picks, counts, rows = clock.fetch(picks, counts,
+                                              rows if fetch_rows else None)
         self.last_stats = stats
         if counts is not None:
             clock.work.update(self._account_experts(clock.call, counts))
@@ -625,9 +679,20 @@ class DecodeEngine:
         slots are routed and computed too, and not counted: the counters
         hold the work the mathematics needs): assignments, experts that
         got at least one row, and the worst layer's largest load over its
-        mean."""
+        mean. Where the layers hold a SHARE of their experts ``counts`` is
+        the pair (the held experts' rows, every layer's assignments held
+        here or elsewhere): the old counters and args keep the work done
+        here, and two more say of how much it is the share."""
+        total = None
+        if isinstance(counts, tuple):
+            counts, total = counts
         work = {'expert_assignments': int(counts.sum()),
                 'experts_touched': int((counts > 0).sum())}
+        if total is not None:
+            work['assignments_held'] = work['expert_assignments']
+            work['assignments_total'] = int(total.sum())
+            _m.decode_expert_assignments_held.inc(work['assignments_held'])
+            _m.decode_expert_assignments_total.inc(work['assignments_total'])
         _m.decode_expert_assignments.inc(work['expert_assignments'])
         _m.decode_experts_touched.inc(work['experts_touched'])
         _m.decode_expert_load_max_over_mean.labels(call=call).observe(
@@ -712,6 +777,9 @@ class DecodeEngine:
         if folded:
             _m.decode_state_tokens_folded.inc(folded)
             clock.work['state_tokens_folded'] = folded
+        if self.layer_spans is not None:
+            # positions the prompt leaves in each class's blocks
+            clock.work.update(self._class_positions([P])[1])
         clock.record(prompt_len=P, bucket=bucket)
         self._after_prefill(bucket)
         return token
@@ -720,7 +788,7 @@ class DecodeEngine:
         if bucket not in self._prefill_compiled:
             self._prefill_compiled.add(bucket)
             _m.decode_prefill_compiles.inc()
-        _m.decode_cache_blocks_used.set(self.pool.allocator.used)
+        self._set_block_gauges()
         _m.kv_cache_bytes_in_hbm.set(self.pool.bytes_in_hbm())
         _m.kv_cache_row_bytes.set(self.pool.row_bytes())
         self._set_state_gauges()
@@ -807,16 +875,31 @@ class DecodeEngine:
             return 0
         if self.cache_kind != 'kv':
             return entries
-        if self.window > 1:
-            # the block read walks whole groups of blocks, in whole chunks
-            # of groups (ops/nn_ops.py::live_group_list)
-            per_group = live_group_blocks(self.block_size,
-                                          self.pool.max_blocks_per_seq)
-            _, chunk = live_group_chunk(self.slots, self.block_size,
+        if self.window > 1 or self.layer_spans is not None:
+            # the block read and the grouped one-token reads walk whole
+            # groups of blocks, in whole chunks of groups
+            # (ops/nn_ops.py::live_group_list)
+            bs = self.block_size
+            per_group = live_group_blocks(bs, self.pool.max_blocks_per_seq)
+            _, chunk = live_group_chunk(self.slots, bs,
                                         self.pool.max_blocks_per_seq)
-            live = sum(-(-int(c) // (per_group * self.block_size))
-                       for c in ctx_lens)
-            return -(-live // chunk) * chunk * per_group
+            live = sum(-(-int(c) // (per_group * bs)) for c in ctx_lens)
+            full = -(-live // chunk) * chunk * per_group
+            if self.layer_spans is None:
+                return full
+            # layer classes: a pair, (a full layer's, a sliding layer's);
+            # a sliding layer walks the groups of its ring that its span
+            # touches (ops/nn_ops.py::live_ring_group_list)
+            sliding = 0
+            if self.span:
+                per_group, _, chunk = live_ring_group_chunk(
+                    self.slots, bs, self.pool.ring, self.span)
+                keys = per_group * bs
+                live = sum((int(c) - 1) // keys
+                           - max(int(c) - self.span, 0) // keys + 1
+                           for c in ctx_lens)
+                sliding = -(-live // chunk) * chunk * per_group
+            return full, sliding
         chunk = live_block_chunk(entries)
         live = sum(-(-int(c) // self.block_size) for c in ctx_lens)
         return -(-live // chunk) * chunk
@@ -835,12 +918,21 @@ class DecodeEngine:
         # the layers that cache rows (a state layer attends no position and
         # reads no block: it advances one state a live slot)
         layers = self.pool.num_row_layers
-        positions = layers * (attended if attended is not None else sum(
-            t.context_len for t in tables if t is not None))
+        if self.layer_spans is not None:
+            # two classes: a sliding layer attends its span's positions
+            # and walks its ring's groups
+            positions, by_class = self._class_positions(
+                [t.context_len for t in tables if t is not None])
+            clock.work.update(by_class)
+            blocks = sum(n * b for n, b in zip(self._class_layers, blocks))
+        else:
+            positions = layers * (attended if attended is not None else sum(
+                t.context_len for t in tables if t is not None))
+            blocks = layers * blocks
         _m.decode_context_positions_read.inc(positions)
-        _m.decode_kv_blocks_read.inc(layers * blocks)
+        _m.decode_kv_blocks_read.inc(blocks)
         clock.work['context_positions'] = positions
-        clock.work['kv_blocks'] = layers * blocks
+        clock.work['kv_blocks'] = blocks
         updates = self.pool.num_state_layers * active
         if updates:
             _m.decode_state_updates.inc(updates)
@@ -851,6 +943,23 @@ class DecodeEngine:
         # sliding-window views for /healthz slo + fleet snapshots
         _dobs.series('occupancy').observe(active / max(self.slots, 1))
         _dobs.series('decode_step').observe(dt)
+
+    def _class_positions(self, contexts):
+        """(positions the layers hold of ``contexts``, the call's span args
+        by class): Σ over the contexts of ``context`` a full layer and
+        ``min(context, span)`` a sliding layer. Books
+        ``decode_kv_positions_held`` with it and
+        ``decode_kv_positions_if_unwindowed`` with what every layer would
+        hold were none sliding: the ring's saving, read off two counters."""
+        n_full, n_sliding = self._class_layers
+        total = sum(int(c) for c in contexts)
+        within = sum(min(int(c), self.span) for c in contexts)
+        full, sliding = n_full * total, n_sliding * within
+        _m.decode_kv_positions_held.inc(full + sliding)
+        _m.decode_kv_positions_if_unwindowed.inc(
+            (n_full + n_sliding) * total)
+        return full + sliding, {'full_positions': full,
+                                'sliding_positions': sliding}
 
     def window_step(self, blocks, masked, quota, tables, commits,
                     return_rows=False):

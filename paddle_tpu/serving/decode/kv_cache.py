@@ -64,6 +64,28 @@ step advances it in place, inside the same donated programs. The block
 allocator still books the request's lengths; no HBM stands behind a block of
 a model whose every layer is a state layer.
 
+Layer classes (docs/SERVING.md "Layer classes"): a model may bound what a
+layer's queries see to the last ``span`` positions (sliding attention). Such
+a layer gives back what lies behind its span: the K/V layers of a pool are
+of two CLASSES under this one manager. The FULL class is everything above: a
+table that grows with the context. The SLIDING class holds per request a
+RING of ``ring`` = ceil(span / block_size) + 1 blocks (`BlockTable.ring`):
+position p lives in ring block ``(p // block_size) mod ring``, a block is
+overwritten in place when the ring comes round, and the request never holds
+more however long it grows. Each class has its own free list
+(`KVCachePool.allocator`, `KVCachePool.sliding`), its own depth of array
+(``num_blocks``, ``sliding_blocks``), its own scratch block 0 and its own
+columns in :func:`prefill_coords` / :func:`decode_coords`
+(``sliding_tables``, ``sliding_write_ids``, ``sliding_write_offs``). Which
+layer is of which class the model says as it attends
+(`CacheContext.attend(span=)`) and in ``kv_cache_spec()['layer_spans']``. A
+row that has left the span is never read: the reads mask by a key's
+POSITION, rebuilt from its place in the ring and the context length. The
+ring is taken WHOLE at admission, as many blocks as the request's prompt
+and budget can ever touch (at most ``ring``), like the full class's
+reservation and for its reason: a generation never dies of a missing block
+mid-flight, and the free list's count is what admission can promise.
+
 Quantized storage (``kv_dtype``, docs/SERVING.md "Tiered KV cache"): the
 pools hold payload at ``f32`` (exact, the default), ``bf16`` (half the
 bytes; decode reads cast back to f32 — an exact roundtrip for every
@@ -295,10 +317,15 @@ class BlockTable:
     number of cached tokens (prompt + generated so far)."""
 
     __slots__ = ('blocks', 'block_size', 'context_len', 'cached_len',
-                 'state_row')
+                 'state_row', 'ring')
 
-    def __init__(self, blocks, block_size, cached_len=0, state_row=0):
+    def __init__(self, blocks, block_size, cached_len=0, state_row=0,
+                 ring=()):
         self.blocks = list(blocks)
+        # the request's blocks of the SLIDING class (none where the model
+        # has no such layer): position p in ring[(p // block_size) % R], R
+        # the pool's ring length
+        self.ring = list(ring)
         # the request's row of the state layers (0: none, the scratch row)
         self.state_row = int(state_row)
         self.block_size = int(block_size)
@@ -344,7 +371,8 @@ class KVCachePool:
     """
 
     def __init__(self, block_size=None, num_blocks=None,
-                 max_blocks_per_seq=None, kv_dtype=None, state_rows=0):
+                 max_blocks_per_seq=None, kv_dtype=None, state_rows=0,
+                 span=0, sliding_blocks=0):
         self.block_size = int(block_size or DEFAULT_BLOCK_SIZE)
         self.num_blocks = int(num_blocks or DEFAULT_MAX_BLOCKS)
         self.max_blocks_per_seq = int(max_blocks_per_seq or 8)
@@ -356,6 +384,14 @@ class KVCachePool:
         self.kv_dtype = kv_dtype
         self.dtype = KV_PAYLOAD_DTYPES[kv_dtype]
         self.allocator = BlockAllocator(self.num_blocks)
+        # the SLIDING class (span 0: the model has no such layer): what a
+        # layer of it lets a query see, the ring a request holds of it, the
+        # depth of its arrays and its own free list
+        self.span = int(span)
+        self.ring = -(-self.span // self.block_size) + 1 if self.span else 0
+        self.sliding_blocks = int(sliding_blocks)
+        self.sliding = BlockAllocator(self.sliding_blocks) if self.span \
+            else None
         # rows of the state layers' arrays (0: the model has none), row 0
         # scratch, and who holds which
         self.state_rows = StateRows(state_rows)
@@ -383,7 +419,8 @@ class KVCachePool:
         the constructor's arguments: hashable, so it can key a compiled
         program, and engines of equal geometry share executables."""
         return (self.block_size, self.num_blocks, self.max_blocks_per_seq,
-                self.kv_dtype, self.state_rows.num_rows)
+                self.kv_dtype, self.state_rows.num_rows, self.span,
+                self.sliding_blocks)
 
     @classmethod
     def over(cls, geometry, layers, scales):
@@ -442,8 +479,10 @@ class KVCachePool:
         kv_cache_row_bytes gauge. 0 where no layer caches rows."""
         if not self.num_row_layers:
             return 0
-        return self.bytes_in_hbm() // (
-            self.num_row_layers * self.num_blocks * self.block_size)
+        positions = sum(
+            arrs[0].shape[0] * self.block_size
+            for arrs in self._layers.values() if not self._is_state(arrs))
+        return self.bytes_in_hbm() // positions
 
     def new_table(self, total_tokens):
         """Allocate a table holding ``total_tokens`` (prompt + budget), and
@@ -455,30 +494,50 @@ class KVCachePool:
                 f'{total_tokens} tokens need {nb} blocks > '
                 f'max_blocks_per_seq={self.max_blocks_per_seq}')
         row = self.state_rows.take() if self.state_rows.num_rows else 0
+        blocks = ring = ()
         try:
             blocks = self.allocator.allocate(nb)
-        except OutOfBlocks:
+            if self.span:
+                ring = self.sliding.allocate(min(nb, self.ring))
+        except OutOfBlocks as e:
+            # nothing is kept: what the other class gave goes back
+            if blocks:
+                self.allocator.free(blocks)
             if row:
                 self.state_rows.give_back(row)
+            if self.span and not isinstance(e, OutOfStateRows):
+                raise OutOfBlocks(e.requested, e.available,
+                                  'sliding' if blocks else 'full') from None
             raise
-        return BlockTable(blocks, self.block_size, state_row=row)
+        return BlockTable(blocks, self.block_size, state_row=row, ring=ring)
 
     def free_table(self, table):
         if table.blocks:
             self.allocator.free(table.blocks)
             table.blocks = []
+        if table.ring:
+            self.sliding.free(table.ring)
+            table.ring = []
         if table.state_row:
             self.state_rows.give_back(table.state_row)
             table.state_row = 0
 
-    def ensure_layer(self, layer, n_heads, head_dim):
+    def ensure_layer(self, layer, n_heads, head_dim, sliding=False):
         """The layer's arrays, made on first use: the one place that decides
         their shapes. [k, v] of (NB, BS, row_lanes(n_heads·head_dim)), a
         token's row of all heads contiguous (the module docstring says
-        why); with ``n_heads`` None a latent (MLA) layer, ONE array of rows
-        (NB, BS, row_lanes(head_dim))."""
+        why), NB the depth of the layer's class (``sliding``: the sliding
+        class's); with ``n_heads`` None a latent (MLA) layer, ONE array of
+        rows (NB, BS, row_lanes(head_dim))."""
         if n_heads is not None:
             self.heads[layer] = (int(n_heads), int(head_dim))
+        if sliding:
+            if not self.span:
+                raise ValueError(
+                    'a sliding layer over a pool built with span=0: the '
+                    'model must say kv_cache_spec()["layer_spans"]')
+            if self.kv_dtype == 'int8':
+                raise UnsupportedCacheFeature(['kv_dtype=int8'], 'sliding')
         if layer not in self._layers:
             import jax.numpy as jnp
             if n_heads is None:
@@ -489,7 +548,8 @@ class KVCachePool:
                     (self.num_blocks, self.block_size,
                      row_lanes(head_dim)), self.dtype)]
                 return self._layers[layer]
-            rows = (self.num_blocks, self.block_size)
+            rows = (self.sliding_blocks if sliding else self.num_blocks,
+                    self.block_size)
             shape = rows + (row_lanes(n_heads * head_dim),)
             self._layers[layer] = [jnp.zeros(shape, self.dtype),
                                    jnp.zeros(shape, self.dtype)]
@@ -561,7 +621,7 @@ class KVCachePool:
         states = self.ensure_state(layer, block.shape)
         states[0] = _put_row(states[0], row, block.astype('float32'))
 
-    def write_prefill(self, layer, block_ids, k, v):
+    def write_prefill(self, layer, block_ids, k, v, sliding=False):
         """Write the prompt's K/V rows. ``k``/``v``: (H, L, D) — the bucket-
         padded projections; ``block_ids``: (ceil(L/bs),) int32 from
         :func:`prefill_coords`, the table's first ``ceil(context/bs)`` blocks
@@ -576,7 +636,7 @@ class KVCachePool:
         has it — after warm-up, where chip_smoke.py counts none."""
         import jax.numpy as jnp
         h, L, d = k.shape
-        self.ensure_layer(layer, h, d)
+        self.ensure_layer(layer, h, d, sliding)
         nb = -(-L // self.block_size)
         target = nb * self.block_size
         if L < target:
@@ -587,13 +647,13 @@ class KVCachePool:
                     (jnp.asarray(block_ids, jnp.int32),),
                     (nb, self.block_size), k, v)
 
-    def write_tokens(self, layer, block_ids, offsets, k, v):
+    def write_tokens(self, layer, block_ids, offsets, k, v, sliding=False):
         """One decode step's K/V: ``k``/``v`` (H, S, D) written at
         (block_ids[s], offsets[s]) per slot. Inactive slots point at the
         scratch block."""
         import jax.numpy as jnp
         h, s, d = k.shape
-        self.ensure_layer(layer, h, d)
+        self.ensure_layer(layer, h, d, sliding)
         self._write(layer, _scatter_tokens,
                     (jnp.asarray(block_ids, jnp.int32),
                      jnp.asarray(offsets, jnp.int32)), (s,), k, v)
@@ -739,7 +799,29 @@ def prefill_coords(pool, table, bucket):
     if pool.state_rows.num_rows:
         # (1,): the request's row of the state layers
         coords['state_rows'] = np.asarray([table.state_row], np.int32)
+    if pool.span:
+        # the sliding class's columns: the request's ring padded with that
+        # class's scratch block, and of the bucket's blocks the last
+        # ``ring`` that hold the prompt, each into its place in the ring;
+        # a block the prompt has already left behind goes to scratch
+        last = -(-table.context_len // bs) - 1        # the prompt's last
+        ids = [SCRATCH_BLOCK] * nb
+        if table.ring:
+            for b in range(max(0, last - pool.ring + 1), min(last, nb - 1)
+                           + 1):
+                ids[b] = table.ring[b % pool.ring]
+        coords['sliding_tables'] = np.asarray([_padded_ring(pool, table)],
+                                              np.int32)
+        coords['sliding_write_ids'] = np.asarray(ids, np.int32)
     return coords
+
+
+def _padded_ring(pool, table):
+    """The table's ring padded to the pool's ring length with the sliding
+    class's scratch block (a request that can never pass the span holds
+    fewer blocks than a whole ring); None: an idle slot's."""
+    ring = [] if table is None else table.ring
+    return ring + [SCRATCH_BLOCK] * (pool.ring - len(ring))
 
 
 def decode_coords(pool, tables, context_lens, fed_counts=None, window=1,
@@ -792,6 +874,26 @@ def decode_coords(pool, tables, context_lens, fed_counts=None, window=1,
         # scratch row
         coords['state_rows'] = np.asarray(
             [0 if t is None else t.state_row for t in tables], np.int32)
+    if pool.span:
+        if window != 1:
+            raise UnsupportedCacheFeature(
+                ['a decode window of more than one token (speculation, '
+                 'chunked suffix fill, a window model)'], 'sliding')
+        # the sliding class's columns: every slot's ring, and the fed
+        # token's place in it
+        s_ids, s_offs = [], []
+        for t, c in zip(tables, context_lens):
+            if t is None:
+                s_ids.append(SCRATCH_BLOCK)
+                s_offs.append(0)
+                continue
+            p = int(c) - 1
+            s_ids.append(t.ring[p // pool.block_size % pool.ring])
+            s_offs.append(p % pool.block_size)
+        coords['sliding_tables'] = np.asarray(
+            [_padded_ring(pool, t) for t in tables], np.int32)
+        coords['sliding_write_ids'] = np.asarray(s_ids, np.int32)
+        coords['sliding_write_offs'] = np.asarray(s_offs, np.int32)
     return coords
 
 
@@ -834,6 +936,7 @@ class CacheContext:
         self._layer = 0
         self._live = None          # `live_blocks`, once a layer asked
         self._groups = None        # `live_groups`, once a layer asked
+        self._ring_groups = None   # `live_ring_groups`, once a layer asked
         self.stats = {}            # name -> [what a layer noted], `note`
 
     def note(self, name, value):
@@ -878,6 +981,19 @@ class CacheContext:
             self._groups = list(live_group_list(
                 c['block_tables'], c['context_lens'], self.pool.block_size))
         return self._groups
+
+    def live_ring_groups(self):
+        """:meth:`live_groups` of the SLIDING class: the groups of each
+        slot's ring that hold a position inside its span
+        (ops/nn_ops.py::live_ring_group_list), made once a program and
+        shared by its sliding layers."""
+        if self._ring_groups is None:
+            from ...ops.nn_ops import live_ring_group_list
+            c = self.coords
+            self._ring_groups = list(live_ring_group_list(
+                c['sliding_tables'], c['context_lens'],
+                self.pool.block_size, self.pool.span))
+        return self._ring_groups
 
     def _scale_inputs(self, layer):
         """Extra dispatch inputs for int8 pools ({} otherwise — the f32/bf16
@@ -950,8 +1066,19 @@ class CacheContext:
         states[0] = state.value
         return out
 
-    def attend(self, q, k, v, sm_scale=1.0, block_len=0):
+    def attend(self, q, k, v, sm_scale=1.0, block_len=0, span=None):
+        """``span`` (None: a model of one class of layer, whose key/value
+        heads are its query heads' or a window model's) is the layer's
+        class in a model that has two: 0 a FULL layer, S > 0 a SLIDING
+        layer whose row i sees keys j with 0 <= i - j < S (the pool's
+        ``span``). Such a layer's k/v hold the model's key/value heads (G ≤
+        q's H), a prefill attends the raw projections causally
+        (`paged_prefill_attention` with ``kv_heads``) and a decode step
+        reads the live groups of the class's blocks (`paged_attention`
+        with ``kv_heads``, scopes `kv/decode_read` / `kv/sliding_read`)."""
         from ...dygraph.tape import Tensor, dispatch_op
+        if span is not None:
+            return self._attend_class(q, k, v, sm_scale, int(span))
         layer = self._layer
         self._layer += 1
         c = self.coords
@@ -1000,4 +1127,50 @@ class CacheContext:
             out = dispatch_op(
                 'paged_attention',
                 dict(inputs, q=q3, live=self.live_blocks()), attrs)
+        return dispatch_op('reshape', {'x': out}, {'shape': [s, h, 1, d]})
+
+    def _attend_class(self, q, k, v, sm_scale, span):
+        """`attend` for a layer that names its class (``span``)."""
+        from ...dygraph.tape import Tensor, dispatch_op
+        layer = self._layer
+        self._layer += 1
+        c = self.coords
+        sliding = span > 0
+        if sliding and span != self.pool.span:
+            raise ValueError(f'a sliding layer of span {span} over a pool '
+                             f'whose sliding class spans {self.pool.span}')
+        kv = k.value if isinstance(k, Tensor) else k
+        vv = v.value if isinstance(v, Tensor) else v
+        groups = int(kv.shape[1])
+        tables = c['sliding_tables' if sliding else 'block_tables']
+        ids = c['sliding_write_ids' if sliding else 'write_ids']
+        attrs = {'sm_scale': float(sm_scale), 'kv_heads': groups,
+                 'span': span}
+        if self.mode == 'prefill':
+            self.pool.write_prefill(layer, ids, kv[0], vv[0], sliding)
+            k_pages, v_pages = self.pool.pages(layer)
+            # the scopes name the two prefill attentions' device ops
+            with jax.named_scope('attn/sliding_prefill' if sliding
+                                 else 'attn/full_prefill'):
+                return dispatch_op('paged_prefill_attention', {
+                    'q': q, 'k': k, 'v': v, 'k_pages': k_pages,
+                    'v_pages': v_pages, 'block_tables': tables}, attrs)
+        s, _, k_w, d = kv.shape
+        if k_w != 1:
+            raise UnsupportedCacheFeature(
+                ['a decode window of more than one token (speculation, '
+                 'chunked suffix fill)'], 'sliding')
+        offs = c['sliding_write_offs' if sliding else 'write_offs']
+        self.pool.write_tokens(layer, ids, offs, kv[:, :, 0].transpose(1, 0, 2),
+                               vv[:, :, 0].transpose(1, 0, 2), sliding)
+        k_pages, v_pages = self.pool.pages(layer)
+        h = q.shape[1]
+        q3 = dispatch_op('reshape', {'x': q}, {'shape': [s, h, d]})
+        live = self.live_ring_groups() if sliding else self.live_groups()
+        with jax.named_scope('kv/sliding_read' if sliding
+                             else 'kv/decode_read'):
+            out = dispatch_op('paged_attention', {
+                'q': q3, 'k_pages': k_pages, 'v_pages': v_pages,
+                'block_tables': tables, 'context_lens': c['context_lens'],
+                'live': live}, attrs)
         return dispatch_op('reshape', {'x': out}, {'shape': [s, h, 1, d]})

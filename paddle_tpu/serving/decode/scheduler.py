@@ -26,7 +26,13 @@ Admission takes the request's full block reservation (prompt + token
 budget) up front, so a generation can never die of OutOfBlocks mid-flight;
 when the pool can't cover the next waiting request the scheduler simply
 keeps stepping until a finishing slot frees blocks (FIFO admission — no
-starvation of big requests behind small ones).
+starvation of big requests behind small ones). Over a model with layer
+classes (docs/SERVING.md "Layer classes") the reservation is of BOTH free
+lists, the full class's table and the sliding class's ring
+(`engine.reserve_table` → `KVCachePool.new_table`: all or nothing), the
+`OutOfBlocks` that makes a request wait names the class that ran out, and
+retirement (`_retire`, `_fail_request` → `engine.release_table`) returns
+both.
 
 **Window models** (block diffusion, ``engine.window`` B > 1: docs/SERVING.md
 "Window models"): a slot holds a BLOCK, not a next token: B ids, which of

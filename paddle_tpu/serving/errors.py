@@ -84,15 +84,27 @@ class OutOfBlocks(ServingError):
     """The paged KV-cache pool cannot cover a block reservation right now.
     Inside the decode scheduler this is a WAIT signal (the request stays
     queued until finishing slots free their blocks), never a client error;
-    it only escapes to callers driving a DecodeEngine directly."""
+    it only escapes to callers driving a DecodeEngine directly.
+    ``layer_class`` ('full' | 'sliding') says which class of a pool with
+    layer classes ran out (docs/SERVING.md "Layer classes"): the full
+    class is sized by ``max_blocks``, the sliding class derived from the
+    slots and the span (a ring a slot), so it runs out only with more live
+    tables than slots."""
 
-    def __init__(self, requested, available):
+    def __init__(self, requested, available, layer_class=None):
+        if layer_class == 'sliding':
+            where, cure = ' (the sliding class)', (
+                'a ring a slot is all it holds: lower concurrency')
+        else:
+            where = ' (the full class)' if layer_class else ''
+            cure = ('raise PADDLE_TPU_DECODE_MAX_BLOCKS or lower '
+                    'concurrency')
         super().__init__(
-            f'KV cache pool exhausted: need {requested} blocks, '
-            f'{available} free (raise PADDLE_TPU_DECODE_MAX_BLOCKS or '
-            f'lower concurrency)')
+            f'KV cache pool exhausted{where}: need {requested} blocks, '
+            f'{available} free ({cure})')
         self.requested = requested
         self.available = available
+        self.layer_class = layer_class
 
 
 class OutOfStateRows(OutOfBlocks):
@@ -122,7 +134,12 @@ class UnsupportedCacheFeature(ServingError, ValueError):
     'window': block diffusion, models/block_diffusion_lm.py) caches [k, v]
     rows, but reads them under the block mask and keeps a block's rows only
     at its commit forward: the features that fill, share or verify rows
-    outside that step have no such path yet."""
+    outside that step have no such path yet. A model with a SLIDING class
+    of layer (``kind`` 'sliding', models/sliding_moe_lm.py) keeps a ring
+    of blocks a request in those layers and overwrites it in place: a
+    prefix has no block of its own to share once the ring has come round,
+    a handoff would have to carry rings, a rejected window cannot be rolled
+    back off a block it overwrote, and the ring read takes no row scales."""
 
     _WHY = {
         'latent': ('they read and write [k, v] pairs of per-head rows',
@@ -133,13 +150,17 @@ class UnsupportedCacheFeature(ServingError, ValueError):
         'window': ('a window model reads its rows under the block mask and '
                    'keeps a block\'s rows only at its commit forward, and '
                    'these have no path under that mask yet',
-                   'Window models')}
+                   'Window models'),
+        'sliding': ('a sliding layer keeps a ring of blocks a request and '
+                    'overwrites it in place, and these have no path over a '
+                    'ring yet', 'Layer classes')}
 
     def __init__(self, features, kind):
         features = list(features)
         why, section = self._WHY.get(kind, self._WHY['latent'])
         what = {'state': 'the state cache',
-                'window': 'a window model\'s KV cache'}.get(
+                'window': 'a window model\'s KV cache',
+                'sliding': 'a KV cache with a sliding class of layer'}.get(
                     kind, f'a {kind} KV cache')
         super().__init__(
             f'{", ".join(features)} cannot be used with {what}: {why} '

@@ -259,6 +259,33 @@ decode_expert_load_max_over_mean = _LazyMetric(
     'per engine call (label call), the worst layer\'s largest expert load '
     'over its mean load', bounds=(1.0, 1.5, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0,
                                    16.0, 32.0, 64.0, 128.0))
+decode_expert_assignments_total = _LazyMetric(
+    'counter', 'decode_expert_assignments_total',
+    'a model whose expert layers hold a SHARE of their experts: every '
+    'token-to-expert assignment of the calls\' live tokens, to an expert '
+    'held here or elsewhere')
+decode_expert_assignments_held = _LazyMetric(
+    'counter', 'decode_expert_assignments_held',
+    'of decode_expert_assignments_total, those to an expert this model '
+    'holds: the ones computed here (held experts / the router\'s width of '
+    'them, if the router spreads evenly)')
+decode_full_blocks_held = _LazyMetric(
+    'gauge', 'decode_full_blocks_held',
+    'a model with layer classes: blocks of the FULL class held by live '
+    'requests (a table that grows with the context)')
+decode_sliding_blocks_held = _LazyMetric(
+    'gauge', 'decode_sliding_blocks_held',
+    'a model with layer classes: blocks of the SLIDING class held by live '
+    'requests (a ring a request, never more than span / block + 1)')
+decode_kv_positions_held = _LazyMetric(
+    'counter', 'decode_kv_positions_held',
+    'a model with layer classes: positions its layers hold of the live '
+    'contexts, per prefill and per step: context a full layer, '
+    'min(context, span) a sliding layer, summed over slots and layers')
+decode_kv_positions_if_unwindowed = _LazyMetric(
+    'counter', 'decode_kv_positions_if_unwindowed',
+    'what decode_kv_positions_held would count were every layer full: '
+    'context x layers; 1 - held / this is what the ring gives back')
 decode_context_positions_read = _LazyMetric(
     'counter', 'decode_context_positions_read',
     'cached positions a decode step attends: the live context of every '

@@ -132,6 +132,9 @@ class PrefillReplica:
     then free. One worker thread owns it (``LocalPrefillWorker``)."""
 
     def __init__(self, engine):
+        if getattr(engine, 'span', 0):
+            raise UnsupportedCacheFeature(['the disaggregated handoff'],
+                                          'sliding')
         if engine.cache_kind != 'kv' or getattr(engine, 'window', 1) > 1:
             raise UnsupportedCacheFeature(
                 ['the disaggregated handoff'],

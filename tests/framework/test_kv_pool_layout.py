@@ -423,3 +423,81 @@ def test_compiled_for_the_chip_the_experts_run_the_grouped_kernel(
             kernels = kernel_op_names(text)
             assert len(kernels) == 2 * expert_layers, kernels
             assert all('/moe/experts/' in name for name in kernels), kernels
+
+
+def test_compiled_for_the_chip_a_model_with_layer_classes(v5e, monkeypatch):
+    """A model with a sliding and a full class of layer and an expert of the
+    published 3,072 x 3,072 (18.9 MB a matrix: three width blocks of 6 MiB),
+    its step and a prefill rung compiled for the chip: Mosaic takes the
+    kernel's width axis (two custom calls an expert layer, under
+    `moe/experts`) and the splash-attention kernel of the causal grouped
+    prefill, no program moves either class's arrays, and the step and
+    the rung hold the scopes the benchmark sums device time by (compiled
+    here: no chip, no time). Shapes alone."""
+    from paddle_tpu.core.random import default_generator
+    from paddle_tpu.models.sliding_moe_lm import (SlidingMoEConfig,
+                                                  SlidingMoELM)
+    from paddle_tpu.ops import llm_ops, nn_ops
+    from paddle_tpu.ops.pallas_moe import kernel_op_names, width_block
+    from paddle_tpu.serving.decode.kv_cache import (BlockTable,
+                                                    prefill_coords)
+    monkeypatch.setattr(llm_ops, 'on_tpu', lambda: True)
+    monkeypatch.setattr(nn_ops, 'on_tpu', lambda: True)
+    assert width_block(3072, 3072, 2) == 1024
+    made = {}
+
+    def init(key):
+        with default_generator.bind_base(key):
+            made['model'] = SlidingMoELM(SlidingMoEConfig.tiny(
+                vocab_size=512, hidden_size=3072, intermediate_size=256,
+                moe_intermediate_size=3072, num_hidden_layers=3,
+                layer_types=['sliding_attention', 'sliding_attention',
+                             'full_attention'],
+                num_attention_heads=48, num_key_value_heads=8, head_dim=128,
+                sliding_window=4096, num_experts=2, router_width=8,
+                experts_held=(2, 2), max_position_embeddings=2048,
+                dtype='bfloat16'))
+        return {n: p.value for n, p in made['model'].named_parameters()}
+
+    with dygraph.guard():
+        default_generator.seed(3)
+        shapes = jax.eval_shape(init, default_generator.base_key())
+        model = made['model']
+        model.eval()
+        for name, p in model.named_parameters():
+            p.value = shapes[name]
+        # both classes' arrays past what the compiler would prefetch whole
+        # into VMEM (135 and 147 MB; an array of a few MB it does, as a
+        # copy-start that is no relayout)
+        eng = DecodeEngine(model, slots=16, block_size=16, max_blocks=4500,
+                           max_prompt_len=1024, max_new_tokens_cap=512,
+                           prompt_buckets=[1024], prefix_cache=False,
+                           kv_dtype='bf16')
+        pool, prog = eng.pool, eng._program
+        assert (pool.ring, pool.sliding_blocks) == (257, 16 * 257 + 8)
+        out = jax.eval_shape(
+            lambda pv, *rest: prog.jitted('prefill', pool.geometry, pv, {},
+                                          {}, {}, *rest),
+            {n: p.value for n, p in prog._params.items()},
+            np.zeros((1, 1024), np.int64), None,
+            prefill_coords(pool, BlockTable([], 16), 1024), np.int32(0))
+        pool.adopt({k: list(v) for k, v in out[3].items()}, {})
+        layers, _ = pool.arrays()
+        assert [layers[i][0].shape for i in range(3)] == [
+            (4120, 16, 1024), (4120, 16, 1024), (4500, 16, 1024)]
+        for bucket in (None, 1024):
+            text = eng.lowered(bucket, v5e).compile().as_text()
+            assert 'ragged-dot' not in text and 'ragged_dot' not in text
+            kernels = kernel_op_names(text)
+            # a rung's three attentions are the splash kernel, whose call
+            # the compiler leaves without an op_name (benchmark/lib/
+            # layer_class_ops.py finds it by its own name)
+            assert kernels.count('') == (0 if bucket is None else 3)
+            assert text.count('_splash_attention_') >= kernels.count('')
+            kernels = [name for name in kernels if name]
+            assert len(kernels) == 2 * 2, kernels
+            assert all('/moe/experts/' in name for name in kernels), kernels
+            assert eng.pool_moves(bucket, v5e) == []
+            scopes = ('kv/sliding_read', 'kv/decode_read') if bucket is None \
+                else ('attn/sliding_prefill', 'attn/full_prefill')
+            assert all(f'/{s}/' in text for s in scopes + ('attn/gate',))

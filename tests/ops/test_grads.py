@@ -317,6 +317,9 @@ GRAD_SPECS = {
                                 f32(r.standard_normal((4, 4, 6)) * 0.5),
                                 f32(r.standard_normal((4, 6, 4)) * 0.5)],
                      diff=(0, 2, 3, 4, 5)),
+    'sigmoid_gate': S(lambda r: [f32(r.standard_normal((2, 3, 4))),
+                                 f32(r.standard_normal((2, 3, 4)))],
+                      diff=(0, 1)),
     'mla_prefill_attention': S(
         lambda r: [f32(r.standard_normal((1, 5, 2, 6))),
                    f32(r.standard_normal((1, 5, 10))),
