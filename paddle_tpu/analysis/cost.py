@@ -594,7 +594,10 @@ def _c_mla_prefill(ctx):
 @cost_rule('mla_decode_attention')
 def _c_mla_decode(ctx):
     # absorbed: W_UK into the query, the padded extent of latent rows read
-    # once for all heads, W_UV after the sum
+    # once for all heads, W_UV after the sum. The lockstep read walks the
+    # LIVE groups alone (ops/llm_ops.py), but how many are live is in the
+    # context lengths' values: a rule over shapes prices the static bound,
+    # every slot at the table's whole width
     s, k, heads, qk, rank, nope, v = _mla_dims(ctx)
     a = ctx.assume_dim
     pages, tables = ctx.input('pages'), ctx.input('block_tables')

@@ -4,7 +4,8 @@ or softmax scores) and a grouped expert feed-forward that drops no token
 (on a TPU the pallas grouped matmul of ops/pallas_moe.py, the one op here
 with a kernel and a predicate beside its call, `experts_kernel_applies`),
 latent (MLA) attention in its two forms (expanded over a prompt, absorbed
-over the paged latent cache), power retention, and a block-diffusion
+over the paged latent cache: the lockstep step's read walks the batch's
+live groups, ops/nn_ops.py), power retention, and a block-diffusion
 forward's pick (a token and the confidence in it per row).
 
 Precision rule, the same in every op: matmuls take their operands as they
@@ -29,6 +30,7 @@ import numpy as np
 from jax import lax
 
 from ..core.places import on_tpu
+from .nn_ops import _live_group_attention, live_group_list
 from .pallas_moe import expert_ffn
 from .registry import register_op
 
@@ -281,23 +283,41 @@ def mla_prefill_attention(q, latent, w_kvb, *, qk_nope_dim, v_dim,
 
 
 @register_op('mla_decode_attention')
-def mla_decode_attention(q, pages, block_tables, context_lens, w_kvb, *,
-                         qk_nope_dim, v_dim, sm_scale=1.0):
+def mla_decode_attention(q, pages, block_tables, context_lens, w_kvb,
+                         live=None, *, qk_nope_dim, v_dim, sm_scale=1.0):
     """Latent attention in its absorbed form, over the paged latent cache:
     the same function of the weights as `mla_prefill_attention`, with
     W_UK folded into the query and W_UV applied after the sum, so that the
     cache is read as it is stored and never expanded per head.
 
     q (S, K, H, nope + rope): K fed tokens per slot (1 in the lockstep
-    step), rotary part turned; pages (blocks, block, rank + rope);
-    block_tables (S, blocks per slot) int32; context_lens (S,): row j of
-    slot s sees positions < context_lens[s] + j (the staircase of
-    `paged_attention`); w_kvb (rank, H·(nope + v)). Returns (S, K, H·v).
+    step), rotary part turned; pages (blocks, block, lanes >= rank + rope),
+    a row [c | k_rope] and zeros past it; block_tables (S, blocks per slot)
+    int32; context_lens (S,): row j of slot s sees positions <
+    context_lens[s] + j (the staircase of `paged_attention`); w_kvb (rank,
+    H·(nope + v)); ``live`` optional, the :func:`~.nn_ops.live_group_list`
+    of these tables and lengths, for a caller that reads many layers
+    through them (made here otherwise; the K = 1 read alone takes it).
+    Returns (S, K, H·v).
 
     q̃_j = q_nope_j · W_UK,jᵀ; score_j = (q̃_j · c + q_rope_j · k_rope) ·
     sm_scale; u_j = Σ p · c; o_j = u_j · W_UV,j. Positions past a slot's
     context are masked to exactly zero probability, so the scratch block's
-    and a freed block's stale rows never reach a result."""
+    and a freed block's stale rows never reach a result.
+
+    K = 1 (the lockstep step) is the GROUPED read of ops/nn_ops.py with one
+    key/value head: a slot's H absorbed queries [q̃_j | q_rope_j] are the
+    rows of one group, the pool's rows are the keys as stored, and their
+    first ``rank`` lanes the values. It walks the batch's live groups in
+    chunks with a running softmax (:func:`~.nn_ops._live_group_attention`),
+    so its work follows the contexts and no per-slot copy of the padded
+    tables exists. The query is padded with zeros to the rows' lanes, so
+    that a chunk is used as it is taken (a zero lane of the query makes a
+    row's pad lanes moot), and the sum is cut to ``rank`` lanes after the
+    walk. K > 1 (the staircase of a speculative verify or a chunked
+    suffix) keeps the dense form, every slot's padded table gathered, as
+    `paged_attention`'s (S, K) staircase does: no served cell runs it, and
+    the walker's "every row of a slot at one extent" stays true."""
     q, pages = jnp.asarray(q), jnp.asarray(pages)
     s, kq, heads, _ = q.shape
     rank = w_kvb.shape[0]
@@ -306,19 +326,29 @@ def mla_decode_attention(q, pages, block_tables, context_lens, w_kvb, *,
                        preferred_element_type=_F32).astype(q.dtype)
     query = jnp.concatenate([q_abs, q[..., qk_nope_dim:]], -1)
     tables = jnp.asarray(block_tables, jnp.int32)
-    rows = pages[tables].reshape(s, -1, pages.shape[-1])   # (S, T, W)
-    rows = rows[..., :query.shape[-1]].astype(q.dtype)
-    scores = jnp.einsum('skhw,stw->skht', query, rows,
-                        preferred_element_type=_F32) * sm_scale
-    extent = jnp.asarray(context_lens, jnp.int32)[:, None] \
-        + jnp.arange(kq, dtype=jnp.int32)[None, :]         # (S, K)
-    seen = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, None, :] \
-        < extent[..., None]
-    scores = jnp.where(seen[:, :, None, :], scores,
-                       jnp.finfo(_F32).min)
-    p = jax.nn.softmax(scores, -1).astype(q.dtype)
-    u = jnp.einsum('skht,str->skhr', p, rows[..., :rank],
-                   preferred_element_type=_F32).astype(q.dtype)
+    context_lens = jnp.asarray(context_lens, jnp.int32)
+    if kq == 1:
+        if live is None:
+            live = live_group_list(tables, context_lens, pages.shape[1])
+        query = jnp.pad(query, ((0, 0), (0, 0), (0, 0),
+                                (0, pages.shape[-1] - query.shape[-1])))
+        u = _live_group_attention(
+            query.transpose(0, 2, 1, 3), pages, pages, context_lens, live,
+            1, sm_scale).transpose(0, 2, 1, 3)[..., :rank]
+    else:
+        rows = pages[tables].reshape(s, -1, pages.shape[-1])   # (S, T, W)
+        rows = rows[..., :query.shape[-1]].astype(q.dtype)
+        scores = jnp.einsum('skhw,stw->skht', query, rows,
+                            preferred_element_type=_F32) * sm_scale
+        extent = context_lens[:, None] \
+            + jnp.arange(kq, dtype=jnp.int32)[None, :]         # (S, K)
+        seen = jnp.arange(rows.shape[1], dtype=jnp.int32)[None, None, :] \
+            < extent[..., None]
+        scores = jnp.where(seen[:, :, None, :], scores,
+                           jnp.finfo(_F32).min)
+        p = jax.nn.softmax(scores, -1).astype(q.dtype)
+        u = jnp.einsum('skht,str->skhr', p, rows[..., :rank],
+                       preferred_element_type=_F32).astype(q.dtype)
     out = jnp.einsum('skhr,rhd->skhd', u, w_uv,
                      preferred_element_type=_F32).astype(q.dtype)
     return out.reshape(s, kq, heads * v_dim)

@@ -1051,7 +1051,13 @@ def _live_group_attention(q, k_pages, v_pages, context_lens, live, kv_heads,
     (S, C) of the chunk's ``slot`` as in :func:`_live_block_attention`.
     Masked positions get exactly-zero mass. ``span`` S > 0: a position p
     counts only if context - S <= p as well (``live``'s ``first_pos`` are
-    then positions of the sequence, :func:`live_ring_group_list`)."""
+    then positions of the sequence, :func:`live_ring_group_list`).
+
+    ``v_pages is k_pages``: ONE array holds keys and values (a latent pool,
+    ops/llm_ops.py::mla_decode_attention: G = 1, a row is the key and its
+    leading lanes the value). A chunk's rows are then taken once and serve
+    both matmuls, whole: the result is D wide, and the caller keeps the
+    lanes that are values."""
     f32, exact = jnp.float32, lax.Precision.HIGHEST
     s, h, kq, d = q.shape
     g = int(kv_heads)
@@ -1067,6 +1073,7 @@ def _live_group_attention(q, k_pages, v_pages, context_lens, live, kv_heads,
     scale = jnp.asarray(sm_scale, f32)
     offsets = jnp.arange(keys, dtype=jnp.int32)
     slots = jnp.arange(s, dtype=jnp.int32)
+    shared = v_pages is k_pages
 
     def rows_of(pages, ids):
         got = jnp.take(pages, ids.reshape(-1), axis=0)[..., :g * d]
@@ -1085,7 +1092,8 @@ def _live_group_attention(q, k_pages, v_pages, context_lens, live, kv_heads,
         seen = seen[:, None, None, :]                             # (C,1,1,T)
         mine = of[None, :] == slots[:, None]                      # (S, C)
         to_slot = mine.astype(f32)
-        scores = jnp.einsum('cgrd,ctgd->cgrt', qg[of], rows_of(k_pages, ids),
+        queries, k = qg[of], rows_of(k_pages, ids)
+        scores = jnp.einsum('cgrd,ctgd->cgrt', queries, k,
                             preferred_element_type=f32) * scale
         scores = jnp.where(seen, scores, neg)
         m_new = jnp.maximum(m, jnp.max(
@@ -1096,7 +1104,7 @@ def _live_group_attention(q, k_pages, v_pages, context_lens, live, kv_heads,
         l = l * rescale + jnp.matmul(
             to_slot, p.sum(-1).reshape(chunk, g * r),
             precision=exact).reshape(s, g, r)
-        v = rows_of(v_pages, ids)
+        v = k if shared else rows_of(v_pages, ids)
         weighted = jnp.einsum('cgrt,ctgd->cgrd', p.astype(v.dtype), v,
                               preferred_element_type=f32)
         acc = acc * rescale[..., None] + jnp.matmul(

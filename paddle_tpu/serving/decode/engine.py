@@ -744,10 +744,12 @@ class DecodeEngine:
         """The instructions of the lockstep step, compiled as `pool_moves`
         compiles it, that hold an array over every slot's whole padded
         context (S × ``padded_context`` positions). Must be empty for a K/V
-        pool: the step's read walks the live blocks a chunk at a time
-        (ops/nn_ops.py::paged_attention) and builds no per-slot dense copy.
-        It says so only where the tables hold more than one chunk of
-        blocks: a shorter table is one chunk, whole."""
+        pool and for a latent one: the step's read walks the live blocks,
+        or the live groups of blocks, a chunk at a time
+        (ops/nn_ops.py::paged_attention, ops/llm_ops.py::
+        mla_decode_attention) and builds no per-slot dense copy. It says so
+        only where the tables hold more than one chunk of blocks (of
+        groups): a shorter table is one chunk, whole."""
         return _arrays_spanning(self._compiled_text(None, sharding),
                                 self.slots * self.padded_context)
 
@@ -868,16 +870,16 @@ class DecodeEngine:
         """Blocks a layer's read takes from the pool in a lockstep step over
         ``ctx_lens`` (an idle slot's 1: the scratch block). A K/V pool's
         read walks the live blocks in whole chunks (ops/nn_ops.py::
-        paged_attention); a latent pool's gathers every slot's whole
-        table; a state layer reads no block at all."""
+        paged_attention), a latent pool's the live groups of blocks in
+        whole chunks (ops/llm_ops.py::mla_decode_attention); a state layer
+        reads no block at all."""
         entries = self.slots * self.pool.max_blocks_per_seq
         if self.cache_kind == 'state':
             return 0
-        if self.cache_kind != 'kv':
-            return entries
-        if self.window > 1 or self.layer_spans is not None:
-            # the block read and the grouped one-token reads walk whole
-            # groups of blocks, in whole chunks of groups
+        if (self.cache_kind == 'latent' or self.window > 1
+                or self.layer_spans is not None):
+            # the latent read, the block read and the grouped one-token
+            # reads walk whole groups of blocks, in whole chunks of groups
             # (ops/nn_ops.py::live_group_list)
             bs = self.block_size
             per_group = live_group_blocks(bs, self.pool.max_blocks_per_seq)

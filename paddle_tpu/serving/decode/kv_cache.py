@@ -365,9 +365,9 @@ class KVCachePool:
     ``max_blocks_per_seq`` fixes the batched block-table width — and with
     it ``padded_context = max_blocks_per_seq * block_size``, the most a
     slot can hold and the key extent of the reads that gather a table whole
-    (prefill rungs below 128, the (S, K) step, a latent pool's step); the
-    lockstep step over a K/V pool reads the live blocks alone (see
-    ops/nn_ops.py).
+    (prefill rungs below 128, the (S, K) step over a K/V or a latent pool);
+    the lockstep step, over a K/V pool and over a latent one, reads what is
+    live alone (see ops/nn_ops.py).
     """
 
     def __init__(self, block_size=None, num_blocks=None,
@@ -962,8 +962,9 @@ class CacheContext:
         program's block tables and context lengths the first time a layer
         asks and shared by every layer after it: a read that walks it does
         work in proportion to the contexts, not to the tables' padded
-        width. A program none of whose layers asks (a latent pool's, the
-        (S, K) step's) holds no such op."""
+        width. A program none of whose layers asks (a latent pool's, which
+        asks for :meth:`live_groups`; the (S, K) step's) holds no such
+        op."""
         if self._live is None:
             from ...ops.nn_ops import live_block_list
             c = self.coords
@@ -972,9 +973,10 @@ class CacheContext:
         return self._live
 
     def live_groups(self):
-        """:meth:`live_blocks` for a window model's block read: the live
-        context in groups of whole blocks (ops/nn_ops.py::live_group_list),
-        made once a program and shared by its layers."""
+        """:meth:`live_blocks` for the reads that walk GROUPS of whole
+        blocks (ops/nn_ops.py::live_group_list): a window model's block
+        read, a full layer's grouped read, a latent pool's lockstep read.
+        Made once a program and shared by its layers."""
         if self._groups is None:
             from ...ops.nn_ops import live_group_list
             c = self.coords
@@ -1009,7 +1011,10 @@ class CacheContext:
         are to be cached, and ``w_kvb``; ``attrs`` those of the two ops
         (ops/llm_ops.py). Prefill writes the prompt's rows and attends in
         the expanded form over the prompt itself; decode writes each slot's
-        K fed rows and reads the pool in the absorbed form."""
+        K fed rows and reads the pool in the absorbed form: the lockstep
+        step (K = 1) walks :meth:`live_groups`, one list a program shared
+        by its layers; the (S, K) step gathers every table and takes no
+        list."""
         from ...dygraph.tape import dispatch_op
         layer = self._layer
         self._layer += 1
@@ -1023,13 +1028,16 @@ class CacheContext:
             layer, c['write_ids'], c['write_offs'],
             rows.reshape(-1, rows.shape[-1]))
         # the scope names the read's device ops in a profiler trace (the
-        # absorbing matmuls before and after it included: small beside it)
+        # walk's `while` and the absorbing matmuls before and after it; in
+        # the first layer the list of live groups too)
         with jax.named_scope('mla/decode_read'):
             return dispatch_op('mla_decode_attention', {
                 'q': inputs['q'], 'pages': self.pool.pages(layer)[0],
                 'block_tables': c['block_tables'],
                 'context_lens': c['context_lens'],
-                'w_kvb': inputs['w_kvb']}, attrs)
+                'w_kvb': inputs['w_kvb'],
+                'live': self.live_groups() if rows.shape[1] == 1 else None},
+                attrs)
 
     def attend_retention(self, inputs, attrs):
         """A power-retention layer through its recurrent state. ``inputs``:

@@ -3,6 +3,7 @@ engine's phase histogram and the scheduler's cycle and queue-wait
 histograms fill with telemetry off and no traced request; with telemetry on
 the same stamps become per-call spans; and a traced request costs the worker
 thread one append per step, its spans made in one batch per iteration."""
+import functools
 import json
 import threading
 import time
@@ -252,6 +253,19 @@ def test_one_engine_span_per_call_inside_its_cycle(lm):
     assert obs.tracer.snapshot()['otherData']['dropped_events'] == 0
 
 
+def _step_reads(engine, tables, contexts):
+    """One lockstep step with slot i at ``contexts[i]`` cached tokens (None:
+    idle); (blocks, positions) the counters gained."""
+    for t, c in zip(tables, contexts):
+        t.context_len = c or 0
+    before = (_counter('decode_kv_blocks_read'),
+              _counter('decode_context_positions_read'))
+    engine.decode_step([1 if c else None for c in contexts],
+                       [t if c else None for t, c in zip(tables, contexts)])
+    return (_counter('decode_kv_blocks_read') - before[0],
+            _counter('decode_context_positions_read') - before[1])
+
+
 def test_blocks_read_counts_the_live_blocks_walked_in_whole_chunks(lm):
     """`decode_kv_blocks_read` against a hand count, beside
     `decode_context_positions_read`, and both in the `engine/step` span's
@@ -264,19 +278,7 @@ def test_blocks_read_counts_the_live_blocks_walked_in_whole_chunks(lm):
     layers = lm.num_cache_layers
     assert engine.slots * engine.pool.max_blocks_per_seq == 320
     tables = [engine.reserve_table(16, 112) for _ in range(10)]
-
-    def step(contexts):
-        """One lockstep step with slot i at ``contexts[i]`` cached tokens
-        (None: idle); (blocks, positions) the counters gained."""
-        for t, c in zip(tables, contexts):
-            t.context_len = c or 0
-        before = (_counter('decode_kv_blocks_read'),
-                  _counter('decode_context_positions_read'))
-        engine.decode_step([1 if c else None for c in contexts],
-                           [t if c else None for t, c in zip(tables,
-                                                             contexts)])
-        return (_counter('decode_kv_blocks_read') - before[0],
-                _counter('decode_context_positions_read') - before[1])
+    step = functools.partial(_step_reads, engine, tables)
 
     with obs.telemetry_guard(True):
         # the step feeds one token: contexts 4, 5 and 12 attend 5, 6 and 13
@@ -298,6 +300,55 @@ def test_blocks_read_counts_the_live_blocks_walked_in_whole_chunks(lm):
     before = _counter('decode_kv_blocks_read')
     engine.spec_step([[1, 2]] * 10, tables)
     assert _counter('decode_kv_blocks_read') - before == layers * 320
+
+
+def test_blocks_read_of_a_latent_pool_counts_the_live_groups_in_whole_chunks(
+        monkeypatch):
+    """`decode_kv_blocks_read` of a latent engine against a hand count: its
+    lockstep read walks the live GROUPS of blocks (32 blocks of 4 tokens a
+    group: 128 keys) in whole chunks of groups, and the quotient over
+    steps x layers x slots x `max_blocks_per_seq` is how far the walk
+    engages (1.0 when the read gathered every table). 6 slots of 96 blocks:
+    3 groups a slot, 18 in all, a chunk cut to 4 groups so that a step
+    walks several."""
+    from paddle_tpu.core.random import default_generator
+    from paddle_tpu.models.latent_moe_lm import LatentMoEConfig, LatentMoELM
+    from paddle_tpu.ops import nn_ops
+    monkeypatch.setattr(nn_ops, 'LIVE_GROUP_CHUNK', 4)
+    with guard():
+        default_generator.seed(5)
+        model = LatentMoELM(LatentMoEConfig.tiny(
+            max_position_embeddings=512))
+        model.eval()
+        engine = make_engine(model, slots=6, max_blocks=640,
+                             max_prompt_len=16, max_new_tokens_cap=368,
+                             prompt_buckets=[16], prefix_cache=False)
+        layers = model.cfg.num_hidden_layers
+        assert engine.cache_kind == 'latent'
+        assert engine.pool.max_blocks_per_seq == 96
+        assert nn_ops.live_group_chunk(6, 4, 96) == (3, 4)
+        tables = [engine.reserve_table(16, 368) for _ in range(6)]
+        step = functools.partial(_step_reads, engine, tables)
+
+        with obs.telemetry_guard(True):
+            # contexts 4 and 127 attend 5 and 128 positions in one group
+            # each, 128 attends 129 in two; three idle slots read the
+            # scratch block, a group each: 7 live groups, two chunks of 4,
+            # 8 groups of 32 blocks walked
+            assert step([4, 127, 128] + [None] * 3) == (
+                layers * 8 * 32, layers * (5 + 128 + 129))
+            # every slot at 383 of its 384 positions: 18 groups, 5 chunks
+            assert step([383] * 6)[0] == layers * 20 * 32
+            # one slot alone: its group and five idle ones, 6 groups
+            assert step([9] + [None] * 5)[0] == layers * 8 * 32
+        spans = [e for e in _spans('engine/step')
+                 if e['name'] == 'engine/step']
+        assert [e['args']['kv_blocks'] for e in spans] == [
+            layers * 256, layers * 640, layers * 256]
+        # the quotient: of the first step's padded read (6 x 96 blocks a
+        # layer) 256 / 576 is walked
+        assert spans[0]['args']['kv_blocks'] / (layers * 6 * 96) \
+            == pytest.approx(0.444, abs=1e-3)
 
 
 def test_traced_requests_cost_the_per_slot_loop_no_child_contexts(

@@ -370,6 +370,44 @@ def test_compiled_for_the_chip_no_program_moves_a_state_array(v5e):
             assert 'f32[6,8,8256,128]' not in text
 
 
+def _latent_engine_of_shapes(sizes, rung, **engine):
+    """(model, engine) of a bf16 `LatentMoELM.tiny` with ``sizes`` over a
+    bf16 pool of blocks of 16, with one prefill rung ``rung``: parameters
+    and pool are shapes alone (`jax.eval_shape` of the constructor and of
+    the rung, which tells the pool its arrays), for programs that are
+    compiled and never run. Call under `dygraph.guard()`."""
+    from paddle_tpu.core.random import default_generator
+    from paddle_tpu.models.latent_moe_lm import LatentMoEConfig, LatentMoELM
+    from paddle_tpu.serving.decode.kv_cache import (BlockTable,
+                                                    prefill_coords)
+    made = {}
+
+    def init(key):
+        with default_generator.bind_base(key):
+            made['model'] = LatentMoELM(LatentMoEConfig.tiny(
+                dtype='bfloat16', **sizes))
+        return {n: p.value for n, p in made['model'].named_parameters()}
+
+    default_generator.seed(3)
+    shapes = jax.eval_shape(init, default_generator.base_key())
+    model = made['model']
+    model.eval()
+    for name, p in model.named_parameters():
+        p.value = shapes[name]
+    eng = DecodeEngine(model, block_size=16, max_prompt_len=rung,
+                       prompt_buckets=[rung], prefix_cache=False,
+                       kv_dtype='bf16', **engine)
+    pool, prog = eng.pool, eng._program
+    out = jax.eval_shape(
+        lambda pv, *rest: prog.jitted('prefill', pool.geometry, pv, {}, {},
+                                      {}, *rest),
+        {n: p.value for n, p in prog._params.items()},
+        np.zeros((1, rung), np.int64), None,
+        prefill_coords(pool, BlockTable([], 16), rung), np.int32(0))
+    pool.adopt({k: list(v) for k, v in out[3].items()}, {})
+    return model, eng
+
+
 def test_compiled_for_the_chip_the_experts_run_the_grouped_kernel(
         v5e, monkeypatch):
     """A routed model's step and a prefill rung, compiled for the chip with
@@ -380,41 +418,14 @@ def test_compiled_for_the_chip_the_experts_run_the_grouped_kernel(
     `op_name`, which benchmark/lib/scoped_ops.py sums device time by. The
     step's 4 × 2 assignments and the rung's 128 × 2 are padded to whole
     row tiles (compiled here: no chip, no time). Shapes alone."""
-    from paddle_tpu.core.random import default_generator
-    from paddle_tpu.models.latent_moe_lm import LatentMoEConfig, LatentMoELM
     from paddle_tpu.ops import llm_ops
     from paddle_tpu.ops.pallas_moe import kernel_op_names
-    from paddle_tpu.serving.decode.kv_cache import (BlockTable,
-                                                    prefill_coords)
     monkeypatch.setattr(llm_ops, 'on_tpu', lambda: True)
-    made = {}
-
-    def init(key):
-        with default_generator.bind_base(key):
-            made['model'] = LatentMoELM(LatentMoEConfig.tiny(
-                hidden_size=256, moe_intermediate_size=128,
-                max_position_embeddings=256, dtype='bfloat16'))
-        return {n: p.value for n, p in made['model'].named_parameters()}
-
     with dygraph.guard():
-        default_generator.seed(3)
-        shapes = jax.eval_shape(init, default_generator.base_key())
-        model = made['model']
-        model.eval()
-        for name, p in model.named_parameters():
-            p.value = shapes[name]
-        eng = DecodeEngine(model, slots=4, block_size=16, max_blocks=64,
-                           max_prompt_len=128, max_new_tokens_cap=64,
-                           prompt_buckets=[128], prefix_cache=False,
-                           kv_dtype='bf16')
-        pool, prog = eng.pool, eng._program
-        out = jax.eval_shape(
-            lambda pv, *rest: prog.jitted('prefill', pool.geometry, pv, {},
-                                          {}, {}, *rest),
-            {n: p.value for n, p in prog._params.items()},
-            np.zeros((1, 128), np.int64), None,
-            prefill_coords(pool, BlockTable([], 16), 128), np.int32(0))
-        pool.adopt({k: list(v) for k, v in out[3].items()}, {})
+        model, eng = _latent_engine_of_shapes(
+            dict(hidden_size=256, moe_intermediate_size=128,
+                 max_position_embeddings=256), 128, slots=4, max_blocks=64,
+            max_new_tokens_cap=64)
         expert_layers = model.cfg.num_hidden_layers \
             - model.cfg.first_k_dense_replace
         for bucket in (None, 128):
@@ -423,6 +434,36 @@ def test_compiled_for_the_chip_the_experts_run_the_grouped_kernel(
             kernels = kernel_op_names(text)
             assert len(kernels) == 2 * expert_layers, kernels
             assert all('/moe/experts/' in name for name in kernels), kernels
+
+
+def test_compiled_for_the_chip_the_latent_read_walks_the_live_groups(v5e):
+    """A latent engine whose tables hold more than one chunk of groups (16
+    slots x 9 groups of 8 blocks: 144 > 128), its step compiled for the
+    chip: no array over every slot's padded context (the dense
+    `pages[tables]` copy of 16 x 1,152 rows and the scores over it are
+    gone), no move of the pool, and one `while` a layer under the caller's
+    scope `mla/decode_read`, which benchmark/lib/scoped_ops.py sums device
+    time by (compiled here: no chip, no time). Shapes alone."""
+    from paddle_tpu.ops.nn_ops import live_group_chunk
+    with dygraph.guard():
+        model, eng = _latent_engine_of_shapes(
+            dict(max_position_embeddings=2048), 1024, slots=16,
+            max_blocks=1160, max_new_tokens_cap=128)
+        pool = eng.pool
+        per_slot, chunk = live_group_chunk(16, 16, pool.max_blocks_per_seq)
+        assert (per_slot, chunk) == (9, 128) and 16 * per_slot > chunk
+        rows = pool.arrays()[0][0][0]
+        assert rows.shape == (1160, 16, 128)
+        text = eng.lowered(None, v5e).compile().as_text()
+        # what `step_context_arrays` and `pool_moves` look for, in the one
+        # compile
+        assert _arrays_spanning(text, 16 * eng.padded_context) == []
+        assert _moves_of_size(text, {int(rows.size)}) == []
+        walks = [line for line in text.splitlines()
+                 if ' while(' in line and 'op_name=' in line]
+        assert len(walks) == model.cfg.num_hidden_layers, walks
+        assert all('/mla/decode_read/' in line.split('op_name="')[1]
+                   .split('"')[0] for line in walks), walks
 
 
 def test_compiled_for_the_chip_a_model_with_layer_classes(v5e, monkeypatch):
