@@ -532,7 +532,9 @@ def _c_rope(ctx):
 
 @cost_rule('lm_head')
 def _c_lm_head(ctx):
-    return 2 * ctx.in_elems('x') * _pdim(ctx.input('w'), 1, ctx.assume_dim)
+    vocab = 0 if ctx.attr('tied', False) else 1
+    return 2 * ctx.in_elems('x') * _pdim(ctx.input('w'), vocab,
+                                         ctx.assume_dim)
 
 
 @cost_rule('diffusion_pick')
@@ -648,6 +650,13 @@ def _c_retention_prefill(ctx):
     per_query = -(-length // chunk) * chunk * per_key
     return b * length * (heads * per_query
                          + groups * (2 * big + 2 * big * (d + 1)))
+
+
+@cost_rule('short_conv_prefill', 'short_conv_step')
+def _c_short_conv(ctx):
+    # per row and channel: u = B·z, L taps' products and their sum, the gate
+    taps = _pdim(ctx.input('w'), 0, ctx.assume_dim)
+    return ctx.in_elems('x') // 3 * (2 * taps + 1)
 
 
 # ---------------------------------------------------------------------------

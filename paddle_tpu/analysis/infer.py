@@ -1531,8 +1531,10 @@ def _rope(ctx):
 @infer_rule('lm_head')
 def _lm_head(ctx):
     x, w = ctx.require('x'), ctx.require('w')
-    _contracts('lm_head', _dim(x, -1), _dim(w, 0))
-    shape = None if x.shape is None else tuple(x.shape[:-1]) + (_dim(w, 1),)
+    hidden, vocab = (1, 0) if ctx.attr('tied', False) else (0, 1)
+    _contracts('lm_head', _dim(x, -1), _dim(w, hidden))
+    shape = None if x.shape is None \
+        else tuple(x.shape[:-1]) + (_dim(w, vocab),)
     return {'Out': VarInfo(shape, 'float32')}
 
 
@@ -1705,6 +1707,47 @@ def _power_retention_step(ctx):
             f'not the block {block} of these heads')
     _contracts('power_retention_step rows against slots',
                _dim(ctx.require('rows'), 0), _dim(ctx.require('q'), 0))
+    return {'Out': out, 'State': VarInfo(state.shape, 'float32')}
+
+
+def _short_conv(ctx, what):
+    """((.., T, h) of a gated short convolution, its (1, L - 1, h) state
+    block): x (.., T, 3h) rows [B | C | z] under w (L, h) taps."""
+    x, w = ctx.require('x'), ctx.require('w')
+    if x.shape is not None and len(x.shape) != 3:
+        raise InferError(f'{what} expects x of rank 3 (.., T, 3h), got rank '
+                         f'{len(x.shape)}')
+    if w.shape is not None and len(w.shape) != 2:
+        raise InferError(f'{what} expects w of rank 2 (L, h), got rank '
+                         f'{len(w.shape)}')
+    h, taps = _dim(w, 1), _dim(w, 0)
+    _contracts(f'{what} rows [B | C | z] against 3 x the taps\' width',
+               _dim(x, -1), _mul_dims(h, 3))
+    if known(taps) and taps < 2:
+        raise InferError(f'{what}: a filter of {taps} tap(s) keeps no state')
+    block = (1, taps - 1, h) if known(taps) else None
+    return VarInfo((_dim(x, 0), _dim(x, 1), h), x.dtype), block
+
+
+@infer_rule('short_conv_prefill')
+def _short_conv_prefill(ctx):
+    out, block = _short_conv(ctx, 'short_conv_prefill')
+    state = None if block is None else (_dim(ctx.require('x'), 0),) + block
+    return {'Out': out, 'State': VarInfo(state, 'float32')}
+
+
+@infer_rule('short_conv_step')
+def _short_conv_step(ctx):
+    out, block = _short_conv(ctx, 'short_conv_step')
+    state = ctx.require('state')
+    if state.shape is not None and block is not None and not (
+            len(state.shape) == 4 and all(
+                dims_agree(a, b) for a, b in zip(state.shape[1:], block))):
+        raise InferError(
+            f'short_conv_step: state rows of {state.shape[1:]} are not the '
+            f'block {block} of these taps')
+    _contracts('short_conv_step rows against slots',
+               _dim(ctx.require('rows'), 0), _dim(ctx.require('x'), 0))
     return {'Out': out, 'State': VarInfo(state.shape, 'float32')}
 
 

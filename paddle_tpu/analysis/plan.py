@@ -39,6 +39,7 @@ passes the actual shapes, making the plan exact for static programs).
 """
 from __future__ import annotations
 
+import math
 import time
 from typing import Dict, List, Optional, Set
 
@@ -520,7 +521,10 @@ def _decode_kv_geometry(model):
     layer, :func:`decode_layer_classes`) or
     ``{'kind': 'latent', 'layers', 'row_width'}`` for one latent row; per
     REQUEST per layer ``{'kind': 'state', 'layers', 'heads', 'state_rows',
-    'head_dim'}`` for one recurrent state). Raises a ValueError
+    'head_dim'}`` for one recurrent state; a HYBRID names each layer's kind
+    in ``'layer_kinds'`` beside the ``kind`` of its row layers, with
+    ``'state_block'``, the float32 block a state layer keeps a request:
+    :func:`decode_layer_counts`). Raises a ValueError
     naming what is missing — a budget solve over unknown geometry would
     silently size the pool wrong."""
     spec = getattr(model, 'kv_cache_spec', None)
@@ -539,8 +543,9 @@ def decode_token_layer_bytes(model, kv_dtype='f32'):
     row of every head, or one latent row, each in the lanes the pool gives
     it, priced by kv_cache.kv_row_bytes at the storage dtype (int8 rows
     carry an f32 scale a head; a latent row has no int8 form). A state
-    cache holds no row per token: 0, and its price is per slot
-    (:func:`decode_state_row_bytes`)."""
+    layer holds no row per token, so a model of state layers alone costs 0
+    here and its price is per slot (:func:`decode_state_row_bytes`); of a
+    hybrid this is a ROW layer's price."""
     from ..serving.decode.kv_cache import kv_row_bytes
     spec = _decode_kv_geometry(model)
     if spec['kind'] == 'state':
@@ -555,6 +560,14 @@ def decode_token_layer_bytes(model, kv_dtype='f32'):
     return 2 * kv_row_bytes(spec['heads'], spec['head_dim'], kv_dtype)
 
 
+def decode_layer_counts(model):
+    """(row layers, state layers) of the model: the layers that cache a row
+    a token (K/V or latent) and those that keep one state a request
+    (serving/decode/kv_cache.py::layer_counts of its spec)."""
+    from ..serving.decode.kv_cache import layer_counts
+    return layer_counts(_decode_kv_geometry(model))
+
+
 def decode_layer_classes(model):
     """(full layers, sliding layers, span) of a K/V model
     (``kv_cache_spec()['layer_spans']``; a model that names no classes has
@@ -565,7 +578,7 @@ def decode_layer_classes(model):
     spec = _decode_kv_geometry(model)
     spans = spec.get('layer_spans')
     if spans is None:
-        return spec['layers'], 0, 0
+        return decode_layer_counts(model)[0], 0, 0
     sliding = [s for s in spans if s]
     return len(spans) - len(sliding), len(sliding), max(sliding, default=0)
 
@@ -577,6 +590,16 @@ def decode_context_bytes(model, context, kv_dtype='f32'):
     full, sliding, span = decode_layer_classes(model)
     return decode_token_layer_bytes(model, kv_dtype) * (
         full * int(context) + sliding * min(int(context), span))
+
+
+def decode_request_bytes(model, context, kv_dtype='f32'):
+    """HBM bytes ONE request of ``context`` positions holds: its rows in
+    the row layers (:func:`decode_context_bytes`) and, where the model has
+    state layers, its row of each (:func:`decode_state_row_bytes`),
+    whatever the context. What a hybrid request costs."""
+    rows, states = decode_layer_counts(model)
+    return (decode_context_bytes(model, context, kv_dtype) if rows else 0) \
+        + (decode_state_row_bytes(model) if states else 0)
 
 
 def decode_sliding_class_bytes(model, slots, block_size, kv_dtype='f32'):
@@ -607,23 +630,27 @@ def decode_pool_block_bytes(model, block_size, kv_dtype='f32'):
     :func:`decode_sliding_class_bytes`)."""
     spec = _decode_kv_geometry(model)
     layers = decode_layer_classes(model)[0] if spec['kind'] == 'kv' \
-        else spec['layers']
+        else decode_layer_counts(model)[0]
     return layers * int(block_size) * decode_token_layer_bytes(model,
                                                                kv_dtype)
 
 
 def decode_state_row_bytes(model):
-    """HBM bytes ONE request's recurrent state costs across every layer: a
-    row of each state layer's array, ``heads`` blocks of ``state_rows`` ×
-    ``head_dim`` float32 values (ops/llm_ops.py::retention_state_rows),
-    whatever the request's context. What a slot costs a state cache."""
+    """HBM bytes ONE request's recurrent state costs across every state
+    layer: a row of each one's array, float32, whatever the request's
+    context: ``state_block`` values where the model names the block (a
+    short convolution's (1, L - 1, h)), else ``heads`` blocks of
+    ``state_rows`` × ``head_dim`` (ops/llm_ops.py::retention_state_rows).
+    What a slot costs the state layers."""
     spec = _decode_kv_geometry(model)
-    if spec['kind'] != 'state':
+    states = decode_layer_counts(model)[1]
+    if not states:
         raise ValueError(
             f"a {spec['kind']} cache holds rows per token, no state row: "
             f'price it by decode_pool_block_bytes')
-    return (spec['layers'] * spec['heads'] * spec['state_rows']
-            * spec['head_dim'] * 4)
+    block = spec.get('state_block') or (spec['heads'], spec['state_rows'],
+                                        spec['head_dim'])
+    return states * math.prod(int(n) for n in block) * 4
 
 
 def solve_decode_state_slots(model, hbm_mb):
@@ -651,9 +678,19 @@ def solve_decode_pool_blocks(model, hbm_mb, block_size, kv_dtype='f32',
     not even cover the model's resident state — a silent floor there
     would hide that the budget is fiction. A model with a sliding class of
     layer needs ``slots``: that class's arrays (a ring a slot) come off
-    the budget first, and the blocks solved for are the full class's."""
+    the budget first, and the blocks solved for are the full class's. So
+    does a hybrid's state cache: ``slots + 1`` rows of its state layers
+    come off first, and the blocks are bought for the row layers alone."""
     budget = int(hbm_mb) << 20
     state = _model_state_bytes(model)
+    rows, states = decode_layer_counts(model)
+    if rows and states:
+        if slots is None:
+            raise ValueError(
+                'a model with state layers beside row layers is sized per '
+                'kind: solve_decode_pool_blocks needs slots (a state row a '
+                'slot and the scratch row)')
+        state += (int(slots) + 1) * decode_state_row_bytes(model)
     if _decode_kv_geometry(model)['kind'] == 'kv' \
             and decode_layer_classes(model)[1]:
         if slots is None:
@@ -676,18 +713,21 @@ def solve_decode_pool_blocks(model, hbm_mb, block_size, kv_dtype='f32',
 
 
 def decode_pool_report(model, hbm_mb, block_size, kv_dtype='f32',
-                       min_blocks=2):
+                       min_blocks=2, slots=None):
     """The solve, itemized for tools/plan_program.py — every term of the
-    closed form inspectable next to the resulting block count."""
+    closed form inspectable next to the resulting block count (``slots``
+    as `solve_decode_pool_blocks` takes it)."""
     spec = _decode_kv_geometry(model)
     state = _model_state_bytes(model)
     block_bytes = decode_pool_block_bytes(model, block_size, kv_dtype)
     blocks = solve_decode_pool_blocks(model, hbm_mb, block_size, kv_dtype,
-                                      min_blocks)
+                                      min_blocks, slots)
     extra = {}
     if spec['kind'] == 'state':
         extra = {'state_row_bytes': decode_state_row_bytes(model),
                  'state_slots': solve_decode_state_slots(model, hbm_mb)}
+    elif decode_layer_counts(model)[1]:
+        extra = {'state_row_bytes': decode_state_row_bytes(model)}
     return {
         **extra,
         'budget_mb': int(hbm_mb),

@@ -204,6 +204,14 @@ class RoutedExperts(Layer):
                        'norm_topk_prob': cfg.norm_topk_prob}
         if scoring != 'sigmoid':    # the sigmoid dispatch stays as it was
             self._route['scoring_func'] = scoring
+        # what the normaliser adds to the chosen scores' sum: a family that
+        # says otherwise than the op's 1e-20 names it (`lfm2_moe`: 1e-6)
+        if hasattr(cfg, 'router_norm_epsilon'):
+            self._route['norm_epsilon'] = cfg.router_norm_epsilon
+        # whose choices a prefill notes for the host: the scored row's
+        # alone, or every row's where a later row READS its neighbours'
+        # values directly (a short convolution: models/hybrid_conv_moe_lm.py)
+        self.note_every_row = getattr(cfg, 'note_every_rows_experts', False)
 
     def forward(self, x, cache=None):
         b, s, h = x.shape
@@ -235,7 +243,8 @@ class RoutedExperts(Layer):
                 # all the live rows' assignments, held here or elsewhere
                 cache.note('expert_assignments', live.sum(
                     dtype=jnp.int32) * ids.shape[-1])
-            cache.note('expert_ids', _scored_rows(cache, ids.value, 0))
+            cache.note('expert_ids', ids.value if self.note_every_row
+                       else _scored_rows(cache, ids.value, 0))
         if self.shared is not None:
             with jax.named_scope('moe/shared'):
                 routed = routed + self.shared(flat)

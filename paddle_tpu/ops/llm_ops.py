@@ -5,8 +5,9 @@ or softmax scores) and a grouped expert feed-forward that drops no token
 with a kernel and a predicate beside its call, `experts_kernel_applies`),
 latent (MLA) attention in its two forms (expanded over a prompt, absorbed
 over the paged latent cache: the lockstep step's read walks the batch's
-live groups, ops/nn_ops.py), power retention, and a block-diffusion
-forward's pick (a token and the confidence in it per row).
+live groups, ops/nn_ops.py), power retention, the gated short convolution
+of a hybrid decoder (a state of L - 1 rows a request), and a
+block-diffusion forward's pick (a token and the confidence in it per row).
 
 Precision rule, the same in every op: matmuls take their operands as they
 are stored (bf16 weights and activations on the served path) and accumulate
@@ -77,11 +78,16 @@ def rope(x, pos, *, theta=10000.0, nope_dim=0):
 
 
 @register_op('lm_head')
-def lm_head(x, w):
-    """Logits of an untied head, x (..., h) · w (h, V), in float32 whatever
-    the operands are stored in: the rows go to the host's sampler."""
-    return jnp.matmul(jnp.asarray(x), jnp.asarray(w),
-                      preferred_element_type=_F32)
+def lm_head(x, w, *, tied=False):
+    """Logits of a head, x (..., h) · w (h, V), in float32 whatever the
+    operands are stored in: the rows go to the host's sampler. ``tied``: w
+    is the embedding's own array (V, h), contracted over its h as it lies
+    (no transposed copy of it exists)."""
+    x, w = jnp.asarray(x), jnp.asarray(w)
+    if tied:
+        return lax.dot_general(x, w, (((x.ndim - 1,), (1,)), ((), ())),
+                               preferred_element_type=_F32)
+    return jnp.matmul(x, w, preferred_element_type=_F32)
 
 
 @register_op('diffusion_pick', outputs=('Ids', 'Confidence'))
@@ -123,7 +129,8 @@ def sigmoid_gate(x, gate):
 
 @register_op('moe_router', outputs=('Ids', 'Weights'))
 def moe_router(x, w_gate, bias=None, *, top_k, routed_scaling_factor=1.0,
-               norm_topk_prob=True, scoring_func='sigmoid'):
+               norm_topk_prob=True, scoring_func='sigmoid',
+               norm_epsilon=1e-20):
     """A router's choice of ``top_k`` of E experts and their weights, all
     float32. ``scoring_func``:
 
@@ -134,8 +141,10 @@ def moe_router(x, w_gate, bias=None, *, top_k, routed_scaling_factor=1.0,
       over the E experts; the ``top_k`` largest are chosen (``bias`` None:
       the family has none) and weigh by their p.
 
-    The weights are normalised over the chosen (``norm_topk_prob``) and
-    scaled. Of equal scores the lower expert is chosen first.
+    The weights are normalised over the chosen (``norm_topk_prob``: divided
+    by their sum + ``norm_epsilon``, 1e-20 in `deepseek_v3`, `sdar_moe` and
+    `afmoe`, 1e-6 in `lfm2_moe`) and scaled. Of equal scores the lower
+    expert is chosen first.
 
     x (T, h), w_gate (h, E), bias (E,) or None -> ids (T, k) int32, weights
     (T, k) float32."""
@@ -154,7 +163,8 @@ def moe_router(x, w_gate, bias=None, *, top_k, routed_scaling_factor=1.0,
     _, ids = lax.top_k(biased, int(top_k))
     weights = jnp.take_along_axis(scores, ids, -1)
     if norm_topk_prob:
-        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+        weights = weights / (weights.sum(-1, keepdims=True)
+                             + _F32(norm_epsilon))
     return ids.astype(jnp.int32), weights * routed_scaling_factor
 
 
@@ -605,3 +615,75 @@ def power_retention_step(q, k, v, log_gate, state, rows):
                          jnp.zeros((slots, g, rep), _F32)))
     out = (num / den[..., None]).reshape(slots, 1, heads * d)
     return out.astype(q.dtype), state
+
+
+# ---------------------------------------------------------------------------
+# gated short convolution (`lfm2`): a depthwise causal filter of L taps over
+# u = B ⊙ z, gated by C, between an input projection h -> 3h = [B | C | z]
+# and an output projection (both the caller's: models/hybrid_conv_moe_lm.py):
+#
+#     u_t = B_t ⊙ z_t      c_t = Σ_{j<L} w[j] ⊙ u_{t-(L-1)+j}      y_t = C_t ⊙ c_t
+#
+# with u before position 0 zero: torch Conv1d's cross-correlation order, the
+# LAST tap on the newest position. No activation: the two gates are products.
+# What a request carries from step to step is its last L - 1 values of u,
+# oldest first, whatever its context: a state block (1, L - 1, h), h values
+# on the lanes.
+#
+# Precision: the rows arrive as stored (bf16 on the served path); u, the
+# filter's products and sum, the gate's product and the state are float32 (a
+# product of two bf16 values is exact there); y returns in the rows' dtype.
+# ---------------------------------------------------------------------------
+
+def _conv_parts(x, w):
+    """(u (.., T, h) float32, C (.., T, h) float32, taps (L, h) float32) of
+    rows x (.., T, 3h) = [B | C | z]."""
+    x = jnp.asarray(x)
+    h = x.shape[-1] // 3
+    b, c, z = (x[..., i * h:(i + 1) * h].astype(_F32) for i in range(3))
+    return b * z, c, jnp.asarray(w).astype(_F32)
+
+
+@register_op('short_conv_prefill', outputs=('Out', 'State'))
+def short_conv_prefill(x, w, last=None):
+    """The gated short convolution over whole sequences (see above).
+
+    x (B, T, 3h), the input projection's rows [B | C | z]; w (L, h), tap j
+    on position t - (L - 1) + j; ``last`` () or (B,) int32, the index of a
+    sequence's last row (rows past it are a rung's padding: a causal filter
+    never lets them reach a row at or before it, and the state is taken at
+    ``last``, never at the rung's end; None: every row is live). Returns
+    (B, T, h) in x's dtype and the (B, 1, L - 1, h) float32 states after row
+    ``last``: u_{last-(L-2)} .. u_{last}, zero where the sequence has not
+    that many rows."""
+    u, gate, taps = _conv_parts(x, w)
+    batch, length, h = u.shape
+    n = taps.shape[0]
+    if last is None:
+        last = length - 1
+    last = jnp.broadcast_to(jnp.asarray(last, jnp.int32), (batch,))
+    up = jnp.pad(u, ((0, 0), (n - 1, 0), (0, 0)))         # up[i] = u[i-(L-1)]
+    conv = sum(taps[j] * up[:, j:j + length] for j in range(n))
+    state = jax.vmap(lambda rows, at: lax.dynamic_slice_in_dim(
+        rows, at + 1, n - 1, 0))(up, last)
+    return (gate * conv).astype(jnp.asarray(x).dtype), state[:, None]
+
+
+@register_op('short_conv_step', outputs=('Out', 'State'))
+def short_conv_step(x, w, state, rows):
+    """One token for each of S slots over their conv states: read the
+    slot's L - 1 last values of u, filter, shift the new one in.
+
+    x (S, 1, 3h); w (L, h); state (rows, 1, L - 1, h) float32, one row a
+    request, oldest value first, and row 0 for idle slots; rows (S,) int32,
+    each slot's row. Returns (S, 1, h) in x's dtype and the state with every
+    slot's row advanced. The rows are gathered, advanced and scattered back
+    whole: a row is 2(L - 1)h·4 bytes (16 KB at h = 2,048), and idle slots
+    all write the scratch row, whichever of them lands."""
+    u, gate, taps = _conv_parts(x, w)
+    state = jnp.asarray(state)
+    rows = jnp.asarray(rows, jnp.int32)
+    window = jnp.concatenate([state[rows][:, 0], u], 1)   # (S, L, h)
+    conv = (taps[None] * window).sum(1, keepdims=True)
+    state = state.at[rows].set(window[:, None, 1:])
+    return (gate * conv).astype(jnp.asarray(x).dtype), state

@@ -69,7 +69,7 @@ from ..engine import bucket_ladder
 from ..errors import InvalidRequest, UnsupportedCacheFeature
 from .diffusion import unmask_most_confident
 from .kv_cache import (BlockTable, CacheContext, KVCachePool, decode_coords,
-                       prefill_coords, DEFAULT_BLOCK_SIZE,
+                       layer_counts, prefill_coords, DEFAULT_BLOCK_SIZE,
                        DEFAULT_MAX_BLOCKS, DEFAULT_SLOTS)
 
 __all__ = ['DecodeEngine', 'SLIDING_SPARE_BLOCKS']
@@ -391,7 +391,18 @@ class DecodeEngine:
         # REQUEST per layer one recurrent state (models/retention_lm.py)
         spec = getattr(model, 'kv_cache_spec', None)
         spec = spec() if spec else {'kind': 'kv'}
+        # the kind of the layers that cache rows ('kv' | 'latent'), or
+        # 'state' where every layer is a state layer
         self.cache_kind = spec['kind']
+        # what EACH layer caches (kv_cache.py "Hybrid models"): a model may
+        # hold state layers beside row layers, and says so per layer; the
+        # pool is sized over the row layers, a row a slot is kept wherever
+        # any layer is a state layer, and a step's counters go by these
+        self.row_layers, self.state_layers = layer_counts(spec)
+        # of the state layers, those a gated short convolution advances
+        # (`decode_conv_rows`, the span arg `conv_rows`)
+        self.conv_layers = self.state_layers \
+            if spec.get('state_op') == 'short_conv' else 0
         # the K/V layers' classes (kv_cache.py "Layer classes"): each
         # layer's span, 0 a full layer; None where the model names none
         # (one class, full). The sliding class's span is the one they share
@@ -439,14 +450,14 @@ class DecodeEngine:
                                         'f32')
         num_blocks = self._resolve_num_blocks(model, max_blocks, block_size,
                                               max_bps, kv_dtype, self.slots)
-        # a state cache: a row a slot, and the scratch row of idle slots.
+        # state layers: a row a slot, and the scratch row of idle slots.
         # The sliding class's depth is DERIVED, never asked for: a ring a
         # slot and the spare, since a slot never holds more of it
         ring = -(-self.span // block_size) + 1 if self.span else 0
         self.pool = KVCachePool(
             block_size=block_size, num_blocks=num_blocks,
             max_blocks_per_seq=max_bps, kv_dtype=kv_dtype,
-            state_rows=self.slots + 1 if self.cache_kind == 'state' else 0,
+            state_rows=self.slots + 1 if self.state_layers else 0,
             span=self.span,
             sliding_blocks=self.slots * ring + SLIDING_SPARE_BLOCKS
             if self.span else 0)
@@ -501,22 +512,28 @@ class DecodeEngine:
                 ('kv_dtype=int8', kv_dtype == 'int8')) if on]
             if asked:
                 raise UnsupportedCacheFeature(asked, 'latent')
-        if self.cache_kind == 'state':
-            # nothing of a state can be shared, quantized or rolled back
-            # (docs/SERVING.md "Recurrent state"); the handoff is refused
-            # where its prefill role is built (serving/tier/disagg.py)
+        if self.state_layers:
+            # nothing of a state can be shared or rolled back, in a model
+            # of state layers alone or beside row layers (docs/SERVING.md
+            # "Recurrent state", "Hybrid models"), and a state is float32:
+            # ``kv_dtype`` is the ROW layers', and means nothing where there
+            # are none; the handoff is refused where its prefill role is
+            # built (serving/tier/disagg.py)
             asked = [name for name, on in (
                 ('the prefix cache (and its spill and reinject)',
                  self.prefix_cache is not None),
                 ('speculative decoding (its (S, K) verify step)',
                  self.spec_enabled),
-                (f'kv_dtype={kv_dtype}', kv_dtype != 'f32')) if on]
+                (f'kv_dtype={kv_dtype}',
+                 kv_dtype != 'f32' and not self.row_layers)) if on]
             if asked:
                 raise UnsupportedCacheFeature(asked, 'state')
-        if self.span:
-            # none has a path over a ring yet (docs/SERVING.md "Layer
-            # classes"); the handoff is refused where its prefill role is
-            # built (serving/tier/disagg.py)
+        if self.layer_spans is not None:
+            # a model that names its layers' classes reads its rows through
+            # the grouped reads, full layers and rings alike: none of these
+            # has a path there yet (docs/SERVING.md "Layer classes"); the
+            # handoff is refused where its prefill role is built
+            # (serving/tier/disagg.py)
             asked = [name for name, on in (
                 ('the prefix cache (and its spill and reinject)',
                  self.prefix_cache is not None),
@@ -526,7 +543,8 @@ class DecodeEngine:
                 ('a window model\'s block step', self.window > 1))
                 if on]
             if asked:
-                raise UnsupportedCacheFeature(asked, 'sliding')
+                raise UnsupportedCacheFeature(
+                    asked, 'sliding' if self.span else 'grouped')
         if self.window > 1:
             # each needs a path under the block mask that is not written
             # yet (docs/SERVING.md "Window models"); the handoff is refused
@@ -636,10 +654,10 @@ class DecodeEngine:
                 self.pool.sliding.used if self.span else 0)
 
     def _set_state_gauges(self):
-        """The state cache's three gauges (a telemetry reset clears gauges,
-        so every call that changes one sets all three); nothing for a cache
-        of rows."""
-        if self.cache_kind == 'state':
+        """The state layers' three gauges (a telemetry reset clears gauges,
+        so every call that changes one sets all three); nothing for a model
+        of row layers alone."""
+        if self.state_layers:
             _m.state_cache_bytes_in_hbm.set(self.pool.state_bytes_in_hbm())
             _m.state_cache_rows_total.set(self.pool.state_rows.capacity)
             _m.state_cache_rows_used.set(self.pool.state_rows.used)
@@ -779,6 +797,7 @@ class DecodeEngine:
         if folded:
             _m.decode_state_tokens_folded.inc(folded)
             clock.work['state_tokens_folded'] = folded
+        self._account_conv(clock, P, rung=bucket)
         if self.layer_spans is not None:
             # positions the prompt leaves in each class's blocks
             clock.work.update(self._class_positions([P])[1])
@@ -872,9 +891,9 @@ class DecodeEngine:
         read walks the live blocks in whole chunks (ops/nn_ops.py::
         paged_attention), a latent pool's the live groups of blocks in
         whole chunks (ops/llm_ops.py::mla_decode_attention); a state layer
-        reads no block at all."""
+        reads no block at all (0 where no layer caches rows)."""
         entries = self.slots * self.pool.max_blocks_per_seq
-        if self.cache_kind == 'state':
+        if not self.row_layers:
             return 0
         if (self.cache_kind == 'latent' or self.window > 1
                 or self.layer_spans is not None):
@@ -939,12 +958,23 @@ class DecodeEngine:
         if updates:
             _m.decode_state_updates.inc(updates)
             clock.work['state_updates'] = updates
+        self._account_conv(clock, active)
         clock.record()
         _m.decode_slots_active.set(active)
         _m.decode_slot_occupancy.observe(active / max(self.slots, 1))
         # sliding-window views for /healthz slo + fleet snapshots
         _dobs.series('occupancy').observe(active / max(self.slots, 1))
         _dobs.series('decode_step').observe(dt)
+
+    def _account_conv(self, clock, rows, **args):
+        """A call's live rows through the gated short convolutions, ``rows``
+        x conv layers (a prefill's prompt, never its rung; a step's live
+        slots): the counter, and the span's ``conv_rows`` with ``args``
+        beside it. Nothing for a model without such a layer."""
+        if self.conv_layers:
+            conv = rows * self.conv_layers
+            _m.decode_conv_rows.inc(conv)
+            clock.work.update(args, conv_rows=conv)
 
     def _class_positions(self, contexts):
         """(positions the layers hold of ``contexts``, the call's span args

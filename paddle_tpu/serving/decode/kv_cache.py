@@ -64,6 +64,16 @@ step advances it in place, inside the same donated programs. The block
 allocator still books the request's lengths; no HBM stands behind a block of
 a model whose every layer is a state layer.
 
+Hybrid models (docs/SERVING.md "Hybrid models"): state layers and row
+layers in ONE model (models/hybrid_conv_moe_lm.py: gated short convolutions
+beside grouped-head attention). Nothing above changes: a table is given
+its blocks AND a row at admission (`new_table`, all or nothing) and returns
+both; the pool's depth is bought for the row layers alone, ``state_rows``
+is ``slots + 1`` wherever ANY layer is a state layer; the row layers hold
+``kv_dtype`` rows beside the float32 state rows; a state of any block shape
+goes through `CacheContext.attend_state` with the two ops that make and
+advance it handed in.
+
 Layer classes (docs/SERVING.md "Layer classes"): a model may bound what a
 layer's queries see to the last ``span`` positions (sliding attention). Such
 a layer gives back what lies behind its span: the K/V layers of a pool are
@@ -104,6 +114,7 @@ probabilities are exactly zero regardless.
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 import threading
@@ -115,7 +126,7 @@ from ..errors import (InvalidRequest, OutOfBlocks, OutOfStateRows,
                       UnsupportedCacheFeature)
 
 __all__ = ['BlockAllocator', 'BlockTable', 'KVCachePool', 'CacheContext',
-           'StateRows',
+           'StateRows', 'layer_kinds', 'layer_counts',
            'prefill_coords', 'decode_coords', 'DEFAULT_SLOTS',
            'DEFAULT_BLOCK_SIZE', 'DEFAULT_MAX_BLOCKS', 'SCRATCH_BLOCK',
            'KV_PAYLOAD_DTYPES', 'KV_DTYPE_CODES', 'kv_row_bytes',
@@ -150,6 +161,27 @@ def kv_row_bytes(heads, head_dim, kv_dtype):
     return (row_lanes(int(heads) * int(head_dim))
             * _KV_PAYLOAD_BYTES[kv_dtype]
             + (4 * int(heads) if kv_dtype == 'int8' else 0))
+
+
+def layer_kinds(spec):
+    """What each layer of a model caches, from its ``kv_cache_spec()``:
+    'kv' (K and V rows a token), 'latent' (one row a token) or 'state' (one
+    fixed-size state a request). A model of one kind says ``kind`` and
+    ``layers``; a hybrid says ``layer_kinds``, a layer at a time, beside
+    ``kind``, the kind of its row layers."""
+    kinds = spec.get('layer_kinds')
+    if kinds is None:
+        return (spec['kind'],) * int(spec.get('layers', 1))
+    return tuple(kinds)
+
+
+def layer_counts(spec):
+    """(row layers, state layers) of a model's ``kv_cache_spec()``: the
+    layers that cache a row a token (K/V or latent) and those that keep one
+    state a request."""
+    kinds = layer_kinds(spec)
+    states = sum(kind == 'state' for kind in kinds)
+    return len(kinds) - states, states
 
 
 LANES = 128
@@ -195,7 +227,7 @@ def _scatter_tokens(pages, block_ids, offsets, vals):
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def _put_row(states, row, block):
-    """states (R, G, P, d) ← block (G, P, d) at row () int32, whole."""
+    """states (R, *block) ← block at row () int32, whole."""
     return jax.lax.dynamic_update_index_in_dim(states, block, row, 0)
 
 
@@ -397,8 +429,8 @@ class KVCachePool:
         self.state_rows = StateRows(state_rows)
         # layer idx -> [k_pages, v_pages], each (NB, BS, lanes of H·D), or
         # for a latent (MLA) layer -> [rows] of (NB, BS, lanes of W): one
-        # row a token; for a state layer -> [states] of (state rows, G, P,
-        # d) float32: one row a request
+        # row a token; for a state layer -> [states] of (state rows,
+        # *block) float32, a block of three axes: one row a request
         self._layers = {}
         self._scales = {}          # int8 only: layer -> [k_scales, v_scales]
         # layer idx -> (H, D), how a K/V row splits into heads: what the
@@ -602,22 +634,24 @@ class KVCachePool:
     # -- state layers: one row a request, whole ----------------------------
     def ensure_state(self, layer, block_shape):
         """The state layer's one array, (state rows, *block_shape) float32
-        zeros, made on first use; ``block_shape`` (G, P, d) is what the
-        layer's op returned for one request."""
+        zeros, made on first use; ``block_shape`` (three axes: a retention
+        layer's (G, P, d), a short convolution's (1, L - 1, h)) is what the
+        layer's op returns for one request."""
         if layer not in self._layers:
             import jax.numpy as jnp
             if not self.state_rows.num_rows:
                 raise ValueError(
                     'a state layer over a pool built with state_rows=0: '
-                    'the model must say kv_cache_spec() kind "state"')
+                    'the model must name its state layers in '
+                    'kv_cache_spec() (kind "state", or "layer_kinds")')
             self._layers[layer] = [jnp.zeros(
                 (self.state_rows.num_rows,) + tuple(block_shape),
                 'float32')]
         return self._layers[layer]
 
     def write_state(self, layer, row, block):
-        """A prefill's final state (G, P, d) over the whole of ``row``: a
-        row reused after a free carries nothing over."""
+        """A prefill's final state (one block) over the whole of ``row``:
+        a row reused after a free carries nothing over."""
         states = self.ensure_state(layer, block.shape)
         states[0] = _put_row(states[0], row, block.astype('float32'))
 
@@ -1039,40 +1073,56 @@ class CacheContext:
                 'live': self.live_groups() if rows.shape[1] == 1 else None},
                 attrs)
 
-    def attend_retention(self, inputs, attrs):
-        """A power-retention layer through its recurrent state. ``inputs``:
-        q (B, L, H, d), k and v (B, L, G, d), ``log_gate`` (B, L, G);
-        ``attrs`` those of `power_retention_prefill` (ops/llm_ops.py).
-        Prefill scans the bucket (rows past ``last`` never enter the state)
-        and writes the final state over the request's whole row; a decode
-        step advances every slot's row in place and reads it, idle slots on
-        the scratch row. The scopes name the two ops' device ops in a
-        profiler trace."""
+    def attend_state(self, ops, inputs, attrs, block_shape, scopes=None):
+        """A STATE layer through its row, whatever the state is: ``ops`` =
+        (prefill op, step op) are handed in (ops/llm_ops.py), each returning
+        (out, state). The prefill op takes ``inputs`` and ``last`` under
+        ``attrs`` and returns the (1, *block_shape) state after the
+        prompt's TRUE last row (rows past ``last`` are the rung's padding
+        and never enter it), which is written over the request's whole
+        row: a row taken again carries nothing of its last request. The
+        step op takes ``inputs``, the layer's array ``state`` (state rows,
+        *block_shape) and each slot's ``rows`` (one fed token a slot), and
+        returns the array with every slot's row advanced, idle slots on the
+        scratch row. ``scopes`` (prefill, step) name the two ops' device
+        ops in a profiler trace; None where the caller's own scope does."""
         from ...dygraph.tape import dispatch_op
         layer = self._layer
         self._layer += 1
         rows = self.coords['state_rows']
-        if self.mode == 'prefill':
-            with jax.named_scope('retention/prefill_scan'):
+        prefill = self.mode == 'prefill'
+        scope = jax.named_scope(scopes[0 if prefill else 1]) if scopes \
+            else contextlib.nullcontext()
+        if prefill:
+            with scope:
                 out, state = dispatch_op(
-                    'power_retention_prefill',
-                    dict(inputs, last=self.last), attrs)
+                    ops[0], dict(inputs, last=self.last), attrs)
             self.pool.write_state(layer, rows[0], state.value[0])
             return out
-        if inputs['q'].shape[1] != 1:
+        if self.coords['write_ids'].shape[0] != rows.shape[0]:
             raise UnsupportedCacheFeature(
                 ['a decode window of more than one token (speculation, '
                  'chunked suffix fill)'], 'state')
-        from ...ops.llm_ops import retention_state_rows
-        _, _, groups, d = inputs['k'].shape
-        states = self.pool.ensure_state(
-            layer, (groups, retention_state_rows(d)[2], d))
-        with jax.named_scope('retention/decode_update'):
+        states = self.pool.ensure_state(layer, block_shape)
+        with scope:
             out, state = dispatch_op(
-                'power_retention_step',
-                dict(inputs, state=states[0], rows=rows), {})
+                ops[1], dict(inputs, state=states[0], rows=rows), {})
         states[0] = state.value
         return out
+
+    def attend_retention(self, inputs, attrs):
+        """A power-retention layer through its recurrent state
+        (:meth:`attend_state` with the two retention ops). ``inputs``: q (B,
+        L, H, d), k and v (B, L, G, d), ``log_gate`` (B, L, G); ``attrs``
+        those of `power_retention_prefill` (ops/llm_ops.py). Prefill scans
+        the bucket and writes the final state over the request's whole row;
+        a decode step advances every slot's row in place and reads it."""
+        from ...ops.llm_ops import retention_state_rows
+        _, _, groups, d = inputs['k'].shape
+        return self.attend_state(
+            ('power_retention_prefill', 'power_retention_step'), inputs,
+            attrs, (groups, retention_state_rows(d)[2], d),
+            ('retention/prefill_scan', 'retention/decode_update'))
 
     def attend(self, q, k, v, sm_scale=1.0, block_len=0, span=None):
         """``span`` (None: a model of one class of layer, whose key/value

@@ -32,7 +32,10 @@ lists, the full class's table and the sliding class's ring
 (`engine.reserve_table` → `KVCachePool.new_table`: all or nothing), the
 `OutOfBlocks` that makes a request wait names the class that ran out, and
 retirement (`_retire`, `_fail_request` → `engine.release_table`) returns
-both.
+both. Over a model with state layers, alone or beside row layers
+(docs/SERVING.md "Recurrent state", "Hybrid models"), the same call takes a
+state ROW as well, from a free list of its own: `OutOfStateRows` is an
+`OutOfBlocks`, the same wait, and retirement returns the row with the blocks.
 
 **Window models** (block diffusion, ``engine.window`` B > 1: docs/SERVING.md
 "Window models"): a slot holds a BLOCK, not a next token: B ids, which of

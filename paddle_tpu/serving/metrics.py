@@ -304,6 +304,11 @@ decode_state_tokens_folded = _LazyMetric(
     'counter', 'decode_state_tokens_folded',
     'prompt tokens a prefill folded into a recurrent state: prompt length '
     'x state layers, summed over prefills (a rung\'s padding not counted)')
+decode_conv_rows = _LazyMetric(
+    'counter', 'decode_conv_rows_total',
+    'live rows through the gated short convolutions: prompt length x conv '
+    'layers a prefill (a rung\'s padding not counted), live slots x conv '
+    'layers a decode step')
 # block diffusion (a window model: serving/decode/engine.py::window_step)
 decode_diffusion_denoise_forwards = _LazyMetric(
     'counter', 'decode_diffusion_denoise_forwards',

@@ -135,6 +135,10 @@ class PrefillReplica:
         if getattr(engine, 'span', 0):
             raise UnsupportedCacheFeature(['the disaggregated handoff'],
                                           'sliding')
+        if getattr(engine, 'state_layers', 0):
+            # a state has no blocks to hand off, beside row layers or alone
+            raise UnsupportedCacheFeature(['the disaggregated handoff'],
+                                          'state')
         if engine.cache_kind != 'kv' or getattr(engine, 'window', 1) > 1:
             raise UnsupportedCacheFeature(
                 ['the disaggregated handoff'],

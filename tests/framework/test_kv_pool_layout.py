@@ -542,3 +542,89 @@ def test_compiled_for_the_chip_a_model_with_layer_classes(v5e, monkeypatch):
             scopes = ('kv/sliding_read', 'kv/decode_read') if bucket is None \
                 else ('attn/sliding_prefill', 'attn/full_prefill')
             assert all(f'/{s}/' in text for s in scopes + ('attn/gate',))
+
+
+def test_compiled_for_the_chip_a_hybrid_of_state_and_row_layers(v5e,
+                                                                monkeypatch):
+    """A model of gated short-convolution layers beside grouped-head
+    attention layers at the published widths of `lfm2_8b_a1b` (hidden 2,048,
+    32 query heads over 8 key/value heads of 64: a K row of 512 lanes;
+    experts of 2,048 x 1,792, 7.34 MB a matrix: two width blocks of 896
+    columns for gate and up, two of 1,024 for down), its step and a prefill
+    rung compiled for the chip: Mosaic takes the expert kernel at the new
+    shape (two custom calls an expert layer, under `moe/experts`) and the
+    splash-attention kernel at heads of 64, no program copies or transposes
+    an array of the K/V pool's or the state rows' size (a state array of a
+    few hundred KB the compiler may prefetch whole, a copy-start that is no
+    relayout), and the programs hold the scopes the benchmark sums device
+    time by (compiled here: no chip, no time). Shapes alone."""
+    from paddle_tpu.core.random import default_generator
+    from paddle_tpu.models.hybrid_conv_moe_lm import (HybridConvMoEConfig,
+                                                      HybridConvMoELM)
+    from paddle_tpu.ops import llm_ops, nn_ops
+    from paddle_tpu.ops.pallas_moe import kernel_op_names, width_block
+    from paddle_tpu.serving.decode.kv_cache import (BlockTable,
+                                                    prefill_coords)
+    monkeypatch.setattr(llm_ops, 'on_tpu', lambda: True)
+    monkeypatch.setattr(nn_ops, 'on_tpu', lambda: True)
+    assert width_block(2048, 1792, 2) == 896
+    assert width_block(1792, 2048, 2) == 1024
+    made = {}
+
+    def init(key):
+        with default_generator.bind_base(key):
+            made['model'] = HybridConvMoELM(HybridConvMoEConfig.tiny(
+                vocab_size=512, hidden_size=2048, intermediate_size=256,
+                moe_intermediate_size=1792, num_hidden_layers=3,
+                layer_types=['conv', 'full_attention', 'conv'],
+                num_attention_heads=32, num_key_value_heads=8,
+                num_experts=4, num_experts_per_tok=2,
+                max_position_embeddings=2048, dtype='bfloat16'))
+        return {n: p.value for n, p in made['model'].named_parameters()}
+
+    with dygraph.guard():
+        default_generator.seed(3)
+        shapes = jax.eval_shape(init, default_generator.base_key())
+        model = made['model']
+        model.eval()
+        for name, p in model.named_parameters():
+            p.value = shapes[name]
+        eng = DecodeEngine(model, slots=16, block_size=16, max_blocks=9000,
+                           max_prompt_len=512, max_new_tokens_cap=512,
+                           prompt_buckets=[512], prefix_cache=False,
+                           kv_dtype='bf16')
+        pool, prog = eng.pool, eng._program
+        out = jax.eval_shape(
+            lambda pv, *rest: prog.jitted('prefill', pool.geometry, pv, {},
+                                          {}, {}, *rest),
+            {n: p.value for n, p in prog._params.items()},
+            np.zeros((1, 512), np.int64), None,
+            prefill_coords(pool, BlockTable([], 16), 512), np.int32(0))
+        pool.adopt({k: list(v) for k, v in out[3].items()}, {})
+        layers, _ = pool.arrays()
+        # a row of 8 heads of 64 is 4 whole tiles; a state row is float32
+        # whatever the pool's kv_dtype
+        assert [[(a.shape, str(a.dtype)) for a in layers[i]]
+                for i in range(3)] == [
+            [((17, 1, 2, 2048), 'float32')],
+            [((9000, 16, 512), 'bfloat16')] * 2,
+            [((17, 1, 2, 2048), 'float32')]]
+        for bucket in (None, 512):
+            text = eng.lowered(bucket, v5e).compile().as_text()
+            assert 'ragged-dot' not in text and 'ragged_dot' not in text
+            kernels = kernel_op_names(text)
+            # the rung's one attention is the splash kernel, whose call the
+            # compiler leaves without an op_name
+            assert kernels.count('') == (0 if bucket is None else 1)
+            assert text.count('_splash_attention_') >= kernels.count('')
+            kernels = [name for name in kernels if name]
+            assert len(kernels) == 2 * 2, kernels
+            assert all('/moe/experts/' in name for name in kernels), kernels
+            moves = [line for line in eng.pool_moves(bucket, v5e)
+                     if 'copy-start' not in line]
+            assert moves == []
+            scopes = ('conv/step', 'kv/decode_read') if bucket is None \
+                else ('conv/prefill', 'attn/full_prefill')
+            assert all(f'/{s}/' in text for s in scopes + ('moe/experts',))
+            other = ('conv/prefill',) if bucket is None else ('conv/step',)
+            assert not any(f'/{s}/' in text for s in other)

@@ -337,6 +337,11 @@ GRAD_SPECS = {
                    f32(r.standard_normal((1, 5, 1, 4))),
                    -pos(r, (1, 5, 1), 0.05, 0.5)],
         diff=(0, 1, 2, 3), attrs={'chunk': 2}),
+    # the differentiable form of the gated short convolution: rows and taps
+    'short_conv_prefill': S(
+        lambda r: [f32(r.standard_normal((2, 5, 12))),
+                   f32(r.standard_normal((3, 4)))],
+        diff=(0, 1)),
     'instance_norm': S(lambda r: [f32(r.standard_normal((2, 3, 4, 4))),
                                   pos(r, (3,)),
                                   f32(r.standard_normal((3,)))],
@@ -699,6 +704,10 @@ NONDIFF = {
         '(serving/decode/); training gradients flow through '
         'power_retention_prefill, parity tested in tests/ops/'
         'test_power_retention.py',
+    'short_conv_step':
+        'inference-only shift-and-filter of the conv state cache '
+        '(serving/decode/); training gradients flow through '
+        'short_conv_prefill, parity tested in tests/ops/test_short_conv.py',
     'paged_prefill_attention':
         'inference-only prefill-phase cache read (serving/decode/); '
         'parity tested in tests/ops/test_paged_attention.py',
