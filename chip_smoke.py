@@ -17,6 +17,9 @@ repo's own means:
   kernels    fused_attention where its pallas kernel applies, and
              paged_attention's decode read at the head sizes, row paddings
              and pool dtypes no served cell has, against plain references
+  group_read the grouped read's pallas kernel at the four served callers'
+             shapes, one Mosaic call under each caller's scope, against
+             the XLA walk
   static     models.lenet.build_static_lenet under
              fluid.Executor(fluid.TPUPlace(0)), fed from the DataLoader ring
   train_dp4  only with >= 4 devices: BERT-base S=128, 128 sequences per chip,
@@ -677,6 +680,123 @@ def kernels_phase(fused_shape=(8, 12, 512, 64), paged_slots=32,
                     'paged': paged_err, 'experts': experts_err}}
 
 
+# the four served callers of the grouped read at their cells' shapes: (name,
+# the caller's scope, slots, query heads, key/value heads, query rows a slot,
+# head_dim, the pool's lanes, blocks a slot's table or ring, span, one array
+# for keys and values, contexts drawn from [lo, hi])
+GROUP_READS = (
+    ('trinity_full', 'kv/decode_read', 24, 48, 8, 1, 128, 1024, 1056, 0,
+     False, 512, 16896),
+    ('trinity_sliding', 'kv/sliding_read', 24, 48, 8, 1, 128, 1024, 257,
+     4096, False, 512, 16896),
+    ('sdar_block', 'kv/block_read', 128, 32, 4, 4, 128, 512, 160, 0, False,
+     64, 2560),
+    ('kanana2_latent', 'mla/decode_read', 128, 32, 1, 1, 640, 640, 280, 0,
+     True, 128, 4480),
+    ('lfm2_grouped', 'kv/decode_read', 128, 32, 8, 1, 64, 512, 280, 0,
+     False, 128, 4480),
+)
+
+
+def group_read_phase(cases=GROUP_READS, block_size=16, tol=2e-2, calls=10,
+                     seed=13):
+    """The grouped read (`ops/nn_ops.py::_live_group_attention`) at the
+    served cells' shapes, bf16 pools: per case one slot at 1 position, one
+    at its whole table, one idle on the scratch block, the others drawn.
+    The op is reached as a model's forward reaches it (a jit of its own
+    under the caller's scope, inside the program's jit) and compiled for
+    this backend. Where its predicate holds (a TPU) the compiled program
+    must hold ONE Mosaic custom call (ops/pallas_group_read.py) with the
+    scope in its `op_name`, which the benchmark sums device time by, and no
+    `while` (the XLA walk's loop). Its result is held to the XLA walk's
+    (`_live_group_walk`) at ``tol`` of the output scale (both round the
+    probabilities and the result to bf16), and both are timed over
+    ``calls`` calls: µs a call and the share of HBM speed at which the
+    attended rows cross (819 GB/s; informational). Returns {case: figures}."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops import nn_ops
+    from paddle_tpu.ops.pallas_group_read import group_read_kernel_applies
+    from paddle_tpu.ops.pallas_moe import kernel_op_names
+
+    rng = np.random.RandomState(seed)
+    out = {}
+    for (name, scope, slots, heads, groups, rows, head_dim, lanes, width,
+         span, shared, lo, hi) in cases:
+        blocks = 1 + slots * width
+        tables = (1 + rng.permutation(slots * width)).reshape(
+            slots, width).astype('int32')
+        tables[-1] = 0                              # an idle slot
+        ctx = rng.randint(lo, hi + 1, slots).astype('int32')
+        ctx[0], ctx[1], ctx[-1] = 1, width * block_size, 1
+        if not span:
+            ctx = np.minimum(ctx, width * block_size)
+        keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+        k = jax.random.normal(keys[0], (blocks, block_size, lanes),
+                              jnp.bfloat16)
+        v = k if shared else jax.random.normal(
+            keys[1], (blocks, block_size, lanes), jnp.bfloat16)
+        q = jax.random.normal(keys[2], (slots, heads, rows, head_dim),
+                              jnp.bfloat16)
+        context = jnp.asarray(ctx)
+        if span:
+            live = jax.jit(nn_ops.live_ring_group_list, static_argnums=(
+                2, 3))(jnp.asarray(tables), context, block_size, span)
+        else:
+            live = jax.jit(nn_ops.live_group_list, static_argnums=(2,))(
+                jnp.asarray(tables), context, block_size)
+        scale = 1.0 / math.sqrt(head_dim)
+        op = jax.jit(lambda q, k, v, c, live: nn_ops._live_group_attention(
+            q, k, k if shared else v, c, live, groups, scale, span))
+
+        def scoped(*args):
+            with jax.named_scope(scope):
+                return op(*args)
+        args = (q, k, v, context, tuple(live))
+        read = jax.jit(scoped).lower(*args).compile()
+        walk = jax.jit(lambda q, k, v, c, live: nn_ops._live_group_walk(
+            q, k, k if shared else v, c, live, groups, scale, span)
+        ).lower(*args).compile()
+        kernel = group_read_kernel_applies(q, k)
+        if kernel:
+            text = read.as_text()
+            names = kernel_op_names(text)
+            assert len(names) == 1 and f'/{scope}/' in names[0], names
+            assert not [line for line in text.splitlines()
+                        if ' while(' in line], name
+        figures = {}
+        for label, fn in (('read', read), ('walk', walk)):
+            got = fn(*args)
+            got.block_until_ready()
+            t = time.perf_counter()
+            for _ in range(calls):
+                again = fn(*args)
+            again.block_until_ready()
+            figures[label] = (np.asarray(got, np.float32),
+                              (time.perf_counter() - t) / calls)
+        want = figures['walk'][0]
+        got = figures['read'][0]
+        assert got.shape == q.shape and np.isfinite(got).all(), name
+        err = float(np.abs(got - want).max() / np.abs(want).max())
+        assert err <= tol, (name, err, tol)
+        attended = int(np.minimum(ctx, span).sum() if span else ctx.sum())
+        nbytes = attended * lanes * 2 * (1 if shared else 2)
+        out[name] = {'path': 'pallas kernel' if kernel else 'XLA walk',
+                     'err': err,
+                     'us': {k: round(t * 1e6, 1)
+                            for k, (_, t) in figures.items()},
+                     'hbm_share': {k: round(nbytes / t / 819e9, 3)
+                                   for k, (_, t) in figures.items()}}
+        say('group_read', f"ok {name} under {scope}: path "
+                          f"{out[name]['path']}, vs the XLA walk {err:.2e} "
+                          f"of scale (tolerance {tol:g}); "
+                          f"{out[name]['us']['read']} µs a call, the walk "
+                          f"{out[name]['us']['walk']} µs "
+                          f"({nbytes / 1e6:.1f} MB attended)")
+    return out
+
+
 # -- static ------------------------------------------------------------------
 
 def static_phase(counter, batch=128, steps=24, seed=0):
@@ -917,6 +1037,7 @@ def main():
         'train': train_phase(counter),
         'serve': serve_phase(counter),
         'kernels': kernels_phase(),
+        'group_read': group_read_phase(),
         'static': static_phase(counter),
     }
     gc.collect()

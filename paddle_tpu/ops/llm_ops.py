@@ -318,13 +318,14 @@ def mla_decode_attention(q, pages, block_tables, context_lens, w_kvb,
     K = 1 (the lockstep step) is the GROUPED read of ops/nn_ops.py with one
     key/value head: a slot's H absorbed queries [q̃_j | q_rope_j] are the
     rows of one group, the pool's rows are the keys as stored, and their
-    first ``rank`` lanes the values. It walks the batch's live groups in
-    chunks with a running softmax (:func:`~.nn_ops._live_group_attention`),
-    so its work follows the contexts and no per-slot copy of the padded
-    tables exists. The query is padded with zeros to the rows' lanes, so
-    that a chunk is used as it is taken (a zero lane of the query makes a
-    row's pad lanes moot), and the sum is cut to ``rank`` lanes after the
-    walk. K > 1 (the staircase of a speculative verify or a chunked
+    first ``rank`` lanes the values. It walks the batch's live groups with
+    a running softmax (:func:`~.nn_ops._live_group_attention`: on a TPU the
+    pallas kernel of ops/pallas_group_read.py, elsewhere the XLA walk in
+    chunks), so its work follows the contexts and no per-slot copy of the
+    padded tables exists. The query is padded with zeros to the rows'
+    lanes, so that the rows are used as they are stored (a zero lane of the
+    query makes a row's pad lanes moot), and the sum is cut to ``rank``
+    lanes after the walk. K > 1 (the staircase of a speculative verify or a chunked
     suffix) keeps the dense form, every slot's padded table gathered, as
     `paged_attention`'s (S, K) staircase does: no served cell runs it, and
     the walker's "every row of a slot at one extent" stays true."""

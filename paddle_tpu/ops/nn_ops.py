@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .pallas_group_read import group_read, group_read_kernel_applies
 from .registry import register_op
 from ..core.dtypes import to_jax_dtype
 from ..core.places import on_tpu
@@ -639,9 +640,12 @@ def norm(x, *, axis=-1, epsilon=1e-10):
 # time inside the eager kernel-cache jit, so a Mosaic refusal would surface
 # at compile anyway, outside any handler here. The causal grouped form of
 # `paged_prefill_attention` (a model with layer classes) has the stock
-# splash-attention kernel and `causal_kernel_applies`. `paged_attention` has no
-# kernel and no predicate: its single-query read is one XLA formulation for
-# every head_dim and pool dtype (:func:`_live_block_attention`).
+# splash-attention kernel and `causal_kernel_applies`. `paged_attention`'s
+# single-query read of a pool of q's own heads has no kernel and no
+# predicate: one XLA formulation for every head_dim and pool dtype
+# (:func:`_live_block_attention`). Its grouped reads (and the latent read)
+# walk live groups (:func:`_live_group_attention`): the repo's own pallas
+# kernel, ops/pallas_group_read.py, and `group_read_kernel_applies`.
 #
 # The flash rule was established on a TPU v5e with jax 0.9.0 (PERF.md
 # section 6, PR 21): the kernel lowers at head_dim 16/64/128 whenever both
@@ -751,10 +755,12 @@ def live_block_list(block_tables, context_lens, block_size):
 # 128 keys a group where the table is that wide: a group's K or V is one
 # (128, heads·head_dim) tile per key/value head, so that a group's scores are
 # one (query rows, head_dim) x (head_dim, 128) matmul a key/value head, not
-# eight of 16 keys. A chunk of the walk holds this many groups (at SDAR's
+# eight of 16 keys. A chunk of the XLA walk holds this many groups (at SDAR's
 # rows a chunk's K is 128 x 128 x 512 bf16 = 16.8 MB); fewer, larger chunks
 # rewrite the per-slot running state less often, and the last chunk's
-# padding (half a chunk on average) names the scratch block.
+# padding (half a chunk on average) names the scratch block. The pallas
+# kernel that replaces the walk on a TPU (ops/pallas_group_read.py) takes
+# the same list and has no chunk.
 LIVE_GROUP_KEYS = 128
 LIVE_GROUP_CHUNK = 128
 
@@ -1035,6 +1041,22 @@ def _live_block_attention(q, k_pages, v_pages, context_lens, live,
 
 def _live_group_attention(q, k_pages, v_pages, context_lens, live, kv_heads,
                           sm_scale, span=0):
+    """The grouped read over the pool's LIVE groups, the four callers' one
+    entry (a window model's block read, a full and a sliding layer's
+    one-token read, the latent read). On a TPU
+    (`pallas_group_read.group_read_kernel_applies`) it is the pallas kernel
+    of ops/pallas_group_read.py, which copies each live block from the pool
+    into VMEM once; elsewhere the XLA walk :func:`_live_group_walk`, the
+    same mathematics, because the code says so (the CPU tests)."""
+    if group_read_kernel_applies(q, k_pages):
+        return group_read(q, k_pages, v_pages, context_lens, live, kv_heads,
+                          sm_scale, span)
+    return _live_group_walk(q, k_pages, v_pages, context_lens, live,
+                            kv_heads, sm_scale, span)
+
+
+def _live_group_walk(q, k_pages, v_pages, context_lens, live, kv_heads,
+                     sm_scale, span=0):
     """q (S, H, K, D) against the pool's LIVE groups
     (:func:`live_group_list`), a chunk of C groups at a time, only as many
     chunks as hold live groups; every row of a slot sees positions <

@@ -65,6 +65,7 @@ from ...dygraph.tape import Tensor, no_grad_guard
 from ...ops.llm_ops import diffusion_pick
 from ...ops.nn_ops import (live_block_chunk, live_group_blocks,
                            live_group_chunk, live_ring_group_chunk)
+from ...ops.pallas_group_read import group_read_kernel_applies
 from ..engine import bucket_ladder
 from ..errors import InvalidRequest, UnsupportedCacheFeature
 from .diffusion import unmask_most_confident
@@ -889,38 +890,45 @@ class DecodeEngine:
         """Blocks a layer's read takes from the pool in a lockstep step over
         ``ctx_lens`` (an idle slot's 1: the scratch block). A K/V pool's
         read walks the live blocks in whole chunks (ops/nn_ops.py::
-        paged_attention), a latent pool's the live groups of blocks in
-        whole chunks (ops/llm_ops.py::mla_decode_attention); a state layer
-        reads no block at all (0 where no layer caches rows)."""
+        paged_attention), a latent pool's the live groups of blocks
+        (ops/llm_ops.py::mla_decode_attention); a state layer reads no
+        block at all (0 where no layer caches rows)."""
         entries = self.slots * self.pool.max_blocks_per_seq
         if not self.row_layers:
             return 0
         if (self.cache_kind == 'latent' or self.window > 1
                 or self.layer_spans is not None):
             # the latent read, the block read and the grouped one-token
-            # reads walk whole groups of blocks, in whole chunks of groups
+            # reads walk the live groups of blocks
             # (ops/nn_ops.py::live_group_list)
             bs = self.block_size
+            rows = jax.ShapeDtypeStruct((), self.pool.dtype)
+            # the XLA walk reads whole chunks of groups; the pallas kernel
+            # (ops/pallas_group_read.py) copies the live groups alone
+            whole_chunks = not group_read_kernel_applies(rows, rows)
             per_group = live_group_blocks(bs, self.pool.max_blocks_per_seq)
             _, chunk = live_group_chunk(self.slots, bs,
                                         self.pool.max_blocks_per_seq)
             live = sum(-(-int(c) // (per_group * bs)) for c in ctx_lens)
-            full = -(-live // chunk) * chunk * per_group
+            if whole_chunks:
+                live = -(-live // chunk) * chunk
             if self.layer_spans is None:
-                return full
+                return live * per_group
             # layer classes: a pair, (a full layer's, a sliding layer's);
             # a sliding layer walks the groups of its ring that its span
             # touches (ops/nn_ops.py::live_ring_group_list)
             sliding = 0
             if self.span:
-                per_group, _, chunk = live_ring_group_chunk(
+                ring_group, _, ring_chunk = live_ring_group_chunk(
                     self.slots, bs, self.pool.ring, self.span)
-                keys = per_group * bs
-                live = sum((int(c) - 1) // keys
-                           - max(int(c) - self.span, 0) // keys + 1
-                           for c in ctx_lens)
-                sliding = -(-live // chunk) * chunk * per_group
-            return full, sliding
+                keys = ring_group * bs
+                ring_live = sum((int(c) - 1) // keys
+                                - max(int(c) - self.span, 0) // keys + 1
+                                for c in ctx_lens)
+                if whole_chunks:
+                    ring_live = -(-ring_live // ring_chunk) * ring_chunk
+                sliding = ring_live * ring_group
+            return live * per_group, sliding
         chunk = live_block_chunk(entries)
         live = sum(-(-int(c) // self.block_size) for c in ctx_lens)
         return -(-live // chunk) * chunk

@@ -1062,8 +1062,9 @@ class CacheContext:
             layer, c['write_ids'], c['write_offs'],
             rows.reshape(-1, rows.shape[-1]))
         # the scope names the read's device ops in a profiler trace (the
-        # walk's `while` and the absorbing matmuls before and after it; in
-        # the first layer the list of live groups too)
+        # read of the live groups, on a TPU one pallas custom call, and the
+        # absorbing matmuls before and after it; in the first layer the
+        # list of live groups too)
         with jax.named_scope('mla/decode_read'):
             return dispatch_op('mla_decode_attention', {
                 'q': inputs['q'], 'pages': self.pool.pages(layer)[0],

@@ -123,3 +123,17 @@ def test_train_phase_tiny(children):
     res = json.loads(next(ln for ln in out.splitlines()
                           if ln.startswith('RESULT ')).split(' ', 1)[1])
     assert len(res['losses']) == 4 and res['losses'][-1] < res['losses'][0]
+
+
+def test_group_read_phase_tiny():
+    """The grouped read's four callers, each geometry cut to a few slots of
+    small tables: off the chip the op is the XLA walk itself."""
+    cases = tuple(
+        (name, scope, 4, heads, groups, rows, head_dim, lanes, 6 if not span
+         else span // 4 + 1, span and 8, shared, 1, 40)
+        for (name, scope, _, heads, groups, rows, head_dim, lanes, _, span,
+             shared, _, _) in chip_smoke.GROUP_READS)
+    out = chip_smoke.group_read_phase(cases=cases, block_size=4, calls=1)
+    assert set(out) == {c[0] for c in chip_smoke.GROUP_READS}
+    assert all(v['path'] == 'XLA walk' and v['err'] == 0.0
+               for v in out.values())

@@ -628,3 +628,64 @@ def test_compiled_for_the_chip_a_hybrid_of_state_and_row_layers(v5e,
             assert all(f'/{s}/' in text for s in scopes + ('moe/experts',))
             other = ('conv/prefill',) if bucket is None else ('conv/step',)
             assert not any(f'/{s}/' in text for s in other)
+
+
+# the four callers of the grouped read at their published widths: (query
+# heads, key/value heads, query rows a slot, head_dim, span, the caller's
+# scope); the latent read's rows are rank 512 + rope 64 in 640 lanes
+GROUP_READS = {
+    'full_read': (48, 8, 1, 128, 0, 'kv/decode_read'),
+    'sliding_read': (48, 8, 1, 128, 4096, 'kv/sliding_read'),
+    'block_read': (32, 4, 4, 128, 0, 'kv/block_read'),
+    'latent_read': (32, 1, 1, 192, 0, 'mla/decode_read'),
+    'heads_of_64': (32, 8, 1, 64, 0, 'kv/decode_read'),
+}
+
+
+@pytest.mark.parametrize('name', sorted(GROUP_READS))
+def test_compiled_for_the_chip_a_grouped_read_is_one_kernel_under_its_scope(
+        v5e, monkeypatch, name):
+    """Each caller of the grouped read over bf16 pools, compiled for the chip
+    with the kernel's predicate holding as it does there
+    (ops/pallas_group_read.py) and the op reached as a model's forward
+    reaches it, a jit of its own under the caller's scope: one Mosaic custom
+    call with the scope in its `op_name`, which the benchmark sums device
+    time by, and no `while` (the XLA walk's loop). 16 slots of 1,056-block
+    tables (a 257-block ring for the sliding read) at the published widths
+    (compiled here: no chip, no time). Shapes alone."""
+    from paddle_tpu.ops import llm_ops, nn_ops
+    from paddle_tpu.ops.pallas_moe import kernel_op_names
+    heads, groups, rows, head_dim, span, scope = GROUP_READS[name]
+    monkeypatch.setattr(nn_ops, 'group_read_kernel_applies',
+                        lambda q, pages: True)
+    slots, width = 16, (span // 16 + 1 if span else 1056)
+    lanes = 640 if name == 'latent_read' else groups * head_dim
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=v5e)
+    pages = sds((1 + slots * width, 16, lanes), jnp.bfloat16)
+    tables = sds((slots, width), jnp.int32)
+    ctx = sds((slots,), jnp.int32)
+
+    def read(q, k, v, tables, ctx):
+        if name == 'latent_read':
+            w_kvb = jnp.zeros((512, heads * 256), q.dtype)
+            return llm_ops.mla_decode_attention(
+                q, k, tables, ctx, w_kvb, qk_nope_dim=128, v_dim=128)
+        if name == 'block_read':
+            return nn_ops.paged_attention(q, k, v, tables, ctx,
+                                          block_window=True, kv_heads=groups)
+        return nn_ops.paged_attention(q, k, v, tables, ctx, kv_heads=groups,
+                                      span=span)
+    op = jax.jit(read)
+
+    def scoped(*args):
+        with jax.named_scope(scope):
+            return op(*args)
+    q = sds((slots, 1, heads, head_dim) if name == 'latent_read'
+            else (slots, heads, rows, head_dim) if rows > 1
+            else (slots, heads, head_dim), jnp.bfloat16)
+    text = jax.jit(scoped).lower(q, pages, pages, tables, ctx).compile() \
+        .as_text()
+    names = kernel_op_names(text)
+    assert len(names) == 1 and f'/{scope}/' in names[0], names
+    assert not [line for line in text.splitlines() if ' while(' in line]
