@@ -39,7 +39,6 @@ passes the actual shapes, making the plan exact for static programs).
 """
 from __future__ import annotations
 
-import math
 import time
 from typing import Dict, List, Optional, Set
 
@@ -51,8 +50,7 @@ from .cost import (OpCost, dtype_nbytes, has_cost_rule, info_nbytes,
 from .infer import UNKNOWN, VarInfo, declared_info, infer_op, seed_env
 
 __all__ = ['MemoryPlan', 'plan_program', 'select_checkpoints',
-           'gradient_bytes', 'solve_decode_pool_blocks',
-           'decode_pool_report']
+           'gradient_bytes']
 
 
 class Resident:
@@ -495,253 +493,6 @@ def gradient_bytes(program, assume_dim=1):
         if blk.has_var(p):
             total += info_nbytes(declared_info(blk.var(p)), assume_dim)
     return total
-
-
-# ---------------------------------------------------------------------------
-# decode-pool sizing (PADDLE_TPU_DECODE_HBM_MB → KV blocks)
-# ---------------------------------------------------------------------------
-
-def _model_state_bytes(model):
-    """Σ parameter bytes of a dygraph model (runtime widths — the same 1×
-    resident-state term plan_program charges for persistables)."""
-    total = 0
-    for p in model.parameters():
-        v = getattr(p, 'value', p)
-        total += int(getattr(v, 'nbytes', 0))
-    return total
-
-
-def _decode_kv_geometry(model):
-    """What the model caches, asked of the model
-    (``model.kv_cache_spec()``: per token per layer ``{'kind': 'kv',
-    'layers', 'heads', 'head_dim'}`` for K and V rows of every head the
-    model caches (its key/value heads; with ``'window'`` B where a step
-    feeds B rows a slot, :func:`decode_step_rows`; with ``'layer_spans'``
-    where its layers are of two classes, each layer's span and 0 a full
-    layer, :func:`decode_layer_classes`) or
-    ``{'kind': 'latent', 'layers', 'row_width'}`` for one latent row; per
-    REQUEST per layer ``{'kind': 'state', 'layers', 'heads', 'state_rows',
-    'head_dim'}`` for one recurrent state; a HYBRID names each layer's kind
-    in ``'layer_kinds'`` beside the ``kind`` of its row layers, with
-    ``'state_block'``, the float32 block a state layer keeps a request:
-    :func:`decode_layer_counts`). Raises a ValueError
-    naming what is missing — a budget solve over unknown geometry would
-    silently size the pool wrong."""
-    spec = getattr(model, 'kv_cache_spec', None)
-    if spec is None:
-        raise ValueError(
-            'decode-pool budget solve needs model.kv_cache_spec() (the '
-            'models/causal_lm.py, latent_moe_lm.py and retention_lm.py '
-            'contract); '
-            'pass an explicit max_blocks / PADDLE_TPU_DECODE_MAX_BLOCKS '
-            'for models without it')
-    return spec()
-
-
-def decode_token_layer_bytes(model, kv_dtype='f32'):
-    """HBM bytes ONE token's cached state costs in ONE layer: a K and a V
-    row of every head, or one latent row, each in the lanes the pool gives
-    it, priced by kv_cache.kv_row_bytes at the storage dtype (int8 rows
-    carry an f32 scale a head; a latent row has no int8 form). A state
-    layer holds no row per token, so a model of state layers alone costs 0
-    here and its price is per slot (:func:`decode_state_row_bytes`); of a
-    hybrid this is a ROW layer's price."""
-    from ..serving.decode.kv_cache import kv_row_bytes
-    spec = _decode_kv_geometry(model)
-    if spec['kind'] == 'state':
-        if kv_dtype != 'f32':
-            raise ValueError('a state cache is float32: it has no '
-                             f'kv_dtype={kv_dtype} form')
-        return 0
-    if spec['kind'] == 'latent':
-        if kv_dtype == 'int8':
-            raise ValueError('a latent KV cache has no int8 rows')
-        return kv_row_bytes(1, spec['row_width'], kv_dtype)
-    return 2 * kv_row_bytes(spec['heads'], spec['head_dim'], kv_dtype)
-
-
-def decode_layer_counts(model):
-    """(row layers, state layers) of the model: the layers that cache a row
-    a token (K/V or latent) and those that keep one state a request
-    (serving/decode/kv_cache.py::layer_counts of its spec)."""
-    from ..serving.decode.kv_cache import layer_counts
-    return layer_counts(_decode_kv_geometry(model))
-
-
-def decode_layer_classes(model):
-    """(full layers, sliding layers, span) of a K/V model
-    (``kv_cache_spec()['layer_spans']``; a model that names no classes has
-    full layers alone, span 0): a full layer holds every position of a
-    context, a sliding layer ``min(context, span)`` of them, in a ring of
-    ``span / block + 1`` blocks a request (serving/decode/kv_cache.py
-    "Layer classes")."""
-    spec = _decode_kv_geometry(model)
-    spans = spec.get('layer_spans')
-    if spans is None:
-        return decode_layer_counts(model)[0], 0, 0
-    sliding = [s for s in spans if s]
-    return len(spans) - len(sliding), len(sliding), max(sliding, default=0)
-
-
-def decode_context_bytes(model, context, kv_dtype='f32'):
-    """HBM bytes the cached rows of ONE context of ``context`` positions
-    take across every layer: each class priced by what it holds,
-    ``context`` a full layer and ``min(context, span)`` a sliding one."""
-    full, sliding, span = decode_layer_classes(model)
-    return decode_token_layer_bytes(model, kv_dtype) * (
-        full * int(context) + sliding * min(int(context), span))
-
-
-def decode_request_bytes(model, context, kv_dtype='f32'):
-    """HBM bytes ONE request of ``context`` positions holds: its rows in
-    the row layers (:func:`decode_context_bytes`) and, where the model has
-    state layers, its row of each (:func:`decode_state_row_bytes`),
-    whatever the context. What a hybrid request costs."""
-    rows, states = decode_layer_counts(model)
-    return (decode_context_bytes(model, context, kv_dtype) if rows else 0) \
-        + (decode_state_row_bytes(model) if states else 0)
-
-
-def decode_sliding_class_bytes(model, slots, block_size, kv_dtype='f32'):
-    """HBM bytes of the SLIDING class's arrays: its depth is derived (a
-    ring a slot and the spare, serving/decode/engine.py), so it is a fixed
-    cost beside the weights, whatever the budget. 0 for a model without."""
-    _, sliding, span = decode_layer_classes(model)
-    if not sliding:
-        return 0
-    from ..serving.decode.engine import SLIDING_SPARE_BLOCKS
-    ring = -(-span // int(block_size)) + 1
-    return (sliding * (int(slots) * ring + SLIDING_SPARE_BLOCKS)
-            * int(block_size) * decode_token_layer_bytes(model, kv_dtype))
-
-
-def decode_step_rows(model, slots):
-    """Rows the lockstep decode step feeds the model: one a slot, or for a
-    WINDOW model (``kv_cache_spec()['window']`` B: block diffusion feeds a
-    slot's whole block every forward) B a slot. What a step's matmuls, its
-    router and its head are priced over."""
-    return int(slots) * int(_decode_kv_geometry(model).get('window', 1))
-
-
-def decode_pool_block_bytes(model, block_size, kv_dtype='f32'):
-    """HBM bytes ONE KV-cache block costs across every layer that holds it:
-    all of them, or for a model with layer classes the FULL layers (the
-    block count a budget buys is the full class's; the sliding class is
-    :func:`decode_sliding_class_bytes`)."""
-    spec = _decode_kv_geometry(model)
-    layers = decode_layer_classes(model)[0] if spec['kind'] == 'kv' \
-        else decode_layer_counts(model)[0]
-    return layers * int(block_size) * decode_token_layer_bytes(model,
-                                                               kv_dtype)
-
-
-def decode_state_row_bytes(model):
-    """HBM bytes ONE request's recurrent state costs across every state
-    layer: a row of each one's array, float32, whatever the request's
-    context: ``state_block`` values where the model names the block (a
-    short convolution's (1, L - 1, h)), else ``heads`` blocks of
-    ``state_rows`` × ``head_dim`` (ops/llm_ops.py::retention_state_rows).
-    What a slot costs the state layers."""
-    spec = _decode_kv_geometry(model)
-    states = decode_layer_counts(model)[1]
-    if not states:
-        raise ValueError(
-            f"a {spec['kind']} cache holds rows per token, no state row: "
-            f'price it by decode_pool_block_bytes')
-    block = spec.get('state_block') or (spec['heads'], spec['state_rows'],
-                                        spec['head_dim'])
-    return states * math.prod(int(n) for n in block) * 4
-
-
-def solve_decode_state_slots(model, hbm_mb):
-    """Slots a budget covers for a state cache: (budget − model state) //
-    the bytes of one state row, less the scratch row of idle slots. Raises
-    when the budget does not cover the weights and one slot."""
-    budget = int(float(hbm_mb) * (1 << 20))
-    state = _model_state_bytes(model)
-    rows = (budget - state) // decode_state_row_bytes(model)
-    if rows < 2:
-        raise ValueError(
-            f'a budget of {hbm_mb} MiB ({budget} bytes) does not cover the '
-            f'model state ({state} bytes) and two state rows of '
-            f'{decode_state_row_bytes(model)} bytes (a slot and the '
-            f'scratch row)')
-    return int(rows) - 1
-
-
-def solve_decode_pool_blocks(model, hbm_mb, block_size, kv_dtype='f32',
-                             min_blocks=2, slots=None):
-    """The ``PADDLE_TPU_DECODE_HBM_MB`` budget solve: blocks =
-    (budget − model state) // per-block KV bytes, floored at
-    ``min_blocks`` (the engine passes max_blocks_per_seq + 1 so an empty
-    pool always covers one maximal request). Raises when the budget does
-    not even cover the model's resident state — a silent floor there
-    would hide that the budget is fiction. A model with a sliding class of
-    layer needs ``slots``: that class's arrays (a ring a slot) come off
-    the budget first, and the blocks solved for are the full class's. So
-    does a hybrid's state cache: ``slots + 1`` rows of its state layers
-    come off first, and the blocks are bought for the row layers alone."""
-    budget = int(hbm_mb) << 20
-    state = _model_state_bytes(model)
-    rows, states = decode_layer_counts(model)
-    if rows and states:
-        if slots is None:
-            raise ValueError(
-                'a model with state layers beside row layers is sized per '
-                'kind: solve_decode_pool_blocks needs slots (a state row a '
-                'slot and the scratch row)')
-        state += (int(slots) + 1) * decode_state_row_bytes(model)
-    if _decode_kv_geometry(model)['kind'] == 'kv' \
-            and decode_layer_classes(model)[1]:
-        if slots is None:
-            raise ValueError(
-                'a model with a sliding class of layer is sized per class: '
-                'solve_decode_pool_blocks needs slots (the sliding class '
-                'holds a ring a slot)')
-        state += decode_sliding_class_bytes(model, slots, block_size,
-                                            kv_dtype)
-    block_bytes = decode_pool_block_bytes(model, block_size, kv_dtype)
-    if budget <= state:
-        raise ValueError(
-            f'PADDLE_TPU_DECODE_HBM_MB={hbm_mb} ({budget} bytes) does not '
-            f'cover the model state ({state} bytes); nothing left for the '
-            f'KV pool')
-    if not block_bytes:
-        # a state cache: blocks book lengths and no HBM stands behind them
-        return int(min_blocks)
-    return max(int(min_blocks), (budget - state) // block_bytes)
-
-
-def decode_pool_report(model, hbm_mb, block_size, kv_dtype='f32',
-                       min_blocks=2, slots=None):
-    """The solve, itemized for tools/plan_program.py — every term of the
-    closed form inspectable next to the resulting block count (``slots``
-    as `solve_decode_pool_blocks` takes it)."""
-    spec = _decode_kv_geometry(model)
-    state = _model_state_bytes(model)
-    block_bytes = decode_pool_block_bytes(model, block_size, kv_dtype)
-    blocks = solve_decode_pool_blocks(model, hbm_mb, block_size, kv_dtype,
-                                      min_blocks, slots)
-    extra = {}
-    if spec['kind'] == 'state':
-        extra = {'state_row_bytes': decode_state_row_bytes(model),
-                 'state_slots': solve_decode_state_slots(model, hbm_mb)}
-    elif decode_layer_counts(model)[1]:
-        extra = {'state_row_bytes': decode_state_row_bytes(model)}
-    return {
-        **extra,
-        'budget_mb': int(hbm_mb),
-        'kv_dtype': kv_dtype,
-        'block_size': int(block_size),
-        'model_state_bytes': state,
-        'kv_layers': spec['layers'],
-        'kv_cache': spec,
-        'step_rows_per_slot': int(spec.get('window', 1)),
-        'row_bytes': decode_token_layer_bytes(model, kv_dtype),
-        'block_bytes': block_bytes,
-        'num_blocks': int(blocks),
-        'pool_bytes': int(blocks) * block_bytes,
-    }
 
 
 # ---------------------------------------------------------------------------

@@ -20,7 +20,8 @@ schedule in serving/decode/diffusion.py).
 
 The forward contract is models/causal_lm.py's: ``model(ids, pos_ids=None,
 cache=None)``. Whole-sequence (``cache=None``) attends under the block mask.
-Under the decode engine the model is a WINDOW model (``decode_window`` = B):
+Under the decode engine the model is a WINDOW model (its layout's ``window``
+= B):
 a prefill writes the K/V of the prompt's whole blocks and scores nothing
 (it returns None: the first block's first forward reads the prompt's tail
 beside its masks), and a step feeds B rows a slot, every row at the extent
@@ -228,23 +229,19 @@ class BlockDiffusionMoELM(Layer):
         self.head = _linear(cfg, cfg.hidden_size, cfg.vocab_size)
 
     @property
-    def decode_window(self):
-        """Rows a slot feeds a lockstep step of the decode engine: one whole
-        block (serving/decode/engine.py sizes its step program by it)."""
-        return self.cfg.block_length
-
-    @property
     def mask_token_id(self):
         return self.cfg.mask_token_id
 
-    def kv_cache_spec(self):
-        """What the decode pool holds of this model: K and V rows of the
-        KEY/VALUE heads, per token per layer (serving/decode/kv_cache.py,
-        analysis/plan.py), and the window a step feeds."""
+    def cache_layout(self):
+        """What the decode engine caches of this model: K and V rows of the
+        KEY/VALUE heads per token per layer, read a block at a time, and
+        the window a step feeds: one whole block of rows a slot
+        (serving/decode/layout.py)."""
+        from ..serving.decode.layout import CacheLayout, LayerCache
         cfg = self.cfg
-        return {'kind': 'kv', 'layers': cfg.num_hidden_layers,
-                'heads': cfg.num_key_value_heads, 'head_dim': cfg.head_dim,
-                'window': cfg.block_length}
+        return CacheLayout((LayerCache.kv(cfg.num_key_value_heads,
+                                          cfg.head_dim, read='window'),)
+                           * cfg.num_hidden_layers, window=cfg.block_length)
 
     def forward(self, input_ids, pos_ids=None, cache=None):
         """``input_ids`` (B, S) -> float32 logits (B, S, V), row i the

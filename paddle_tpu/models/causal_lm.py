@@ -88,14 +88,15 @@ class TransformerLM(Layer):
     def num_cache_layers(self):
         return self.cfg.num_hidden_layers
 
-    def kv_cache_spec(self):
-        """What the decode pool holds of this model: K and V rows of every
-        head, per token per layer (serving/decode/kv_cache.py,
-        analysis/plan.py)."""
+    def cache_layout(self):
+        """What the decode engine caches of this model: K and V rows of
+        every head per token per layer, read over the live blocks
+        (serving/decode/layout.py)."""
+        from ..serving.decode.layout import CacheLayout, LayerCache
         cfg = self.cfg
-        return {'kind': 'kv', 'layers': cfg.num_hidden_layers,
-                'heads': cfg.num_attention_heads,
-                'head_dim': cfg.hidden_size // cfg.num_attention_heads}
+        heads = cfg.num_attention_heads
+        return CacheLayout((LayerCache.kv(heads, cfg.hidden_size // heads),)
+                           * cfg.num_hidden_layers)
 
     def forward(self, input_ids, pos_ids=None, cache=None):
         """``input_ids`` (B, S) → logits (B, S, V). ``pos_ids`` defaults to

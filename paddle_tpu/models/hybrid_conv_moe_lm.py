@@ -262,22 +262,19 @@ class HybridConvMoELM(Layer):
         # the family's name for the final norm: it sits at the OUTPUT
         self.embedding_norm = RMSNorm(cfg, cfg.hidden_size)
 
-    def kv_cache_spec(self):
-        """What the decode engine holds of this model, a layer at a time
-        (``layer_kinds``): a conv layer one float32 state a REQUEST
-        (``state_block``, advanced by the short convolution: ``state_op``),
-        an attention layer K and V rows of the key/value heads a TOKEN, all
-        of the full class (``layer_spans`` over the row layers)
-        (serving/decode/kv_cache.py "Hybrid models", analysis/plan.py)."""
+    def cache_layout(self):
+        """What the decode engine caches of this model, a layer at a time: a
+        conv layer one float32 state a REQUEST (``conv_state_block``,
+        advanced by the short convolution), an attention layer K and V rows
+        of the key/value heads a TOKEN, full layers read over the live
+        groups (serving/decode/layout.py, kv_cache.py "Hybrid models")."""
+        from ..serving.decode.layout import CacheLayout, LayerCache
         cfg = self.cfg
-        kinds = tuple('state' if cfg.is_conv(i) else 'kv'
-                      for i in range(cfg.num_hidden_layers))
-        return {'kind': 'kv', 'layers': cfg.num_hidden_layers,
-                'layer_kinds': kinds, 'heads': cfg.num_key_value_heads,
-                'head_dim': cfg.head_dim,
-                'layer_spans': (0,) * kinds.count('kv'),
-                'state_block': cfg.conv_state_block,
-                'state_op': 'short_conv'}
+        conv = LayerCache.state(cfg.conv_state_block, 'short_conv')
+        attention = LayerCache.kv(cfg.num_key_value_heads, cfg.head_dim,
+                                  read='groups')
+        return CacheLayout(tuple(conv if cfg.is_conv(i) else attention
+                                 for i in range(cfg.num_hidden_layers)))
 
     def forward(self, input_ids, pos_ids=None, cache=None):
         """``input_ids`` (B, S) -> float32 logits (B, S, V); ``pos_ids``

@@ -331,11 +331,12 @@ class LatentMoELM(Layer):
         self.final_norm = RMSNorm(cfg, cfg.hidden_size)
         self.head = _linear(cfg, cfg.hidden_size, cfg.vocab_size)
 
-    def kv_cache_spec(self):
-        """What the decode pool holds of this model: one latent row per
-        token per layer (serving/decode/kv_cache.py, analysis/plan.py)."""
-        return {'kind': 'latent', 'layers': self.cfg.num_hidden_layers,
-                'row_width': self.cfg.latent_row_width}
+    def cache_layout(self):
+        """What the decode engine caches of this model: one latent row per
+        token per layer (serving/decode/layout.py)."""
+        from ..serving.decode.layout import CacheLayout, LayerCache
+        return CacheLayout((LayerCache.latent(self.cfg.latent_row_width),)
+                           * self.cfg.num_hidden_layers)
 
     def forward(self, input_ids, pos_ids=None, cache=None):
         """``input_ids`` (B, S) -> float32 logits (B, S, V); ``pos_ids``

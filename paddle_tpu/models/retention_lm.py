@@ -165,16 +165,18 @@ class RetentionLM(Layer):
         self.final_norm = RMSNorm(cfg, cfg.hidden_size)
         self.head = _linear(cfg, cfg.hidden_size, cfg.vocab_size)
 
-    def kv_cache_spec(self):
-        """What the decode engine holds of this model: per REQUEST per layer
-        one recurrent state, ``heads`` blocks of ``state_rows`` × ``head_dim``
-        float32 values (serving/decode/kv_cache.py, analysis/plan.py), and
-        no row per token."""
+    def cache_layout(self):
+        """What the decode engine caches of this model: per REQUEST per
+        layer one recurrent state, key/value heads' blocks of
+        (ops/llm_ops.py::retention_state_rows) × ``head_dim`` float32
+        values, and no row per token (serving/decode/layout.py)."""
         from ..ops.llm_ops import retention_state_rows
+        from ..serving.decode.layout import CacheLayout, LayerCache
         cfg = self.cfg
-        return {'kind': 'state', 'layers': cfg.num_hidden_layers,
-                'heads': cfg.num_key_value_heads, 'head_dim': cfg.head_dim,
-                'state_rows': retention_state_rows(cfg.head_dim)[2]}
+        block = (cfg.num_key_value_heads,
+                 retention_state_rows(cfg.head_dim)[2], cfg.head_dim)
+        return CacheLayout((LayerCache.state(block, 'retention'),)
+                           * cfg.num_hidden_layers)
 
     def forward(self, input_ids, pos_ids=None, cache=None):
         """``input_ids`` (B, S) -> float32 logits (B, S, V); ``pos_ids``

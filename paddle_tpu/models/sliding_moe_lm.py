@@ -278,17 +278,20 @@ class SlidingMoELM(Layer):
         self.final_norm = RMSNorm(cfg, cfg.hidden_size)
         self.head = _linear(cfg, cfg.hidden_size, cfg.vocab_size)
 
-    def kv_cache_spec(self):
-        """What the decode pool holds of this model: K and V rows of the
-        KEY/VALUE heads per token per layer, and each layer's class: its
-        span in ``layer_spans`` (0: a full layer, whose table grows with
-        the context; S: a sliding layer, which holds the last S positions
-        in a ring) (serving/decode/kv_cache.py, analysis/plan.py)."""
+    def cache_layout(self):
+        """What the decode engine caches of this model: K and V rows of the
+        KEY/VALUE heads per token per layer, read a group of query heads at
+        a time, and each layer's class: its span (0: a full layer, whose
+        table grows with the context, read over the live groups; S: a
+        sliding layer, which holds the last S positions in a ring, read over
+        the ring's groups) (serving/decode/layout.py)."""
+        from ..serving.decode.layout import CacheLayout, LayerCache
         cfg = self.cfg
-        return {'kind': 'kv', 'layers': cfg.num_hidden_layers,
-                'heads': cfg.num_key_value_heads, 'head_dim': cfg.head_dim,
-                'layer_spans': tuple(cfg.span(i) for i in range(
-                    cfg.num_hidden_layers))}
+        return CacheLayout(tuple(
+            LayerCache.kv(cfg.num_key_value_heads, cfg.head_dim,
+                          read='ring' if cfg.span(i) else 'groups',
+                          span=cfg.span(i))
+            for i in range(cfg.num_hidden_layers)))
 
     def forward(self, input_ids, pos_ids=None, cache=None):
         """``input_ids`` (B, S) -> float32 logits (B, S, V); ``pos_ids``

@@ -853,6 +853,46 @@ def live_ring_group_list(ring_tables, context_lens, block_size, span):
     return block_ids, slot, first_pos, n_live
 
 
+# The blocks a read over each of the three lists above takes from the pool,
+# counted on the host from a step's context lengths (an idle slot's 1: the
+# scratch block): the decode engine's `decode_kv_blocks_read` a layer. A
+# grouped read takes whole chunks of groups where ``whole_chunks`` (the XLA
+# walk, :func:`group_walk_pads`), the live groups alone where the pallas
+# kernel copies them.
+
+def group_walk_pads(dtype):
+    rows = jax.ShapeDtypeStruct((), dtype)
+    return not group_read_kernel_applies(rows, rows)
+
+
+def live_blocks_taken(context_lens, block_size, max_blocks):
+    chunk = live_block_chunk(len(context_lens) * int(max_blocks))
+    live = sum(-(-int(c) // block_size) for c in context_lens)
+    return -(-live // chunk) * chunk
+
+
+def live_groups_taken(context_lens, block_size, max_blocks, whole_chunks):
+    per_group = live_group_blocks(block_size, max_blocks)
+    live = sum(-(-int(c) // (per_group * block_size)) for c in context_lens)
+    if whole_chunks:
+        chunk = live_group_chunk(len(context_lens), block_size,
+                                 max_blocks)[1]
+        live = -(-live // chunk) * chunk
+    return live * per_group
+
+
+def live_ring_groups_taken(context_lens, block_size, ring, span,
+                           whole_chunks):
+    m, _, chunk = live_ring_group_chunk(len(context_lens), block_size, ring,
+                                        span)
+    keys = m * block_size
+    live = sum((int(c) - 1) // keys - max(int(c) - span, 0) // keys + 1
+               for c in context_lens)
+    if whole_chunks:
+        live = -(-live // chunk) * chunk
+    return live * m
+
+
 @register_op('paged_attention')
 def paged_attention(q, k_pages, v_pages, block_tables, context_lens,
                     k_scales=None, v_scales=None, live=None, *,

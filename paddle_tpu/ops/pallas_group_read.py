@@ -75,8 +75,8 @@ def group_read_kernel_applies(q, k_pages):
     rows. The repo's one convention (ops/nn_ops.py, "explicit kernel
     dispatch"): where it holds the kernel runs, and a Mosaic refusal is an
     error; elsewhere the XLA walk runs because the code says so (the CPU
-    tests). The engine asks it too, to count the blocks a step's read
-    copies (`DecodeEngine._blocks_walked`)."""
+    tests). `nn_ops.group_walk_pads` asks it too, for the count of the
+    blocks a step's read copies."""
     return (on_tpu() and q.dtype in (jnp.bfloat16, jnp.float32)
             and k_pages.dtype in (jnp.bfloat16, jnp.float32))
 

@@ -63,22 +63,17 @@ from ...core.compile_cache import setup_persistent_cache
 from ...dygraph.jit import _bind
 from ...dygraph.tape import Tensor, no_grad_guard
 from ...ops.llm_ops import diffusion_pick
-from ...ops.nn_ops import (live_block_chunk, live_group_blocks,
-                           live_group_chunk, live_ring_group_chunk)
-from ...ops.pallas_group_read import group_read_kernel_applies
+from ...ops.nn_ops import (group_walk_pads, live_blocks_taken,
+                           live_groups_taken, live_ring_groups_taken)
 from ..engine import bucket_ladder
-from ..errors import InvalidRequest, UnsupportedCacheFeature
+from ..errors import InvalidRequest
 from .diffusion import unmask_most_confident
 from .kv_cache import (BlockTable, CacheContext, KVCachePool, decode_coords,
-                       layer_counts, prefill_coords, DEFAULT_BLOCK_SIZE,
-                       DEFAULT_MAX_BLOCKS, DEFAULT_SLOTS)
+                       prefill_coords, DEFAULT_BLOCK_SIZE, DEFAULT_MAX_BLOCKS,
+                       DEFAULT_SLOTS, KV_DTYPE_CODES)
+from .layout import SLIDING_SPARE_BLOCKS, solve_decode_pool_blocks
 
 __all__ = ['DecodeEngine', 'SLIDING_SPARE_BLOCKS']
-
-# blocks the sliding class's arrays hold beyond every slot's ring: the
-# class's scratch block and a few spare, as a K/V pool sized by its slots
-# holds (`slots × blocks a slot + 8`)
-SLIDING_SPARE_BLOCKS = 8
 
 _NULL_LOCK = contextlib.nullcontext()
 
@@ -138,7 +133,7 @@ class _Program:
     first NaN's index: numpy's argmax on the same rows). ``rows``, with
     ``picks`` in brackets: prefill (1, L) -> row ``last`` (V,) [()]; decode
     (S, 1) -> (S, V) [(S,)]; decode (S, K) -> (S, K, V) [(S, K)]. A WINDOW
-    model (``model.decode_window`` B > 1, block diffusion) steps (S, B) ->
+    model (its layout's ``window`` B > 1, block diffusion) steps (S, B) ->
     (S, B, V) [a pair: (S, B) int32 ids with the `MASK` column left out,
     and (S, B) float32 softmax probabilities of those ids,
     ops/llm_ops.py::diffusion_pick], and its prefill returns neither rows
@@ -176,9 +171,9 @@ class _Program:
         # not say it
         heads = self._heads = {}
         # a window model (models/block_diffusion_lm.py) feeds a step
-        # ``decode_window`` rows a slot and hands the host, per row, a pick
-        # and the confidence in it; its prefill scores nothing
-        window = int(getattr(model, 'decode_window', 1))
+        # ``window`` rows a slot and hands the host, per row, a pick and the
+        # confidence in it; its prefill scores nothing
+        window = model.cache_layout().window
         pick = {'mask_token_id': int(getattr(model, 'mask_token_id', -1))}
 
         def run(mode, geometry, pvals, bvals, layers, scales, ids, pos,
@@ -356,8 +351,9 @@ class _CallClock:
 class DecodeEngine:
     """Stateful generation over ``model`` (anything with the
     models/causal_lm.py forward contract: ``model(ids, pos_ids=None,
-    cache=None) -> logits``; attention layers must route ``cache=`` into
-    MultiHeadAttention).
+    cache=None) -> logits``, its layers routing ``cache=`` into the
+    `CacheContext`, and ``model.cache_layout()``: what each of them caches,
+    layout.py).
 
     - ``slots``: fixed lockstep decode batch size S.
     - ``block_size`` / ``max_blocks``: KV-cache pool geometry.
@@ -387,40 +383,13 @@ class DecodeEngine:
         # tape's no_grad flag is process-global). None = zero overhead.
         self._model_lock = model_lock
         self._program = _Program.of(model)
-        # what the model caches: per token per layer [k, v] rows per head
-        # (the default) or one latent row (models/latent_moe_lm.py), or per
-        # REQUEST per layer one recurrent state (models/retention_lm.py)
-        spec = getattr(model, 'kv_cache_spec', None)
-        spec = spec() if spec else {'kind': 'kv'}
-        # the kind of the layers that cache rows ('kv' | 'latent'), or
-        # 'state' where every layer is a state layer
-        self.cache_kind = spec['kind']
-        # what EACH layer caches (kv_cache.py "Hybrid models"): a model may
-        # hold state layers beside row layers, and says so per layer; the
-        # pool is sized over the row layers, a row a slot is kept wherever
-        # any layer is a state layer, and a step's counters go by these
-        self.row_layers, self.state_layers = layer_counts(spec)
-        # of the state layers, those a gated short convolution advances
-        # (`decode_conv_rows`, the span arg `conv_rows`)
-        self.conv_layers = self.state_layers \
-            if spec.get('state_op') == 'short_conv' else 0
-        # the K/V layers' classes (kv_cache.py "Layer classes"): each
-        # layer's span, 0 a full layer; None where the model names none
-        # (one class, full). The sliding class's span is the one they share
-        self.layer_spans = spec.get('layer_spans')
-        spans = {s for s in self.layer_spans or () if s}
-        if len(spans) > 1:
-            raise ValueError(
-                f'the model\'s sliding layers span {sorted(spans)}: the '
-                f'pool holds one sliding class, of one span')
-        self.span = int(spans.pop()) if spans else 0
-        # (full layers, sliding layers) of a model that names its classes
-        n_sliding = sum(bool(s) for s in self.layer_spans or ())
-        self._class_layers = (len(self.layer_spans or ()) - n_sliding,
-                              n_sliding)
+        # what each layer caches, how a step reads it, what it costs and
+        # what it refuses (layout.py): the pool, the counters and the
+        # refusals below all ask it
+        self.layout = layout = model.cache_layout()
         # rows a slot feeds the lockstep step: 1 for every model but a
         # WINDOW model (block diffusion), whose step is `window_step`
-        self.window = int(getattr(model, 'decode_window', 1))
+        self.window = layout.window
         # the last call's ``stats`` as its program returned them (device
         # arrays, read by whoever asks: nothing is copied on the served
         # path): ``expert_ids`` says which experts made the rows just read
@@ -451,17 +420,19 @@ class DecodeEngine:
                                         'f32')
         num_blocks = self._resolve_num_blocks(model, max_blocks, block_size,
                                               max_bps, kv_dtype, self.slots)
-        # state layers: a row a slot, and the scratch row of idle slots.
-        # The sliding class's depth is DERIVED, never asked for: a ring a
-        # slot and the spare, since a slot never holds more of it
-        ring = -(-self.span // block_size) + 1 if self.span else 0
+        # state layers: a row a slot, and the scratch row of idle slots;
+        # the sliding class: a ring a slot and the spare
         self.pool = KVCachePool(
             block_size=block_size, num_blocks=num_blocks,
             max_blocks_per_seq=max_bps, kv_dtype=kv_dtype,
-            state_rows=self.slots + 1 if self.state_layers else 0,
-            span=self.span,
-            sliding_blocks=self.slots * ring + SLIDING_SPARE_BLOCKS
-            if self.span else 0)
+            state_rows=layout.state_rows(self.slots), span=layout.span,
+            sliding_blocks=layout.sliding_blocks(self.slots, block_size),
+            layout=layout)
+        # whether the grouped reads take whole chunks of groups (the XLA
+        # walk) or the live groups alone (the pallas kernel on a TPU): what
+        # a step's count of the blocks it read goes by
+        self._group_pads = layout.group_reads \
+            and group_walk_pads(self.pool.dtype)
         if self.pool.allocator.capacity < max_bps:
             # an empty pool must always cover one maximal request, or the
             # scheduler's FIFO head could wait forever
@@ -471,7 +442,6 @@ class DecodeEngine:
                 f'{max_total} tokens at block_size={block_size})')
         _m.decode_slots_total.set(self.slots)
         _m.decode_cache_blocks_total.set(self.pool.allocator.capacity)
-        from .kv_cache import KV_DTYPE_CODES
         _m.kv_cache_dtype.set(KV_DTYPE_CODES[self.pool.kv_dtype])
         self._set_state_gauges()
         self._prefill_compiled = set()
@@ -506,58 +476,11 @@ class DecodeEngine:
             self.prefix_cache = PrefixCache(self.pool)
         else:
             self.prefix_cache = prefix_cache
-        if self.cache_kind == 'latent':
-            asked = [name for name, on in (
-                ('the prefix cache (and its spill and reinject)',
-                 self.prefix_cache is not None),
-                ('kv_dtype=int8', kv_dtype == 'int8')) if on]
-            if asked:
-                raise UnsupportedCacheFeature(asked, 'latent')
-        if self.state_layers:
-            # nothing of a state can be shared or rolled back, in a model
-            # of state layers alone or beside row layers (docs/SERVING.md
-            # "Recurrent state", "Hybrid models"), and a state is float32:
-            # ``kv_dtype`` is the ROW layers', and means nothing where there
-            # are none; the handoff is refused where its prefill role is
-            # built (serving/tier/disagg.py)
-            asked = [name for name, on in (
-                ('the prefix cache (and its spill and reinject)',
-                 self.prefix_cache is not None),
-                ('speculative decoding (its (S, K) verify step)',
-                 self.spec_enabled),
-                (f'kv_dtype={kv_dtype}',
-                 kv_dtype != 'f32' and not self.row_layers)) if on]
-            if asked:
-                raise UnsupportedCacheFeature(asked, 'state')
-        if self.layer_spans is not None:
-            # a model that names its layers' classes reads its rows through
-            # the grouped reads, full layers and rings alike: none of these
-            # has a path there yet (docs/SERVING.md "Layer classes"); the
-            # handoff is refused where its prefill role is built
-            # (serving/tier/disagg.py)
-            asked = [name for name, on in (
-                ('the prefix cache (and its spill and reinject)',
-                 self.prefix_cache is not None),
-                ('speculative decoding (its (S, K) verify step)',
-                 self.spec_enabled),
-                ('kv_dtype=int8', kv_dtype == 'int8'),
-                ('a window model\'s block step', self.window > 1))
-                if on]
-            if asked:
-                raise UnsupportedCacheFeature(
-                    asked, 'sliding' if self.span else 'grouped')
-        if self.window > 1:
-            # each needs a path under the block mask that is not written
-            # yet (docs/SERVING.md "Window models"); the handoff is refused
-            # where its prefill role is built (serving/tier/disagg.py)
-            asked = [name for name, on in (
-                ('the prefix cache (and its spill and reinject)',
-                 self.prefix_cache is not None),
-                ('speculative decoding (its (S, K) verify step)',
-                 self.spec_enabled),
-                ('kv_dtype=int8', kv_dtype == 'int8')) if on]
-            if asked:
-                raise UnsupportedCacheFeature(asked, 'window')
+        # what the layout cannot serve is refused here, never under traffic
+        # (layout.py, the one table); the handoff where its prefill role is
+        # built (serving/tier/disagg.py)
+        layout.refuse(prefix_cache=self.prefix_cache is not None,
+                      spec_decode=self.spec_enabled, kv_dtype=kv_dtype)
 
     @staticmethod
     def _resolve_num_blocks(model, max_blocks, block_size, max_bps,
@@ -567,7 +490,7 @@ class DecodeEngine:
         ``PADDLE_TPU_DECODE_MAX_BLOCKS`` env (checked live, not the
         import-time default — an operator pinning the block count must
         beat any budget), then the ``PADDLE_TPU_DECODE_HBM_MB`` budget
-        solve (analysis/plan.py prices model state + per-block KV bytes at
+        solve (layout.py prices model state + per-block KV bytes at
         ``kv_dtype``), else the module default."""
         if max_blocks:
             return int(max_blocks)
@@ -578,7 +501,6 @@ class DecodeEngine:
         from ..tier.knobs import ENV_DECODE_HBM_MB, parse_int_env
         hbm_mb = parse_int_env(ENV_DECODE_HBM_MB, 0, minimum=1)
         if hbm_mb:
-            from ...analysis.plan import solve_decode_pool_blocks
             return solve_decode_pool_blocks(
                 model, hbm_mb, block_size=block_size, kv_dtype=kv_dtype,
                 min_blocks=max_bps + 1, slots=slots)
@@ -649,16 +571,16 @@ class DecodeEngine:
         class's own."""
         used = self.pool.allocator.used
         _m.decode_cache_blocks_used.set(used)
-        if self.layer_spans is not None:
+        if self.layout.classes:
             _m.decode_full_blocks_held.set(used)
             _m.decode_sliding_blocks_held.set(
-                self.pool.sliding.used if self.span else 0)
+                self.pool.sliding.used if self.layout.span else 0)
 
     def _set_state_gauges(self):
         """The state layers' three gauges (a telemetry reset clears gauges,
         so every call that changes one sets all three); nothing for a model
         of row layers alone."""
-        if self.state_layers:
+        if self.layout.state_layers:
             _m.state_cache_bytes_in_hbm.set(self.pool.state_bytes_in_hbm())
             _m.state_cache_rows_total.set(self.pool.state_rows.capacity)
             _m.state_cache_rows_used.set(self.pool.state_rows.used)
@@ -794,12 +716,12 @@ class DecodeEngine:
         _m.decode_prefill_seconds.observe(clock.last - t0)
         token = int(pick if sampler is None else sampler(row))
         clock.end('sample')
-        folded = P * self.pool.num_state_layers
+        folded = P * self.layout.state_layers
         if folded:
             _m.decode_state_tokens_folded.inc(folded)
             clock.work['state_tokens_folded'] = folded
         self._account_conv(clock, P, rung=bucket)
-        if self.layer_spans is not None:
+        if self.layout.classes:
             # positions the prompt leaves in each class's blocks
             clock.work.update(self._class_positions([P])[1])
         clock.record(prompt_len=P, bucket=bucket)
@@ -887,55 +809,27 @@ class DecodeEngine:
         return out
 
     def _blocks_walked(self, ctx_lens):
-        """Blocks a layer's read takes from the pool in a lockstep step over
-        ``ctx_lens`` (an idle slot's 1: the scratch block). A K/V pool's
-        read walks the live blocks in whole chunks (ops/nn_ops.py::
-        paged_attention), a latent pool's the live groups of blocks
-        (ops/llm_ops.py::mla_decode_attention); a state layer reads no
-        block at all (0 where no layer caches rows)."""
-        entries = self.slots * self.pool.max_blocks_per_seq
-        if not self.row_layers:
-            return 0
-        if (self.cache_kind == 'latent' or self.window > 1
-                or self.layer_spans is not None):
-            # the latent read, the block read and the grouped one-token
-            # reads walk the live groups of blocks
-            # (ops/nn_ops.py::live_group_list)
-            bs = self.block_size
-            rows = jax.ShapeDtypeStruct((), self.pool.dtype)
-            # the XLA walk reads whole chunks of groups; the pallas kernel
-            # (ops/pallas_group_read.py) copies the live groups alone
-            whole_chunks = not group_read_kernel_applies(rows, rows)
-            per_group = live_group_blocks(bs, self.pool.max_blocks_per_seq)
-            _, chunk = live_group_chunk(self.slots, bs,
-                                        self.pool.max_blocks_per_seq)
-            live = sum(-(-int(c) // (per_group * bs)) for c in ctx_lens)
-            if whole_chunks:
-                live = -(-live // chunk) * chunk
-            if self.layer_spans is None:
-                return live * per_group
-            # layer classes: a pair, (a full layer's, a sliding layer's);
-            # a sliding layer walks the groups of its ring that its span
-            # touches (ops/nn_ops.py::live_ring_group_list)
-            sliding = 0
-            if self.span:
-                ring_group, _, ring_chunk = live_ring_group_chunk(
-                    self.slots, bs, self.pool.ring, self.span)
-                keys = ring_group * bs
-                ring_live = sum((int(c) - 1) // keys
-                                - max(int(c) - self.span, 0) // keys + 1
-                                for c in ctx_lens)
-                if whole_chunks:
-                    ring_live = -(-ring_live // ring_chunk) * ring_chunk
-                sliding = ring_live * ring_group
-            return live * per_group, sliding
-        chunk = live_block_chunk(entries)
-        live = sum(-(-int(c) // self.block_size) for c in ctx_lens)
-        return -(-live // chunk) * chunk
+        """Blocks the row layers' reads take from the pool in a lockstep
+        step over ``ctx_lens`` (an idle slot's 1: the scratch block), summed
+        over the layers, each read as ops/nn_ops.py walks it (the layout's
+        ``read``); a state layer reads no block."""
+        pool = self.pool
+        bs, mb = pool.block_size, pool.max_blocks_per_seq
+        walked = 0
+        for read, layers in self.layout.reads:
+            if read == 'blocks':
+                walked += layers * live_blocks_taken(ctx_lens, bs, mb)
+            elif read == 'ring':
+                walked += layers * live_ring_groups_taken(
+                    ctx_lens, bs, pool.ring, pool.span, self._group_pads)
+            else:       # a latent row, a window's block, a full class
+                walked += layers * live_groups_taken(ctx_lens, bs, mb,
+                                                     self._group_pads)
+        return walked
 
     def _account_step(self, clock, dt, tables, blocks, attended=None):
         """What every decode step books, lockstep or speculative, and the
-        call's record; ``blocks`` the cache blocks a layer's read took,
+        call's record; ``blocks`` the cache blocks the layers' reads took,
         ``attended`` the positions a layer's read attended where that is
         not the tables' contexts (a window step reads a block it may not
         keep)."""
@@ -946,23 +840,20 @@ class DecodeEngine:
         # the blocks its reads took from the pool to attend it: both over
         # the layers that cache rows (a state layer attends no position and
         # reads no block: it advances one state a live slot)
-        layers = self.pool.num_row_layers
-        if self.layer_spans is not None:
+        if self.layout.classes:
             # two classes: a sliding layer attends its span's positions
-            # and walks its ring's groups
             positions, by_class = self._class_positions(
                 [t.context_len for t in tables if t is not None])
             clock.work.update(by_class)
-            blocks = sum(n * b for n, b in zip(self._class_layers, blocks))
         else:
-            positions = layers * (attended if attended is not None else sum(
-                t.context_len for t in tables if t is not None))
-            blocks = layers * blocks
+            positions = self.layout.row_layers * (
+                attended if attended is not None else sum(
+                    t.context_len for t in tables if t is not None))
         _m.decode_context_positions_read.inc(positions)
         _m.decode_kv_blocks_read.inc(blocks)
         clock.work['context_positions'] = positions
         clock.work['kv_blocks'] = blocks
-        updates = self.pool.num_state_layers * active
+        updates = self.layout.state_layers * active
         if updates:
             _m.decode_state_updates.inc(updates)
             clock.work['state_updates'] = updates
@@ -979,8 +870,8 @@ class DecodeEngine:
         x conv layers (a prefill's prompt, never its rung; a step's live
         slots): the counter, and the span's ``conv_rows`` with ``args``
         beside it. Nothing for a model without such a layer."""
-        if self.conv_layers:
-            conv = rows * self.conv_layers
+        if self.layout.conv_layers:
+            conv = rows * self.layout.conv_layers
             _m.decode_conv_rows.inc(conv)
             clock.work.update(args, conv_rows=conv)
 
@@ -991,9 +882,10 @@ class DecodeEngine:
         ``decode_kv_positions_held`` with it and
         ``decode_kv_positions_if_unwindowed`` with what every layer would
         hold were none sliding: the ring's saving, read off two counters."""
-        n_full, n_sliding = self._class_layers
+        layout = self.layout
+        n_full, n_sliding = layout.full_layers, layout.sliding_layers
         total = sum(int(c) for c in contexts)
-        within = sum(min(int(c), self.span) for c in contexts)
+        within = sum(min(int(c), layout.span) for c in contexts)
         full, sliding = n_full * total, n_sliding * within
         _m.decode_kv_positions_held.inc(full + sliding)
         _m.decode_kv_positions_if_unwindowed.inc(
@@ -1113,8 +1005,8 @@ class DecodeEngine:
         dt = clock.last - t0
         self._spec_compiled = True
         # it IS the decode step; its (S, K) read gathers every table whole
-        self._account_step(clock, dt, tables,
-                           S * self.pool.max_blocks_per_seq)
+        self._account_step(clock, dt, tables, self.layout.row_layers * S
+                           * self.pool.max_blocks_per_seq)
         _m.decode_spec_verify_seconds.observe(dt)
         _m.decode_spec_rounds.inc()
         return rows

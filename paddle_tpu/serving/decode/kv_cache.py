@@ -79,21 +79,21 @@ layer's queries see to the last ``span`` positions (sliding attention). Such
 a layer gives back what lies behind its span: the K/V layers of a pool are
 of two CLASSES under this one manager. The FULL class is everything above: a
 table that grows with the context. The SLIDING class holds per request a
-RING of ``ring`` = ceil(span / block_size) + 1 blocks (`BlockTable.ring`):
-position p lives in ring block ``(p // block_size) mod ring``, a block is
-overwritten in place when the ring comes round, and the request never holds
-more however long it grows. Each class has its own free list
-(`KVCachePool.allocator`, `KVCachePool.sliding`), its own depth of array
-(``num_blocks``, ``sliding_blocks``), its own scratch block 0 and its own
-columns in :func:`prefill_coords` / :func:`decode_coords`
+RING of ``ring`` = ceil(span / block_size) + 1 blocks (`BlockTable.ring`,
+`layout.ring_blocks`): position p lives in ring block ``(p // block_size)
+mod ring``, a block is overwritten in place when the ring comes round, and
+the request never holds more however long it grows. Each class has its own
+free list (`KVCachePool.allocator`, `KVCachePool.sliding`), its own depth of
+array (``num_blocks``, ``sliding_blocks``), its own scratch block 0 and its
+own columns in :func:`prefill_coords` / :func:`decode_coords`
 (``sliding_tables``, ``sliding_write_ids``, ``sliding_write_offs``). Which
 layer is of which class the model says as it attends
-(`CacheContext.attend(span=)`) and in ``kv_cache_spec()['layer_spans']``. A
-row that has left the span is never read: the reads mask by a key's
-POSITION, rebuilt from its place in the ring and the context length. The
-ring is taken WHOLE at admission, as many blocks as the request's prompt
-and budget can ever touch (at most ``ring``), like the full class's
-reservation and for its reason: a generation never dies of a missing block
+(`CacheContext.attend(span=)`) and in its layout (layout.py). A row that
+has left the span is never read: the reads mask by a key's POSITION,
+rebuilt from its place in the ring and the context length. The ring is
+taken WHOLE at admission, as many blocks as the request's prompt and budget
+can ever touch (at most ``ring``), like the full class's reservation and
+for its reason: a generation never dies of a missing block
 mid-flight, and the free list's count is what admission can promise.
 
 Quantized storage (``kv_dtype``, docs/SERVING.md "Tiered KV cache"): the
@@ -124,13 +124,12 @@ import numpy as np
 
 from ..errors import (InvalidRequest, OutOfBlocks, OutOfStateRows,
                       UnsupportedCacheFeature)
+from .layout import KV_PAYLOAD_DTYPES, ring_blocks, row_lanes
 
 __all__ = ['BlockAllocator', 'BlockTable', 'KVCachePool', 'CacheContext',
-           'StateRows', 'layer_kinds', 'layer_counts',
-           'prefill_coords', 'decode_coords', 'DEFAULT_SLOTS',
+           'StateRows', 'prefill_coords', 'decode_coords', 'DEFAULT_SLOTS',
            'DEFAULT_BLOCK_SIZE', 'DEFAULT_MAX_BLOCKS', 'SCRATCH_BLOCK',
-           'KV_PAYLOAD_DTYPES', 'KV_DTYPE_CODES', 'kv_row_bytes',
-           'row_lanes']
+           'KV_PAYLOAD_DTYPES', 'KV_DTYPE_CODES', 'row_lanes']
 
 DEFAULT_SLOTS = int(os.environ.get('PADDLE_TPU_DECODE_SLOTS', '8'))
 DEFAULT_BLOCK_SIZE = int(os.environ.get('PADDLE_TPU_DECODE_BLOCK_SIZE', '16'))
@@ -139,65 +138,9 @@ DEFAULT_MAX_BLOCKS = int(os.environ.get('PADDLE_TPU_DECODE_MAX_BLOCKS',
 
 SCRATCH_BLOCK = 0
 
-# storage payload width per element, by kv_dtype; int8 additionally carries
-# one f32 scale per (position, head) row — kv_row_bytes() is the closed
-# form the pool-sizing solve prices
-KV_PAYLOAD_DTYPES = {'f32': 'float32', 'bf16': 'bfloat16', 'int8': 'int8'}
-_KV_PAYLOAD_BYTES = {'f32': 4, 'bf16': 2, 'int8': 1}
 # stable small-int codes: the kv_cache_dtype gauge and the disagg KVPayload
 # wire meta both speak these (0 is also what a legacy 3-int meta implies)
 KV_DTYPE_CODES = {'f32': 0, 'bf16': 1, 'int8': 2}
-
-
-def kv_row_bytes(heads, head_dim, kv_dtype):
-    """Bytes ONE token's row takes in one pool array (its K, its V, or its
-    latent row with ``heads`` 1) at ``kv_dtype``, as allocated: the payload
-    of all heads in :func:`row_lanes` lanes + (int8 only) one f32 scale a
-    head."""
-    if kv_dtype not in _KV_PAYLOAD_BYTES:
-        raise ValueError(
-            f'kv_dtype={kv_dtype!r} is not supported; supported values: '
-            + ', '.join(repr(c) for c in KV_PAYLOAD_DTYPES))
-    return (row_lanes(int(heads) * int(head_dim))
-            * _KV_PAYLOAD_BYTES[kv_dtype]
-            + (4 * int(heads) if kv_dtype == 'int8' else 0))
-
-
-def layer_kinds(spec):
-    """What each layer of a model caches, from its ``kv_cache_spec()``:
-    'kv' (K and V rows a token), 'latent' (one row a token) or 'state' (one
-    fixed-size state a request). A model of one kind says ``kind`` and
-    ``layers``; a hybrid says ``layer_kinds``, a layer at a time, beside
-    ``kind``, the kind of its row layers."""
-    kinds = spec.get('layer_kinds')
-    if kinds is None:
-        return (spec['kind'],) * int(spec.get('layers', 1))
-    return tuple(kinds)
-
-
-def layer_counts(spec):
-    """(row layers, state layers) of a model's ``kv_cache_spec()``: the
-    layers that cache a row a token (K/V or latent) and those that keep one
-    state a request."""
-    kinds = layer_kinds(spec)
-    states = sum(kind == 'state' for kind in kinds)
-    return len(kinds) - states, states
-
-
-LANES = 128
-
-
-def row_lanes(width):
-    """Lanes a token's row of ``width`` values takes in the pool: the next
-    multiple of the TPU's 128. A (blocks, block, 576) array (a latent row)
-    or (blocks, block, 320) (5 heads of 64) is given the compact layout
-    with the BLOCK axis minor, and every engine program then copies each
-    layer's whole pool into the scatter's row-major layout and back (found
-    in the compiled step, PERF.md section 6, PR 26, and for K/V rows PR 27);
-    at 640 or 384 lanes row-major is the compact layout, the writes are in
-    place, and the bytes are those the tiles of the unpadded row-major array
-    would take anyway."""
-    return -(-int(width) // LANES) * LANES
 
 
 def _to_lanes(rows, lanes):
@@ -399,12 +342,13 @@ class KVCachePool:
     slot can hold and the key extent of the reads that gather a table whole
     (prefill rungs below 128, the (S, K) step over a K/V or a latent pool);
     the lockstep step, over a K/V pool and over a latent one, reads what is
-    live alone (see ops/nn_ops.py).
+    live alone (see ops/nn_ops.py). ``layout`` (layout.py, the model's)
+    says which layers keep a state a request.
     """
 
     def __init__(self, block_size=None, num_blocks=None,
                  max_blocks_per_seq=None, kv_dtype=None, state_rows=0,
-                 span=0, sliding_blocks=0):
+                 span=0, sliding_blocks=0, layout=None):
         self.block_size = int(block_size or DEFAULT_BLOCK_SIZE)
         self.num_blocks = int(num_blocks or DEFAULT_MAX_BLOCKS)
         self.max_blocks_per_seq = int(max_blocks_per_seq or 8)
@@ -420,13 +364,18 @@ class KVCachePool:
         # layer of it lets a query see, the ring a request holds of it, the
         # depth of its arrays and its own free list
         self.span = int(span)
-        self.ring = -(-self.span // self.block_size) + 1 if self.span else 0
+        self.ring = ring_blocks(self.span, self.block_size)
         self.sliding_blocks = int(sliding_blocks)
         self.sliding = BlockAllocator(self.sliding_blocks) if self.span \
             else None
         # rows of the state layers' arrays (0: the model has none), row 0
         # scratch, and who holds which
         self.state_rows = StateRows(state_rows)
+        # the layers that keep a state, as the model's layout says (none for
+        # a pool built without one): the bytes of rows and of states apart
+        self._state_layers = frozenset(
+            i for i, layer in enumerate(layout.layers)
+            if layer.kind == 'state') if layout else frozenset()
         # layer idx -> [k_pages, v_pages], each (NB, BS, lanes of H·D), or
         # for a latent (MLA) layer -> [rows] of (NB, BS, lanes of W): one
         # row a token; for a state layer -> [states] of (state rows,
@@ -491,30 +440,18 @@ class KVCachePool:
                 store[layer] = [jnp.zeros(a.shape, a.dtype) for a in arrs]
         self.heads.update(heads)
 
-    @staticmethod
-    def _is_state(arrs):
-        return len(arrs) == 1 and arrs[0].ndim == 4
-
-    @property
-    def num_row_layers(self):
-        """Layers that cache a row per token (K/V or latent)."""
-        return sum(not self._is_state(a) for a in self._layers.values())
-
-    @property
-    def num_state_layers(self):
-        """Layers that cache one recurrent state per request."""
-        return sum(self._is_state(a) for a in self._layers.values())
+    def _arrays_of(self, states):
+        """The arrays of the state layers, or of the row layers."""
+        return [arrs for layer, arrs in self._layers.items()
+                if (layer in self._state_layers) == states]
 
     def row_bytes(self):
         """Resident bytes of one token's cached rows in one layer (a
         layer's arrays over the positions they hold): the
         kv_cache_row_bytes gauge. 0 where no layer caches rows."""
-        if not self.num_row_layers:
-            return 0
-        positions = sum(
-            arrs[0].shape[0] * self.block_size
-            for arrs in self._layers.values() if not self._is_state(arrs))
-        return self.bytes_in_hbm() // positions
+        positions = sum(arrs[0].shape[0] * self.block_size
+                        for arrs in self._arrays_of(states=False))
+        return self.bytes_in_hbm() // positions if positions else 0
 
     def new_table(self, total_tokens):
         """Allocate a table holding ``total_tokens`` (prompt + budget), and
@@ -563,19 +500,13 @@ class KVCachePool:
         rows (NB, BS, row_lanes(head_dim))."""
         if n_heads is not None:
             self.heads[layer] = (int(n_heads), int(head_dim))
-        if sliding:
-            if not self.span:
-                raise ValueError(
-                    'a sliding layer over a pool built with span=0: the '
-                    'model must say kv_cache_spec()["layer_spans"]')
-            if self.kv_dtype == 'int8':
-                raise UnsupportedCacheFeature(['kv_dtype=int8'], 'sliding')
+        if sliding and not self.span:
+            raise ValueError(
+                'a sliding layer over a pool built with span=0: the '
+                'model\'s cache_layout() must name its span')
         if layer not in self._layers:
             import jax.numpy as jnp
             if n_heads is None:
-                if self.kv_dtype == 'int8':
-                    raise UnsupportedCacheFeature(['kv_dtype=int8'],
-                                                  'latent')
                 self._layers[layer] = [jnp.zeros(
                     (self.num_blocks, self.block_size,
                      row_lanes(head_dim)), self.dtype)]
@@ -617,19 +548,14 @@ class KVCachePool:
     def bytes_in_hbm(self):
         """Resident bytes of the layers that cache rows: payload arrays
         plus (int8) their scale arrays — the kv_cache_bytes_in_hbm gauge."""
-        total = 0
-        for arrs in self._layers.values():
-            if not self._is_state(arrs):
-                total += sum(int(a.nbytes) for a in arrs)
-        for arrs in self._scales.values():
-            total += sum(int(a.nbytes) for a in arrs)
-        return total
+        return sum(int(a.nbytes) for arrs in self._arrays_of(states=False)
+                   + list(self._scales.values()) for a in arrs)
 
     def state_bytes_in_hbm(self):
         """Resident bytes of the state layers, every row of every layer —
         the state_cache_bytes_in_hbm gauge."""
-        return sum(int(arrs[0].nbytes) for arrs in self._layers.values()
-                   if self._is_state(arrs))
+        return sum(int(arrs[0].nbytes)
+                   for arrs in self._arrays_of(states=True))
 
     # -- state layers: one row a request, whole ----------------------------
     def ensure_state(self, layer, block_shape):
@@ -642,8 +568,8 @@ class KVCachePool:
             if not self.state_rows.num_rows:
                 raise ValueError(
                     'a state layer over a pool built with state_rows=0: '
-                    'the model must name its state layers in '
-                    'kv_cache_spec() (kind "state", or "layer_kinds")')
+                    'the model\'s cache_layout() must name its state '
+                    'layers')
             self._layers[layer] = [jnp.zeros(
                 (self.state_rows.num_rows,) + tuple(block_shape),
                 'float32')]
@@ -809,10 +735,6 @@ class KVCachePool:
                 x.reshape(nb, bs, h * d), pages[i].shape[-1]))
             if sc is not None:
                 sc[i] = _scatter_blocks(sc[i], ids, xs)
-
-    # -- observability -----------------------------------------------------
-    def utilization(self):
-        return self.allocator.used / max(self.allocator.capacity, 1)
 
 
 def prefill_coords(pool, table, bucket):

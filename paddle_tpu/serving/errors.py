@@ -122,59 +122,15 @@ class OutOfStateRows(OutOfBlocks):
 
 
 class UnsupportedCacheFeature(ServingError, ValueError):
-    """A cache feature was asked of a model whose cached state it cannot
-    hold. Raised when the engine (or the prefill role beside it) is built,
-    never under traffic. The prefix cache with its host spill and reinject,
-    the disaggregated handoff and int8 rows all move ``[k, v]`` pairs of
-    per-head rows: a latent (MLA) layer caches ONE array of rows with no
-    head axis (``kind`` 'latent'), and a retention layer no row per token at
-    all but one recurrent state per request (``kind`` 'state'), which has no
-    prefix to share, no blocks to hand off, no rollback for a speculative
-    window and no storage type but float32; the same holds of the state
-    layers of a HYBRID model (a gated short convolution beside attention,
-    models/hybrid_conv_moe_lm.py), whose row layers alone take ``kv_dtype``.
-    A WINDOW model (``kind``
-    'window': block diffusion, models/block_diffusion_lm.py) caches [k, v]
-    rows, but reads them under the block mask and keeps a block's rows only
-    at its commit forward: the features that fill, share or verify rows
-    outside that step have no such path yet. A model with a SLIDING class
-    of layer (``kind`` 'sliding', models/sliding_moe_lm.py) keeps a ring
-    of blocks a request in those layers and overwrites it in place: a
-    prefix has no block of its own to share once the ring has come round,
-    a handoff would have to carry rings, a rejected window cannot be rolled
-    back off a block it overwrote, and the ring read takes no row scales.
-    A model that names its layers' classes and has no sliding one (``kind``
-    'grouped') reads its full layers through the same grouped reads."""
-
-    _WHY = {
-        'latent': ('they read and write [k, v] pairs of per-head rows',
-                   'Latent pool'),
-        'state': ('a state layer holds one float32 recurrent state per '
-                  'request, advanced in place: no row per token to share, '
-                  'hand off, quantize or roll back', 'Recurrent state'),
-        'window': ('a window model reads its rows under the block mask and '
-                   'keeps a block\'s rows only at its commit forward, and '
-                   'these have no path under that mask yet',
-                   'Window models'),
-        'sliding': ('a sliding layer keeps a ring of blocks a request and '
-                    'overwrites it in place, and these have no path over a '
-                    'ring yet', 'Layer classes'),
-        'grouped': ('a model that names its layers\' classes reads its '
-                    'rows a key/value head\'s group of query heads at a '
-                    'time, and these have no path through those reads yet',
-                    'Layer classes')}
+    """A cache feature (``features``) was asked of a model whose cached
+    state cannot hold it, refused as a cache of ``kind`` ('latent', 'state',
+    'sliding', 'grouped' or 'window'). Raised when the engine (or the
+    prefill role beside it) is built, never under traffic: which kind
+    refuses what, and why, is one table (serving/decode/layout.py,
+    docs/SERVING.md "Cache layout")."""
 
     def __init__(self, features, kind):
-        features = list(features)
-        why, section = self._WHY.get(kind, self._WHY['latent'])
-        what = {'state': 'a model with state layers (its state cache)',
-                'window': 'a window model\'s KV cache',
-                'sliding': 'a KV cache with a sliding class of layer',
-                'grouped': 'a KV cache whose model names its layers\' '
-                           'classes'}.get(
-                    kind, f'a {kind} KV cache')
-        super().__init__(
-            f'{", ".join(features)} cannot be used with {what}: {why} '
-            f'(docs/SERVING.md "{section}")')
-        self.features = features
+        from .decode.layout import refusal_message
+        self.features = list(features)
         self.kind = kind
+        super().__init__(refusal_message(self.features, kind))

@@ -37,7 +37,7 @@ import time
 import numpy as np
 
 from .. import metrics as _m
-from ..errors import ServingError, UnsupportedCacheFeature
+from ..errors import ServingError
 
 __all__ = ['KVPayload', 'PrefillReplica', 'LocalPrefillWorker']
 
@@ -132,17 +132,9 @@ class PrefillReplica:
     then free. One worker thread owns it (``LocalPrefillWorker``)."""
 
     def __init__(self, engine):
-        if getattr(engine, 'span', 0):
-            raise UnsupportedCacheFeature(['the disaggregated handoff'],
-                                          'sliding')
-        if getattr(engine, 'state_layers', 0):
-            # a state has no blocks to hand off, beside row layers or alone
-            raise UnsupportedCacheFeature(['the disaggregated handoff'],
-                                          'state')
-        if engine.cache_kind != 'kv' or getattr(engine, 'window', 1) > 1:
-            raise UnsupportedCacheFeature(
-                ['the disaggregated handoff'],
-                engine.cache_kind if engine.cache_kind != 'kv' else 'window')
+        # the handoff moves [k, v] blocks of per-head rows: what the
+        # engine's layout cannot hand off is refused here (layout.py)
+        engine.layout.refuse(handoff=True)
         self.engine = engine
 
     def prefill_to_payload(self, prompt, max_new_tokens=0):

@@ -157,16 +157,16 @@ def test_config_takes_published_keys_and_refuses_what_it_cannot_compute():
 
 
 def test_the_model_says_what_it_caches_and_what_a_step_feeds(lm):
-    assert lm.kv_cache_spec() == {'kind': 'kv', 'layers': 3, 'heads': 2,
-                                  'head_dim': 8, 'window': 4}
-    assert lm.decode_window == 4 and lm.mask_token_id == MASK
+    from paddle_tpu.serving.decode.layout import CacheLayout, LayerCache
+    layout = lm.cache_layout()
+    assert layout == CacheLayout(
+        (LayerCache.kv(2, 8, read='window'),) * 3, window=4)
+    assert layout.window == 4 and lm.mask_token_id == MASK
     names = [n for n, _ in lm.named_parameters()]
     # softmax router: no selection bias; no shared expert
     assert not [n for n in names if 'router_bias' in n or 'shared' in n]
-    from paddle_tpu.analysis.plan import (decode_pool_block_bytes,
-                                          decode_step_rows)
-    assert decode_step_rows(lm, 128) == 512
-    assert decode_pool_block_bytes(lm, 16, 'bf16') == 3 * 16 * 2 * 128 * 2
+    assert layout.step_rows(128) == 512
+    assert layout.block_bytes(16, 'bf16') == 3 * 16 * 2 * 128 * 2
 
 
 # -- the softmax router ---------------------------------------------------

@@ -19,6 +19,7 @@ from paddle_tpu.models.causal_lm import CausalLMConfig, TransformerLM
 from paddle_tpu.models.latent_moe_lm import LatentMoEConfig, LatentMoELM
 from paddle_tpu.models.retention_lm import RetentionLM, RetentionLMConfig
 from paddle_tpu.serving import DecodeEngine, DecodeScheduler, metrics
+from paddle_tpu.serving.errors import UnsupportedCacheFeature
 
 KINDS = {'kv': lambda: TransformerLM(CausalLMConfig.tiny()),
          'latent': lambda: LatentMoELM(LatentMoEConfig.tiny()),
@@ -147,8 +148,8 @@ def test_the_verify_steps_program_picks_every_window_row(lm, monkeypatch):
     """The (S, K) program returns (S, K) picks of its (S, K, V) rows; the
     accept loop still reads the rows on the host, so they cross."""
     from paddle_tpu.serving.decode import engine as eng
-    if lm.kv_cache_spec()['kind'] == 'state':
-        with pytest.raises(eng.UnsupportedCacheFeature, match='verify'):
+    if lm.cache_layout().kind == 'state':
+        with pytest.raises(UnsupportedCacheFeature, match='verify'):
             _engine(lm, spec_decode=True, spec_k=3)
         return
     fetched = []

@@ -20,6 +20,11 @@ from paddle_tpu.serving import DecodeEngine, DecodeScheduler
 ENGINE_HIST = 'decode_engine_phase_seconds'
 SCHED_HIST = 'decode_scheduler_phase_seconds'
 PHASES = ('pack', 'forward', 'device_wait', 'logits_copy', 'sample')
+# what an engine call may spend outside its stamped phases: making its clock
+# and, after the last stamp, its bookkeeping (0.10-0.16 ms on a quiet CPU),
+# in which a worker beside five other test workers can lose the CPU for a
+# scheduler tick
+BOOKKEEPING_S = 2e-3
 
 
 @pytest.fixture(scope='module')
@@ -124,7 +129,7 @@ def test_the_idle_worker_books_its_waits(lm):
 @pytest.mark.parametrize('call', ['prefill', 'step', 'spec_step'])
 def test_phases_tile_the_call_and_keep_the_old_histograms_meaning(lm, call):
     engine = make_engine(lm, spec_decode=(call == 'spec_step'), spec_k=3)
-    walls = []
+    walls, clocks = [], []
     for _ in range(5):
         table = engine.reserve_table(5, 8)
         t0 = time.perf_counter()
@@ -141,11 +146,16 @@ def test_phases_tile_the_call_and_keep_the_old_histograms_meaning(lm, call):
                 engine.spec_step([[token, 4]] + [None] * 3,
                                  [table, None, None, None])
             walls.append(time.perf_counter() - t0)
+        clocks.append(engine.last_call)
         engine.release_table(table)
     phase_s = {p: _hist(ENGINE_HIST, call=call, phase=p)[0] for p in PHASES}
     wall = sum(walls)
-    # the last stamp to the return is bookkeeping: a few observations
-    assert 0.98 * wall - 5e-4 <= sum(phase_s.values()) <= wall
+    # the phases tile each call from its first stamp to its last
+    assert sum(phase_s.values()) == pytest.approx(
+        sum(c.last - c.start for c in clocks), rel=1e-9)
+    # and the wall holds them, less BOOKKEEPING_S a call
+    assert 0.98 * wall - len(walls) * BOOKKEEPING_S \
+        <= sum(phase_s.values()) <= wall
     inner = phase_s['forward'] + phase_s['device_wait'] \
         + phase_s['logits_copy']
     if call == 'prefill':
@@ -187,7 +197,10 @@ def test_scheduler_engine_phase_is_the_engines_time(lm):
     seen = _hist(SCHED_HIST, phase='engine')[0]
     cycle = _hist(SCHED_HIST, phase='cycle')[0]
     wait = _hist(SCHED_HIST, phase='wait')[0]
-    assert own <= seen <= 1.05 * own + 1e-3
+    # what the scheduler sees of a call beyond its phases is the engine's
+    # bookkeeping after the last stamp, BOOKKEEPING_S a call at most
+    calls = 4 + _counter('decode_steps')
+    assert own <= seen <= 1.05 * own + calls * BOOKKEEPING_S
     assert seen <= cycle - wait
 
 
@@ -324,7 +337,7 @@ def test_blocks_read_of_a_latent_pool_counts_the_live_groups_in_whole_chunks(
                              max_prompt_len=16, max_new_tokens_cap=368,
                              prompt_buckets=[16], prefix_cache=False)
         layers = model.cfg.num_hidden_layers
-        assert engine.cache_kind == 'latent'
+        assert engine.layout.kind == 'latent'
         assert engine.pool.max_blocks_per_seq == 96
         assert nn_ops.live_group_chunk(6, 4, 96) == (3, 4)
         tables = [engine.reserve_table(16, 368) for _ in range(6)]

@@ -11,12 +11,14 @@ import numpy as np
 import pytest
 
 from paddle_tpu import dygraph
-from paddle_tpu.analysis import plan
 from paddle_tpu.models.hybrid_conv_moe_lm import (HybridConvMoEConfig,
                                                   HybridConvMoELM)
 from paddle_tpu.serving.decode.engine import DecodeEngine
-from paddle_tpu.serving.decode.kv_cache import (KVCachePool, layer_kinds,
-                                                kv_row_bytes)
+from paddle_tpu.serving.decode.kv_cache import KVCachePool
+from paddle_tpu.serving.decode.layout import (CacheLayout, LayerCache,
+                                              decode_pool_report,
+                                              kv_row_bytes,
+                                              solve_decode_pool_blocks)
 from paddle_tpu.serving.errors import (OutOfBlocks, OutOfStateRows,
                                        UnsupportedCacheFeature)
 
@@ -43,11 +45,19 @@ def _engine(lm, slots=3, **kw):
 
 
 def test_layer_kinds_reads_both_forms_of_a_spec(lm):
-    assert layer_kinds({'kind': 'state', 'layers': 3}) == ('state',) * 3
-    assert layer_kinds({'kind': 'latent', 'layers': 2}) == ('latent',) * 2
-    assert layer_kinds({'kind': 'kv'}) == ('kv',)
-    assert layer_kinds(lm.kv_cache_spec()) == ('state', 'kv', 'state',
-                                               'state')
+    """A layout says each layer's kind; its own ``kind`` is its row
+    layers', or 'state' where it has none."""
+    states = CacheLayout((LayerCache.state((2, 3, 4), 'retention'),) * 3)
+    assert (states.kind, states.row_layers, states.state_layers) \
+        == ('state', 0, 3)
+    latent = CacheLayout((LayerCache.latent(8),) * 2)
+    assert (latent.kind, latent.row_layers, latent.reads) \
+        == ('latent', 2, (('groups', 2),))
+    assert CacheLayout((LayerCache.kv(2, 8),)).reads == (('blocks', 1),)
+    layout = lm.cache_layout()
+    assert tuple(layer.kind for layer in layout.layers) == (
+        'state', 'kv', 'state', 'state')
+    assert layout.kind == 'kv' and layout.reads == (('groups', ATTN),)
 
 
 @pytest.mark.parametrize('kv_dtype', ['f32', 'bf16'])
@@ -57,15 +67,17 @@ def test_blocks_are_sized_over_row_layers_and_rows_over_state_layers(
     pool's depth at ``kv_dtype``; each conv layer one float32 array of
     slots + 1 rows, whatever ``kv_dtype``."""
     engine = _engine(lm, kv_dtype=kv_dtype)
-    assert (engine.cache_kind, engine.state_layers, engine.row_layers,
-            engine.conv_layers) == ('kv', CONV, ATTN, CONV)
-    assert engine.layer_spans == (0,) and engine.span == 0
+    layout = engine.layout
+    assert (layout.kind, layout.state_layers, layout.row_layers,
+            layout.conv_layers) == ('kv', CONV, ATTN, CONV)
+    assert layout.classes and (layout.full_layers, layout.span) == (ATTN, 0)
     table = engine.reserve_table(5, 3)
     engine.prefill([3, 4, 5, 6, 7], table)
     pool = engine.pool
     layers, _ = pool.arrays()
     assert pool.state_rows.num_rows == engine.slots + 1
-    assert (pool.num_row_layers, pool.num_state_layers) == (ATTN, CONV)
+    assert pool.state_bytes_in_hbm() + pool.bytes_in_hbm() == sum(
+        int(a.nbytes) for arrs in layers.values() for a in arrs)
     width = {'f32': 'float32', 'bf16': 'bfloat16'}[kv_dtype]
     assert [(a.shape, str(a.dtype)) for a in layers[1]] == [
         ((pool.num_blocks, 4, 128), width)] * 2         # 2 heads of 8: a tile
@@ -135,15 +147,16 @@ def test_one_step_books_both_kinds_and_the_spans_carry_both(lm):
             'kv_cache_row_bytes', 'decode_cache_blocks_used')}
         registry = obs.registry.to_dict()
         obs.reset()
+    # the attention layer's live groups: the one read of the step
     walked = engine._blocks_walked([7, 1, 4])
-    assert isinstance(walked, tuple) and walked[0] > 0 and walked[1] == 0
+    assert walked > 0
     # live tokens and live slots alone: 6 + 3 prompt tokens of two 8-row
     # rungs, two of three slots; each counter over ITS layers
     assert folded == (6 + 3) * CONV
     assert after == {'decode_state_updates': 2 * CONV,
                      'decode_state_tokens_folded': (6 + 3) * CONV,
                      'decode_context_positions_read': (7 + 4) * ATTN,
-                     'decode_kv_blocks_read': walked[0] * ATTN,
+                     'decode_kv_blocks_read': walked,
                      'decode_conv_rows': (6 + 3 + 2) * CONV}
     spans = {e['name']: e.get('args') or {} for e in events
              if e.get('ph') == 'X' and e['name'] in ('engine/prefill',
@@ -155,7 +168,7 @@ def test_one_step_books_both_kinds_and_the_spans_carry_both(lm):
     assert spans['engine/prefill']['state_tokens_folded'] == 3 * CONV
     step = spans['engine/step']
     assert (step['state_updates'], step['conv_rows']) == (2 * CONV,) * 2
-    assert step['kv_blocks'] == walked[0] * ATTN > 0
+    assert step['kv_blocks'] == walked > 0
     assert step['context_positions'] == (7 + 4) * ATTN
     # both sets of gauges in one /metrics
     pool = engine.pool
@@ -222,22 +235,23 @@ def test_the_plan_prices_a_hybrid_request(lm):
     budget buys blocks for the row layers alone, after the weights and the
     slots' state rows."""
     row = 2 * kv_row_bytes(2, 8, 'bf16')          # K and V, a 128-lane tile
-    assert plan.decode_layer_counts(lm) == (ATTN, CONV)
-    assert plan.decode_layer_classes(lm) == (ATTN, 0, 0)
-    assert plan.decode_token_layer_bytes(lm, 'bf16') == row == 512
-    assert plan.decode_state_row_bytes(lm) == STATE_ROW
-    assert plan.decode_pool_block_bytes(lm, 4, 'bf16') == ATTN * 4 * row
-    assert plan.decode_context_bytes(lm, 100, 'bf16') == ATTN * 100 * row
-    assert plan.decode_request_bytes(lm, 100, 'bf16') \
+    layout = lm.cache_layout()
+    assert (layout.row_layers, layout.state_layers) == (ATTN, CONV)
+    assert (layout.full_layers, layout.sliding_layers, layout.span) \
+        == (ATTN, 0, 0)
+    assert layout.token_bytes('bf16') == row == 512
+    assert layout.state_row_bytes() == STATE_ROW
+    assert layout.block_bytes(4, 'bf16') == ATTN * 4 * row
+    assert layout.context_bytes(100, 'bf16') == ATTN * 100 * row
+    assert layout.request_bytes(100, 'bf16') \
         == ATTN * 100 * row + STATE_ROW
     weights = sum(int(p.value.nbytes) for p in lm.parameters())
     with pytest.raises(ValueError, match='slots'):
-        plan.solve_decode_pool_blocks(lm, 1, block_size=4, kv_dtype='bf16')
-    blocks = plan.solve_decode_pool_blocks(lm, 1, block_size=4,
-                                           kv_dtype='bf16', slots=3)
+        solve_decode_pool_blocks(lm, 1, block_size=4, kv_dtype='bf16')
+    blocks = solve_decode_pool_blocks(lm, 1, block_size=4, kv_dtype='bf16',
+                                      slots=3)
     assert blocks == ((1 << 20) - weights - 4 * STATE_ROW) // (4 * row)
-    doc = plan.decode_pool_report(lm, 1, block_size=4, kv_dtype='bf16',
-                                  slots=3)
+    doc = decode_pool_report(lm, 1, block_size=4, kv_dtype='bf16', slots=3)
     assert doc['num_blocks'] == blocks and doc['state_row_bytes'] == STATE_ROW
     assert doc['block_bytes'] == 4 * row and 'state_slots' not in doc
 
@@ -248,12 +262,12 @@ def test_the_budget_knob_sizes_a_hybrid_engine(lm, monkeypatch):
     engine = DecodeEngine(lm, slots=3, block_size=4, max_prompt_len=32,
                           max_new_tokens_cap=24, prompt_buckets=[32],
                           prefix_cache=False, kv_dtype='bf16')
-    assert engine.pool.num_blocks == plan.solve_decode_pool_blocks(
+    assert engine.pool.num_blocks == solve_decode_pool_blocks(
         lm, 1, block_size=4, kv_dtype='bf16', min_blocks=15, slots=3)
 
 
 def test_the_published_cell_is_priced_from_the_configuration_file():
-    """The cell's own numbers from the model's spec at the published
+    """The cell's own numbers from the model's layout at the published
     widths, shapes alone: 6,144 B a token over the 3 attention layers,
     163,840 B of state a request over the 10 conv layers, 3.52 GB of pool
     and 21 MB of state rows at 128 slots."""
@@ -285,12 +299,13 @@ def test_the_published_cell_is_priced_from_the_configuration_file():
     assert round(parameters / 1e9, 3) == 4.606
     assert {str(s.dtype) for n, s in shapes.items()
             if 'router_bias' not in n} == {'bfloat16'}
-    assert plan.decode_layer_counts(model) == (3, 10)
-    assert 3 * plan.decode_token_layer_bytes(model, 'bf16') == 6144
-    assert plan.decode_state_row_bytes(model) == 10 * 2 * 2048 * 4
-    pool = engine['max_blocks'] * plan.decode_pool_block_bytes(
-        model, engine['block_size'], 'bf16')
-    states = (engine['slots'] + 1) * plan.decode_state_row_bytes(model)
+    layout = model.cache_layout()
+    assert (layout.row_layers, layout.state_layers) == (3, 10)
+    assert 3 * layout.token_bytes('bf16') == 6144
+    assert layout.state_row_bytes() == 10 * 2 * 2048 * 4
+    pool = engine['max_blocks'] * layout.block_bytes(engine['block_size'],
+                                                     'bf16')
+    states = layout.state_rows(engine['slots']) * layout.state_row_bytes()
     assert round(pool / 1e9, 2) == 3.52 and round(states / 1e6) == 21
-    assert plan.decode_request_bytes(model, 4480, 'bf16') \
+    assert layout.request_bytes(4480, 'bf16') \
         == 4480 * 6144 + 163840

@@ -133,11 +133,13 @@ def test_the_model_is_what_the_file_says():
             'operator.taps'}
     assert not [n for n in names if 'bias' in n and 'router_bias' not in n]
     assert not [n for n in names if 'shared' in n]
-    spec = model.kv_cache_spec()
-    assert spec['layer_kinds'] == ('state', 'kv', 'state', 'state')
-    assert spec['layer_spans'] == (0,) and spec['kind'] == 'kv'
-    assert (spec['heads'], spec['head_dim']) == (2, 8)
-    assert spec['state_block'] == (1, 2, 32)
+    layout = model.cache_layout()
+    assert tuple(layer.kind for layer in layout.layers) == (
+        'state', 'kv', 'state', 'state')
+    assert layout.kind == 'kv' and layout.reads == (('groups', 1),)
+    assert layout.layers[1].shape == (2, 8) and layout.layers[1].span == 0
+    assert {layer.shape for layer in layout.layers
+            if layer.kind == 'state'} == {(1, 2, 32)}
 
 
 def test_whole_sequence_logits_equal_the_reference():
@@ -347,7 +349,8 @@ def test_served_over_http_on_the_normal_path():
             prefix_cache=False, disagg=False, spec_decode=False)
         assert isinstance(scheduler, DecodeScheduler)
         assert isinstance(engine.model, HybridConvMoELM)
-        assert (engine.state_layers, engine.row_layers) == (3, 1)
+        assert (engine.layout.state_layers, engine.layout.row_layers) \
+            == (3, 1)
         server = ServingServer(None, host='127.0.0.1', port=0,
                                generator=scheduler)
         server.start()

@@ -15,8 +15,8 @@ from paddle_tpu.ops.nn_ops import LIVE_BLOCK_CHUNK, _gather_pages
 from paddle_tpu.serving.decode.engine import (DecodeEngine, _arrays_spanning,
                                               _moves_of_size)
 from paddle_tpu.serving.decode.kv_cache import (KV_PAYLOAD_DTYPES,
-                                                KVCachePool, kv_row_bytes,
-                                                row_lanes)
+                                                KVCachePool, row_lanes)
+from paddle_tpu.serving.decode.layout import kv_row_bytes
 
 KV_DTYPES = ['f32', 'bf16', 'int8']
 H, D, BS, NB = 3, 8, 4, 11          # a row of 24 values: padded to 128 lanes
@@ -360,7 +360,8 @@ def test_compiled_for_the_chip_no_program_moves_a_state_array(v5e):
         pool.adopt({k: list(v) for k, v in out[3].items()}, {})
         state = pool.arrays()[0][0][0]
         assert state.shape == (7, 8, 8328, 128)
-        assert pool.num_state_layers == 2 and pool.num_row_layers == 0
+        assert (eng.layout.state_layers, eng.layout.row_layers) == (2, 0)
+        assert len(pool.arrays()[0]) == 2
         for bucket in (None, 256):
             text = eng.lowered(bucket, v5e).compile().as_text()
             assert 'f32[7,8,8328,128]{3,2,1,0' in text

@@ -153,8 +153,7 @@ def test_pool_bytes_per_dtype_and_what_they_buy(lm_head_dim_32):
     the bf16 one exactly half; at one HBM budget the planner solves more
     slots per chip for the smaller rows, and a host tier extends every
     dtype's effective cache."""
-    from paddle_tpu.analysis.plan import (decode_pool_block_bytes,
-                                          solve_decode_pool_blocks)
+    from paddle_tpu.serving.decode.layout import solve_decode_pool_blocks
     model, work = lm_head_dim_32, _ragged_work()
     pool_bytes, slots_per_chip = {}, {}
     refs = None
@@ -174,7 +173,8 @@ def test_pool_bytes_per_dtype_and_what_they_buy(lm_head_dim_32):
         blocks = solve_decode_pool_blocks(model, 1024, block_size=8,
                                           kv_dtype=dtype)
         slots_per_chip[dtype] = blocks // eng.pool.max_blocks_per_seq
-        host_blocks = (512 << 20) // decode_pool_block_bytes(model, 8, dtype)
+        host_blocks = (512 << 20) // model.cache_layout().block_bytes(
+            8, dtype)
         assert host_blocks > 0                 # the tier adds to `blocks`
     assert pool_bytes['f32'] / pool_bytes['int8'] >= 3.5, pool_bytes
     assert pool_bytes['bf16'] * 2 == pool_bytes['f32']
@@ -254,12 +254,11 @@ def test_legacy_three_int_meta_parses_as_f32():
 # -- planner-backed pool sizing --------------------------------------------
 
 def test_budget_solve_matches_closed_form(lm):
-    from paddle_tpu.analysis.plan import (decode_pool_block_bytes,
-                                          decode_pool_report,
-                                          solve_decode_pool_blocks)
+    from paddle_tpu.serving.decode.layout import (decode_pool_report,
+                                                  solve_decode_pool_blocks)
     state = sum(getattr(p, 'value', p).nbytes for p in lm.parameters())
     for dtype in ('f32', 'bf16', 'int8'):
-        block_bytes = decode_pool_block_bytes(lm, 4, dtype)
+        block_bytes = lm.cache_layout().block_bytes(4, dtype)
         closed = ((8 << 20) - state) // block_bytes
         solved = solve_decode_pool_blocks(lm, 8, block_size=4,
                                           kv_dtype=dtype)
@@ -273,7 +272,7 @@ def test_budget_solve_matches_closed_form(lm):
 
 
 def test_budget_sizes_engine_pool(lm, monkeypatch):
-    from paddle_tpu.analysis.plan import solve_decode_pool_blocks
+    from paddle_tpu.serving.decode.layout import solve_decode_pool_blocks
     monkeypatch.setenv('PADDLE_TPU_DECODE_HBM_MB', '8')
     eng = make_engine(lm, max_blocks=None)
     expect = solve_decode_pool_blocks(
@@ -290,7 +289,7 @@ def test_explicit_max_blocks_wins_over_budget(lm, monkeypatch):
 
 
 def test_budget_smaller_than_state_raises(lm):
-    from paddle_tpu.analysis.plan import solve_decode_pool_blocks
+    from paddle_tpu.serving.decode.layout import solve_decode_pool_blocks
     with pytest.raises(ValueError, match='model state'):
         solve_decode_pool_blocks(lm, 0, block_size=4)
 

@@ -125,7 +125,7 @@ def test_prefill_then_decode_through_the_latent_pool(lm, params, kv_dtype,
     for arrs in layers.values():
         assert [a.shape for a in arrs] == [(64, 4, 128)]
     assert engine.pool.row_bytes() == 128 * (4 if kv_dtype == 'f32' else 2)
-    assert engine.cache_kind == 'latent'
+    assert engine.layout.kind == 'latent'
     for table in tables:
         engine.release_table(table)
 
@@ -707,13 +707,12 @@ def test_infer_rules_refuse_shapes_that_cannot_agree(op_type, change, match):
 
 @pytest.mark.parametrize('kind', ['latent', 'kv'])
 def test_the_budget_solve_prices_what_the_model_caches(lm, kind):
-    from paddle_tpu.analysis.plan import (decode_pool_block_bytes,
-                                          decode_pool_report,
-                                          solve_decode_pool_blocks)
+    from paddle_tpu.serving.decode.layout import (decode_pool_report,
+                                                  solve_decode_pool_blocks)
     if kind == 'latent':
         model, per_token = lm, {'f32': 128 * 4, 'bf16': 128 * 2}
         with pytest.raises(ValueError, match='int8'):
-            decode_pool_block_bytes(lm, 4, 'int8')
+            lm.cache_layout().block_bytes(4, 'int8')
     else:
         from paddle_tpu.serving.tier.replica import build_tiny_lm
         with guard():
@@ -726,26 +725,27 @@ def test_the_budget_solve_prices_what_the_model_caches(lm, kind):
     layers = model.cfg.num_hidden_layers
     state = sum(p.value.nbytes for p in model.parameters())
     for dtype, row in per_token.items():
-        assert decode_pool_block_bytes(model, 4, dtype) == layers * 4 * row
+        assert model.cache_layout().block_bytes(4, dtype) \
+            == layers * 4 * row
         blocks = solve_decode_pool_blocks(model, 8, block_size=4,
                                           kv_dtype=dtype)
         assert blocks == ((8 << 20) - state) // (layers * 4 * row)
         report = decode_pool_report(model, 8, block_size=4, kv_dtype=dtype)
         assert report['row_bytes'] == row
-        assert report['kv_cache']['kind'] == kind
+        assert report['kv_cache'] == kind
     # what the engine's pool then holds is what the solve priced
     engine = DecodeEngine(model, slots=2, block_size=4, max_blocks=32,
                           max_prompt_len=8, max_new_tokens_cap=4,
                           prefix_cache=False, kv_dtype='bf16')
     engine.warmup()
-    assert engine.pool.bytes_in_hbm() == 32 * decode_pool_block_bytes(
-        model, 4, 'bf16')
+    assert engine.pool.bytes_in_hbm() == 32 * model.cache_layout(
+        ).block_bytes(4, 'bf16')
 
     class Bare:
         def parameters(self):
             return []
 
-    with pytest.raises(ValueError, match='kv_cache_spec'):
+    with pytest.raises(ValueError, match='cache_layout'):
         solve_decode_pool_blocks(Bare(), 8, block_size=4)
 
 

@@ -93,9 +93,9 @@ def test_the_tiny_preset_has_grouped_heads_and_gates_near_one(lm):
     assert {'layers.0.attn.q_norm.weight', 'layers.0.attn.k_norm.weight',
             'layers.0.attn.gate.weight', 'layers.2.ffn.down.weight',
             'head.weight', 'embed.weight'} <= names
-    assert lm.kv_cache_spec() == {
-        'kind': 'state', 'layers': 3, 'heads': 2, 'head_dim': 8,
-        'state_rows': PROW}
+    from paddle_tpu.serving.decode.layout import CacheLayout, LayerCache
+    assert lm.cache_layout() == CacheLayout(
+        (LayerCache.state((2, PROW, 8), 'retention'),) * 3)
 
 
 def test_whole_sequence_agrees_with_the_reference(lm, params, rows):
@@ -163,9 +163,9 @@ def test_prefill_then_decode_through_the_state_cache(lm, params, rows,
     for arrs in layers.values():
         assert [(a.shape, str(a.dtype)) for a in arrs] == [
             ((4, 2, PROW, 8), 'float32')]
-    assert engine.cache_kind == 'state'
-    assert engine.pool.num_state_layers == 3
-    assert engine.pool.num_row_layers == 0
+    assert engine.layout.kind == 'state'
+    assert engine.layout.state_layers == 3
+    assert engine.layout.row_layers == 0
     assert engine.pool.bytes_in_hbm() == 0 and engine.pool.row_bytes() == 0
     assert engine.pool.state_bytes_in_hbm() == 3 * 4 * 2 * PROW * 8 * 4
     for table in tables:
@@ -546,12 +546,11 @@ def test_infer_rules_refuse_shapes_that_cannot_agree(op_type, change, match):
 # -- the budget solve prices a state row -------------------------------------
 
 def test_the_budget_solve_prices_a_state_row_per_slot(lm):
-    from paddle_tpu.analysis.plan import (decode_pool_report,
-                                          decode_state_row_bytes,
-                                          solve_decode_pool_blocks,
-                                          solve_decode_state_slots)
+    from paddle_tpu.serving.decode.layout import (decode_pool_report,
+                                                  solve_decode_pool_blocks,
+                                                  solve_decode_state_slots)
     row = 3 * 2 * PROW * 8 * 4                 # layers x heads x P x d x 4
-    assert decode_state_row_bytes(lm) == row
+    assert lm.cache_layout().state_row_bytes() == row
     state = sum(int(p.value.nbytes) for p in lm.parameters())
     # slots + 1 rows fit the budget beside the weights
     budget_mb = -(-(state + 5 * row) // (1 << 20))
@@ -565,7 +564,7 @@ def test_the_budget_solve_prices_a_state_row_per_slot(lm):
     assert solve_decode_pool_blocks(lm, budget_mb, block_size=4,
                                     min_blocks=9) == 9
     doc = decode_pool_report(lm, budget_mb, block_size=4, min_blocks=9)
-    assert doc['kv_cache']['kind'] == 'state'
+    assert doc['kv_cache'] == 'state'
     assert doc['block_bytes'] == 0 and doc['row_bytes'] == 0
     assert doc['state_row_bytes'] == row and doc['state_slots'] == slots
     with pytest.raises(ValueError, match='float32'):
@@ -575,4 +574,4 @@ def test_the_budget_solve_prices_a_state_row_per_slot(lm):
     with guard():
         gpt = TransformerLM(CausalLMConfig.tiny())
     with pytest.raises(ValueError, match='state'):
-        decode_state_row_bytes(gpt)
+        gpt.cache_layout().state_row_bytes()

@@ -13,7 +13,6 @@ import numpy as np
 import pytest
 
 from paddle_tpu import dygraph
-from paddle_tpu.analysis import plan
 from paddle_tpu.models.latent_moe_lm import LatentMoEConfig, RoutedExperts
 from paddle_tpu.models.sliding_moe_lm import (SlidingMoEConfig, SlidingMoELM,
                                               span_mask_bias)
@@ -22,6 +21,8 @@ from paddle_tpu.serving.decode.engine import (SLIDING_SPARE_BLOCKS,
 from paddle_tpu.serving.decode.kv_cache import (BlockTable, KVCachePool,
                                                 decode_coords,
                                                 prefill_coords)
+from paddle_tpu.serving.decode.layout import (model_state_bytes,
+                                              solve_decode_pool_blocks)
 from paddle_tpu.serving.errors import OutOfBlocks, UnsupportedCacheFeature
 
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), '..', '..'))
@@ -122,7 +123,7 @@ def test_prefill_and_decode_through_both_classes_equal_the_reference(
     with dygraph.guard():
         model = _model(2, peaked=True)
         eng = _engine(model)
-        assert (eng.span, eng.pool.ring) == (SPAN, SPAN // BLOCK + 1)
+        assert (eng.layout.span, eng.pool.ring) == (SPAN, SPAN // BLOCK + 1)
         rng = np.random.RandomState(prompt_len)
         prompt = rng.randint(1, 96, prompt_len).tolist()
         rows = []
@@ -180,7 +181,7 @@ def test_a_window_one_block_short_shows(monkeypatch):
     with dygraph.guard():
         model = _model(2, peaked=True)
         eng = _engine(model)
-        assert eng.span == SPAN - BLOCK
+        assert eng.layout.span == SPAN - BLOCK
         prompt = np.random.RandomState(5).randint(1, 96, 20).tolist()
         rows = []
         table = eng.reserve_table(20, 2)
@@ -292,32 +293,34 @@ def test_the_coordinates_place_every_position_in_its_ring_block():
 def test_the_pool_is_sized_per_class_from_the_spec():
     with dygraph.guard():
         model = _model(8)
-        spec = model.kv_cache_spec()
-        assert spec['kind'] == 'kv' and spec['layer_spans'] == (8, 8, 8, 0)
-        assert 'window' not in spec
+        layout = model.cache_layout()
+        assert layout.kind == 'kv' and tuple(
+            layer.span for layer in layout.layers) == (8, 8, 8, 0)
+        assert layout.window == 1
         eng = _engine(model, slots=5, max_blocks=99)
-        assert eng.cache_kind == 'kv' and eng.window == 1
+        assert eng.layout == layout and eng.window == 1
         pool = eng.pool
         assert pool.geometry == (4, 99, 14, 'f32', 0, 8,
                                  5 * 3 + SLIDING_SPARE_BLOCKS)
         eng.prefill([1, 2, 3], eng.reserve_table(3, 1))
         layers, _ = pool.arrays()
         assert [layers[i][0].shape[0] for i in range(4)] == [23, 23, 23, 99]
-        assert plan.decode_layer_classes(model) == (1, 3, 8)
-        row = plan.decode_token_layer_bytes(model)
+        assert (layout.full_layers, layout.sliding_layers, layout.span) \
+            == (1, 3, 8)
+        row = layout.token_bytes()
         assert row == 2 * 128 * 4
-        assert plan.decode_context_bytes(model, 5) == row * 5 * 4
-        assert plan.decode_context_bytes(model, 40) == row * (40 + 3 * 8)
-        assert plan.decode_pool_block_bytes(model, 4) == row * 4
-        assert plan.decode_sliding_class_bytes(model, 5, 4) == \
+        assert layout.context_bytes(5) == row * 5 * 4
+        assert layout.context_bytes(40) == row * (40 + 3 * 8)
+        assert layout.block_bytes(4) == row * 4
+        assert layout.sliding_class_bytes(5, 4) == \
             3 * 23 * 4 * row == sum(int(a.nbytes) for i in range(3)
                                     for a in layers[i])
         assert pool.bytes_in_hbm() == 3 * 23 * 4 * row + 99 * 4 * row
         with pytest.raises(ValueError, match='slots'):
-            plan.solve_decode_pool_blocks(model, 64, 4)
-        state = plan._model_state_bytes(model)
+            solve_decode_pool_blocks(model, 64, 4)
+        state = model_state_bytes(model)
         budget_mb = (state + 3 * 23 * 4 * row + 50 * 4 * row) // 2 ** 20 + 1
-        blocks = plan.solve_decode_pool_blocks(model, budget_mb, 4, slots=5)
+        blocks = solve_decode_pool_blocks(model, budget_mb, 4, slots=5)
         assert 50 <= blocks < 50 + 2 ** 20 // (4 * row) + 1
 
 

@@ -253,35 +253,40 @@ WALKED = [1, 4, 5, 32, 33, 127, 128, 129]
 
 @pytest.mark.parametrize('kind', ['latent', 'window', 'sliding', 'hybrid'])
 def test_the_engine_counts_the_blocks_the_kernel_copies(monkeypatch, kind):
-    """Where the kernel runs (its predicate patched true in the engine, which
-    asks it of the pool's dtype) `_blocks_walked` is the live groups times
-    the blocks a group holds, by hand, with no rounding to whole chunks:
-    blocks of 4 and tables of 32 blocks make a group 32 blocks (128 keys),
-    of which the contexts hold 9; a sliding layer's ring of 3 blocks makes
-    its group 3 blocks (12 keys), of which the window of 8 touches 9. Off
-    the chip it is the walk's whole chunks of groups, above that count."""
+    """Where the kernel runs (its predicate patched true in ops/nn_ops.py,
+    which an engine asks of its pool's dtype as it is built)
+    `_blocks_walked` is, over each read's layers, the live groups times the
+    blocks a group holds, by hand, with no rounding to whole chunks: blocks
+    of 4 and tables of 32 blocks make a group 32 blocks (128 keys), of
+    which the contexts hold 9; a sliding layer's ring of 3 blocks makes its
+    group 3 blocks (12 keys), of which the window of 8 touches 9. Off the
+    chip it is the walk's whole chunks of groups, above that count."""
     with dygraph.guard():
-        engine = _engine(_model(kind), slots=len(WALKED), block_size=4,
-                         max_blocks=len(WALKED) * 32 + 8,
-                         max_new_tokens_cap=112)
-        assert engine.pool.max_blocks_per_seq == 32
-        walk = engine._blocks_walked(WALKED)
-        monkeypatch.setattr(engine_module, 'group_read_kernel_applies',
+        model = _model(kind)
+
+        def engine():
+            return _engine(model, slots=len(WALKED), block_size=4,
+                           max_blocks=len(WALKED) * 32 + 8,
+                           max_new_tokens_cap=112)
+        walking = engine()
+        assert walking.pool.max_blocks_per_seq == 32
+        walk = walking._blocks_walked(WALKED)
+        monkeypatch.setattr(nn_ops, 'group_read_kernel_applies',
                             lambda q, pages: True)
-        copied = engine._blocks_walked(WALKED)
+        copied = engine()._blocks_walked(WALKED)
+    full, sliding = walking.layout.full_layers, walking.layout.sliding_layers
+    assert (full, sliding) == {'latent': (3, 0), 'window': (3, 0),
+                               'sliding': (1, 3), 'hybrid': (1, 0)}[kind]
     groups = sum(-(-c // 128) for c in WALKED)
     assert groups == 1 + 1 + 1 + 1 + 1 + 1 + 1 + 2
     # a sliding layer attends [max(0, c - 8), c): groups of 12 keys
     # (c - 1) // 12 through max(c - 8, 0) // 12
     window = sum((c - 1) // 12 - max(c - 8, 0) // 12 + 1 for c in WALKED)
     assert window == 1 + 1 + 1 + 1 + 1 + 2 + 1 + 1
-    want = {'latent': 9 * 32, 'window': 9 * 32, 'sliding': (9 * 32, 9 * 3),
-            'hybrid': (9 * 32, 0)}[kind]
-    assert copied == want
+    assert copied == full * 9 * 32 + sliding * 9 * 3
     # the walk: chunks of min(128, 8 slots x 1 group) = 8 groups, and of
     # min(128, 8 slots x 2 groups) = 16 a ring
-    assert walk == {'latent': 16 * 32, 'window': 16 * 32,
-                    'sliding': (16 * 32, 16 * 3), 'hybrid': (16 * 32, 0)}[kind]
+    assert walk == full * 16 * 32 + sliding * 16 * 3
 
 
 def test_a_latent_step_books_the_kernels_blocks(monkeypatch):
@@ -292,8 +297,8 @@ def test_a_latent_step_books_the_kernels_blocks(monkeypatch):
     where the walk would read a whole chunk of 8."""
     from paddle_tpu import observability as obs
     from paddle_tpu.serving import metrics as m
-    monkeypatch.setattr(engine_module, 'group_read_kernel_applies',
-                        lambda q, pages: True)
+    # the engine counts as the kernel would copy; its read stays the walk
+    monkeypatch.setattr(engine_module, 'group_walk_pads', lambda dtype: False)
     with dygraph.guard():
         model = _model('latent')
         engine = _engine(model, slots=4, block_size=4, max_blocks=4 * 64 + 8,

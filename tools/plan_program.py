@@ -15,9 +15,9 @@ Milliseconds, zero tracing — nothing is compiled or executed.
         --kv-dtype int8
 
 ``--decode-pool-mb MB`` prints the decode KV pool sizing solve
-(``analysis.plan.decode_pool_report``): the same arithmetic the engine
-runs for ``PADDLE_TPU_DECODE_HBM_MB`` — model state subtracted from the
-budget, the remainder divided by per-block KV bytes at ``--kv-dtype`` —
+(``serving.decode.layout.decode_pool_report``): the same arithmetic the
+engine runs for ``PADDLE_TPU_DECODE_HBM_MB`` — model state subtracted from
+the budget, the remainder divided by per-block KV bytes at ``--kv-dtype`` —
 so the pool a budget buys is inspectable before serving starts.
 
 ``--budget MB`` gates the exit code: 1 when the predicted peak exceeds
@@ -45,7 +45,7 @@ if _TOOLS not in sys.path:
 
 def _decode_pool_doc(args):
     """The itemized PADDLE_TPU_DECODE_HBM_MB solve, as a plain dict."""
-    from paddle_tpu.analysis.plan import decode_pool_report
+    from paddle_tpu.serving.decode.layout import decode_pool_report
     from paddle_tpu.models.causal_lm import CausalLMConfig, TransformerLM
     cfg = (CausalLMConfig.tiny() if args.decode_model == 'tiny'
            else CausalLMConfig())
