@@ -567,13 +567,23 @@ def _c_moe_router(ctx):
 @cost_rule('moe_experts')
 def _c_moe_experts(ctx):
     a = ctx.assume_dim
-    gate = ctx.input('w_gate')
-    h, f = _pdim(gate, 1, a), _pdim(gate, 2, a)
+    up = ctx.input('w_up')
+    h, f = _pdim(up, 1, a), _pdim(up, 2, a)
     assignments = ctx.in_elems('ids')
     # with experts_held (a share of the router's experts) this is the
     # bound: which assignments fall on the held experts a static rule
     # cannot know
+    if ctx.attr('activation', 'silu') == 'relu2':
+        return assignments * (4 * h * f + 2 * f + 2 * h)
     return assignments * (6 * h * f + (TRANSCENDENTAL_FLOPS + 1) * f + 2 * h)
+
+
+@cost_rule('relu2_ffn')
+def _c_relu2_ffn(ctx):
+    f = _pdim(ctx.input('w_up'), 1, ctx.assume_dim)
+    rows = ctx.in_elems('x') // max(1, _pdim(ctx.input('w_up'), 0,
+                                            ctx.assume_dim))
+    return 4 * ctx.in_elems('x') * f + 2 * rows * f
 
 
 def _mla_dims(ctx):
@@ -657,6 +667,41 @@ def _c_short_conv(ctx):
     # per row and channel: u = B·z, L taps' products and their sum, the gate
     taps = _pdim(ctx.input('w'), 0, ctx.assume_dim)
     return ctx.in_elems('x') // 3 * (2 * taps + 1)
+
+
+def _ssm_dims(ctx):
+    a = ctx.assume_dim
+    heads = _pdim(ctx.input('dt_bias'), 0, a)
+    p, groups = int(ctx.attr('head_dim', 1)), int(ctx.attr('groups', 1))
+    conv = ctx.input('conv_w')
+    conv_dim, taps = _pdim(conv, 1, a), _pdim(conv, 0, a)
+    n = max(1, (conv_dim - heads * p) // max(1, 2 * groups))
+    rows = ctx.in_elems('x') // max(1, _pdim(ctx.input('w_in'), 0, a))
+    width = _pdim(ctx.input('w_in'), 1, a)
+    # per row: W_in, the filter and its silu, Δ, the gate and the norm
+    per_row = 2 * ctx.in_elems('x') // max(1, rows) * width \
+        + conv_dim * (2 * taps + TRANSCENDENTAL_FLOPS + 1) \
+        + heads * p * (TRANSCENDENTAL_FLOPS + 6)
+    return rows, heads, p, n, per_row
+
+
+@cost_rule('ssm_scan_step')
+def _c_ssm_step(ctx):
+    # per slot and head: the decay and rank-one update of S (P × N) and its
+    # read against C
+    rows, heads, p, n, per_row = _ssm_dims(ctx)
+    return rows * (per_row + heads * (5 * p * n + TRANSCENDENTAL_FLOPS))
+
+
+@cost_rule('ssm_scan_prefill')
+def _c_ssm_prefill(ctx):
+    # as computed, per row and head: the chunk's quadratic form over its Q
+    # rows (C·B shared by a group's heads, the decayed weights times x), the
+    # start state's read and the row's part of the next state
+    rows, heads, p, n, per_row = _ssm_dims(ctx)
+    length = _pdim(ctx.input('x'), 1, ctx.assume_dim)
+    q = min(int(ctx.attr('chunk', 128)), length)
+    return rows * (per_row + heads * (2 * q * p + 3 * q + 4 * p * n))
 
 
 # ---------------------------------------------------------------------------

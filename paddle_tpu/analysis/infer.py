@@ -1563,6 +1563,16 @@ def _swiglu_ffn(ctx):
     return {'Out': VarInfo(x.shape, x.dtype)}
 
 
+@infer_rule('relu2_ffn')
+def _relu2_ffn(ctx):
+    x = ctx.require('x')
+    _contracts('relu2_ffn x against w_up', _dim(x, -1),
+               _dim(ctx.require('w_up'), 0))
+    _contracts('relu2_ffn w_down against x', _dim(ctx.require('w_down'), 1),
+               _dim(x, -1))
+    return {'Out': VarInfo(x.shape, x.dtype)}
+
+
 @infer_rule('sigmoid_gate')
 def _sigmoid_gate(ctx):
     x, gate = ctx.require('x'), ctx.require('gate')
@@ -1596,22 +1606,30 @@ def _moe_router(ctx):
 
 @infer_rule('moe_experts')
 def _moe_experts(ctx):
-    x, gate = ctx.require('x'), ctx.require('w_gate')
-    if gate.shape is not None and len(gate.shape) != 3:
-        raise InferError(f'moe_experts expects w_gate of rank 3 (E, h, f), '
-                         f'got rank {len(gate.shape)}')
-    _contracts('moe_experts x against w_gate', _dim(x, -1), _dim(gate, 1))
+    x, up = ctx.require('x'), ctx.require('w_up')
+    gate = ctx.input('w_gate')
+    activation = ctx.attr('activation', 'silu')
+    if (activation == 'silu') != (gate is not None) \
+            or activation not in ('silu', 'relu2'):
+        raise InferError(f'moe_experts activation={activation!r}: \'silu\' '
+                         f'takes w_gate, \'relu2\' none', kind='bad-attr')
+    first = up if gate is None else gate
+    if first.shape is not None and len(first.shape) != 3:
+        raise InferError(f'moe_experts expects w_up of rank 3 (E, h, f), '
+                         f'got rank {len(first.shape)}')
+    _contracts('moe_experts x against w_up', _dim(x, -1), _dim(first, 1))
     _contracts('moe_experts w_down against x', _dim(ctx.require('w_down'), 2),
                _dim(x, -1))
     held = ctx.attr('experts_held', None)
     if held is not None:
-        first, count = (int(n) for n in held)
-        if first < 0 or count < 1 or not dims_agree(_dim(gate, 0), count):
+        first_held, count = (int(n) for n in held)
+        if first_held < 0 or count < 1 \
+                or not dims_agree(_dim(first, 0), count):
             raise InferError(
                 f'moe_experts experts_held={tuple(held)!r}: the weights are '
-                f'of {_dim(gate, 0)} experts', kind='bad-attr')
+                f'of {_dim(first, 0)} experts', kind='bad-attr')
     return {'Out': VarInfo(x.shape, x.dtype),
-            'Counts': VarInfo((_dim(gate, 0),), 'int32')}
+            'Counts': VarInfo((_dim(first, 0),), 'int32')}
 
 
 def _mla_out(ctx, what):
@@ -1747,6 +1765,64 @@ def _short_conv_step(ctx):
             f'short_conv_step: state rows of {state.shape[1:]} are not the '
             f'block {block} of these taps')
     _contracts('short_conv_step rows against slots',
+               _dim(ctx.require('rows'), 0), _dim(ctx.require('x'), 0))
+    return {'Out': out, 'State': VarInfo(state.shape, 'float32')}
+
+
+def _ssm(ctx, what):
+    """((.., T, H·P) of a Mamba-2 mixer, its (P·N + W, H) state block): x
+    (.., T, h) under W_in (h, 2·H·P + 2·G·N + H), taps (K, conv_dim)."""
+    x, w_in, conv = ctx.require('x'), ctx.require('w_in'), ctx.require(
+        'conv_w')
+    if x.shape is not None and len(x.shape) != 3:
+        raise InferError(f'{what} expects x of rank 3 (.., T, h), got rank '
+                         f'{len(x.shape)}')
+    _contracts(f'{what} x against w_in', _dim(x, -1), _dim(w_in, 0))
+    heads, p = _dim(ctx.require('dt_bias'), 0), int(ctx.require_attr(
+        'head_dim'))
+    groups = int(ctx.require_attr('groups'))
+    conv_dim, taps = _dim(conv, 1), _dim(conv, 0)
+    block = None
+    if known(heads) and known(conv_dim) and known(taps):
+        d_inner = heads * p
+        n = (conv_dim - d_inner) // (2 * groups) if groups > 0 else 0
+        if n < 1 or d_inner + 2 * groups * n != conv_dim \
+                or heads % max(groups, 1):
+            raise InferError(f'{what}: a filter over {conv_dim} channels is '
+                             f'no x of {heads} heads of {p} and B, C of '
+                             f'{groups} groups', kind='bad-attr')
+        _contracts(f'{what} w_in against z | xBC | dt', _dim(w_in, 1),
+                   d_inner + conv_dim + heads)
+        if taps < 2:
+            raise InferError(f'{what}: a filter of {taps} tap(s) keeps no '
+                             f'state')
+        from ..ops.llm_ops import ssm_state_width
+        block = (sum(ssm_state_width(heads, p, n, conv_dim, taps)), heads)
+    out = VarInfo((_dim(x, 0), _dim(x, 1), _mul_dims(heads, p)), x.dtype)
+    return out, block
+
+
+@infer_rule('ssm_scan_prefill')
+def _ssm_scan_prefill(ctx):
+    out, block = _ssm(ctx, 'ssm_scan_prefill')
+    if int(ctx.attr('chunk', 128)) < 1:
+        raise InferError('ssm_scan_prefill: chunk must be positive',
+                         kind='bad-attr')
+    state = None if block is None else (_dim(ctx.require('x'), 0),) + block
+    return {'Out': out, 'State': VarInfo(state, 'float32')}
+
+
+@infer_rule('ssm_scan_step')
+def _ssm_scan_step(ctx):
+    out, block = _ssm(ctx, 'ssm_scan_step')
+    state = ctx.require('state')
+    if state.shape is not None and block is not None and not (
+            len(state.shape) == 3 and all(
+                dims_agree(a, b) for a, b in zip(state.shape[1:], block))):
+        raise InferError(
+            f'ssm_scan_step: state rows of {state.shape[1:]} are not the '
+            f'block {block} of these heads')
+    _contracts('ssm_scan_step rows against slots',
                _dim(ctx.require('rows'), 0), _dim(ctx.require('x'), 0))
     return {'Out': out, 'State': VarInfo(state.shape, 'float32')}
 

@@ -147,6 +147,20 @@ class RMSNorm(Layer):
                            {'epsilon': self._eps})
 
 
+class Relu2FFN(Layer):
+    """The non-gated feed-forward `w_down(relu(x · w_up)²)` (`nemotron_h`'s
+    shared expert)."""
+
+    def __init__(self, cfg, width):
+        super().__init__()
+        self.up = _linear(cfg, cfg.hidden_size, width)
+        self.down = _linear(cfg, width, cfg.hidden_size)
+
+    def forward(self, x):
+        return dispatch_op('relu2_ffn', {'x': x, 'w_up': self.up.weight,
+                                         'w_down': self.down.weight}, {})
+
+
 class GatedFFN(Layer):
     def __init__(self, cfg, width):
         super().__init__()
@@ -175,7 +189,16 @@ class RoutedExperts(Layer):
     that its own experts give. The shares of a layer, with the shared
     expert counted once, add up to the whole layer. Nothing here stands in
     for the other chips or their exchange. None (the default): every
-    expert is held, and the layer is what it was."""
+    expert is held, and the layer is what it was.
+
+    ``cfg.moe_latent_size`` = ℓ makes the experts LATENT (`nemotron_h`):
+    the router scores the full-width x, the experts run on W_↓ x (h -> ℓ)
+    and their weighted sum comes back through W_↑ (ℓ -> h), under the scope
+    `moe/latent`; the shared expert stays at full width.
+    ``cfg.expert_activation`` = 'relu2' makes every expert, the shared one
+    too, the non-gated `w_down(relu(x · w_up)²)` (no gate weights), the
+    shared one ``cfg.shared_expert_intermediate_size`` wide. Neither set
+    (the default): gated silu experts at full width."""
 
     def __init__(self, cfg):
         super().__init__()
@@ -191,19 +214,34 @@ class RoutedExperts(Layer):
             self.router_bias = self.create_parameter(
                 [e], None, 'float32', default_initializer=NormalInitializer(
                     0.0, cfg.router_bias_scale))
+        latent = getattr(cfg, 'moe_latent_size', None)
+        self.latent = bool(latent)
+        if latent:
+            self.latent_down = _linear(cfg, h, latent)
+            self.latent_up = _linear(cfg, latent, h)
+        width = latent or h         # what an expert reads and returns
+        self.gated = getattr(cfg, 'expert_activation', 'silu') == 'silu'
         self.experts_gate = self.create_parameter(
-            [held, h, f], None, cfg.dtype, default_initializer=normal)
+            [held, width, f], None, cfg.dtype,
+            default_initializer=normal) if self.gated else None
         self.experts_up = self.create_parameter(
-            [held, h, f], None, cfg.dtype, default_initializer=normal)
+            [held, width, f], None, cfg.dtype, default_initializer=normal)
         self.experts_down = self.create_parameter(
-            [held, f, h], None, cfg.dtype, default_initializer=normal)
-        self.shared = GatedFFN(cfg, cfg.n_shared_experts * f) \
-            if cfg.n_shared_experts else None
+            [held, f, width], None, cfg.dtype, default_initializer=normal)
+        if not self.gated:
+            self.shared = Relu2FFN(cfg, cfg.shared_expert_intermediate_size)
+        else:
+            self.shared = GatedFFN(cfg, cfg.n_shared_experts * f) \
+                if cfg.n_shared_experts else None
         self._route = {'top_k': cfg.num_experts_per_tok,
                        'routed_scaling_factor': cfg.routed_scaling_factor,
                        'norm_topk_prob': cfg.norm_topk_prob}
         if scoring != 'sigmoid':    # the sigmoid dispatch stays as it was
             self._route['scoring_func'] = scoring
+        self._experts = {} if self.held is None \
+            else {'experts_held': self.held}
+        if not self.gated:
+            self._experts['activation'] = 'relu2'
         # what the normaliser adds to the chosen scores' sum: a family that
         # says otherwise than the op's 1e-20 names it (`lfm2_moe`: 1e-6)
         if hasattr(cfg, 'router_norm_epsilon'):
@@ -222,12 +260,18 @@ class RoutedExperts(Layer):
             if self.router_bias is not None:
                 router['bias'] = self.router_bias
             ids, weights = dispatch_op('moe_router', router, self._route)
+        inner = flat
+        if self.latent:
+            with jax.named_scope('moe/latent'):
+                inner = self.latent_down(flat)
         with jax.named_scope('moe/experts'):
             routed, counts = dispatch_op('moe_experts', {
-                'x': flat, 'ids': ids, 'weights': weights,
+                'x': inner, 'ids': ids, 'weights': weights,
                 'w_gate': self.experts_gate, 'w_up': self.experts_up,
-                'w_down': self.experts_down},
-                {} if self.held is None else {'experts_held': self.held})
+                'w_down': self.experts_down}, self._experts)
+        if self.latent:
+            with jax.named_scope('moe/latent'):
+                routed = self.latent_up(routed)
         if cache is not None:
             # for the host's counters: the rows each expert was given of the
             # call's LIVE tokens (the work the mathematics needs; `counts`

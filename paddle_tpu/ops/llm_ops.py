@@ -6,18 +6,20 @@ with a kernel and a predicate beside its call, `experts_kernel_applies`),
 latent (MLA) attention in its two forms (expanded over a prompt, absorbed
 over the paged latent cache: the lockstep step's read walks the batch's
 live groups, ops/nn_ops.py), power retention, the gated short convolution
-of a hybrid decoder (a state of L - 1 rows a request), and a
+of a hybrid decoder (a state of L - 1 rows a request), the Mamba-2
+state-space mixer (a state of S and a conv window a request), and a
 block-diffusion forward's pick (a token and the confidence in it per row).
 
 Precision rule, the same in every op: matmuls take their operands as they
 are stored (bf16 weights and activations on the served path) and accumulate
-in float32; norms, the router's scores, softmax and silu are computed in
-float32; an op returns its input's dtype. With float32 operands (the CPU
+in float32; norms, the router's scores, softmax, silu and relu² are computed
+in float32; an op returns its input's dtype. With float32 operands (the CPU
 tests) everything is float32.
 
 The callers name these ops for the device trace (`jax.named_scope` around
-the dispatch: `moe/route`, `moe/experts`, `moe/shared` in
-models/latent_moe_lm.py, `mla/prefill_attention`, `mla/decode_read` in
+the dispatch: `moe/route`, `moe/latent`, `moe/experts`, `moe/shared` in
+models/latent_moe_lm.py, `ssm/prefill`, `ssm/step` in
+models/hybrid_ssm_moe_lm.py, `mla/prefill_attention`, `mla/decode_read` in
 serving/decode/kv_cache.py), which benchmark/lib/scoped_ops.py sums device
 time by. A scope INSIDE an op's function does not survive: every dispatch is
 a jit of its own inside the engine's program, and when XLA inlines it an
@@ -117,6 +119,16 @@ def swiglu_ffn(x, w_gate, w_up, w_down):
     return _dot((jax.nn.silu(g) * u).astype(x.dtype), w_down)
 
 
+@register_op('relu2_ffn')
+def relu2_ffn(x, w_up, w_down):
+    """w_down(relu(x · w_up)²): the non-gated feed-forward of `nemotron_h`;
+    weights (h, f), (f, h); relu² in float32, cast to x's dtype before the
+    second matmul."""
+    x = jnp.asarray(x)
+    u = jnp.matmul(x, w_up, preferred_element_type=_F32)
+    return _dot(jnp.square(jax.nn.relu(u)).astype(x.dtype), w_down)
+
+
 @register_op('sigmoid_gate')
 def sigmoid_gate(x, gate):
     """x ⊙ sigmoid(gate), lane by lane: the gate on an attention layer's
@@ -182,18 +194,23 @@ def experts_kernel_applies(x, w_gate):
 
 
 @register_op('moe_experts', outputs=('Out', 'Counts'))
-def moe_experts(x, ids, weights, w_gate, w_up, w_down, *, experts_held=None):
-    """Σ_k weights[t, k] · E_ids[t, k](x[t]), each expert a gated
-    feed-forward, as grouped matmuls over the T·k assignments sorted by
-    expert. No capacity: every assignment is computed.
+def moe_experts(x, ids, weights, w_gate, w_up, w_down, *, experts_held=None,
+                activation='silu'):
+    """Σ_k weights[t, k] · E_ids[t, k](x[t]), each expert a feed-forward, as
+    grouped matmuls over the T·k assignments sorted by expert. No capacity:
+    every assignment is computed.
+
+    ``activation``: 'silu', a GATED expert `w_down(silu(x · w_gate) ⊙ (x ·
+    w_up))`, or 'relu2', a non-gated one `w_down(relu(x · w_up)²)`
+    (`nemotron_h`), whose ``w_gate`` is None.
 
     On a TPU (`experts_kernel_applies`) the grouped matmuls are the pallas
-    kernel of ops/pallas_moe.py, gate and up in one pass with `silu(g) · u`
-    in its epilogue, then down: an expert's weights are read once and whole
-    for the rows routed to it. Elsewhere one `lax.ragged_dot` a projection.
-    The same precision in both: operands as stored, float32 accumulation,
-    `silu(g) · u` in float32 and cast to x's dtype, the weighted sum in
-    float32.
+    kernel of ops/pallas_moe.py: the first matmul(s) in one pass with the
+    activation in its epilogue, then down: an expert's weights are read
+    once and whole for the rows routed to it. Elsewhere one
+    `lax.ragged_dot` a projection. The same precision in both: operands as
+    stored, float32 accumulation, the activation in float32 and cast to x's
+    dtype, the weighted sum in float32.
 
     ``experts_held`` = (first, count): the layer holds experts [first,
     first + count) of the router's (one chip's share under expert
@@ -209,7 +226,12 @@ def moe_experts(x, ids, weights, w_gate, w_up, w_down, *, experts_held=None):
     assignments)."""
     x = jnp.asarray(x)
     t, k = ids.shape
-    n_experts = w_gate.shape[0]
+    gated = activation == 'silu'
+    if gated != (w_gate is not None) or activation not in ('silu', 'relu2'):
+        raise ValueError(f'moe_experts: activation={activation!r} with '
+                         f'{"a" if w_gate is not None else "no"} w_gate: '
+                         "'silu' takes w_gate, 'relu2' none")
+    n_experts = w_up.shape[0]
     flat = ids.reshape(-1)
     held = None
     if experts_held is not None:
@@ -223,14 +245,19 @@ def moe_experts(x, ids, weights, w_gate, w_up, w_down, *, experts_held=None):
     counts = (flat[:, None] == jnp.arange(n_experts, dtype=flat.dtype)
               ).sum(0, dtype=jnp.int32)
     order = jnp.argsort(flat, stable=True)       # assignments by expert
-    if experts_kernel_applies(x, w_gate):
+    if experts_kernel_applies(x, w_up):
         y = expert_ffn(x, order // k, counts, w_gate, w_up, w_down)
-    else:
+    elif gated:
         rows = x[order // k]                     # (T·k, h)
         g = lax.ragged_dot(rows, w_gate, counts, preferred_element_type=_F32)
         u = lax.ragged_dot(rows, w_up, counts, preferred_element_type=_F32)
         y = lax.ragged_dot((jax.nn.silu(g) * u).astype(x.dtype), w_down,
                            counts, preferred_element_type=_F32)
+    else:
+        u = lax.ragged_dot(x[order // k], w_up, counts,
+                           preferred_element_type=_F32)
+        y = lax.ragged_dot(jnp.square(jax.nn.relu(u)).astype(x.dtype),
+                           w_down, counts, preferred_element_type=_F32)
     y = y[jnp.argsort(order)]                    # back to token order
     if held is not None:
         # a row of nobody is whatever the kernel left there: dropped
@@ -688,3 +715,262 @@ def short_conv_step(x, w, state, rows):
     conv = (taps[None] * window).sum(1, keepdims=True)
     state = state.at[rows].set(window[:, None, 1:])
     return (gate * conv).astype(jnp.asarray(x).dtype), state
+
+
+# ---------------------------------------------------------------------------
+# Mamba-2 state-space mixer (`nemotron_h`): H heads of P values over G groups
+# of N state values, a causal depthwise filter of K taps. Over the mixer's
+# normed input a (the caller's: models/hybrid_ssm_moe_lm.py):
+#
+#     [z | xBC | dt] = a · W_in                 widths H·P | H·P + 2·G·N | H
+#     xBC_t = silu(Σ_{j<K} w[j] ⊙ xBC_{t-(K-1)+j} + b)      (zero before 0)
+#     [x | B | C] = xBC_t;  x (H, P), B and C (G, N); head h reads group
+#                  h // (H / G)
+#     Δ = softplus(dt + dt_bias);  A_h = -exp(A_log_h)
+#     S_t,h = exp(Δ_t,h A_h) S_{t-1},h + Δ_t,h x_t,h ⊗ B_t,g     (P × N)
+#     y_t,h = S_t,h C_t,g + D_h x_t,h
+#     out = GroupRMSNorm(y ⊙ silu(z)) · w_norm   (groups of H·P / G lanes)
+#
+# and W_out after it (the caller's). What a request carries from step to step
+# is S and the last K - 1 rows of xBC before the filter, whatever its
+# context: ONE float32 state block (P·N + W, H) a request, the H heads on
+# the lanes: row p·N + n holds S[h, p, n] of every head, and the last W rows
+# the window's (K - 1)·(H·P + 2·G·N) values, oldest row first, row-major
+# (zero past their count) (`ssm_state_width`, `ssm_state_parts`). With the
+# heads on the lanes a step advances S as it lies (decay and Δ·x are lane
+# vectors, B and C broadcast along them) and the TPU's compact layout of the
+# array is row-major: a slot's row is read and written with no transpose.
+#
+# The prefill is the chunked (SSD) form: chunks of ``chunk`` rows, within a
+# chunk the quadratic form over its rows and the chunk's start state, across
+# chunks the state scanned once a chunk. The step is the recurrence once.
+#
+# Precision: W_in's product is kept in float32 (bf16 operands as stored,
+# float32 accumulation, NOT cast back to the rows' dtype): xBC and dt are
+# what the state is made of, and a bf16 rounding of them would cost the
+# state the bits it is kept in float32 for. The filter, Δ, the decays, the
+# state, the chunk's contractions (at `_STATE_PRECISION`) and the norm are
+# float32; the result returns in a's dtype.
+# ---------------------------------------------------------------------------
+
+def ssm_state_width(heads, head_dim, state_size, conv_dim, kernel):
+    """(P·N, W): the state block's rows of S and of the (K - 1) × conv_dim
+    window, H values a row."""
+    window = (int(kernel) - 1) * int(conv_dim)
+    return int(head_dim) * int(state_size), -(-window // int(heads))
+
+
+def _block_parts(block, head_dim, conv_dim, kernel):
+    """(S (.., P, N, H), window (.., K - 1, conv_dim)) of a (.., P·N + W,
+    H) block, S as it lies."""
+    lead, (rows, heads) = block.shape[:-2], block.shape[-2:]
+    window = (int(kernel) - 1) * int(conv_dim)
+    cells = rows - -(-window // heads)
+    s = block[..., :cells, :].reshape(lead + (int(head_dim), -1, heads))
+    win = block[..., cells:, :].reshape(lead + (-1,))[..., :window]
+    return s, win.reshape(lead + (int(kernel) - 1, int(conv_dim)))
+
+
+def ssm_state_parts(block, head_dim, conv_dim, kernel):
+    """(S (.., H, P, N), window (.., K - 1, conv_dim)) of a (.., P·N + W,
+    H) state block: the block's layout named once, for whoever holds a
+    state to one computed otherwise (a test, the benchmark's check)."""
+    s, window = _block_parts(jnp.asarray(block), head_dim, conv_dim, kernel)
+    return jnp.moveaxis(s, -1, -3), window
+
+
+def _ssm_block(s, window):
+    """S (.., P, N, H) and the window (.., K - 1, conv_dim) as the (.., P·N
+    + W, H) block."""
+    lead, heads = s.shape[:-3], s.shape[-1]
+    flat = window.reshape(lead + (-1,))
+    width = -(-flat.shape[-1] // heads)
+    flat = jnp.pad(flat, [(0, 0)] * len(lead)
+                   + [(0, heads * width - flat.shape[-1])])
+    return jnp.concatenate([s.reshape(lead + (-1, heads)),
+                            flat.reshape(lead + (width, heads))], -2)
+
+
+def _ssm_in(x, w_in, conv_w, dt_bias, head_dim, groups):
+    """The projection's float32 rows split as (z, xBC before the filter,
+    dt + dt_bias), and the sizes (H, P, G, N, conv_dim)."""
+    heads = dt_bias.shape[0]
+    d_inner = heads * int(head_dim)
+    conv_dim = conv_w.shape[1]
+    proj = jnp.matmul(x, w_in, preferred_element_type=_F32)
+    n = (conv_dim - d_inner) // (2 * int(groups))
+    if proj.shape[-1] != d_inner + conv_dim + heads or n < 1:
+        raise ValueError(
+            f'ssm: W_in gives {proj.shape[-1]} columns, not z ({d_inner}) | '
+            f'xBC ({conv_dim}) | dt ({heads}) over {groups} groups')
+    z, xbc = proj[..., :d_inner], proj[..., d_inner:d_inner + conv_dim]
+    dt = proj[..., d_inner + conv_dim:] + jnp.asarray(dt_bias).astype(_F32)
+    return z, xbc, dt, (heads, int(head_dim), int(groups), n, conv_dim)
+
+
+def _ssm_split(xbc, sizes):
+    """x (.., H, P), B and C (.., G, N) of the filtered xBC (.., conv_dim)."""
+    heads, p, g, n, _ = sizes
+    lead = xbc.shape[:-1]
+    d_inner = heads * p
+    return (xbc[..., :d_inner].reshape(lead + (heads, p)),
+            xbc[..., d_inner:d_inner + g * n].reshape(lead + (g, n)),
+            xbc[..., d_inner + g * n:].reshape(lead + (g, n)))
+
+
+def _ssm_out(y, z, norm_w, groups, epsilon, dtype):
+    """GroupRMSNorm(y ⊙ silu(z)) · w_norm over ``groups`` groups of lanes,
+    y and z (.., H·P) float32, in ``dtype``."""
+    g = y * jax.nn.silu(z)
+    lead, width = g.shape[:-1], g.shape[-1]
+    parts = g.reshape(lead + (int(groups), width // int(groups)))
+    inv = lax.rsqrt(jnp.mean(jnp.square(parts), -1, keepdims=True)
+                    + _F32(epsilon))
+    return ((parts * inv).reshape(g.shape)
+            * jnp.asarray(norm_w).astype(_F32)).astype(dtype)
+
+
+def _ssd_scan(xs, bm, cm, delta, a, chunk):
+    """One sequence in chunks: xs (L, H, P), bm, cm (L, G, N), delta (L, H)
+    (0 on a row that must not enter the state), a (H,) = A. Returns y (L, H,
+    P) without the D term, and S (H, P, N) after the last row."""
+    length, heads, p = xs.shape
+    g, n = bm.shape[1:]
+    rep = heads // g
+    chunk = min(int(chunk), length)
+    count = -(-length // chunk)
+    pad = count * chunk - length
+
+    def chunks(v):
+        v = jnp.pad(v, ((0, pad),) + ((0, 0),) * (v.ndim - 1))
+        return v.reshape((count, chunk) + v.shape[1:])
+
+    # heads grouped as (G, R): a head's B and C are its group's
+    xs_c = chunks(xs).reshape(count, chunk, g, rep, p)
+    d_c = chunks(delta).reshape(count, chunk, g, rep)
+    below = jnp.tril(jnp.ones((chunk, chunk), bool))
+
+    def step(state, inputs):
+        xc, bc, cc, dc = inputs
+        logs = dc * a.reshape(g, rep)                         # (Q, G, R)
+        cum = jnp.cumsum(logs, 0)
+        seg = cum[:, None] - cum[None, :]                     # (i, j, G, R)
+        decay = jnp.where(below[:, :, None, None],
+                          jnp.exp(jnp.where(below[:, :, None, None], seg,
+                                            0.0)), 0.0)
+        cb = jnp.einsum('ign,jgn->ijg', cc, bc, precision=_STATE_PRECISION)
+        weights = decay * cb[..., None] * dc[None]            # (i, j, G, R)
+        y = jnp.einsum('ijgr,jgrp->igrp', weights, xc,
+                       precision=_STATE_PRECISION)
+        y = y + jnp.exp(cum)[..., None] * jnp.einsum(
+            'ign,grpn->igrp', cc, state, precision=_STATE_PRECISION)
+        tail = jnp.exp(cum[-1][None] - cum) * dc              # (Q, G, R)
+        state = jnp.exp(cum[-1])[..., None, None] * state + jnp.einsum(
+            'jgr,jgrp,jgn->grpn', tail, xc, bc, precision=_STATE_PRECISION)
+        return state, y
+
+    state, y = lax.scan(step, jnp.zeros((g, rep, p, n), _F32),
+                        (xs_c, chunks(bm), chunks(cm), d_c))
+    return (y.reshape(count * chunk, heads, p)[:length],
+            state.reshape(heads, p, n))
+
+
+@register_op('ssm_scan_prefill', outputs=('Out', 'State'))
+def ssm_scan_prefill(x, w_in, conv_w, conv_b, dt_bias, a_log, d, norm_w,
+                     last=None, *, head_dim, groups, chunk=128,
+                     epsilon=1e-5):
+    """The Mamba-2 mixer from W_in to before W_out over whole sequences (see
+    above), as the chunked (SSD) form.
+
+    x (B, T, h), the mixer's normed rows; w_in (h, 2·H·P + 2·G·N + H);
+    conv_w (K, conv_dim), tap j on position t - (K - 1) + j; conv_b
+    (conv_dim,); dt_bias, a_log, d (H,); norm_w (H·P,); ``last`` () or (B,)
+    int32, the index of a sequence's last row (rows past it are a rung's
+    padding: they enter the scan with Δ = 0, which leaves S as it stood,
+    and the window is taken at ``last``, never at the rung's end; None:
+    every row is live). Returns (B, T, H·P) in x's dtype and the (B, P·N +
+    W, H) float32 states after row ``last``."""
+    x = jnp.asarray(x)
+    z, raw, dt, sizes = _ssm_in(x, w_in, conv_w, dt_bias, head_dim, groups)
+    heads, p, g, n, conv_dim = sizes
+    batch, length = x.shape[:2]
+    taps = jnp.asarray(conv_w).astype(_F32)
+    kernel = taps.shape[0]
+    if last is None:
+        last = length - 1
+    last = jnp.broadcast_to(jnp.asarray(last, jnp.int32), (batch,))
+    up = jnp.pad(raw, ((0, 0), (kernel - 1, 0), (0, 0)))  # raw[i - (K-1)]
+    xbc = jax.nn.silu(sum(taps[j] * up[:, j:j + length] for j in range(kernel))
+                      + jnp.asarray(conv_b).astype(_F32))
+    xs, bm, cm = _ssm_split(xbc, sizes)
+    live = jnp.arange(length, dtype=jnp.int32)[None, :] <= last[:, None]
+    # a dead row's Δ is zeroed by a select: whatever the row holds, it
+    # decays nothing and adds nothing
+    delta = jnp.where(live[..., None], jax.nn.softplus(dt), 0.0)
+    a = -jnp.exp(jnp.asarray(a_log).astype(_F32))
+    y, s = jax.vmap(lambda *v: _ssd_scan(*v, a, chunk))(xs, bm, cm, delta)
+    y = y + jnp.asarray(d).astype(_F32)[:, None] * xs
+    window = jax.vmap(lambda rows, at: lax.dynamic_slice_in_dim(
+        rows, at + 1, kernel - 1, 0))(up, last)
+    out = _ssm_out(y.reshape(batch, length, heads * p), z, norm_w, groups,
+                   epsilon, x.dtype)
+    return out, _ssm_block(s.transpose(0, 2, 3, 1), window)
+
+
+@register_op('ssm_scan_step', outputs=('Out', 'State'))
+def ssm_scan_step(x, w_in, conv_w, conv_b, dt_bias, a_log, d, norm_w, state,
+                  rows, *, head_dim, groups, epsilon=1e-5):
+    """One token for each of S slots over their states: shift the new xBC
+    into the window, filter, advance S once, read it.
+
+    x (S, 1, h); the weights as `ssm_scan_prefill` takes them; state (rows,
+    P·N + W, H) float32, one row a request and row 0 for idle slots; rows
+    (S,) int32, each slot's row. Returns (S, 1, H·P) in x's dtype and the
+    state with every slot's row advanced.
+
+    A walk over the slots, one row at a time, so that the program holds no
+    copy of S rows (0.55 GB a layer at the published widths): each slot's
+    row is read once (a dynamic slice), advanced, read against C while it
+    is at hand, and written once where it lies (a dynamic update slice of
+    the donated array); idle slots all write the scratch row, whichever of
+    them comes last. A gather of the batch's rows and a scatter back, in
+    groups or whole, makes the compiler rewrite the whole array (seen on
+    the chip: 96% of a step)."""
+    x, state = jnp.asarray(x), jnp.asarray(state)
+    z, raw, dt, sizes = _ssm_in(x[:, 0], w_in, conv_w, dt_bias, head_dim,
+                                groups)
+    heads, p, g, n, conv_dim = sizes
+    slots = x.shape[0]
+    taps = jnp.asarray(conv_w).astype(_F32)
+    kernel = taps.shape[0]
+    bias = jnp.asarray(conv_b).astype(_F32)
+    a = -jnp.exp(jnp.asarray(a_log).astype(_F32))
+    skip = jnp.asarray(d).astype(_F32)[:, None]
+    rows = jnp.asarray(rows, jnp.int32)
+    delta = jax.nn.softplus(dt)                            # (S, H)
+    decay = jnp.exp(delta * a)
+    block = state.shape[1:]
+
+    def advance(i, carry):
+        state, y = carry
+        at = (rows[i],) + (0,) * len(block)
+        # S as it lies, (P, N, H): the heads on the lanes
+        s_old, window = _block_parts(
+            lax.dynamic_slice(state, at, (1,) + block)[0], p, conv_dim,
+            kernel)
+        full = jnp.concatenate([window, raw[i][None]], 0)  # (K, conv_dim)
+        xbc = jax.nn.silu((taps * full).sum(0) + bias)
+        xs, bm, cm = _ssm_split(xbc, sizes)
+        b_h, c_h = (jnp.repeat(v, heads // g, 0).T for v in (bm, cm))
+        s_new = decay[i] * s_old \
+            + (delta[i][:, None] * xs).T[:, None, :] * b_h[None]
+        state = lax.dynamic_update_slice(
+            state, _ssm_block(s_new, full[1:])[None], at)
+        return state, lax.dynamic_update_index_in_dim(
+            y, (s_new * c_h[None]).sum(1).T + skip * xs, i, 0)
+
+    state, y = lax.fori_loop(0, slots, advance,
+                             (state, jnp.zeros((slots, heads, p), _F32)))
+    out = _ssm_out(y.reshape(slots, heads * p), z, norm_w, groups, epsilon,
+                   x.dtype)
+    return out[:, None], state

@@ -26,7 +26,9 @@ What is this repo's own:
   the rows, once a width block;
 - gate and up run in ONE pass over the rows (`expert_ffn`): two weight
   blocks a visit, `silu(g) · u` in float32 in the kernel, a result in the
-  rows' dtype: the two float32 (m, f) intermediates never reach HBM;
+  rows' dtype: the two float32 (m, f) intermediates never reach HBM. A
+  non-gated expert (`nemotron_h`'s relu²) has one block there and
+  `relu(u)²` in the same epilogue;
 - the visits are worked out once a call and shared by both passes.
 
 A visit costs the MXU the same for 1 row as for 128 (it loads each 128 × 128
@@ -96,16 +98,20 @@ def width_block(k, n, itemsize, limit=None):
     return max(fits) if fits else (128 if n % 128 == 0 else n)
 
 
-def _visit(visit_axis, offsets, group_ids, tile_ids, x_ref, *refs):
+def _visit(visit_axis, offsets, group_ids, tile_ids, x_ref, *refs,
+           relu2=False):
     """One visit: the tile times the expert's block(s), float32
-    accumulation; with two blocks `silu(x · w0) · (x · w1)` in float32. The
-    expert's rows are stored, the tile's other rows stay as they are."""
+    accumulation; with two blocks `silu(x · w0) · (x · w1)` in float32, with
+    one and ``relu2`` `relu(x · w0)²`. The expert's rows are stored, the
+    tile's other rows stay as they are."""
     *w_refs, o_ref = refs
     visit = pl.program_id(visit_axis)
     group = group_ids[visit]
     x = x_ref[...]
     y = [jnp.dot(x, w[...], preferred_element_type=_F32) for w in w_refs]
     y = y[0] if len(y) == 1 else jax.nn.silu(y[0]) * y[1]
+    if relu2:
+        y = jnp.square(jnp.maximum(y, 0.0))
     row = tile_ids[visit] * ROW_TILE + lax.broadcasted_iota(
         jnp.int32, o_ref.shape, 0)
     mine = (row >= offsets[group]) & (row < offsets[group + 1])
@@ -113,12 +119,13 @@ def _visit(visit_axis, offsets, group_ids, tile_ids, x_ref, *refs):
 
 
 def grouped_matmul(rows, weights, visits, *, out_dtype, interpret=False,
-                   block_bytes=None):
+                   block_bytes=None, relu2=False):
     """rows (m, k) sorted by expert × each of ``weights`` (E, k, n), over
     ``visits`` = `group_visits(counts, m)`: (m, n) in ``out_dtype``. One
-    weight: the product. Two: `silu(rows · w0) ⊙ (rows · w1)`. A row of no
-    expert (the padding) comes back undefined. ``block_bytes`` (the tests')
-    stands in for `WEIGHT_BLOCK_BYTES`."""
+    weight: the product, or with ``relu2`` `relu(rows · w0)²`. Two:
+    `silu(rows · w0) ⊙ (rows · w1)`. A row of no expert (the padding) comes
+    back undefined. ``block_bytes`` (the tests') stands in for
+    `WEIGHT_BLOCK_BYTES`."""
     (offsets, group_ids, tile_ids), n_visits = visits
     m, k = rows.shape
     n = weights[0].shape[2]
@@ -154,8 +161,10 @@ def grouped_matmul(rows, weights, visits, *, out_dtype, interpret=False,
     out_item = jnp.dtype(out_dtype).itemsize
     resident = (tm * k * rows.dtype.itemsize + tm * tn * out_item
                 + sum(k * tn * w.dtype.itemsize for w in weights))
+    kernel = functools.partial(_visit, visit_axis, relu2=True) if relu2 \
+        else functools.partial(_visit, visit_axis)
     return pl.pallas_call(
-        functools.partial(_visit, visit_axis),
+        kernel,
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
@@ -182,17 +191,20 @@ def grouped_matmul(rows, weights, visits, *, out_dtype, interpret=False,
 def expert_ffn(x, source, counts, w_gate, w_up, w_down, *, interpret=False,
                block_bytes=None):
     """w_down,e(silu(x_s · w_gate,e) ⊙ (x_s · w_up,e)) for the assignments
-    sorted by expert: ``source`` (m,) the row of x (T, h) behind each,
-    ``counts`` (E,) how many each expert owns. Returns (≥ m, h) float32:
-    the m results in the order of ``source``, then the padding to whole
-    tiles, undefined. bf16 or float32 operands as stored, float32
+    sorted by expert, or w_down,e(relu(x_s · w_up,e)²) where ``w_gate`` is
+    None (a non-gated expert): ``source`` (m,) the row of x (T, h) behind
+    each, ``counts`` (E,) how many each expert owns. Returns (≥ m, h)
+    float32: the m results in the order of ``source``, then the padding to
+    whole tiles, undefined. bf16 or float32 operands as stored, float32
     accumulation, the hidden (m, f) in x's dtype."""
     m = source.shape[0]
     padded = -(-m // ROW_TILE) * ROW_TILE
     rows = x[jnp.pad(source, (0, padded - m))]
     visits = group_visits(counts, padded)
-    hidden = grouped_matmul(rows, (w_gate, w_up), visits, out_dtype=x.dtype,
-                            interpret=interpret, block_bytes=block_bytes)
+    hidden = grouped_matmul(
+        rows, (w_up,) if w_gate is None else (w_gate, w_up), visits,
+        out_dtype=x.dtype, interpret=interpret, block_bytes=block_bytes,
+        relu2=w_gate is None)
     return grouped_matmul(hidden, (w_down,), visits, out_dtype=_F32,
                           interpret=interpret, block_bytes=block_bytes)
 

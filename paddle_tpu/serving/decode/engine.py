@@ -720,7 +720,7 @@ class DecodeEngine:
         if folded:
             _m.decode_state_tokens_folded.inc(folded)
             clock.work['state_tokens_folded'] = folded
-        self._account_conv(clock, P, rung=bucket)
+        self._account_state_rows(clock, P, rung=bucket)
         if self.layout.classes:
             # positions the prompt leaves in each class's blocks
             clock.work.update(self._class_positions([P])[1])
@@ -857,7 +857,7 @@ class DecodeEngine:
         if updates:
             _m.decode_state_updates.inc(updates)
             clock.work['state_updates'] = updates
-        self._account_conv(clock, active)
+        self._account_state_rows(clock, active)
         clock.record()
         _m.decode_slots_active.set(active)
         _m.decode_slot_occupancy.observe(active / max(self.slots, 1))
@@ -865,15 +865,20 @@ class DecodeEngine:
         _dobs.series('occupancy').observe(active / max(self.slots, 1))
         _dobs.series('decode_step').observe(dt)
 
-    def _account_conv(self, clock, rows, **args):
-        """A call's live rows through the gated short convolutions, ``rows``
-        x conv layers (a prefill's prompt, never its rung; a step's live
-        slots): the counter, and the span's ``conv_rows`` with ``args``
-        beside it. Nothing for a model without such a layer."""
+    def _account_state_rows(self, clock, rows, **args):
+        """A call's live rows through the gated short convolutions and the
+        Mamba-2 mixers, ``rows`` x layers of each (a prefill's prompt, never
+        its rung; a step's live slots): the counters, and the span's
+        ``conv_rows`` / ``ssm_rows`` with ``args`` beside them. Nothing for
+        a model without such a layer."""
         if self.layout.conv_layers:
             conv = rows * self.layout.conv_layers
             _m.decode_conv_rows.inc(conv)
             clock.work.update(args, conv_rows=conv)
+        if self.layout.ssm_layers:
+            ssm = rows * self.layout.ssm_layers
+            _m.decode_ssm_rows.inc(ssm)
+            clock.work.update(args, ssm_rows=ssm)
 
     def _class_positions(self, contexts):
         """(positions the layers hold of ``contexts``, the call's span args

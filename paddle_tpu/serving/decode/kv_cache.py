@@ -996,7 +996,8 @@ class CacheContext:
                 'live': self.live_groups() if rows.shape[1] == 1 else None},
                 attrs)
 
-    def attend_state(self, ops, inputs, attrs, block_shape, scopes=None):
+    def attend_state(self, ops, inputs, attrs, block_shape, scopes=None,
+                     step_attrs=None):
         """A STATE layer through its row, whatever the state is: ``ops`` =
         (prefill op, step op) are handed in (ops/llm_ops.py), each returning
         (out, state). The prefill op takes ``inputs`` and ``last`` under
@@ -1005,10 +1006,11 @@ class CacheContext:
         and never enter it), which is written over the request's whole
         row: a row taken again carries nothing of its last request. The
         step op takes ``inputs``, the layer's array ``state`` (state rows,
-        *block_shape) and each slot's ``rows`` (one fed token a slot), and
-        returns the array with every slot's row advanced, idle slots on the
-        scratch row. ``scopes`` (prefill, step) name the two ops' device
-        ops in a profiler trace; None where the caller's own scope does."""
+        *block_shape) and each slot's ``rows`` (one fed token a slot) under
+        ``step_attrs`` (None: none), and returns the array with every
+        slot's row advanced, idle slots on the scratch row. ``scopes``
+        (prefill, step) name the two ops' device ops in a profiler trace;
+        None where the caller's own scope does."""
         from ...dygraph.tape import dispatch_op
         layer = self._layer
         self._layer += 1
@@ -1029,7 +1031,8 @@ class CacheContext:
         states = self.pool.ensure_state(layer, block_shape)
         with scope:
             out, state = dispatch_op(
-                ops[1], dict(inputs, state=states[0], rows=rows), {})
+                ops[1], dict(inputs, state=states[0], rows=rows),
+                step_attrs or {})
         states[0] = state.value
         return out
 

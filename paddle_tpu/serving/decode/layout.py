@@ -122,7 +122,8 @@ class LayerCache:
     shape: tuple            # (heads, head_dim) | (width,) | the state block
     span: int = 0           # a sliding layer's span; 0 a full layer
     read: str = ''          # 'blocks' | 'groups' | 'ring' | 'window'
-    op: str = ''            # what advances a state: 'retention' | 'short_conv'
+    op: str = ''            # what advances a state: 'retention' |
+    #                         'short_conv' | 'ssm'
 
     @classmethod
     def kv(cls, heads, head_dim, read='blocks', span=0):
@@ -165,6 +166,7 @@ class CacheLayout:
     row_layers: int = _derived()        # a row a token: K/V or latent
     state_layers: int = _derived()      # one state a request
     conv_layers: int = _derived()       # state layers a short conv advances
+    ssm_layers: int = _derived()        # state layers a Mamba-2 scan advances
     kind: str = _derived()              # the row layers', or 'state'
     span: int = _derived()              # the sliding class's; 0 none
     sliding_layers: int = _derived()
@@ -189,6 +191,7 @@ class CacheLayout:
             row_layers=len(rows), state_layers=len(self.layers) - len(rows),
             conv_layers=sum(layer.op == 'short_conv'
                             for layer in self.layers),
+            ssm_layers=sum(layer.op == 'ssm' for layer in self.layers),
             kind=rows[0].kind if rows else 'state',
             span=spans[0] if spans else 0,
             sliding_layers=sum(bool(layer.span) for layer in rows),

@@ -309,6 +309,11 @@ decode_conv_rows = _LazyMetric(
     'live rows through the gated short convolutions: prompt length x conv '
     'layers a prefill (a rung\'s padding not counted), live slots x conv '
     'layers a decode step')
+decode_ssm_rows = _LazyMetric(
+    'counter', 'decode_ssm_rows_total',
+    'live rows through the Mamba-2 state-space mixers: prompt length x SSM '
+    'layers a prefill (a rung\'s padding not counted), live slots x SSM '
+    'layers a decode step')
 # block diffusion (a window model: serving/decode/engine.py::window_step)
 decode_diffusion_denoise_forwards = _LazyMetric(
     'counter', 'decode_diffusion_denoise_forwards',

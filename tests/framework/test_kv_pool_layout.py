@@ -690,3 +690,88 @@ def test_compiled_for_the_chip_a_grouped_read_is_one_kernel_under_its_scope(
     names = kernel_op_names(text)
     assert len(names) == 1 and f'/{scope}/' in names[0], names
     assert not [line for line in text.splitlines() if ' while(' in line]
+
+
+def test_compiled_for_the_chip_a_hybrid_of_ssm_expert_and_row_layers(
+        v5e, monkeypatch):
+    """A `nemotron_h` model (Mamba-2 layers, latent relu² experts, grouped
+    attention) at the published Mamba head size and state width (heads of
+    64, 128 state values, a filter of 4 taps, chunk 128) and a latent expert
+    of 128 x 256: its step and a prefill rung compiled for the chip. Mosaic
+    takes the expert kernel's relu² epilogue (two custom calls an expert
+    layer, under `moe/experts`); the step walks the slots a row at a time
+    and holds no array of the state's size (its temporaries are below one
+    state array; a gather and scatter of the batch's rows held 4.13 GB at
+    the cell's size, several arrays' worth); no program moves the pool; the
+    scopes the benchmark sums device time by are there (compiled here: no
+    chip, no time). Shapes alone."""
+    from paddle_tpu.core.random import default_generator
+    from paddle_tpu.models.hybrid_ssm_moe_lm import (HybridSSMMoEConfig,
+                                                     HybridSSMMoELM)
+    from paddle_tpu.ops import llm_ops, nn_ops, pallas_group_read
+    from paddle_tpu.ops.pallas_moe import kernel_op_names
+    from paddle_tpu.serving.decode.kv_cache import (BlockTable,
+                                                    prefill_coords)
+    for module in (llm_ops, nn_ops, pallas_group_read):
+        monkeypatch.setattr(module, 'on_tpu', lambda: True)
+    made = {}
+
+    def init(key):
+        with default_generator.bind_base(key):
+            made['model'] = HybridSSMMoELM(HybridSSMMoEConfig.tiny(
+                vocab_size=512, hidden_size=256, head_dim=128,
+                num_attention_heads=2, num_key_value_heads=1,
+                mamba_num_heads=8, mamba_head_dim=64, n_groups=2,
+                ssm_state_size=128, chunk_size=128, moe_latent_size=128,
+                moe_intermediate_size=256,
+                moe_shared_expert_intermediate_size=512,
+                max_position_embeddings=1024, dtype='bfloat16'))
+        return {n: p.value for n, p in made['model'].named_parameters()}
+
+    with dygraph.guard():
+        default_generator.seed(3)
+        shapes = jax.eval_shape(init, default_generator.base_key())
+        model = made['model']
+        model.eval()
+        for name, p in model.named_parameters():
+            p.value = shapes[name]
+        eng = DecodeEngine(model, slots=32, block_size=16, max_blocks=2056,
+                           max_prompt_len=512, max_new_tokens_cap=512,
+                           prompt_buckets=[512], prefix_cache=False,
+                           kv_dtype='bf16')
+        pool, prog = eng.pool, eng._program
+        out = jax.eval_shape(
+            lambda pv, *rest: prog.jitted('prefill', pool.geometry, pv, {},
+                                          {}, {}, *rest),
+            {n: p.value for n, p in prog._params.items()},
+            np.zeros((1, 512), np.int64), None,
+            prefill_coords(pool, BlockTable([], 16), 512), np.int32(0))
+        pool.adopt({k: list(v) for k, v in out[3].items()}, {})
+        layers, _ = pool.arrays()
+        # 8 heads of 64 x 128 state values and 3 x 1,024 window values, the
+        # heads on the lanes: (8,576, 8) float32 a row
+        state = ((33, 64 * 128 + 3 * 1024 // 8, 8), 'float32')
+        assert [[(a.shape, str(a.dtype)) for a in layers[i]]
+                for i in range(3)] == [
+            [state], [state], [((2056, 16, 128), 'bfloat16')] * 2]
+        state_bytes = int(np.prod(state[0])) * 4
+        for bucket in (None, 512):
+            compiled = eng.lowered(bucket, v5e).compile()
+            text = compiled.as_text()
+            assert 'ragged-dot' not in text and 'ragged_dot' not in text
+            kernels = [name for name in kernel_op_names(text) if name]
+            experts = [name for name in kernels if '/moe/experts/' in name]
+            assert len(experts) == 2 * 2, kernels
+            # (a pool of 8 MB the compiler may prefetch whole at this size:
+            # a copy-start that is no relayout)
+            assert [line for line in eng.pool_moves(bucket, v5e)
+                    if 'copy-start' not in line] == []
+            scopes = ('ssm/step', 'kv/decode_read') if bucket is None \
+                else ('ssm/prefill', 'attn/full_prefill')
+            assert all(f'/{s}/' in text
+                       for s in scopes + ('moe/experts', 'moe/latent'))
+            other = 'ssm/prefill' if bucket is None else 'ssm/step'
+            assert f'/{other}/' not in text
+            if bucket is None:
+                temp = compiled.memory_analysis().temp_size_in_bytes
+                assert temp < state_bytes, (temp, state_bytes)

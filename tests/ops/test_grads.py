@@ -337,6 +337,25 @@ GRAD_SPECS = {
                    f32(r.standard_normal((1, 5, 1, 4))),
                    -pos(r, (1, 5, 1), 0.05, 0.5)],
         diff=(0, 1, 2, 3), attrs={'chunk': 2}),
+    # the non-gated feed-forward of `nemotron_h`'s shared expert
+    'relu2_ffn': S(lambda r: [f32(r.standard_normal((3, 4))),
+                              f32(r.standard_normal((4, 6)) * 0.5),
+                              f32(r.standard_normal((6, 4)) * 0.5)],
+                   diff=(0, 1, 2)),
+    # autodiff through the Mamba-2 mixer's chunked scan (two chunks and a
+    # padded third): rows and every weight; no backward kernel of its own
+    # (ROADMAP R5)
+    'ssm_scan_prefill': S(
+        lambda r: [f32(r.standard_normal((1, 5, 6))),
+                   f32(r.standard_normal((6, 14)) * 0.4),
+                   f32(r.standard_normal((3, 8)) * 0.5),
+                   f32(r.standard_normal((8,)) * 0.1),
+                   f32(r.standard_normal((2,)) * 0.3 - 1.0),
+                   f32(np.log(r.uniform(1, 4, (2,)))),
+                   f32(r.standard_normal((2,))),
+                   pos(r, (4,), 0.5, 1.5)],
+        diff=(0, 1, 2, 3, 4, 5, 6, 7),
+        attrs={'head_dim': 2, 'groups': 1, 'chunk': 2}),
     # the differentiable form of the gated short convolution: rows and taps
     'short_conv_prefill': S(
         lambda r: [f32(r.standard_normal((2, 5, 12))),
@@ -704,6 +723,10 @@ NONDIFF = {
         '(serving/decode/); training gradients flow through '
         'power_retention_prefill, parity tested in tests/ops/'
         'test_power_retention.py',
+    'ssm_scan_step':
+        'inference-only step of the Mamba-2 state cache (serving/decode/); '
+        'training gradients flow through ssm_scan_prefill, parity tested in '
+        'tests/ops/test_ssm_scan.py',
     'short_conv_step':
         'inference-only shift-and-filter of the conv state cache '
         '(serving/decode/); training gradients flow through '
